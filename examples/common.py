@@ -20,9 +20,12 @@ import kfac_tpu
 def distributed_init() -> None:
     """Join the multi-host world before first backend use (no-op on a
     single host). Trainers call this first so ``jax.devices()`` sees the
-    global world under ``scripts/run_pod.sh`` / TPU pod launches."""
+    global world under ``scripts/run_pod.sh`` / TPU pod launches, and so
+    the persistent compile cache is placed before the first compile."""
     from kfac_tpu.parallel import multihost
+    from kfac_tpu.utils import compile_cache
 
+    compile_cache.configure()
     multihost.initialize()
 
 
@@ -58,6 +61,12 @@ def add_kfac_args(parser: argparse.ArgumentParser) -> None:
         'elsewhere). Pin an explicit value when a stacked checkpoint '
         'must restore on a different platform; see '
         'KFACPreconditioner.bucket_granularity',
+    )
+    g.add_argument(
+        '--kfac-compile-watch', action='store_true',
+        help='dispatch the jitted steps through CompileWatch: lowering and '
+        'compile seconds, recompile counts and XLA memory per entry '
+        '(docs/OBSERVABILITY.md "Compile & memory truth")',
     )
     g.add_argument(
         '--kfac-verbose', action='store_true',
@@ -225,6 +234,7 @@ def build_kfac(args, registry, mesh=None, lr=None, verbose_dump=True):
             else args.kfac_compute_method
         ),
         bucket_granularity=args.kfac_bucket_granularity,
+        compile_watch=args.kfac_compile_watch or None,
     )
     if mesh is not None:
         from kfac_tpu.parallel import DistributedKFAC
@@ -240,32 +250,45 @@ def build_kfac(args, registry, mesh=None, lr=None, verbose_dump=True):
     return cfg
 
 
-def log_inverse_residuals(args, kfac_engine, kfac_state) -> None:
-    """Under ``--kfac-verbose``, print the worst per-slot damped-inverse
-    residual of a DistributedKFAC INVERSE engine (out-of-band
-    Newton-Schulz quality monitoring — the stacked vmapped solve cannot
-    surface convergence info in-band). No-op for other engines/methods."""
-    if not getattr(args, 'kfac_verbose', False):
-        return
+def inverse_residuals(kfac_engine, kfac_state) -> dict[str, float] | None:
+    """Worst damped-inverse residual per storage bucket (``'a/<key>'``,
+    ``'g/<key>'``) of a DistributedKFAC INVERSE engine — out-of-band
+    Newton-Schulz quality monitoring (the stacked vmapped solve cannot
+    surface convergence info in-band), to compare against
+    ``kfac_tpu.ops.factors.NS_FALLBACK_RESIDUAL``. None for engines or
+    methods the query does not apply to."""
     if kfac_engine is None or not hasattr(kfac_engine, 'inverse_residuals'):
-        return
-    import jax
-    import jax.numpy as jnp
+        return None
 
-    # the reduction runs under jit to ONE replicated scalar: the state
+    # the reduction runs under jit to replicated scalars: the state
     # arrays are sharded (non-addressable on multi-host pods), so eager
     # ops / np.asarray on them would fail exactly where this monitoring
     # matters most. jnp.max propagates NaN — a diverged solve reports NaN.
     def _worst(state):
         res = kfac_engine.inverse_residuals(state)
-        return jnp.max(jnp.stack([
-            jnp.max(r) for side in res.values() for r in side.values()
-        ]))
+        return {
+            f'{side}/{key}': jnp.max(r)
+            for side, buckets in res.items() for key, r in buckets.items()
+        }
 
     try:
-        worst = float(jax.jit(_worst)(kfac_state))
+        worst = jax.jit(_worst)(kfac_state)
     except ValueError:  # EIGEN method: the query is meaningless
+        return None
+    return {k: float(v) for k, v in worst.items()}
+
+
+def log_inverse_residuals(args, kfac_engine, kfac_state) -> None:
+    """Under ``--kfac-verbose``, print the worst of
+    :func:`inverse_residuals`."""
+    if not getattr(args, 'kfac_verbose', False):
         return
+    residuals = inverse_residuals(kfac_engine, kfac_state)
+    if residuals is None:
+        return
+    import numpy as np
+
+    worst = float(np.max(list(residuals.values())))  # NaN propagates
     from kfac_tpu.ops.factors import NS_FALLBACK_RESIDUAL
 
     # NaN must flag as bad (all NaN comparisons are False, so test the
@@ -297,14 +320,13 @@ def make_epoch_batches(
     if getattr(args, 'native_loader', False):
         from kfac_tpu.utils import native_loader
 
-        try:
-            prefetcher = native_loader.PrefetchLoader(
-                x_train, y_train, batch_size=args.batch_size, seed=args.seed,
-                augment={'pad': 4, 'flip': True} if augment else None,
-                start_epoch=start_epoch,
-            )
-        except native_loader.NativeLoaderUnavailable as e:
-            print(f'native loader unavailable ({e}); using python batches')
+        # an explicit --native-loader that cannot load is an error
+        # (NativeLoaderUnavailable), never a quiet switch to python batches
+        prefetcher = native_loader.PrefetchLoader(
+            x_train, y_train, batch_size=args.batch_size, seed=args.seed,
+            augment={'pad': 4, 'flip': True} if augment else None,
+            start_epoch=start_epoch,
+        )
 
     def epoch_batches(epoch):
         import numpy as np
@@ -337,6 +359,22 @@ class Timer:
 
     def elapsed(self) -> float:
         return time.perf_counter() - self.start
+
+
+def timed_step(trainer, state, batch, on_step=None):
+    """One ``trainer.step`` timed to completion: returns ``(state,
+    loss)`` with the loss already on the host. ``on_step(trainer, state,
+    loss, seconds)``, when given, sees every step — ``chip_smoke.py``
+    records its per-step evidence through it. With ``donate_state`` the
+    state it receives is consumed by the next step, so a callback keeps
+    only what it reads from it, or the last one."""
+    timer = Timer()
+    state, loss = trainer.step(state, batch)
+    jax.block_until_ready(state)
+    loss = float(loss)
+    if on_step is not None:
+        on_step(trainer, state, loss, timer.elapsed())
+    return state, loss
 
 
 def _extra_payload(state, epoch: int):

@@ -24,7 +24,8 @@ from kfac_tpu.models import resnet
 from kfac_tpu.parallel import batch_sharding, kaisa_mesh
 
 
-def main(argv=None) -> float:
+def main(argv=None, on_step=None) -> float:
+    """``on_step``: see :func:`examples.common.timed_step`."""
     p = argparse.ArgumentParser(description='ImageNet ResNet-50 + K-FAC')
     p.add_argument('--image-size', type=int, default=224)
     p.add_argument(
@@ -54,9 +55,12 @@ def main(argv=None) -> float:
     bs = batch_sharding(mesh)
 
     real_data = data.imagenet_on_disk(args.data_dir)
+    # synthetic data (no --data-dir): enough images for --limit-steps
+    # batches whatever the device count made of the global batch
     (x_train, y_train), (x_test, y_test) = data.imagenet_like(
         args.data_dir, image_size=args.image_size,
-        n_train=max(args.batch_size * 8, 1024), n_test=args.batch_size * 2,
+        n_train=max(args.batch_size * max(8, args.limit_steps or 0), 1024),
+        n_test=args.batch_size * 2,
     )
     augment = real_data if args.augment is None else args.augment
     model = getattr(resnet, args.arch)(
@@ -114,6 +118,13 @@ def main(argv=None) -> float:
         ),
     )
 
+    # jitted: un-jitted, a ResNet-50 forward compiles op by op on a TPU
+    @jax.jit
+    def predict(params, batch_stats, xb):
+        return model.apply(
+            {'params': params, 'batch_stats': batch_stats}, xb, train=False
+        )
+
     acc_val = 0.0
     writer = common.MetricsWriter(args.metrics_csv)
     for epoch in range(start_epoch, args.epochs):
@@ -127,7 +138,7 @@ def main(argv=None) -> float:
                 jax.device_put(jnp.asarray(xb), bs),
                 jax.device_put(jnp.asarray(yb), bs),
             )
-            state, loss = trainer.step(state, batch)
+            state, loss = common.timed_step(trainer, state, batch, on_step)
             train_loss.update(loss, len(xb))
             n_steps += 1
         train_secs = epoch_timer.elapsed()
@@ -139,9 +150,8 @@ def main(argv=None) -> float:
                 break
             if real_data:
                 xb = data.normalize(xb, data.IMAGENET_MEAN, data.IMAGENET_STD)
-            logits = model.apply(
-                {'params': state.params, 'batch_stats': state.model_state},
-                jnp.asarray(xb), train=False,
+            logits = predict(
+                state.params, state.model_state, jnp.asarray(xb)
             )
             acc.update(common.accuracy(logits, jnp.asarray(yb)), len(xb))
         acc_val = acc.avg

@@ -71,7 +71,9 @@ def _steps_per_epoch(args, tokens_np) -> int:
     return steps
 
 
-def main(argv=None) -> float:
+def main(argv=None, on_step=None) -> float:
+    """``on_step``: see :func:`examples.common.timed_step` (not called on
+    the ``--pipeline-stages`` path, which has its own step)."""
     p = argparse.ArgumentParser(description='Transformer LM + K-FAC')
     p.add_argument('--d-model', type=int, default=256)
     p.add_argument('--num-heads', type=int, default=8)
@@ -170,7 +172,7 @@ def main(argv=None) -> float:
             jax.device_put(jnp.asarray(xb), ts),
             jax.device_put(jnp.asarray(yb), ts),
         )
-        state, l = trainer.step(state, batch)
+        state, l = common.timed_step(trainer, state, batch, on_step)
         return l
 
     def on_epoch_end(epoch):

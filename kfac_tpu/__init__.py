@@ -8,7 +8,6 @@ checkpointing — built on pjit/shard_map collectives instead of
 torch.distributed.
 """
 
-from kfac_tpu import compat  # noqa: F401  (installs JAX API shims first)
 from kfac_tpu import checkpoint, enums, health, hyperparams, tracing, warnings
 from kfac_tpu import autotune
 from kfac_tpu import observability

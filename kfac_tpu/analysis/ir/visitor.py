@@ -361,8 +361,9 @@ def while_dot_flops(jaxpr, iters: int) -> float:
 def pallas_call_summaries(jaxpr) -> list[dict[str, Any]]:
     """One summary dict per ``pallas_call`` eqn in the program.
 
-    ``name`` is the kernel function's name (``name_and_src_info`` —
-    stable under ``functools.partial`` binding of trace-time constants),
+    ``name`` is the ``pallas_call``'s ``name=`` (the kernels in
+    ``kfac_tpu/ops`` pass their function's name), else the kernel
+    function's name from the body's debug info,
     ``grid`` the launch grid, and ``dot_flops_per_tile`` the summed
     ``dot_general`` FLOPs of ONE kernel-body invocation. The caller owns
     the grid arithmetic: total MXU FLOPs = Σ over executing grid points
@@ -374,18 +375,15 @@ def pallas_call_summaries(jaxpr) -> list[dict[str, Any]]:
     for eqn, _ in iter_eqns(jaxpr):
         if eqn.primitive.name != 'pallas_call':
             continue
-        info = eqn.params.get('name_and_src_info')
         grid_mapping = eqn.params.get('grid_mapping')
-        inner = eqn.params.get('jaxpr')
-        dot = 0.0
-        if inner is not None:
-            dot = sum(
-                _dot_flops(sub)
-                for sub, _ in iter_eqns(inner)
-                if sub.primitive.name == 'dot_general'
-            )
+        inner = eqn.params['jaxpr']
+        dot = sum(
+            _dot_flops(sub)
+            for sub, _ in iter_eqns(inner)
+            if sub.primitive.name == 'dot_general'
+        )
         out.append({
-            'name': getattr(info, 'name', None),
+            'name': eqn.params.get('name') or inner.debug_info.func_name,
             'grid': tuple(getattr(grid_mapping, 'grid', ()) or ()),
             'dot_flops_per_tile': dot,
         })

@@ -540,7 +540,8 @@ def _raw_host_restore(path: str) -> dict[str, Any]:
     from orbax.checkpoint import checkpoint_utils
 
     reader = ocp.Checkpointer(ocp.PyTreeCheckpointHandler())
-    meta = reader.metadata(path)
+    # orbax wraps the saved tree's metadata in StepMetadata
+    meta = reader.metadata(path).item_metadata.tree
     meta = jax.tree_util.tree_map(
         lambda m: (
             dataclasses.replace(m, sharding=None)

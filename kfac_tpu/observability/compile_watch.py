@@ -89,8 +89,8 @@ class CompileWatchConfig:
             default) disables journaling — events are still recorded
             in memory. When ``None`` and the ``KFAC_COMPILE_JOURNAL``
             environment variable is set, that path is used instead, so
-            chip-session scripts (scripts/tpu_session2b.sh) can arm
-            journaling fleet-wide without touching configs.
+            a launcher can arm journaling fleet-wide without touching
+            configs.
         include_sharding: record each array leaf's sharding repr in the
             fingerprint, so a resharding-forced recompile names its
             cause in the event diff. Shardings never key the dispatch
@@ -339,12 +339,9 @@ class PersistentCacheCounters:
 
     @staticmethod
     def _cache_dir() -> str | None:
-        try:
-            import jax
+        from kfac_tpu.utils import compile_cache
 
-            return jax.config.jax_compilation_cache_dir
-        except Exception:
-            return None
+        return compile_cache.current_dir()
 
 
 _GLOBAL_COUNTERS: PersistentCacheCounters | None = None
@@ -383,6 +380,7 @@ class CompileWatch:
         self.run_id: str | None = None
         self._counts: dict[str, int] = {}
         self._last_fp: dict[str, dict[str, Any]] = {}
+        self._wrapped: dict[str, WatchedFunction] = {}
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------- wrapping
@@ -396,7 +394,9 @@ class CompileWatch:
         """Wrap a jitted callable as a watched entry point. ``fn`` must
         support ``.lower()`` (i.e. be a ``jax.jit`` product); declared
         ``static_argnames`` must match the jit's own."""
-        return WatchedFunction(self, entry, fn, tuple(static_argnames))
+        watched = WatchedFunction(self, entry, fn, tuple(static_argnames))
+        self._wrapped[entry] = watched
+        return watched
 
     # ------------------------------------------------------------- counters
 
@@ -419,6 +419,16 @@ class CompileWatch:
 
     def events_for(self, entry: str) -> list[dict[str, Any]]:
         return [e for e in self.events if e['entry'] == entry]
+
+    def executables(self) -> dict[str, list[Any]]:
+        """The compiled executables each wrapped entry currently
+        dispatches to (``jax.stages.Compiled``; ``.as_text()`` is the
+        optimized HLO — where ``chip_smoke.py`` looks for the Pallas
+        kernels that really are in the step programs)."""
+        return {
+            entry: watched.executables()
+            for entry, watched in self._wrapped.items()
+        }
 
     def memory_report(self) -> dict[str, dict[str, Any]]:
         """Latest XLA memory snapshot per entry: ``{entry: {'memory':
@@ -488,6 +498,10 @@ class WatchedFunction:
         """Distinct fingerprints compiled so far for this wrapper."""
         return len(self._cache)
 
+    def executables(self) -> list[Any]:
+        """The AOT-compiled executables this wrapper dispatches to."""
+        return [e for e in self._cache.values() if e is not _FALLBACK]
+
     @property
     def watch(self) -> 'CompileWatch':
         """The :class:`CompileWatch` this wrapper reports into."""
@@ -541,13 +555,14 @@ class WatchedFunction:
             fsync=True)
         perf0 = time.perf_counter()
         aot = True
+        aot_error = None  # why AOT gave way: never dropped on the floor
         executable = None
         lowering_s = 0.0
         try:
             lowered = self._fn.lower(*args, **kwargs)
             lowering_s = time.perf_counter() - perf0
-        except Exception:
-            aot = False
+        except Exception as exc:
+            aot, aot_error = False, f'lower: {type(exc).__name__}: {exc}'
         watch._journal(
             {'phase': 'compiling', 'entry': self.entry, 'n': ordinal,
              't': time.time(), 'lowering_s': lowering_s, 'aot': aot},
@@ -560,8 +575,8 @@ class WatchedFunction:
         if aot:
             try:
                 executable = lowered.compile()
-            except Exception:
-                aot = False
+            except Exception as exc:
+                aot, aot_error = False, f'compile: {type(exc).__name__}: {exc}'
         if not aot:
             # plain dispatch still compiles under the hood on first call;
             # time that as the compile cost and pin this fingerprint to
@@ -580,6 +595,7 @@ class WatchedFunction:
             'fingerprint_key': key,
             'diff': diff,
             'aot': aot,
+            'aot_error': aot_error,
             'memory': memory,
         }
         watch._record_event(event)

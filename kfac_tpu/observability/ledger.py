@@ -304,8 +304,8 @@ def parse_trace(source: Any) -> list[dict[str, Any]]:
 
 
 def parse_bench(source: Any) -> list[dict[str, Any]]:
-    """A bench round: committed ``BENCH_r0N.json`` (``{'parsed': ...}``)
-    or a flat ``bench_runs/run_*.json`` record."""
+    """A bench round: a driver round file (``{'parsed': ...}``) or a
+    flat ``bench_runs/run_*.json`` record."""
     if isinstance(source, (str, os.PathLike)):
         with open(source, encoding='utf-8') as f:
             data = json.load(f)

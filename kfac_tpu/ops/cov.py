@@ -38,10 +38,10 @@ def get_cov(
 
     On TPU, f32 self-covariances with factor dims spanning ≥ 2 MXU tiles
     dispatch to the triangular Pallas kernel (exactly symmetric by
-    construction, half the MXU FLOPs; measured 5x over the dense
-    contraction on-chip — bf16 inputs stay on XLA, which is faster
-    there): via its GSPMD partitioning rule under jit, or directly on
-    the local rows inside ``shard_map``.
+    construction, half the MXU FLOPs; bf16 inputs stay on XLA) where a
+    raw Mosaic call can run: in a one-device process, or on the local
+    rows inside a fully-manual ``shard_map``
+    (:func:`kfac_tpu.ops.pallas_cov.use_pallas_for`).
     """
     if a.ndim != 2:
         raise ValueError(f'expected 2D tensor, got shape {a.shape}')
@@ -53,30 +53,12 @@ def get_cov(
         from kfac_tpu.ops import pallas_cov
 
         if pallas_cov.use_pallas_for(a.shape[1], a.dtype):
-            # Context decides which kernel form can trace here
-            # (pallas_gate.manual_context — axis types are the reliable
-            # signal, probed on this install):
-            # - fully-manual shard_map: raw local kernel (rows are
-            #   device-local; custom_partitioning cannot trace inside a
-            #   manual region)
-            # - no manual axes: the custom_partitioning spmd wrapper
-            #   (GSPMD applies the local-kernel + psum rule — this also
-            #   covers mesh-less sharded inputs)
-            # - PARTIAL manual (e.g. the pipeline: manual pipe+data, TP
-            #   automatic): NEITHER traces — a raw Mosaic call would need
-            #   auto-partitioning over the automatic axes, which Mosaic
-            #   rejects (measured on-chip) — so fall through to XLA.
-            from kfac_tpu.ops import pallas_gate
-
-            _has_mesh, manual_any, manual_all = pallas_gate.manual_context()
-            if manual_all:  # shard_map body: rows are already device-local
-                c = pallas_cov.sym_cov(
-                    a, scale=1.0, interpret=pallas_cov.interpret_mode()
-                )
-                return c / scale
-            if not manual_any:
-                return pallas_cov.sym_cov_spmd(a) / scale
-            # partial-manual region: XLA contraction below
+            # the gate has checked the trace context: one device, or a
+            # fully-manual shard_map where the rows are device-local
+            c = pallas_cov.sym_cov(
+                a, scale=1.0, interpret=pallas_cov.interpret_mode()
+            )
+            return c / scale
         cov = a.T @ (a / scale)
         return (cov + cov.T) / 2.0
     return a.T @ (b / scale)

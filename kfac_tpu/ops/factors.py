@@ -63,8 +63,8 @@ def batched_eigh(
     sequential panel algorithm that leaves the MXU idle and compiles
     pathologically slowly at LM factor sizes (measured on v5e: tens of
     seconds of compile per distinct shape; the batched vmap form never
-    finished compiling in 20 min — docs/ROADMAP.md), which is why the
-    repo's TPU default is INVERSE+Newton-Schulz.
+    finished compiling in 20 min), which is why the repo's TPU default
+    is INVERSE+Newton-Schulz.
 
     ``impl='host'``: ``jax.pure_callback`` to LAPACK (``numpy.linalg.eigh``,
     syevd) on the host CPU. Factors are small (d^2 fp32: 4 MB at d=1024),
@@ -202,6 +202,36 @@ def gershgorin_condition_bound(
     return jnp.minimum(lam_max / jnp.maximum(d, fi.tiny), fi.max)
 
 
+# Newton-Schulz and its residual monitor are f32 algorithms: the stopping
+# rule, the 1e-6 tolerance and NS_FALLBACK_RESIDUAL all assume f32
+# products. A TPU's DEFAULT matmul precision rounds f32 operands to bf16
+# (one MXU pass, ~2^-9 per product), and the iteration's attainable
+# residual is ~kappa times that: measured on a v5e (PR 21) on factors
+# with kappa ~300, the default stalled at 1-2e-2 where HIGHEST reaches
+# 1e-6 in as many iterations. So every product of the solve — here, in
+# the fused Pallas pair and in the engines' residual monitor — names
+# this precision.
+NS_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def newton_schulz_step(
+    m: jax.Array, x: jax.Array, mx: jax.Array
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One unfused Newton-Schulz iteration on ``m = factor + damping*I``
+    with ``mx`` the cached ``m @ x``: ``(x_new, mx_new, resid)`` where
+    ``x_new = x (2I - mx)``, ``mx_new = m x_new`` and ``resid =
+    ||I - mx_new||_F / sqrt(d)``. The XLA expression the fused Pallas
+    pair (:func:`kfac_tpu.ops.pallas_ns.fused_ns_step`) replaces."""
+    d = m.shape[-1]
+    eye = jnp.eye(d, dtype=jnp.float32)
+    x_new = jnp.matmul(x, 2.0 * eye - mx, precision=NS_PRECISION)
+    mx_new = jnp.matmul(m, x_new, precision=NS_PRECISION)
+    resid = jnp.linalg.norm(eye - mx_new) / jnp.sqrt(
+        jnp.asarray(d, jnp.float32)
+    )
+    return x_new, mx_new, resid
+
+
 class NewtonSchulzInfo(NamedTuple):
     """Result of the residual-monitored Newton-Schulz inversion.
 
@@ -232,13 +262,17 @@ def newton_schulz_inverse_info(
     PREVIOUS inverse at each ``inv_update_steps`` refresh: the factor EMA
     moves slowly, so the old inverse sits deep inside the quadratic
     convergence basin and the refresh needs a handful of iterations
-    instead of the cold ~log2(kappa)+5. Safeguarded: the warm init is
-    used only when its own residual ``||I - M X0||_F/sqrt(d) < 0.5``
-    (comfortably inside the ``< 1`` convergence condition), else the
-    Gershgorin cold start runs — an all-zeros x0 (a fresh engine state)
-    therefore falls back automatically. Free: the safeguard's
-    ``M @ X0`` product is the iteration's first cached ``mx``, so a warm
-    call costs no extra matmuls over a cold one.
+    instead of the cold ~log2(kappa)+5. Safeguarded twice. Up front, the
+    warm init is used only when its own residual
+    ``||I - M X0||_F/sqrt(d) < 0.5``, else the Gershgorin cold start
+    runs — an all-zeros x0 (a fresh engine state) therefore falls back
+    automatically. Free: the safeguard's ``M @ X0`` product is the
+    iteration's first cached ``mx``, so a warm call costs no extra
+    matmuls over a cold one. That RMS test cannot see the spectral
+    radius of ``I - M X0``, which is what convergence depends on, so a
+    warm-started solve that ends above ``NS_FALLBACK_RESIDUAL`` starts
+    over once from the cold init inside the same loop (``iterations``
+    counts both attempts).
 
     ``X_{k+1} = X_k (2I - M X_k)`` with ``M = factor + damping*I`` converges
     quadratically to ``M^{-1}`` whenever ``||I - M X_0|| < 1``; the init
@@ -304,9 +338,34 @@ def newton_schulz_inverse_info(
     # (marginally) worse and the reported residual honestly says so. Each
     # body still costs exactly two matmuls: the update reuses the cached
     # ``mx`` and the new residual's product is next iteration's cache.
-    def cond(carry):
-        _, _, resid, prev, k = carry
+    def running(resid, prev, k):
         return (k < max_iters) & (resid > tol) & (resid < prev)
+
+    # A warm start is on probation. The ``< 0.5`` test below reads the
+    # RMS residual, which says nothing about the one direction that
+    # decides convergence: the iteration squares ``I - M X`` each step,
+    # so it converges only if that matrix's spectral radius is < 1, and a
+    # factor whose top eigenvalue grew between refreshes (early training,
+    # while the EMA still forgets its identity init) breaks that in a
+    # single direction the RMS barely registers — measured on a v5e
+    # (PR 21): the d512 LM's q/k/v/o A factors warm-started at RMS 0.1,
+    # diverged, and were served with residual 0.5-1.6. Norm bounds on the
+    # radius are sound but sit near 1 even for a good start, so the test
+    # is the outcome: a warm-started solve that stops above the
+    # usable-residual line starts over from the Gershgorin init, once.
+    def needs_restart(resid, prev, k, on_probation):
+        return (
+            on_probation
+            & ~running(resid, prev, k)
+            & ~(resid <= NS_FALLBACK_RESIDUAL)  # NaN restarts too
+            & (k < max_iters)
+        )
+
+    def cond(carry):
+        _, _, resid, prev, k, on_probation = carry
+        return running(resid, prev, k) | needs_restart(
+            resid, prev, k, on_probation
+        )
 
     # trace-time dispatch of the iteration body: in the fused kernel's
     # win regime (TPU, whole tiles, artifact-backed — see
@@ -322,55 +381,58 @@ def newton_schulz_inverse_info(
             return pallas_ns.fused_ns_step(
                 m, x, mx, interpret=pallas_ns.interpret_mode()
             )
-        x_new = x @ (2.0 * eye - mx)
-        mx_new = m @ x_new
-        return x_new, mx_new, residual(mx_new)
+        return newton_schulz_step(m, x, mx)
+
+    x_cold = eye / lam_max
+    mx_cold = m / lam_max  # == m @ x_cold, sans the matmul
+    r_cold = residual(mx_cold)
 
     def body(carry):
-        x, mx, resid, _, k = carry
+        """One iteration — from the cold init instead, if the warm start
+        just failed."""
+        x, mx, resid, prev, k, on_probation = carry
+        restart = needs_restart(resid, prev, k, on_probation)
+        x = jnp.where(restart, x_cold, x)
+        mx = jnp.where(restart, mx_cold, mx)
+        resid = jnp.where(restart, r_cold, resid)
         x_new, mx_new, r_new = step(x, mx)
-        return x_new, mx_new, r_new, resid, k + 1
+        return x_new, mx_new, r_new, resid, k + 1, on_probation & ~restart
 
     if x0 is not None:
         # safeguarded warm start: keep the caller's init only if it is
-        # well inside the convergence region, else the Gershgorin cold
-        # start (jnp.where keeps this vmap/shard_map-friendly). The
+        # plausibly inside the convergence region, else the Gershgorin
+        # cold start (jnp.where keeps this vmap/shard_map-friendly). The
         # m @ warm product doubles as the iteration's cached mx0, and the
         # cold init's product is a scalar rescale of m — so the warm
         # start costs NO extra matmul over a cold start.
         warm = x0.astype(jnp.float32)
-        m_warm = m @ warm
+        m_warm = jnp.matmul(m, warm, precision=NS_PRECISION)
         use_warm = residual(m_warm) < 0.5
-        x0 = jnp.where(use_warm, warm, eye / lam_max)
-        mx0 = jnp.where(use_warm, m_warm, m / lam_max)
+        x0 = jnp.where(use_warm, warm, x_cold)
+        mx0 = jnp.where(use_warm, m_warm, mx_cold)
     else:
-        x0 = eye / lam_max
-        mx0 = m / lam_max  # == m @ (eye / lam_max), sans the matmul
+        x0, mx0 = x_cold, mx_cold
+        use_warm = lam_max < 0.0  # False, typed like the rest of the carry
 
     # prev starts at inf so the first step always runs; it derives from
     # lam_max (not a fresh constant) so that under shard_map the carry init
     # has the same varying-manual-axes type as the residuals the body
     # computes from ``m``.
-    init = (x0, mx0, residual(mx0), lam_max * 0.0 + jnp.inf, 0)
+    init = (x0, mx0, residual(mx0), lam_max * 0.0 + jnp.inf, 0, use_warm)
     if differentiable:
         # fixed-trip scan with where-frozen lanes: same outputs as the
         # while_loop (frozen lanes never change), reverse-differentiable
         def scan_body(carry, _):
-            x, mx, resid, prev, k = carry
-            active = (resid > tol) & (resid < prev)
-            x_new, mx_new, r_new = step(x, mx)
-            x = jnp.where(active, x_new, x)
-            mx = jnp.where(active, mx_new, mx)
-            prev = jnp.where(active, resid, prev)
-            resid = jnp.where(active, r_new, resid)
-            k = k + active.astype(jnp.int32)
-            return (x, mx, resid, prev, k), None
+            active = cond(carry)
+            return jax.tree_util.tree_map(
+                lambda n, c: jnp.where(active, n, c), body(carry), carry
+            ), None
 
-        (x, _, resid, _, k), _ = jax.lax.scan(
+        (x, _, resid, _, k, _), _ = jax.lax.scan(
             scan_body, init, None, length=max_iters
         )
     else:
-        x, _, resid, _, k = jax.lax.while_loop(cond, body, init)
+        x, _, resid, _, k, _ = jax.lax.while_loop(cond, body, init)
     return NewtonSchulzInfo(
         inverse=x.astype(inv_dtype),
         residual=resid,

@@ -248,12 +248,11 @@ def _call(kern, offs, q, k, v, b, h, s_q, s_k, d, block_q, n_q, interpret):
     )
     # inside a vma-checked shard_map the outputs vary over the same mesh
     # axes as the (device-local) inputs
-    vma = getattr(jax.typeof(q), 'vma', None)
-    struct = (
-        (lambda s: jax.ShapeDtypeStruct(s, jnp.float32, vma=vma))
-        if vma is not None
-        else (lambda s: jax.ShapeDtypeStruct(s, jnp.float32))
-    )
+    vma = jax.typeof(q).vma
+
+    def struct(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
+
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -263,6 +262,7 @@ def _call(kern, offs, q, k, v, b, h, s_q, s_k, d, block_q, n_q, interpret):
             struct((b * h, s_q, _LANE)),
         ],
         interpret=interpret,
+        name='_flash_kernel',
     )(offs, bh(q), bh(k), bh(v))
 
 
@@ -274,17 +274,15 @@ def _call(kern, offs, q, k, v, b, h, s_q, s_k, d, block_q, n_q, interpret):
 _VMEM_KV_BYTES = 8 * 1024 * 1024
 
 
-# measured on-chip win regimes (TPU v5 lite, run 20260731_034720,
-# BENCH_TPU.md / micro_full.jsonl):
+# dispatch regimes (priors from one 2026-07-31 chip session whose records
+# are gone; not measured on today's code — ROADMAP S5):
 # - DENSE single-device attention competes against XLA's fused
 #   softmax(QK^T)V: the flagship with kernels enabled ran slower at
 #   s=512, so the dense path only dispatches flash at s_k >= 2048 where
 #   the S x S HBM materialization the kernel eliminates is large.
 # - The BLOCKWISE-PARTIALS form (ring/zigzag steps) competes against
 #   attend_partials_einsum, which must materialize unfused (acc, m, l)
-#   partials; the kernel computed the same partials 300x faster at the
-#   measured s=2048 and has no measured loss regime, so no length floor
-#   applies there.
+#   partials, so no length floor applies there.
 _MIN_FLASH_SK_DENSE = 2048
 
 

@@ -9,18 +9,18 @@ afterwards. Numerically the result is exactly symmetric, so the reference's
 defensive ``(C + C^T)/2`` symmetrization (kfac/layers/utils.py:18-59)
 becomes a no-op by construction.
 
-GSPMD integration: batch-sharded activation rows cannot flow into a plain
-``pallas_call`` (XLA cannot partition an opaque custom call — it would force
-a gather). :func:`sym_cov_spmd` wraps the kernel in
-``jax.experimental.custom_partitioning`` with the local-rows + psum rule:
-each device runs the triangular kernel on its row shard and the partial
-covariances all-reduce over the row-sharding axes — the same schedule GSPMD
-derives for a plain ``a^T a`` contraction, minus the redundant lower
-triangle. ``ops.cov.get_cov`` dispatches here on TPU for f32 inputs with
-factor dims spanning ≥ 2 MXU tiles — the measured on-chip win regime
-(:func:`use_pallas_for`; at bf16 XLA's native contraction is faster);
-inside ``shard_map`` (manual axes) the raw kernel runs directly on the
-local rows.
+Where it runs: batch-sharded activation rows cannot flow into a plain
+``pallas_call`` (XLA cannot partition an opaque custom call), so
+``ops.cov.get_cov`` dispatches here only where a raw Mosaic call can
+execute — a one-device process, or a fully-manual ``shard_map`` region,
+where it runs on the device-local rows (:func:`use_pallas_for`). Under
+GSPMD on several devices the plain ``a^T a`` contraction stays with XLA,
+which partitions it as local rows + psum by itself. (A
+``custom_partitioning`` wrapper used to carry the kernel through GSPMD;
+on a four-chip v5e host under jax 0.9.0 / libtpu 0.0.34 its
+``CustomSPMDPartitioning`` call reached the TPU backend unpartitioned —
+"Custom emitter for CustomSPMDPartitioning not found", PR 21 — and it
+went.)
 """
 
 from __future__ import annotations
@@ -30,8 +30,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 TILE = 128       # lane-aligned C-block edge
 K_BLOCK = 512    # rows of `a` consumed per reduction step
@@ -79,15 +77,11 @@ def sym_cov(a: jax.Array, scale=None, interpret: bool = False) -> jax.Array:
 
     # inside a vma-checked shard_map the output varies over the same mesh
     # axes as the (device-local) input rows
-    vma = getattr(jax.typeof(ap), 'vma', None)
-    out_shape = (
-        jax.ShapeDtypeStruct((d_pad, d_pad), jnp.float32, vma=vma)
-        if vma is not None
-        else jax.ShapeDtypeStruct((d_pad, d_pad), jnp.float32)
-    )
     upper = pl.pallas_call(
         _sym_cov_kernel,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct(
+            (d_pad, d_pad), jnp.float32, vma=jax.typeof(ap).vma
+        ),
         grid=(nblk, nblk, nk),
         in_specs=[
             pl.BlockSpec((K_BLOCK, TILE), lambda i, j, k: (k, i)),
@@ -95,6 +89,7 @@ def sym_cov(a: jax.Array, scale=None, interpret: bool = False) -> jax.Array:
         ],
         out_specs=pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, j)),
         interpret=interpret,
+        name='_sym_cov_kernel',
     )(ap, ap)
 
     # mirror the strictly-lower-triangle blocks from the computed uppers
@@ -106,81 +101,34 @@ def sym_cov(a: jax.Array, scale=None, interpret: bool = False) -> jax.Array:
 
 
 def interpret_mode() -> bool:
-    """Run the kernel in interpret mode off-TPU (tests, CPU meshes)."""
+    """Run the kernel in interpret mode off-TPU (tests, CPU meshes).
+
+    Every Pallas family routes through this, so off a TPU the kernels
+    silently become the Pallas interpreter — right for tests, and never
+    a measurement. Nothing here proves Mosaic compiled anything:
+    ``chip_smoke.py`` does, by refusing to run unless
+    ``jax.devices()[0].platform == 'tpu'`` and by listing the
+    ``tpu_custom_call`` kernels found in the compiled step programs.
+    """
     return jax.default_backend() != 'tpu'
 
 
-@custom_partitioning
-def sym_cov_spmd(a: jax.Array) -> jax.Array:
-    """Unscaled symmetric second moment ``a^T @ a`` that partitions under
-    GSPMD: row-sharded inputs compute local triangular covariances that
-    psum over the row axes (the schedule the reference gets from NCCL
-    factor allreduce, kfac/layers/base.py:282-336, expressed as a
-    partitioning rule instead of an explicit collective)."""
-    return sym_cov(a, scale=1.0, interpret=interpret_mode())
-
-
-def _spmd_infer(mesh, arg_shapes, result_shape):
-    del arg_shapes, result_shape
-    return NamedSharding(mesh, P())
-
-
-def _spmd_partition(mesh, arg_shapes, result_shape):
-    del result_shape
-    spec = arg_shapes[0].sharding.spec
-    # fully-replicated inputs arrive as the rank-0 PartitionSpec()
-    row_axes = spec[0] if len(spec) > 0 else None
-
-    def lower(a):
-        c = sym_cov(a, scale=1.0, interpret=interpret_mode())
-        if row_axes is not None:
-            c = jax.lax.psum(c, row_axes)
-        return c
-
-    # feature (column) shards gather: the kernel needs full rows, matching
-    # the reference's TP activation gather semantics
-    arg_shardings = (NamedSharding(mesh, P(row_axes, None)),)
-    return mesh, lower, NamedSharding(mesh, P()), arg_shardings
-
-
-try:
-    sym_cov_spmd.def_partition(
-        infer_sharding_from_operands=_spmd_infer,
-        partition=_spmd_partition,
-        # fresh output factors: C's dims never inherit the (gathered)
-        # feature sharding of d1; the contracted row factor n drives the
-        # psum
-        sharding_rule='n d1 -> d2 d3',
-    )
-except TypeError:
-    # older custom_partitioning without shardy rule support: the callback
-    # pair fully determines the GSPMD partitioning, the einsum-style rule
-    # only adds shardy-propagation hints
-    sym_cov_spmd.def_partition(
-        infer_sharding_from_operands=_spmd_infer,
-        partition=_spmd_partition,
-    )
-
-
 def use_pallas_for(d: int, dtype) -> bool:
-    """Dispatch the kernel only in its measured on-chip win regime.
+    """Dispatch the kernel only inside its threshold regime.
 
     The thresholds come from the committed derivation artifact
     (:mod:`kfac_tpu.ops.dispatch_tables`,
     ``kfac_tpu/ops/dispatch_thresholds.json``) with the original
-    measured constants as the load-or-default fallback (TPU v5 lite,
-    run 20260731_034720, BENCH_TPU.md):
+    constants as the load-or-default fallback:
 
     - factor dim spanning >= 2 MXU tiles (small factors are
       latency-bound either way), and
-    - f32 inputs: the triangular kernel measured ~5x faster than XLA's
-      dense contraction at f32 (14-17 ms vs 72-83 ms, d=256..2048) but
-      SLOWER at bf16 (127-161 ms vs 77-85 ms), where XLA's native-input
-      matmul beats the kernel's in-VMEM f32 accumulation layout. NOTE
-      the f32 baseline sweep is latency-floor contaminated (flat across
-      an 8x size range) — the artifact records that verdict, which is
-      why its thresholds are held at these priors until a clean
-      fori_loop-harness sweep replaces them.
+    - f32 inputs only: at bf16 XLA's native-input matmul was the faster
+      one in the single 2026-07-31 chip session these priors come from.
+      That session's f32 baseline sweep was latency-floor contaminated
+      (flat across an 8x size range) and its records are gone, so no
+      speed-up is claimed for the kernel: whether it earns its place is
+      ROADMAP S5's question, on the benchmark.
 
     ``dtype`` is required so a call site cannot silently re-open the
     measured-loss bf16 regime. Overridable via ``KFAC_TPU_PALLAS``
@@ -199,9 +147,12 @@ def use_pallas_for(d: int, dtype) -> bool:
     if sweep is not None:
         kfac_warnings.warn_dispatch_event('cov', sweep)
         return False
+    from kfac_tpu.ops.pallas_attention import _mosaic_context_ok
+
     return (
         d >= dispatch_tables.cov_min_dim(default=2 * TILE)
         and jnp.dtype(dtype).name in dispatch_tables.cov_dtypes(
             default=('float32',)
         )
+        and _mosaic_context_ok()
     )

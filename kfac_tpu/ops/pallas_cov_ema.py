@@ -17,11 +17,8 @@ f32 inputs, ``fused_cov_ema(F, a, alpha, scale)`` is allclose to
 ``ema_update(F, get_cov(a, scale), alpha)`` and exactly symmetric for
 symmetric ``F``.
 
-GSPMD integration mirrors :func:`pallas_cov.sym_cov_spmd` — local rows
-plus psum — with one twist the EMA blend forces: the psum over row
-shards must reproduce ``beta*F`` exactly once, so each shard blends with
-``beta/nshards`` and the all-reduce reassembles
-``sum_s (beta/nshards)*F + c*acc_s = beta*F + c*sum_s acc_s``.
+Like :mod:`pallas_cov` it runs only where a raw Mosaic call can: a
+one-device process or a fully-manual ``shard_map``.
 
 Dispatch (:func:`use_fused_cov_ema_for`) follows the family's row in the
 committed threshold artifact (:mod:`kfac_tpu.ops.dispatch_tables`,
@@ -36,8 +33,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kfac_tpu.ops.pallas_cov import (
     K_BLOCK, TILE, _pad_to, interpret_mode,
@@ -101,17 +96,13 @@ def _fused(
     nblk = d_pad // TILE
     nk = n_pad // K_BLOCK
 
-    vma = getattr(jax.typeof(ap), 'vma', None)
-    out_shape = (
-        jax.ShapeDtypeStruct((d_pad, d_pad), jnp.float32, vma=vma)
-        if vma is not None
-        else jax.ShapeDtypeStruct((d_pad, d_pad), jnp.float32)
-    )
     upper = pl.pallas_call(
         functools.partial(
             _sym_cov_ema_kernel, beta=beta, coeff=coeff
         ),
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct(
+            (d_pad, d_pad), jnp.float32, vma=jax.typeof(ap).vma
+        ),
         grid=(nblk, nblk, nk),
         in_specs=[
             pl.BlockSpec((K_BLOCK, TILE), lambda i, j, k: (k, i)),
@@ -120,6 +111,7 @@ def _fused(
         ],
         out_specs=pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, j)),
         interpret=interpret,
+        name='_sym_cov_ema_kernel',
     )(ap, ap, fp)
 
     # mirror the blended upper-triangle blocks; symmetric F means the
@@ -128,64 +120,6 @@ def _fused(
     cols = jnp.arange(d_pad)[None, :] // TILE
     full = jnp.where(cols >= rows, upper, upper.T)
     return full[:d, :d]
-
-
-@functools.partial(custom_partitioning, static_argnums=(2, 3))
-def sym_cov_ema_spmd(
-    f: jax.Array, a: jax.Array, beta: float, coeff: float
-) -> jax.Array:
-    """GSPMD-partitionable fused cov+EMA: row-sharded activations blend
-    per-shard with ``beta/nshards`` and psum over the row axes (the same
-    local-rows schedule as :func:`pallas_cov.sym_cov_spmd`, carrying the
-    EMA through the all-reduce)."""
-    return _fused(f, a, beta, coeff, interpret=interpret_mode())
-
-
-def _spmd_infer(beta, coeff, mesh, arg_shapes, result_shape):
-    del beta, coeff, arg_shapes, result_shape
-    return NamedSharding(mesh, P())
-
-
-def _spmd_partition(beta, coeff, mesh, arg_shapes, result_shape):
-    del result_shape
-    spec = arg_shapes[1].sharding.spec
-    row_axes = spec[0] if len(spec) > 0 else None
-    nshards = 1
-    if row_axes is not None:
-        axes = row_axes if isinstance(row_axes, tuple) else (row_axes,)
-        for ax in axes:
-            nshards *= int(mesh.shape[ax])
-
-    def lower(f, a):
-        out = _fused(
-            f, a, beta / nshards, coeff, interpret=interpret_mode()
-        )
-        if row_axes is not None:
-            out = jax.lax.psum(out, row_axes)
-        return out
-
-    # the running factor is replicated (every shard blends its beta/s
-    # share); activation rows stay on their shard, features gather
-    arg_shardings = (
-        NamedSharding(mesh, P()),
-        NamedSharding(mesh, P(row_axes, None)),
-    )
-    return mesh, lower, NamedSharding(mesh, P()), arg_shardings
-
-
-try:
-    sym_cov_ema_spmd.def_partition(
-        infer_sharding_from_operands=_spmd_infer,
-        partition=_spmd_partition,
-        # fresh output factors, rows drive the psum — same rule shape as
-        # sym_cov_spmd with the replicated running factor prepended
-        sharding_rule='e1 e2, n d1 -> d2 d3',
-    )
-except TypeError:
-    sym_cov_ema_spmd.def_partition(
-        infer_sharding_from_operands=_spmd_infer,
-        partition=_spmd_partition,
-    )
 
 
 def use_fused_cov_ema_for(d: int, dtype) -> bool:
@@ -205,11 +139,14 @@ def use_fused_cov_ema_for(d: int, dtype) -> bool:
     if sweep is not None:
         kfac_warnings.warn_dispatch_event('cov_ema', sweep)
         return False
+    from kfac_tpu.ops.pallas_attention import _mosaic_context_ok
+
     return (
         d >= dispatch_tables.family_min_dim('cov_ema', default=2 * TILE)
         and jnp.dtype(dtype).name in dispatch_tables.family_dtypes(
             'cov_ema', default=('float32',)
         )
+        and _mosaic_context_ok()
     )
 
 
@@ -221,15 +158,15 @@ def fused_cov_ema(
 ) -> jax.Array:
     """Drop-in fusion of ``ema_update(running, get_cov(a, scale), alpha)``.
 
-    Dispatches the fused kernel in its win regime (TPU, artifact-backed
-    threshold, fully-manual or fully-automatic trace context); otherwise
+    Dispatches the fused kernel inside its gate (TPU, artifact-backed
+    threshold, a trace context a raw Mosaic call can run in); otherwise
     runs the unfused pair, so callers never need their own fallback.
     ``running=None`` follows ``ema_update``'s cold-start semantics
     (identity running factor). Returns the running factor's dtype (f32
     accumulation inside either path).
     """
     from kfac_tpu.ops import cov as cov_lib
-    from kfac_tpu.ops import factors, pallas_gate
+    from kfac_tpu.ops import factors
 
     n, d = a.shape
     if scale is None:
@@ -250,17 +187,5 @@ def fused_cov_ema(
 
     beta = float(alpha)
     coeff = (1.0 - beta) / float(scale)
-    # same trace-context split as get_cov: fully-manual shard_map runs
-    # the raw kernel on local rows, no-manual contexts go through the
-    # custom_partitioning wrapper, partial-manual falls back to the
-    # unfused pair (neither kernel form traces there)
-    _has_mesh, manual_any, manual_all = pallas_gate.manual_context()
-    if manual_all:
-        out = _fused(running, a, beta, coeff, interpret=interpret_mode())
-    elif not manual_any:
-        out = sym_cov_ema_spmd(running, a, beta, coeff)
-    else:
-        return factors.ema_update(
-            running, cov_lib.get_cov(a, scale=scale), alpha
-        )
+    out = _fused(running, a, beta, coeff, interpret=interpret_mode())
     return out.astype(out_dtype)

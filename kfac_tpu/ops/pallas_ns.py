@@ -28,9 +28,11 @@ contraction's f32 upcast in VMEM. The scalar *decision*
 (``kl_clip_scale``: ``min(1, sqrt(kl/|vg|))``) is unchanged — it is
 cross-layer, so it cannot fuse into any per-layer kernel.
 
-Equivalence contract (pinned by tests/ops/test_fused_kernels.py): f32
-allclose to the unfused expressions above, for dense and stacked
-(vmapped) factors.
+Equivalence contract (pinned by tests/ops/test_fused_kernels.py in the
+interpreter, and on the chip by ``chip_smoke.py``): f32 allclose to the
+unfused expressions above (``ops.factors.newton_schulz_step`` for the
+iteration, contracted at f32 precision on both sides), for dense and
+stacked (vmapped) factors.
 
 Dispatch: families ``ns`` and ``klclip`` in the committed threshold
 artifact (:mod:`kfac_tpu.ops.dispatch_tables`); the NS kernels
@@ -48,7 +50,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from kfac_tpu.ops.factors import NS_PRECISION
 from kfac_tpu.ops.pallas_cov import TILE, _pad_to, interpret_mode
 
 
@@ -74,21 +78,21 @@ def _ns_xupdate_kernel(x_ref, mx_ref, out_ref):
     out_ref[:] += jax.lax.dot_general(
         x_ref[:], y,
         (((1,), (0,)), ((), ())),
+        # Mosaic's default contraction rounds f32 operands to bf16 too
+        precision=NS_PRECISION,
         preferred_element_type=jnp.float32,
     )
 
 
-def _ns_mx_resid_kernel(m_ref, x_ref, out_ref, acc_ref):
+def _ns_mx_resid_kernel(m_ref, x_ref, out_ref, part_ref):
     """``mx_new[i,j] = sum_k m[i,k] @ x_new[k,j]`` with the identity
-    residual ``sum((I - mx_new)^2)`` accumulated in the epilogue while
-    the finished tile is VMEM-resident."""
+    residual ``(I - mx_new)^2`` reduced in the epilogue while the
+    finished tile is VMEM-resident. Each (i, j) tile writes its own
+    lane-shaped (1, TILE) partial — Mosaic cannot store a scalar to
+    VMEM — and the caller sums the partials."""
     i = pl.program_id(0)
     j = pl.program_id(1)
     k = pl.program_id(2)
-
-    @pl.when((i == 0) & (j == 0) & (k == 0))
-    def _init_acc():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
     @pl.when(k == 0)
     def _init():
@@ -97,13 +101,15 @@ def _ns_mx_resid_kernel(m_ref, x_ref, out_ref, acc_ref):
     out_ref[:] += jax.lax.dot_general(
         m_ref[:], x_ref[:],
         (((1,), (0,)), ((), ())),
+        # Mosaic's default contraction rounds f32 operands to bf16 too
+        precision=NS_PRECISION,
         preferred_element_type=jnp.float32,
     )
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _resid():
         delta = _eye_tile(i, j) - out_ref[:]
-        acc_ref[0, 0] += jnp.sum(delta * delta)
+        part_ref[:] = jnp.sum(delta * delta, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=('interpret',))
@@ -122,11 +128,14 @@ def fused_ns_step(
     d = m.shape[-1]
     nb = d // TILE
     grid = (nb, nb, nb)
+    # inside a vma-checked shard_map (the stacked engine's sharded
+    # inverse) the outputs vary over the same mesh axes as the factor
+    vma = jax.typeof(m).vma
     tile_spec = pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, j))
 
     x_new = pl.pallas_call(
         _ns_xupdate_kernel,
-        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32, vma=vma),
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, k)),
@@ -134,13 +143,14 @@ def fused_ns_step(
         ],
         out_specs=tile_spec,
         interpret=interpret,
+        name='_ns_xupdate_kernel',
     )(x, mx)
 
-    mx_new, resid_sq = pl.pallas_call(
+    mx_new, resid_parts = pl.pallas_call(
         _ns_mx_resid_kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((d, d), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((d, d), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((nb, nb, 1, TILE), jnp.float32, vma=vma),
         ],
         grid=grid,
         in_specs=[
@@ -149,13 +159,14 @@ def fused_ns_step(
         ],
         out_specs=[
             tile_spec,
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            pl.BlockSpec((None, None, 1, TILE), lambda i, j, k: (i, j, 0, 0)),
         ],
         interpret=interpret,
+        name='_ns_mx_resid_kernel',
     )(m, x_new)
 
     sqrt_d = jnp.sqrt(jnp.asarray(d, jnp.float32))
-    resid = jnp.sqrt(resid_sq[0, 0]) / sqrt_d
+    resid = jnp.sqrt(jnp.sum(resid_parts)) / sqrt_d
     return x_new, mx_new, resid
 
 
@@ -185,23 +196,19 @@ def use_fused_ns_for(d: int) -> bool:
 # ------------------------------------------------------------------ kl-clip
 
 
-def _klclip_dot_kernel(p_ref, g_ref, acc_ref):
-    """Tiled f32 multiply-reduce ``sum(p * g)`` with the scalar
-    accumulated across the grid."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when((i == 0) & (j == 0))
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    acc_ref[0, 0] += jnp.sum(
-        p_ref[:].astype(jnp.float32) * g_ref[:].astype(jnp.float32)
+def _klclip_dot_kernel(p_ref, g_ref, part_ref):
+    """Tiled f32 multiply-reduce: each tile writes the lane-shaped
+    (1, TILE) column sums of ``p * g`` (Mosaic cannot store a scalar to
+    VMEM); the caller sums the partials."""
+    part_ref[:] = jnp.sum(
+        p_ref[:].astype(jnp.float32) * g_ref[:].astype(jnp.float32),
+        axis=0, keepdims=True,
     )
 
 
-def _klclip_scale_kernel(p_ref, s_ref, out_ref):
-    """Tiled f32 scale application ``p * s`` (s is a traced scalar)."""
+def _klclip_scale_kernel(s_ref, p_ref, out_ref):
+    """Tiled f32 scale application ``p * s``; the traced scalar ``s`` is
+    a (1, 1) SMEM operand."""
     out_ref[:] = p_ref[:].astype(jnp.float32) * s_ref[0, 0]
 
 
@@ -216,18 +223,24 @@ def fused_klclip_dot(
     c_pad = -(-c // TILE) * TILE
     pp = _pad_to(p, r_pad, c_pad)
     gp = _pad_to(g, r_pad, c_pad)
-    acc = pl.pallas_call(
+    grid = (r_pad // TILE, c_pad // TILE)
+    parts = pl.pallas_call(
         _klclip_dot_kernel,
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        grid=(r_pad // TILE, c_pad // TILE),
+        out_shape=jax.ShapeDtypeStruct(
+            (*grid, 1, TILE), jnp.float32, vma=jax.typeof(pp).vma
+        ),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((TILE, TILE), lambda i, j: (i, j)),
             pl.BlockSpec((TILE, TILE), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+        out_specs=pl.BlockSpec(
+            (None, None, 1, TILE), lambda i, j: (i, j, 0, 0)
+        ),
         interpret=interpret,
+        name='_klclip_dot_kernel',
     )(pp, gp)
-    return acc[0, 0]
+    return jnp.sum(parts)
 
 
 @functools.partial(jax.jit, static_argnames=('interpret',))
@@ -243,15 +256,18 @@ def fused_klclip_scale(
     s = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     out = pl.pallas_call(
         _klclip_scale_kernel,
-        out_shape=jax.ShapeDtypeStruct((r_pad, c_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (r_pad, c_pad), jnp.float32, vma=jax.typeof(pp).vma
+        ),
         grid=(r_pad // TILE, c_pad // TILE),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((TILE, TILE), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((TILE, TILE), lambda i, j: (i, j)),
         interpret=interpret,
-    )(pp, s)
+        name='_klclip_scale_kernel',
+    )(s, pp)
     return out[:r, :c]
 
 

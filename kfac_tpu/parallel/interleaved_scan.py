@@ -11,7 +11,7 @@ combined scan of :class:`kfac_tpu.parallel.pipeline.PipelinedLM`
 (schedule='1f1b') structurally caps at ~25%.
 
 The reference rides DeepSpeed's PipelineEngine and has no interleaving;
-this is the beyond-reference pipeline milestone (docs/ROADMAP.md gap #3).
+this is the beyond-reference pipeline milestone.
 
 Execution model (one ``lax.scan`` over ticks inside one ``shard_map``):
 

@@ -1035,12 +1035,9 @@ class DistributedKFAC:
         # to a bf16 factor dtype would inflate the warm residual by
         # eps_bf16 * kappa and reject the warm start exactly in the
         # high-kappa regime where it saves the most
-        # check_vma=False: the NS solver's convergence while_loop has no
-        # replication rule on some installs; the body is forward-only
-        # (never differentiated), so the check buys nothing here.
         return jax.shard_map(
             local, mesh=self.mesh, in_specs=(spec, spec, spec),
-            out_specs=spec, check_vma=False,
+            out_specs=spec,
         )(stack, prev, dmp)
 
     @tracing.scope('dist_kfac.update_inverses')
@@ -1211,8 +1208,12 @@ class DistributedKFAC:
             d = f.shape[-1]
             eye = jnp.eye(d, dtype=jnp.float32)
             m = f.astype(jnp.float32) + damping * eye
+            # f32 products: at a TPU's default (bf16) matmul precision
+            # the monitor's own rounding is ~kappa * 2^-9 and would
+            # drown the residual it reports
             r = eye - jnp.einsum(
-                'lij,ljk->lik', m, finv.astype(jnp.float32)
+                'lij,ljk->lik', m, finv.astype(jnp.float32),
+                precision=factors_lib.NS_PRECISION,
             )
             return jnp.sqrt(jnp.sum(r * r, axis=(-2, -1)) / d)
 
@@ -1721,7 +1722,7 @@ class DistributedKFAC:
 
         Each array's per-device footprint is its sharding's shard shape —
         the truth for asymmetric/real layouts — rather than fraction
-        arithmetic from the strategy (VERDICT round 1: estimates mislead on
+        arithmetic from the strategy (estimates mislead on
         asymmetric layouts). Falls back to strategy fractions only for
         abstract values (e.g. under trace).
 

@@ -1,16 +1,19 @@
 """ctypes bindings for the native prefetching batch loader.
 
 Builds ``native/loader.cpp`` into a shared library on first use (cached
-under ``native/build/``) and exposes :class:`PrefetchLoader`, an iterator of
+under ``native/build/``, named by the source's content hash so a library
+built from other source is never loaded) and exposes :class:`PrefetchLoader`, an iterator of
 shuffled (data, labels) batches assembled by a background C++ thread — host
-input work overlaps device compute. Falls back cleanly if no C++ toolchain
-is available (callers should catch ``NativeLoaderUnavailable`` and use
-``examples.data.batches``).
+input work overlaps device compute. Without a C++ toolchain it raises
+``NativeLoaderUnavailable``; a caller that asked for the native loader
+gets that error, not another loader (``examples.data.batches`` is the
+python one to ask for instead).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,7 +23,6 @@ import numpy as np
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, 'native', 'loader.cpp')
 _BUILD_DIR = os.path.join(_REPO_ROOT, 'native', 'build')
-_SO = os.path.join(_BUILD_DIR, 'libkfacloader.so')
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -35,22 +37,26 @@ def _load_lib() -> ctypes.CDLL:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
-            if not os.path.exists(_SRC):
-                raise NativeLoaderUnavailable(f'missing source {_SRC}')
+        if not os.path.exists(_SRC):
+            raise NativeLoaderUnavailable(f'missing source {_SRC}')
+        # keyed by content, not mtime: a copied or checked-out tree says
+        # nothing true about which file is newer
+        with open(_SRC, 'rb') as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        so = os.path.join(_BUILD_DIR, f'libkfacloader-{digest}.so')
+        if not os.path.exists(so):
             os.makedirs(_BUILD_DIR, exist_ok=True)
+            tmp = f'{so}.tmp.{os.getpid()}'
             cmd = [
                 'g++', '-O2', '-shared', '-fPIC', '-std=c++17', '-pthread',
-                _SRC, '-o', _SO,
+                _SRC, '-o', tmp,
             ]
             try:
                 subprocess.run(cmd, check=True, capture_output=True)
             except (OSError, subprocess.CalledProcessError) as e:
                 raise NativeLoaderUnavailable(f'build failed: {e}') from e
-        lib = ctypes.CDLL(_SO)
+            os.replace(tmp, so)  # atomic: racing builders both succeed
+        lib = ctypes.CDLL(so)
         lib.loader_create.restype = ctypes.c_void_p
         lib.loader_create.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,

@@ -234,6 +234,50 @@ def test_newton_schulz_warm_start_fewer_iters_and_safeguard():
         assert int(fb.iterations) == int(cold.iterations)
 
 
+def test_newton_schulz_warm_start_outside_the_basin_restarts_cold():
+    """A factor whose top eigenvalue grew between refreshes (the EMA
+    forgetting its identity init): the old inverse passes the RMS
+    safeguard (0.12 < 0.5) but ``I - M X0`` has spectral radius 1.76, so
+    the iteration diverges from it. Found on a v5e (PR 21), where such
+    slots were served with residuals of 0.5-1.6. The solve must notice,
+    start over cold once, and land where the cold solve lands — alone,
+    batched, and in the differentiable variant."""
+    rng = np.random.default_rng(0)
+    d = 256
+    u = rng.normal(size=(d,))
+    u /= np.linalg.norm(u)
+    cov = 0.5 * np.eye(d) + 400.0 * np.outer(u, u)
+
+    def ema(n):  # identity init, n captures at decay 0.95
+        return jnp.asarray(
+            0.95 ** n * np.eye(d) + (1 - 0.95 ** n) * cov, jnp.float32
+        )
+
+    old = factors.newton_schulz_inverse(ema(1), 0.003)
+    f = ema(3)
+    r0 = np.eye(d) - (np.asarray(f, np.float64) + 0.003 * np.eye(d)) @ (
+        np.asarray(old, np.float64)
+    )
+    assert np.linalg.norm(r0) / np.sqrt(d) < 0.5  # premise: passes the RMS test
+    assert np.abs(np.linalg.eigvals(r0)).max() > 1.0  # premise: diverges
+
+    cold = factors.newton_schulz_inverse_info(f, 0.003)
+    assert float(cold.residual) <= 1e-5
+    for kwargs in ({}, {'differentiable': True}):
+        warm = factors.newton_schulz_inverse_info(f, 0.003, x0=old, **kwargs)
+        np.testing.assert_array_equal(
+            np.asarray(warm.inverse), np.asarray(cold.inverse)
+        )
+        # the failed attempt is counted
+        assert int(warm.iterations) > int(cold.iterations)
+    # batched with a lane whose warm start is exact: that lane is untouched
+    infos = jax.vmap(
+        lambda ff, w: factors.newton_schulz_inverse_info(ff, 0.003, x0=w)
+    )(jnp.stack([f, ema(1)]), jnp.stack([old, old]))
+    assert float(infos.residual[0]) <= 1e-5
+    assert int(infos.iterations[1]) == 0
+
+
 def test_batched_auto_inverse_single_branch_per_slot_fallback():
     """batched_damped_inverse_auto: well-conditioned slots get the NS
     inverse bitwise (the scalar cond takes the cheap branch when ALL

@@ -1,0 +1,133 @@
+"""Every Pallas entry point lowers for TPU from the CPU.
+
+The kernel tests elsewhere run with ``interpret=True``, which never
+reaches Mosaic's lowering rules — that is how ``fused_ns_step`` and
+``fused_klclip_dot`` shipped with a scalar store into VMEM that no TPU
+could lower. Here each entry point is traced on the CPU and lowered with
+``lowering_platforms=('tpu',)``: the Pallas->Mosaic lowering runs for
+real and the text must carry a ``tpu_custom_call``. This is the check to
+run before spending chip time; it does not replace compiling on the chip
+(Mosaic's own compiler only runs there — ``chip_smoke.py``).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from kfac_tpu.ops import (
+    pallas_attention,
+    pallas_cov,
+    pallas_cov_ema,
+    pallas_ns,
+)
+
+
+@pytest.fixture(autouse=True)
+def _as_on_tpu(monkeypatch):
+    # the dispatchers pick interpret mode and open their gates from the
+    # backend; answer as the chip would so they lower the Mosaic kernel
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+
+
+def _kernels_in(fn, *args) -> int:
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=('tpu',)
+    ).as_text()
+    return text.count('tpu_custom_call')
+
+
+def _f32(*shape):
+    return jnp.ones(shape, jnp.float32)
+
+
+# (rows, d): one whole-tile shape, one ragged in both dims
+COV_SHAPES = [(1024, 256), (700, 300)]
+# (rows, cols) of a preconditioned gradient
+KLCLIP_SHAPES = [(512, 512), (1000, 2049)]
+# Newton-Schulz dispatches whole tiles only (use_fused_ns_for), so its
+# second shape is a second tile count, not a ragged one
+NS_DIMS = [512, 1152]
+BATCH = 3
+
+
+def _stack(x):
+    return jnp.stack([x] * BATCH)
+
+
+@pytest.mark.parametrize('n,d', COV_SHAPES)
+def test_sym_cov_lowers(n, d):
+    a = _f32(n, d)
+    assert _kernels_in(pallas_cov.sym_cov, a) == 1
+    assert _kernels_in(jax.vmap(pallas_cov.sym_cov), _stack(a)) == 1
+
+
+@pytest.mark.parametrize('n,d', COV_SHAPES)
+def test_fused_cov_ema_lowers(n, d):
+    f, a = _f32(d, d), _f32(n, d)
+
+    def fused(f, a):
+        return pallas_cov_ema._fused(f, a, 0.95, 0.05 / n)
+
+    assert _kernels_in(fused, f, a) == 1
+    assert _kernels_in(jax.vmap(fused), _stack(f), _stack(a)) == 1
+
+
+@pytest.mark.parametrize('d', NS_DIMS)
+def test_fused_ns_step_lowers(d):
+    m = _f32(d, d)
+    assert _kernels_in(pallas_ns.fused_ns_step, m, m, m) == 2
+    # the stacked engine reaches the kernel pair under vmap
+    assert _kernels_in(
+        jax.vmap(pallas_ns.fused_ns_step), _stack(m), _stack(m), _stack(m)
+    ) == 2
+
+
+@pytest.mark.parametrize('r,c', KLCLIP_SHAPES)
+def test_fused_klclip_lowers(r, c):
+    p = _f32(r, c)
+    scale = jnp.float32(0.5)
+    assert _kernels_in(pallas_ns.fused_klclip_dot, p, p) == 1
+    assert _kernels_in(
+        jax.vmap(pallas_ns.fused_klclip_dot), _stack(p), _stack(p)
+    ) == 1
+    assert _kernels_in(pallas_ns.fused_klclip_scale, p, scale) == 1
+    # the scale is cross-layer, so under vmap it is either shared...
+    assert _kernels_in(
+        jax.vmap(lambda p: pallas_ns.fused_klclip_scale(p, scale)),
+        _stack(p),
+    ) == 1
+    # ...or one scalar per slot (a batched SMEM operand)
+    assert _kernels_in(
+        jax.vmap(pallas_ns.fused_klclip_scale), _stack(p), jnp.ones(BATCH)
+    ) == 1
+
+
+@pytest.mark.parametrize('s_q,s_k', [(256, 256), (128, 384)])
+def test_flash_partials_lower(s_q, s_k):
+    # blocks must divide the sequence (the dispatcher enforces it), so
+    # the second shape is an uneven ring chunk pair, not a ragged one
+    q = jnp.ones((2, s_q, 2, 128), jnp.bfloat16)
+    kv = jnp.ones((2, s_k, 2, 128), jnp.bfloat16)
+    assert _kernels_in(
+        pallas_attention.flash_attention_partials, q, kv, kv
+    ) == 1
+    assert _kernels_in(
+        jax.vmap(pallas_attention.flash_attention_partials),
+        _stack(q), _stack(kv), _stack(kv),
+    ) == 1
+
+
+def test_newton_schulz_dispatches_fused_pair_when_gates_open(monkeypatch):
+    """End of the chain the README quick-start hits on a TPU: the gate
+    (backend, thresholds, whole tiles, one device) opens at d=512 and
+    the Newton-Schulz inverse lowers with the fused pair in its loop."""
+    from kfac_tpu.ops import factors
+
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, 'devices', lambda *a: one)
+    assert pallas_ns.use_fused_ns_for(512)
+    f = jnp.eye(512, dtype=jnp.float32)
+    n = _kernels_in(
+        lambda f: factors.newton_schulz_inverse(f, 0.003), f
+    )
+    assert n == 2

@@ -475,7 +475,7 @@ def test_newton_schulz_solver_matches_cholesky_distributed():
 
 def test_describe_placement_matches_actual_shard_layout():
     """The dump's executed-placement section must report the device that
-    REALLY holds each layer's factor slot (VERDICT r3 weak #2: the greedy
+    REALLY holds each layer's factor slot (the greedy
     table alone misled load-imbalance debugging), and the greedy table is
     labeled as the cost-model view."""
     _, _, _, _, reg, _, dk, _ = _setup(0.5, kl_clip=None)
@@ -631,7 +631,7 @@ def test_size_classes_collapse_heterogeneous_shapes_exactly():
 
 
 def test_inverse_residuals_out_of_band_monitoring():
-    """VERDICT r4 weak #6: the stacked INVERSE engine exposes per-slot
+    """The stacked INVERSE engine exposes per-slot
     damped-inverse residuals out-of-band; benign factors sit far below
     the NS fallback threshold, EIGEN configs refuse the query."""
     from kfac_tpu.ops import factors as factors_lib

@@ -178,7 +178,7 @@ def test_tp_with_hybrid_kaisa():
 
 class _GenericNet:
     """A model with names unlike anything in kfac_tpu.models — proves the
-    registry-derived TP rules need no name table (VERDICT round 1)."""
+    registry-derived TP rules need no name table."""
 
     def build(self):
         import flax.linen as nn

@@ -236,7 +236,7 @@ def test_pipeline_dp_stats_match_dense_capture():
 
 
 # deliberately NOT slow-marked: this is the equivalence guard on the
-# hardest scheduling code (VERDICT r3 weak #7 — the fast tier must keep
+# hardest scheduling code (the fast tier must keep
 # it); ~60 s warm-cache on the 1-core container
 def test_1f1b_matches_gpipe_loss_grads_stats():
     """The combined-scan 1F1B schedule computes the same loss, parameter

@@ -133,7 +133,7 @@ def test_cifar_example_no_kfac():
 
 def test_cifar_real_npz_with_augmentation(tmp_path):
     """Real-dataset path: a cifar10.npz on disk trains with normalization
-    and crop/flip augmentation (VERDICT: reference examples train real
+    and crop/flip augmentation (reference examples train real
     CIFAR, examples/vision/datasets.py:1-154)."""
     import numpy as np
 

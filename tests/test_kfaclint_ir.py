@@ -80,7 +80,7 @@ def test_kfl201_flags_bf16_demotion_in_factor_math():
 
 
 def test_kfl201_flags_f64_promotion():
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         def factor_update(a):
             return a @ a.astype(jnp.float64).T
 

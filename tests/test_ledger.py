@@ -340,7 +340,7 @@ def test_build_baseline_deterministic_bytes(tmp_path):
 
 def test_build_baseline_drops_off_provenance_rounds():
     rounds = [
-        {'parsed': None},  # BENCH_r01-style provenance-less round
+        {'parsed': None},  # a provenance-less driver round
         {'parsed': {'platform': 'tpu', 'value': 10.0}},
         {'parsed': {'platform': 'cpu', 'value': 99.0}},
         {'parsed': {'platform': 'tpu', 'value': 12.0}},

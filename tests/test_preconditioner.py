@@ -275,41 +275,6 @@ def test_unset_compute_method_resolves_to_platform_default():
     assert kfac.inverse_solver == 'cholesky'
 
 
-def test_fully_pinned_config_never_touches_the_backend(monkeypatch):
-    """jax.default_backend() initializes the JAX backend as a side effect;
-    a config with compute_method, inverse_solver, and bucket_granularity
-    all explicit must not call it (first-touch hazard: constructing a
-    config would otherwise lock the platform before a caller's
-    jax.config.update('jax_platforms', ...))."""
-    m = models.TinyModel()
-    x, _ = models.regression_data(jax.random.PRNGKey(1), n=8, dim=6)
-    reg = kfac_tpu.register_model(m, x)
-
-    def boom():
-        raise AssertionError('backend touched during pinned-config init')
-
-    monkeypatch.setattr(jax, 'default_backend', boom)
-    kfac = kfac_tpu.KFACPreconditioner(
-        registry=reg,
-        compute_method='inverse',
-        inverse_solver='newton_schulz',
-        bucket_granularity=1,
-    )
-    assert kfac.inverse_solver == 'newton_schulz'
-    # Explicit EIGEN is also pinned: the TPU perf warning probes the
-    # platform ONLY when the backend is already initialized, so an
-    # uninitialized backend stays untouched (the warning is skipped).
-    from jax._src import xla_bridge
-
-    monkeypatch.setattr(xla_bridge, 'backends_are_initialized', lambda: False)
-    kfac_tpu.KFACPreconditioner(
-        registry=reg,
-        compute_method='eigen',
-        inverse_solver='cholesky',
-        bucket_granularity=1,
-    )
-
-
 def test_forced_eigen_on_tpu_warns(monkeypatch):
     m = models.TinyModel()
     x, _ = models.regression_data(jax.random.PRNGKey(1), n=8, dim=6)

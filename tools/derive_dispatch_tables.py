@@ -12,9 +12,9 @@ the prior thresholds and says so in the artifact's ``provenance`` —
 this tool can only move a gate on clean numbers.
 
 The committed ``kfac_tpu/ops/dispatch_thresholds.json`` was produced by
-this tool from ``bench_runs/tpu_session_20260731/micro_full.jsonl``
-(see its provenance block). Re-run on a fresh on-chip fori_loop sweep
-to replace it.
+this tool from ``bench_runs/cpu_session_20260806/micro_fused.jsonl`` (a
+CPU sweep — see its provenance block). Re-run on an on-chip fori_loop
+sweep to replace it.
 """
 
 from __future__ import annotations

@@ -22,8 +22,8 @@ A third input kind is the compile-watch heartbeat journal
 done`` records, fsynced before each blocking phase). A journal whose
 last heartbeat for some entry never reached ``done`` yields the
 "died compiling X" verdict: the entry name, the phase it died in, and
-the elapsed time the journal proves — the mid-compile postmortem the
-live-tunnel sessions were missing (ROADMAP item 1). Mixed files work:
+the elapsed time the journal proves — the postmortem for a run killed
+mid-compile. Mixed files work:
 compile records and metric records are partitioned and each analyzed.
 
 Deliberately dependency-free (stdlib only — no jax, no numpy): bundles

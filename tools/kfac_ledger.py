@@ -8,8 +8,8 @@ ledger"):
     # correlated anomaly timeline over a run directory of stream files
     python tools/kfac_ledger.py --timeline runs/2026-08-06/
 
-    # rebuild the committed perf baseline from committed bench rounds
-    python tools/kfac_ledger.py --build-baseline BENCH_r0*.json \\
+    # rebuild the committed perf baseline from bench round records
+    python tools/kfac_ledger.py --build-baseline bench_runs/run_*.json \\
         --out bench_runs/LEDGER.json
 
     # gate one round against the baseline (CI: nonzero exit on
