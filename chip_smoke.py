@@ -18,9 +18,11 @@ kernel disagrees with its reference, if a loss is not finite, if the
 worst Newton-Schulz residual exceeds the library's own
 ``NS_FALLBACK_RESIDUAL``, if the K-FAC step counter or the inverses did
 not advance, or if a step recompiled after its variant's first compile.
-On success the last line of stdout is one JSON object starting
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}``.
-It reports times, never utilisation.
+On success the last two lines of stdout are JSON objects: first the
+report (every field :func:`run_training` returns, plus the kernel checks),
+then, last, exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``
+with the device as JAX reports it. It reports times, never utilisation.
 """
 
 from __future__ import annotations
@@ -363,6 +365,19 @@ def check_training(report: dict) -> None:
 # --------------------------------------------------------------------- main
 
 
+def result_line(devices) -> str:
+    """The last line of a pass: these keys and no others, the device as
+    JAX reports it. Everything else is in the report, the line above."""
+    return json.dumps({
+        'ok': True,
+        'device': {
+            'platform': devices[0].platform,
+            'kind': devices[0].device_kind,
+            'count': len(devices),
+        },
+    })
+
+
 def main() -> None:
     import jax
 
@@ -372,25 +387,22 @@ def main() -> None:
             f'chip_smoke needs a TPU: jax.devices()[0] is {dev.platform!r} '
             f'({dev.device_kind}). It does not run anywhere else.'
         )
-    n = len(jax.devices())
-    log(f'device: {dev.platform} {dev.device_kind} x{n}')
-
+    # before the first line of output: without the repo beside it the
+    # script fails here and prints nothing
     from examples import train_imagenet_resnet
     from kfac_tpu.utils import compile_cache
 
+    n = len(jax.devices())
+    log(f'device: {dev.platform} {dev.device_kind} x{n}')
     log(f'compile cache: {compile_cache.configure()}')
     kernels = check_kernels()
     report = run_training(train_imagenet_resnet.main, resnet50_argv(n))
-    report = {
-        'ok': True,
-        'device': {
-            'platform': dev.platform, 'kind': dev.device_kind, 'count': n,
-        },
+    print(json.dumps({
         'seconds': round(time.perf_counter() - _T0, 1),
         'kernel_checks': kernels,
         **report,
-    }
-    print(json.dumps(report), flush=True)
+    }), flush=True)
+    print(result_line(jax.devices()), flush=True)
 
 
 if __name__ == '__main__':

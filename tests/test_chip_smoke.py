@@ -31,6 +31,40 @@ def test_smoke_refuses_to_run_off_the_chip():
     assert '"ok"' not in r.stdout
 
 
+def test_smoke_alone_fails_and_prints_nothing(tmp_path):
+    """In a directory that holds chip_smoke.py and nothing else of the
+    repo it fails even where there is a TPU (faked here), with no output."""
+    import shutil
+
+    shutil.copy(os.path.join(REPO, 'chip_smoke.py'), tmp_path)
+    code = (
+        'import jax, runpy, types; '
+        'd = types.SimpleNamespace(platform="tpu", device_kind="fake"); '
+        'jax.devices = lambda: [d]; '
+        'runpy.run_path("chip_smoke.py", run_name="__main__")'
+    )
+    env = {k: v for k, v in os.environ.items() if k != 'PYTHONPATH'}
+    r = subprocess.run(
+        [sys.executable, '-c', code], cwd=tmp_path, capture_output=True,
+        env={**env, 'JAX_PLATFORMS': 'cpu'}, text=True, timeout=300,
+    )
+    assert r.returncode != 0
+    assert 'ImportError' in r.stderr or 'ModuleNotFoundError' in r.stderr
+    assert r.stdout == ''
+
+
+def test_result_line_has_the_contract_keys_and_no_others():
+    import json
+
+    import jax
+
+    got = json.loads(chip_smoke.result_line(jax.devices()))
+    assert got == {
+        'ok': True,
+        'device': {'platform': 'cpu', 'kind': 'cpu', 'count': 8},
+    }
+
+
 @pytest.mark.parametrize('env_dir', ['/tmp/some/where/else', None])
 def test_compile_cache_rule(env_dir):
     """JAX_COMPILATION_CACHE_DIR set: that value, and no code sets
