@@ -658,24 +658,6 @@ def _fused_kernel_probe(d: int = 256, rows: int = 512) -> dict:
             row['fused_error'] = f'{type(exc).__name__}: {exc}'
         out[fam] = row
 
-    # device-truth attribution: trace one pass over every variant and
-    # attribute device lanes per probe scope (empty off-TPU)
-    try:
-        from kfac_tpu.observability import profiler, trace_attrib
-
-        tdir = tempfile.mkdtemp(prefix='fused_probe_trace_')
-        order = list(jitted)
-
-        def _traced(i):
-            fn, args = jitted[order[i % len(order)]]
-            return fn(*args)
-
-        profiler.capture_steps(tdir, _traced, steps=len(order))
-        device = trace_attrib.device_breakdown_ms(tdir, scopes=order)
-        if device:
-            out['device_ms'] = device
-    except Exception as exc:
-        out['trace_error'] = f'{type(exc).__name__}: {exc}'
     return out
 
 
@@ -948,31 +930,6 @@ def _obs_probe(result, out_path, reg, run, loss, opt, params, data):
     kstate = _phase('inverses_ms', jax.jit(kfac_m.update_inverses), kstate)
     _phase('precondition_ms', jax.jit(kfac_m.precondition), kstate, grads)
     result['step_breakdown_ms'] = phases
-
-    # device-truth counterpart of the host-clock phases above: capture a
-    # short profiler trace of annotated steps and attribute its DEVICE
-    # lanes per __kfac_scope__ (the host clocks include dispatch latency;
-    # the trace numbers are chip-side — docs/OBSERVABILITY.md
-    # "Measurement truth"). Empty off-TPU (no device lanes) — host
-    # numbers stand alone and no key is emitted.
-    try:
-        from kfac_tpu.observability import profiler, trace_attrib
-
-        tdir = out_path + '.trace'
-        carry = list(args)
-
-        def _traced_step(i):
-            out = plain_step(*carry)
-            carry[:3] = out[0], out[1], out[2]
-            return out
-
-        profiler.capture_steps(tdir, _traced_step, steps=3)
-        device = trace_attrib.device_breakdown_ms(tdir)
-        if device:
-            phases['device'] = device
-        result['trace_dir'] = tdir
-    except Exception as exc:  # the probe never kills the headline
-        result['trace_attrib_error'] = f'{type(exc).__name__}: {exc}'
 
     # async refresh spike probe, after the headline breakdown is safe on
     # disk — a failure here surfaces as obs_probe_error without losing it

@@ -477,7 +477,7 @@ def _kaisa_slice(engine, state, units):
             prev = state.a_inv[key] if side == 'a' else state.g_inv[key]
             cand = engine._sharded_inv(
                 factor, slot_damping(sb.layers, sb.padded), prev=prev
-            ).astype(cfg.inv_dtype)
+            )[0].astype(cfg.inv_dtype)
             upd['a_inv' if side == 'a' else 'g_inv'][key] = (
                 jax.lax.with_sharding_constraint(cand, dec)
             )

@@ -26,6 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from kfac_tpu import tracing
 from kfac_tpu.layers import helpers as helpers_lib
 from kfac_tpu.layers import registry as registry_lib
 
@@ -49,12 +50,13 @@ def _make_gtap(helper: helpers_lib.LayerHelper) -> Callable[..., jax.Array]:
         # invocations sum traffic-weighted and the divisor tracks G
         # mass rather than input mass (see g_factor_for_sum /
         # g_capture_weight)
-        if helper.weighted:
-            return ybar, (
-                helper.g_factor_for_sum(ybar),
-                helper.g_capture_weight(ybar),
-            )
-        return ybar, helper.g_factor_for_sum(ybar)
+        with tracing.capture_scope('g'):
+            if helper.weighted:
+                return ybar, (
+                    helper.g_factor_for_sum(ybar),
+                    helper.g_capture_weight(ybar),
+                )
+            return ybar, helper.g_factor_for_sum(ybar)
 
     gtap.defvjp(fwd, bwd)
     return gtap
@@ -83,7 +85,8 @@ def _make_role_gtap(
         return y, None
 
     def bwd(_, ybar: jax.Array):
-        return ybar, helper.role_g_factor(role, ybar)
+        with tracing.capture_scope('g'):
+            return ybar, helper.role_g_factor(role, ybar)
 
     gtap.defvjp(fwd, bwd)
     return gtap
@@ -164,7 +167,8 @@ class CurvatureCapture:
                 unit, role = registry.taps[name]
                 uhelper = registry.layers[unit]
                 a = jax.lax.stop_gradient(iargs[0])
-                a_fac = uhelper.role_a_factor(role, a)
+                with tracing.capture_scope('a'):
+                    a_fac = uhelper.role_a_factor(role, a)
                 if unit in a_stats:
                     a_stats[unit] = a_stats[unit] + a_fac
                     counts[unit] = counts[unit] + 1
@@ -189,15 +193,17 @@ class CurvatureCapture:
                         return role_tap(name, iargs, ikwargs, next_fun)
                     return next_fun(*iargs, **ikwargs)
                 a = jax.lax.stop_gradient(iargs[0])
-                a_fac = helper.get_a_factor(a)
-                if helper.weighted:
-                    # traffic-weighted accumulation: sum w_i * F_i here,
-                    # divide by sum w_i in run() — a repeated invocation
-                    # that saw no tokens contributes nothing instead of
-                    # dragging the within-capture average toward zero
-                    # (same convention as accumulate_stats/average_stats)
-                    w = helper.capture_weight(a)
-                    a_fac = a_fac * w
+                with tracing.capture_scope('a'):
+                    a_fac = helper.get_a_factor(a)
+                    if helper.weighted:
+                        # traffic-weighted accumulation: sum w_i * F_i
+                        # here, divide by sum w_i in run() — a repeated
+                        # invocation that saw no tokens contributes
+                        # nothing instead of dragging the within-capture
+                        # average toward zero (same convention as
+                        # accumulate_stats/average_stats)
+                        w = helper.capture_weight(a)
+                        a_fac = a_fac * w
                 if name in a_stats:
                     a_stats[name] = a_stats[name] + a_fac
                     counts[name] = counts[name] + 1

@@ -14,10 +14,6 @@ Five small modules, one per concern:
   (``StepTraceAnnotation`` per step, one-call capture).
 - :mod:`kfac_tpu.observability.comms` — host-side byte accounting for
   the KAISA transports and size-class padding waste.
-- :mod:`kfac_tpu.observability.trace_attrib` — stdlib parser of the
-  profiler's trace.json.gz into per-step per-scope DEVICE-time
-  breakdowns (the measurement-truth counterpart of host-clock phase
-  timing).
 - :mod:`kfac_tpu.observability.calibration` — live comparison of
   measured step/spike times (and XLA-reported HBM bytes) against the
   autotune plan's cost model, with a drift bridge into the fleet
@@ -45,7 +41,6 @@ from kfac_tpu.observability import ledger
 from kfac_tpu.observability import metrics
 from kfac_tpu.observability import profiler
 from kfac_tpu.observability import sinks
-from kfac_tpu.observability import trace_attrib
 from kfac_tpu.observability.calibration import (
     CalibrationConfig,
     CalibrationMonitor,
@@ -87,10 +82,6 @@ from kfac_tpu.observability.profiler import (
     step_annotation,
 )
 from kfac_tpu.observability.sinks import JSONLWriter, RateLimitedLogger
-from kfac_tpu.observability.trace_attrib import (
-    device_breakdown_ms,
-    step_attribution,
-)
 
 __all__ = [
     'CalibrationConfig',
@@ -115,7 +106,6 @@ __all__ = [
     'comms',
     'comms_summary',
     'compile_watch',
-    'device_breakdown_ms',
     'drain_flight',
     'fleet_drift_keys',
     'flight_recorder',
@@ -132,6 +122,4 @@ __all__ = [
     'sentinel_check',
     'sinks',
     'step_annotation',
-    'step_attribution',
-    'trace_attrib',
 ]

@@ -207,7 +207,8 @@ def decomp_reshard_bytes(engine: Any) -> int:
     and resharded to the strategy's resident layout: the full
     decomposition payload — eigenvector stacks + eigenvalue vectors
     (EIGEN), fused eigenvalue grids (prediv), or inverse stacks
-    (INVERSE) — at ``inv_dtype``, per ``inv_update_steps`` occurrence.
+    (INVERSE) — at ``inv_dtype``, per ``inv_update_steps`` occurrence;
+    with the inverse stacks of a Newton-Schulz solve, its counters.
     """
     item = _itemsize(engine.config.inv_dtype)
     total = 0
@@ -226,6 +227,14 @@ def decomp_reshard_bytes(engine: Any) -> int:
         for store in (engine.a_store, engine.g_store):
             for sb in store:
                 total += sb.padded * sb.d * sb.d * item  # a_inv/g_inv
+        if engine._ns_refresh:
+            # the Newton-Schulz solve's counters ride along, replicated:
+            # DistKFACState.refresh, a float32 row a slot
+            from kfac_tpu.parallel import kaisa
+
+            total += 4 * len(kaisa.REFRESH_COLUMNS) * sum(
+                sb.padded for sb in engine.a_store + engine.g_store
+            )
     return total
 
 

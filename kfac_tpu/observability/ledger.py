@@ -12,8 +12,7 @@ anomalies across streams into causal timeline annotations, and a
 perf-regression sentinel gates bench rounds against a committed
 provenance-aware baseline (``bench_runs/LEDGER.json``).
 
-Deliberately stdlib-only, like :mod:`trace_attrib` and
-``tools/kfac_inspect.py``: postmortem triage happens on machines without
+Deliberately stdlib-only, like ``tools/kfac_inspect.py``: postmortem triage happens on machines without
 jax. CLIs load this file standalone via
 ``importlib.util.spec_from_file_location`` so importing it never drags
 in the package ``__init__`` (which imports jax).
@@ -280,7 +279,8 @@ def parse_chaos(source: Any) -> list[dict[str, Any]]:
 
 
 def parse_trace(source: Any) -> list[dict[str, Any]]:
-    """A saved :func:`trace_attrib.step_attribution` result (JSON)."""
+    """A saved per-step per-scope device-time attribution (JSON:
+    ``{'steps': {step: {scope: ms}}, 'per_step_ms': {scope: ms}}``)."""
     if isinstance(source, (str, os.PathLike)):
         with open(source, encoding='utf-8') as f:
             data = json.load(f)

@@ -253,9 +253,12 @@ class MetricsCollector:
     """Host-side drain for the in-jit metrics state.
 
     One ``drain(state)`` call performs a single ``jax.device_get`` of the
-    scalar dict (plus the engine step) and folds in the host-side
-    families: ``tracing.health_counters`` when the health sentinel is on,
-    and optionally the ``tracing`` wall-time table as ``time/*`` keys.
+    scalar dict, the engine step and, where the state carries them
+    (``DistKFACState.refresh``), the last Newton-Schulz refresh's counters,
+    whose totals become ``refresh/*`` keys once a refresh has filled them;
+    it folds in the host-side families: ``tracing.health_counters`` when
+    the health sentinel is on, and optionally the ``tracing`` wall-time
+    table as ``time/*`` keys.
     Between drains the telemetry costs zero host syncs.
     """
 
@@ -267,12 +270,11 @@ class MetricsCollector:
     ) -> None:
         self.include_health = include_health
         self.include_trace = include_trace
-        # the tracing table grows one entry per traced call for the life
-        # of the process; averaging the FULL history both skews time/*
-        # toward ancient steps (a warm-up compile forever dominates) and
-        # makes drain cost grow with run length, so the fold-in reads a
-        # bounded most-recent window by default. None = unbounded (the
-        # old behavior).
+        # averaging everything the tracing table keeps
+        # (tracing.TRACE_HISTORY calls a key) skews time/* toward old
+        # steps (a warm-up compile dominates for a thousand steps), so
+        # the fold-in reads a shorter most-recent window by default.
+        # None = all the table keeps.
         self.trace_max_history = trace_max_history
 
     def drain(self, state: Any) -> dict[str, Any]:
@@ -286,9 +288,13 @@ class MetricsCollector:
         kstate = getattr(state, 'kfac_state', state)
         record: dict[str, Any] = {}
         metrics = getattr(kstate, 'metrics', None)
+        refresh = getattr(kstate, 'refresh', None)
+        pulled = jax.device_get({
+            'step': kstate.step if metrics is not None else None,
+            'scalars': metrics.scalars if metrics is not None else None,
+            'refresh': refresh,
+        })
         if metrics is not None:
-            pulled = jax.device_get(
-                {'step': kstate.step, 'scalars': metrics.scalars})
             record['step'] = int(pulled['step'])
             record.update({
                 k: float(v)
@@ -297,6 +303,11 @@ class MetricsCollector:
         if self.include_health:
             from kfac_tpu import tracing
             record.update(tracing.health_counters(kstate))
+        if refresh is not None:
+            from kfac_tpu.parallel import kaisa
+            # {} until a refresh has filled the counters: no key, rather
+            # than a zero residual that reads as a healthy solve
+            record.update(kaisa.refresh_totals(pulled['refresh']))
         if self.include_trace:
             from kfac_tpu import tracing
             trace = tracing.get_trace(

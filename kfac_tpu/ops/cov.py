@@ -15,6 +15,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from kfac_tpu import tracing
+
 
 def append_bias_ones(x: jax.Array) -> jax.Array:
     """Append a column of ones to the last dimension of ``x``.
@@ -240,12 +242,17 @@ def conv2d_a_factor(
     """
     if dtype is not None:
         a = a.astype(dtype)
-    patches = extract_patches_nhwc(a, kernel_size, strides, padding)
-    spatial_size = patches.shape[1] * patches.shape[2]
-    rows = patches.reshape(-1, patches.shape[-1])
-    if has_bias:
-        rows = append_bias_ones(rows)
-    rows = rows / spatial_size
+    # the scope holds everything between the activations and the
+    # covariance's operand: XLA rewrites most of the identity-kernel
+    # convolutions into window copies fused under the division's root, so
+    # the extraction alone keeps the name for a few layers only
+    with tracing.capture_scope('patches'):
+        patches = extract_patches_nhwc(a, kernel_size, strides, padding)
+        spatial_size = patches.shape[1] * patches.shape[2]
+        rows = patches.reshape(-1, patches.shape[-1])
+        if has_bias:
+            rows = append_bias_ones(rows)
+        rows = rows / spatial_size
     return get_cov(rows)
 
 

@@ -238,12 +238,19 @@ class NewtonSchulzInfo(NamedTuple):
     ``inverse``: the damped inverse (inv_dtype); ``residual``: final
     relative identity residual ``||I - M X||_F / sqrt(d)`` (fp32 scalar);
     ``iterations``: matmul-pair iterations actually executed (int32 scalar,
-    <= the cap when the tolerance or the fp32 floor was reached early).
+    <= the cap when the tolerance or the fp32 floor was reached early);
+    ``warm``: the warm start ``x0`` passed its up-front test and the
+    iteration began from it (bool scalar; False without an ``x0``);
+    ``restarted``: that warm start was then abandoned, and the solve
+    started over from the cold init (bool scalar: ``warm & ~restarted``
+    is a warm start that paid off).
     """
 
     inverse: jax.Array
     residual: jax.Array
     iterations: jax.Array
+    warm: jax.Array
+    restarted: jax.Array
 
 
 def newton_schulz_inverse_info(
@@ -428,15 +435,20 @@ def newton_schulz_inverse_info(
                 lambda n, c: jnp.where(active, n, c), body(carry), carry
             ), None
 
-        (x, _, resid, _, k, _), _ = jax.lax.scan(
+        (x, _, resid, _, k, on_probation), _ = jax.lax.scan(
             scan_body, init, None, length=max_iters
         )
     else:
-        x, _, resid, _, k, _ = jax.lax.while_loop(cond, body, init)
+        x, _, resid, _, k, on_probation = jax.lax.while_loop(
+            cond, body, init
+        )
     return NewtonSchulzInfo(
         inverse=x.astype(inv_dtype),
         residual=resid,
         iterations=jnp.asarray(k, jnp.int32),
+        warm=use_warm,
+        # probation starts as ``use_warm`` and ends only at a restart
+        restarted=use_warm & ~on_probation,
     )
 
 
@@ -486,9 +498,9 @@ def damped_inverse(
     residual exceeds ``NS_FALLBACK_RESIDUAL``, i.e. the factor was too
     ill-conditioned for the fp32 iteration). Note ``'auto'`` under ``vmap``
     lowers the cond to a select that executes BOTH branches batched; for
-    stacked/batched callers use :func:`batched_damped_inverse_auto`, whose
-    single scalar cond pays the Cholesky only when some slot actually
-    needs it (the stacked KAISA engine does this).
+    stacked/batched callers use :func:`batched_damped_inverse_auto_info`,
+    whose single scalar cond pays the Cholesky only when some slot
+    actually needs it (the stacked KAISA engine does this).
     """
     if solver == 'newton_schulz':
         return newton_schulz_inverse(
@@ -508,13 +520,13 @@ def damped_inverse(
     return compute_inverse(factor, damping, inv_dtype)
 
 
-def batched_damped_inverse_auto(
+def batched_damped_inverse_auto_info(
     stack: jax.Array,
     damping: float | jax.Array,
     inv_dtype: jnp.dtype = jnp.float32,
     iters: int = 40,
     x0: jax.Array | None = None,
-) -> jax.Array:
+) -> NewtonSchulzInfo:
     """Batched ``'auto'`` inverse paying Cholesky only when NS fails.
 
     ``vmap(damped_inverse(..., 'auto'))`` lowers the per-matrix
@@ -529,6 +541,11 @@ def batched_damped_inverse_auto(
 
     ``damping`` may be a scalar or a per-slot ``(n,)`` vector (per-layer
     escalated damping under factor quarantine) — broadcast into the vmap.
+
+    Returns the batched Newton-Schulz pass's :class:`NewtonSchulzInfo`
+    with ``inverse`` replaced by the served stack: the other fields stay
+    the iteration's own (a slot served by Cholesky keeps the residual
+    that condemned it).
     """
     dmp = jnp.broadcast_to(
         jnp.asarray(damping, jnp.float32), stack.shape[:-2]
@@ -554,7 +571,7 @@ def batched_damped_inverse_auto(
         return jnp.where(bad[:, None, None], chol, infos.inverse)
 
     out = jax.lax.cond(jnp.any(bad), fallback, lambda _: infos.inverse, None)
-    return out.astype(inv_dtype)
+    return infos._replace(inverse=out.astype(inv_dtype))
 
 
 def eigen_preconditioned_grad(

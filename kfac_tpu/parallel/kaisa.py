@@ -28,10 +28,12 @@ fraction buys in the reference (kfac/enums.py:40-54).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from kfac_tpu import assignment as assignment_lib
@@ -281,6 +283,109 @@ class DistKFACState(NamedTuple):
     # replicated, shaped by the host-side chunk plan
     # (``_plan_compression``).
     comp_ef: Any = None
+    # what the last Newton-Schulz refresh (``update_inverses``) reported of
+    # itself: a :class:`RefreshState`, ONE float32 leaf. ``None`` where no
+    # synchronous Newton-Schulz refresh runs (the eigen method, the
+    # Cholesky solver, the async refresh modes). Ephemeral like
+    # metrics/flight: ``init()`` makes it, no checkpoint holds it. Read
+    # with :meth:`DistributedKFAC.refresh_report`.
+    refresh: Any = None
+
+
+# Columns of ``RefreshState.solved``: each slot's
+# ``factors.NewtonSchulzInfo`` without the inverse (``iterations``; final
+# ``residual``; ``warm``: the previous inverse was accepted as the start;
+# ``restarted``: and then abandoned for the cold start).
+REFRESH_COLUMNS = ('iterations', 'residual', 'warm', 'restarted')
+_NS_SOLVERS = ('newton_schulz', 'auto')
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=['solved'], meta_fields=['buckets'],
+)
+@dataclasses.dataclass(frozen=True)
+class RefreshState:
+    """``DistKFACState.refresh``. ``solved``: ``(slots, 4)`` float32, a
+    row a slot and :data:`REFRESH_COLUMNS` across, ``iterations`` -1 in
+    every row until a refresh has filled it. ``buckets`` is static aux
+    data (as ``MetricsState.keys``: the layout travels with the state and
+    costs the device nothing): ``(side, key, padded, live)`` for every
+    bucket of ``DistributedKFAC.a_store`` and then of ``g_store``, in the
+    order of the rows; a bucket's first ``live`` slots hold a layer, the
+    rest identity padding."""
+
+    buckets: tuple[tuple[str, str, int, int], ...]
+    solved: jax.Array
+
+
+def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
+    """A :class:`RefreshState` on the host (one ``device_get``), bucket by
+    bucket: ``side``, ``key``, the live slots' ``iterations``,
+    ``warm_starts``, ``restarts`` and ``worst_residual``, and the
+    ``trips`` its loop ran (the vmapped ``while_loop`` runs every slot of
+    a device's block until the slowest is done: the largest
+    ``iterations`` of all its slots; identity padding converges in 0 or,
+    warm-started across a damping change, in one or two). Empty while no
+    refresh has filled the array."""
+    rows = np.asarray(jax.device_get(refresh.solved), np.float64)
+    col = dict(zip(REFRESH_COLUMNS, rows.T))
+    if not (col['iterations'] >= 0).any():
+        return []
+    out, start = [], 0
+    for side, key, padded, live in refresh.buckets:
+        its = col['iterations'][start:start + live]
+        out.append({
+            'side': side,
+            'key': key,
+            'iterations': [int(v) for v in its],
+            'trips': int(col['iterations'][start:start + padded].max()),
+            'warm_starts': int(col['warm'][start:start + live].sum()),
+            'restarts': int(col['restarted'][start:start + live].sum()),
+            # np.max, not max(): a NaN residual has to show
+            'worst_residual': float(
+                np.max(col['residual'][start:start + live])
+            ),
+        })
+        start += padded
+    return out
+
+
+def refresh_totals(refresh: RefreshState) -> dict[str, float]:
+    """Totals of a :class:`RefreshState` (one ``device_get``), as flat
+    ``refresh/*`` keys for a metrics record; ``{}`` while no refresh has
+    filled it:
+
+    - ``refresh/slots``: slots that hold a layer;
+    - ``refresh/iterations``: Newton-Schulz iterations summed over them:
+      the useful work;
+    - ``refresh/trips``: loop trips the device executed: buckets are
+      solved one after another, each until its slowest slot is done, so
+      the sum over buckets of the largest ``iterations`` of each;
+    - ``refresh/warm_starts``, ``refresh/restarts``: slots whose previous
+      inverse was accepted as the start, and those of them that were
+      restarted cold;
+    - ``refresh/worst_residual``: the largest final residual (NaN if any
+      slot's is).
+    """
+    return _refresh_totals(_refresh_by_bucket(refresh))
+
+
+def _refresh_totals(buckets: list[dict[str, Any]]) -> dict[str, float]:
+    if not buckets:
+        return {}
+    return {
+        'refresh/slots': float(sum(len(b['iterations']) for b in buckets)),
+        'refresh/iterations': float(
+            sum(sum(b['iterations']) for b in buckets)
+        ),
+        'refresh/trips': float(sum(b['trips'] for b in buckets)),
+        'refresh/warm_starts': float(sum(b['warm_starts'] for b in buckets)),
+        'refresh/restarts': float(sum(b['restarts'] for b in buckets)),
+        'refresh/worst_residual': float(
+            np.max([b['worst_residual'] for b in buckets])
+        ),
+    }
 
 
 @dataclasses.dataclass
@@ -384,12 +489,21 @@ class DistributedKFAC:
                 stacklevel=2,
             )
         # inverse_solver='auto' is served by
-        # factors.batched_damped_inverse_auto: one scalar runtime cond per
-        # device-local block, so the batched Cholesky runs only when some
+        # factors.batched_damped_inverse_auto_info: one scalar runtime cond
+        # per device-local block, so the batched Cholesky runs only when some
         # slot's Newton-Schulz residual fails (it used to be a vmapped
         # per-slot cond -> select paying both branches unconditionally,
         # which warranted a TPUPerformanceWarning here).
         self._plan_async()
+        # the synchronous refresh reports on itself where it is a
+        # Newton-Schulz solve (``DistKFACState.refresh``); the async modes
+        # refresh elsewhere (a shadow slice a step, the host's LAPACK) and
+        # carry no counters rather than stale ones
+        self._ns_refresh = (
+            not self._eigen
+            and self.config.inverse_solver in _NS_SOLVERS
+            and self._async_mode is None
+        )
         self._plan_compression()
         self._plan_offload()
 
@@ -547,6 +661,10 @@ class DistributedKFAC:
             flight=flight_sh,
             shadow=shadow_sh,
             comp_ef=comp_ef_sh,
+            refresh=(
+                RefreshState(self._refresh_buckets(), rep)
+                if self._ns_refresh else None
+            ),
         )
 
     # ----------------------------------------------------------------- init
@@ -630,6 +748,14 @@ class DistributedKFAC:
                     if cfg.flight is not None else None
                 ),
                 comp_ef=comp_ef,
+                refresh=(
+                    self._pack_refresh([
+                        # iterations -1: no refresh has filled the row
+                        jnp.zeros((sb.padded, 4), jnp.float32).at[:, 0].set(-1)
+                        for sb in self.a_store + self.g_store
+                    ])
+                    if self._ns_refresh else None
+                ),
             )
 
         def build_with_shadow() -> DistKFACState:
@@ -1000,33 +1126,54 @@ class DistributedKFAC:
 
     def _sharded_inv(
         self, stack: jax.Array, damping, prev: jax.Array | None = None
-    ) -> jax.Array:
+    ) -> tuple[jax.Array, jax.Array | None]:
         """Batched sharded damped inverse; ``prev`` (the resident inverse
         stack) warm-starts Newton-Schulz per slot — safeguarded inside
         the solver, so a fresh state's zero inverses cold-start.
         ``damping`` may be a scalar or a per-slot (L,) vector (per-layer
         escalated damping under factor quarantine) — the vector rides the
-        shard_map with the same slot sharding as the stack."""
+        shard_map with the same slot sharding as the stack.
+
+        Returns the inverse stack and, of a Newton-Schulz solve, what each
+        slot's solve reported: an ``(L, 4)`` float32 array,
+        :data:`REFRESH_COLUMNS` across (``None`` under the Cholesky
+        solver)."""
         dmp = jnp.broadcast_to(
             jnp.asarray(damping, jnp.float32), stack.shape[:1]
         )
+        solver = self.config.inverse_solver
+        iters = self.config.newton_schulz_iters
 
         def local(block, prev_block, dmp_block):
-            if self.config.inverse_solver == 'auto':
+            if solver == 'auto':
                 # one scalar cond per device-local block: Cholesky runs
                 # at runtime only when some slot's NS residual fails —
                 # not the vmapped per-slot cond that lowers to a
                 # pay-both-branches select
-                return factors_lib.batched_damped_inverse_auto(
-                    block, dmp_block, jnp.float32,
-                    self.config.newton_schulz_iters, x0=prev_block,
+                info = factors_lib.batched_damped_inverse_auto_info(
+                    block, dmp_block, jnp.float32, iters, x0=prev_block,
                 )
-            return jax.vmap(
-                lambda m, w, dm: factors_lib.damped_inverse(
-                    m, dm, jnp.float32, self.config.inverse_solver,
-                    self.config.newton_schulz_iters, x0=w,
-                )
-            )(block, prev_block, dmp_block)
+            elif solver == 'newton_schulz':
+                info = jax.vmap(
+                    lambda m, w, dm: factors_lib.newton_schulz_inverse_info(
+                        m, dm, jnp.float32, max_iters=iters, x0=w,
+                    )
+                )(block, prev_block, dmp_block)
+            else:
+                return jax.vmap(
+                    lambda m, w, dm: factors_lib.damped_inverse(
+                        m, dm, jnp.float32, solver, iters, x0=w,
+                    )
+                )(block, prev_block, dmp_block)
+            return info.inverse, jnp.stack(
+                [
+                    v.astype(jnp.float32) for v in (
+                        info.iterations, info.residual, info.warm,
+                        info.restarted,
+                    )
+                ],
+                axis=-1,
+            )
 
         if prev is None:
             prev = jnp.zeros_like(stack)
@@ -1035,10 +1182,11 @@ class DistributedKFAC:
         # to a bf16 factor dtype would inflate the warm residual by
         # eps_bf16 * kappa and reject the warm start exactly in the
         # high-kappa regime where it saves the most
-        return jax.shard_map(
+        out = jax.shard_map(
             local, mesh=self.mesh, in_specs=(spec, spec, spec),
-            out_specs=spec,
+            out_specs=(spec, spec) if solver in _NS_SOLVERS else spec,
         )(stack, prev, dmp)
+        return out if solver in _NS_SOLVERS else (out, None)
 
     @tracing.scope('dist_kfac.update_inverses')
     def update_inverses(self, state: DistKFACState) -> DistKFACState:
@@ -1129,14 +1277,17 @@ class DistributedKFAC:
             )
         else:
             a_inv, g_inv = {}, {}
+            solved = []  # per bucket, A store then G store: (L, 4)
 
             def side(store, side_state, prev, out, ok_slots):
                 for sb in store:
-                    cand = self._sharded_inv(
+                    cand, told = self._sharded_inv(
                         side_state[sb.key],
                         slot_damping(sb.layers, sb.padded),
                         prev=prev[sb.key],
-                    ).astype(cfg.inv_dtype)
+                    )
+                    cand = cand.astype(cfg.inv_dtype)
+                    solved.append(told)
                     if hc is not None:
                         okv = jnp.isfinite(cand).all(axis=(-2, -1))
                         ok_slots[sb.key] = okv
@@ -1151,6 +1302,8 @@ class DistributedKFAC:
                 a_inv=a_inv, g_inv=g_inv,
                 inv_damping=jnp.asarray(damping, jnp.float32),
             )
+            if self._ns_refresh:
+                state = state._replace(refresh=self._pack_refresh(solved))
         ok_layer: dict[str, jax.Array] = {}
         if hc is not None:
             # degradation counter: a refresh is quarantined when it ran
@@ -1176,6 +1329,52 @@ class DistributedKFAC:
                     ms.last_inv_step, ms.names, touched, state.step)))
         return state
 
+    def _refresh_buckets(self) -> tuple[tuple[str, str, int, int], ...]:
+        """``RefreshState.buckets``: the A store's then the G store's."""
+        return tuple(
+            (side, sb.key, sb.padded, len(sb.layers))
+            for side, store in (('a', self.a_store), ('g', self.g_store))
+            for sb in store
+        )
+
+    def _pack_refresh(self, solved: list[jax.Array]) -> RefreshState:
+        """``DistKFACState.refresh`` from what ``_sharded_inv`` returned
+        for every bucket of the A store and then of the G store."""
+        return RefreshState(
+            self._refresh_buckets(),
+            jax.lax.with_sharding_constraint(
+                jnp.concatenate(solved), NamedSharding(self.mesh, P())
+            ),
+        )
+
+    def refresh_report(self, state: DistKFACState) -> dict[str, Any]:
+        """What the last inverse refresh reported of itself, on the host
+        (a few KB off the device; the solve computes it anyway).
+
+        ``{'buckets': {'a': {key: {...}}, 'g': {...}}, 'totals': {...}}``.
+        Per bucket: ``iterations`` of each layer's slot (in the order of
+        the store's ``layers``); ``trips``, the loop trips the device
+        executed (the vmapped ``while_loop`` runs every slot of a block
+        until its slowest is done); ``warm_starts`` accepted and
+        ``restarts`` among them; ``worst_residual``. ``totals``:
+        :func:`refresh_totals` without the ``refresh/`` prefix. ``{}``
+        where the state carries no counters (``DistKFACState.refresh``)
+        and until the first refresh has filled them.
+        """
+        if state.refresh is None:
+            return {}
+        by_bucket = _refresh_by_bucket(state.refresh)
+        if not by_bucket:
+            return {}
+        totals = _refresh_totals(by_bucket)
+        buckets: dict[str, dict[str, Any]] = {'a': {}, 'g': {}}
+        for got in by_bucket:
+            buckets[got.pop('side')][got.pop('key')] = got
+        return {
+            'buckets': buckets,
+            'totals': {k.split('/', 1)[1]: v for k, v in totals.items()},
+        }
+
     def inverse_residuals(
         self, state: DistKFACState
     ) -> dict[str, dict[str, jax.Array]]:
@@ -1183,12 +1382,12 @@ class DistributedKFAC:
         inverses: ``||I - (F + damping*I) F_inv||_F / sqrt(d)``.
 
         Out-of-band quality monitoring for the stacked INVERSE engine:
-        the vmapped ``'newton_schulz'`` solve keeps no per-slot
-        ``NewtonSchulzInfo`` in its output, so callers sample this
+        an independent recomputation (the solve's own final residuals
+        are in :meth:`refresh_report`, for free), so callers sample this
         between steps (e.g. each ``inv_update_steps``) and alert on
         values above :data:`kfac_tpu.ops.factors.NS_FALLBACK_RESIDUAL`.
         (``'auto'`` already self-corrects in-band: its single scalar
-        runtime cond — ``factors.batched_damped_inverse_auto`` — swaps
+        runtime cond — ``factors.batched_damped_inverse_auto_info`` — swaps
         failed slots to the Cholesky inverse at build time.)
         Identity-padded slots report ~0. Returns
         ``{'a': {bucket_key: (L,)}, 'g': {...}}``; jit-friendly.

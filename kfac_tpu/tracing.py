@@ -7,10 +7,27 @@ wall times require blocking on the traced function's outputs —
 ``dist.barrier`` plays for honest distributed timings). For on-device
 profiling, stages are additionally wrapped in ``jax.named_scope`` so they
 are attributable in XLA profiler traces.
+
+The names a profile of a K-FAC step carries, all defined here (how to read
+them: docs/OBSERVABILITY.md "A step that reports on itself"):
+
+- device scopes (``jax.named_scope``: they reach the compiled programs'
+  ``op_name`` metadata; a TPU trace's events carry no scope, so a reducer
+  joins the two by instruction name): the engine entry points
+  (``dist_kfac.step`` / ``.update_factors`` / ``.update_inverses`` /
+  ``.precondition``, :func:`scope`) and the capture layer's two sides,
+  :data:`CAPTURE_SCOPES` (:func:`capture_scope`);
+- host spans (``jax.profiler.TraceAnnotation``: they land on the
+  profile's ``/host:CPU`` line in the device's time base):
+  :data:`HOST_SPANS` inside ``Trainer.step`` (:func:`host_span`).
+
+None of them has a switch: with no profiler session running a span or a
+scope costs well under a microsecond a step.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 import time
@@ -20,8 +37,37 @@ import jax
 
 F = TypeVar('F', bound=Callable[..., Any])
 
-_func_traces: dict[str, list[float]] = {}
+# Wall times a key keeps: the table is read over a recent window
+# (``MetricsCollector`` takes the last 256), so a process that steps for
+# weeks holds this many floats per decorated function and no more.
+TRACE_HISTORY = 1024
+
+_func_traces: dict[str, collections.deque[float]] = collections.defaultdict(
+    lambda: collections.deque(maxlen=TRACE_HISTORY)
+)
 _force_sync: bool = False
+
+# Device scopes of the capture layer (kfac_tpu/layers/capture.py): the A
+# side runs in the forward pass under the module interceptor, the G side
+# in the backward pass under the g-taps' vjp rule. 'patches' nests under
+# the A side ('kfac.capture_a/patches'): the convolution helper's patch
+# rows (im2col, the reshape to rows, the bias column, the scaling), all
+# of a convolution's A side but the covariance itself.
+CAPTURE_SCOPES = {
+    'a': 'kfac.capture_a',
+    'g': 'kfac.capture_g',
+    'patches': 'patches',
+}
+
+# Host spans inside one Trainer step, in order: what runs before the jitted
+# call (async-inverse and offload pumps, the cadence decision), the call
+# itself, and what runs after it (health warnings, checkpoint autopilot,
+# fleet controller).
+HOST_SPANS = {
+    'pre_step': 'kfac.host.pre_step',
+    'launch': 'kfac.host.launch',
+    'post_step': 'kfac.host.post_step',
+}
 
 logger = logging.getLogger(__name__)
 
@@ -90,7 +136,7 @@ def trace(sync: bool = False, name: str | None = None) -> Callable[[F], F]:
                 out = func(*args, **kwargs)
             if sync or _force_sync:
                 _block_all(out)
-            _func_traces.setdefault(key, []).append(time.perf_counter() - start)
+            _func_traces[key].append(time.perf_counter() - start)
             return out
 
         wrapped.__kfac_scope__ = key  # type: ignore[attr-defined]
@@ -121,15 +167,33 @@ def scope(name: str) -> Callable[[F], F]:
     return decorator
 
 
+def capture_scope(side: str):
+    """``jax.named_scope`` of one side of the capture layer: ``'a'``,
+    ``'g'``, or ``'patches'`` inside ``'a'`` (:data:`CAPTURE_SCOPES`)."""
+    return jax.named_scope(CAPTURE_SCOPES[side])
+
+
+def host_span(part: str, step: int | None):
+    """``jax.profiler.TraceAnnotation`` of one part of a Trainer step
+    (:data:`HOST_SPANS`), carrying ``step`` so that the spans of one step
+    share an identifier with its ``StepTraceAnnotation('train',
+    step_num=step)``. ``step`` is ``None`` where the host does not know it
+    (after a compiled scan): the span then carries none."""
+    if step is None:
+        return jax.profiler.TraceAnnotation(HOST_SPANS[part])
+    return jax.profiler.TraceAnnotation(HOST_SPANS[part], step=step)
+
+
 def get_trace(
     average: bool = True,
     max_history: int | None = None,
 ) -> dict[str, float]:
-    """Return recorded times per function, averaged or summed over a bounded
-    history (reference kfac/tracing.py:24-47)."""
+    """Return recorded times per function, averaged or summed over the
+    last ``max_history`` calls (reference kfac/tracing.py:24-47); ``None``
+    takes all that the table keeps, :data:`TRACE_HISTORY` calls a key."""
     out: dict[str, float] = {}
     for key, times in _func_traces.items():
-        window = times[-max_history:] if max_history is not None else times
+        window = times if max_history is None else list(times)[-max_history:]
         if not window:
             continue
         out[key] = sum(window) / len(window) if average else sum(window)

@@ -575,21 +575,34 @@ class Trainer:
         only unless ``tracing.force_sync`` is on) and annotated with
         ``jax.profiler.StepTraceAnnotation`` so profiler captures group
         device activity per training step.
+
+        Inside it, three host spans (``tracing.HOST_SPANS``) that carry the
+        same step number split the call into what runs before the jitted
+        program is launched, the launch, and what runs after: in a
+        profile they say which of them the device waited for.
         """
-        self._sync_step_count(state)
-        state = self._drive_async(state, self._step_count)
-        state = self._drive_offload(state, self._step_count)
-        with jax.profiler.StepTraceAnnotation(
-            'train', step_num=self._step_count
-        ):
-            if self.kfac is not None and self._capture_now():
-                out = self._jit_with_stats(state, batch)
-            else:
-                out = self._jit_no_stats(state, batch)
+        if self._step_count is None:
+            # a first step, or one after a compiled scan: the one call of
+            # a step that can block on a device read. It is pre_step's
+            # too, in a span of its own that cannot carry the number yet
+            with tracing.host_span('pre_step', None):
+                self._sync_step_count(state)
+        step = self._step_count
+        with tracing.host_span('pre_step', step):
+            state = self._drive_async(state, step)
+            state = self._drive_offload(state, step)
+            capture = self.kfac is not None and self._capture_now()
+        with jax.profiler.StepTraceAnnotation('train', step_num=step):
+            with tracing.host_span('launch', step):
+                if capture:
+                    out = self._jit_with_stats(state, batch)
+                else:
+                    out = self._jit_no_stats(state, batch)
         self._step_count += 1
-        self._maybe_warn(out[0])
-        self._drive_checkpoints(out[0])
-        new_state = self._drive_fleet(out[0])
+        with tracing.host_span('post_step', step):
+            self._maybe_warn(out[0])
+            self._drive_checkpoints(out[0])
+            new_state = self._drive_fleet(out[0])
         if new_state is not out[0]:
             out = (new_state, out[1])
         return out
@@ -708,7 +721,8 @@ class Trainer:
             self._jit_scan = self._watched(
                 'trainer.scan_steps', jax.jit(run, donate_argnums=donate)
             )
-        state, losses = self._jit_scan(state, batches)
+        with tracing.host_span('launch', self._step_count):
+            state, losses = self._jit_scan(state, batches)
         self._step_count = None  # host mirror resyncs from the device step
         self._drive_checkpoints(state)
         state = self._drive_fleet(state)
@@ -824,14 +838,15 @@ class Trainer:
         loss = acc['loss'] / n
         state = self._drive_async(state, self._step_count)
         state = self._drive_offload(state, self._step_count)
-        new_state = self._jit_apply_kfac(
-            state,
-            grads_avg,
-            stats_avg,
-            acc['model_state'],
-            loss,
-            with_stats=acc['capture'],
-        )
+        with tracing.host_span('launch', self._step_count):
+            new_state = self._jit_apply_kfac(
+                state,
+                grads_avg,
+                stats_avg,
+                acc['model_state'],
+                loss,
+                with_stats=acc['capture'],
+            )
         self._accum = None
         self._step_count += 1
         self._maybe_warn(new_state)
@@ -941,7 +956,10 @@ class Trainer:
                 jax.jit(accum, static_argnames=('with_stats',)),
                 static_argnames=('with_stats',),
             )
-        out = self._jit_accum_scan(state, microbatches, with_stats=capture_now)
+        with tracing.host_span('launch', self._step_count):
+            out = self._jit_accum_scan(
+                state, microbatches, with_stats=capture_now
+            )
         self._step_count += 1
         self._maybe_warn(out[0])
         self._drive_checkpoints(out[0])

@@ -279,7 +279,7 @@ def test_newton_schulz_warm_start_outside_the_basin_restarts_cold():
 
 
 def test_batched_auto_inverse_single_branch_per_slot_fallback():
-    """batched_damped_inverse_auto: well-conditioned slots get the NS
+    """batched_damped_inverse_auto_info: well-conditioned slots get the NS
     inverse bitwise (the scalar cond takes the cheap branch when ALL
     slots converge); with one pathological slot in the stack, only that
     slot becomes the Cholesky inverse and the good slot keeps NS."""
@@ -292,7 +292,9 @@ def test_batched_auto_inverse_single_branch_per_slot_fallback():
 
     # all-good stack: bitwise the batched NS result
     stack = jnp.stack([good, good])
-    out = factors.batched_damped_inverse_auto(stack, 1e-5, iters=100)
+    out = factors.batched_damped_inverse_auto_info(
+        stack, 1e-5, iters=100
+    ).inverse
     ns_good = np.asarray(
         factors.newton_schulz_inverse(good, 1e-5, iters=100)
     )
@@ -301,9 +303,9 @@ def test_batched_auto_inverse_single_branch_per_slot_fallback():
     # mixed stack: per-slot selection. The good slot is allclose rather
     # than bitwise: the batched while_loop iterates until every lane
     # stops, so it may take extra (stable) NS trips vs the solo run.
-    out = factors.batched_damped_inverse_auto(
+    out = factors.batched_damped_inverse_auto_info(
         jnp.stack([good, bad]), 1e-5, iters=100
-    )
+    ).inverse
     np.testing.assert_allclose(
         np.asarray(out[0]), ns_good, rtol=1e-4, atol=1e-5
     )

@@ -535,7 +535,7 @@ def test_host_eigh_impl_matches_xla_in_stacked_engine():
 def test_auto_solver_stacked_single_runtime_branch():
     """inverse_solver='auto' on the stacked engine runs the batched
     Cholesky behind ONE scalar runtime cond per device-local block
-    (factors.batched_damped_inverse_auto) — no construction-time
+    (factors.batched_damped_inverse_auto_info) — no construction-time
     TPUPerformanceWarning anymore, and on well-conditioned factors the
     preconditioned grads match the pure newton_schulz engine."""
     import warnings as warnings_mod

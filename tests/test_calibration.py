@@ -1,11 +1,7 @@
-"""Measurement-truth layer, host side: trace attribution + calibration.
+"""Measurement-truth layer, host side: calibration.
 
-Three surfaces, all CPU-runnable:
+Two surfaces, both CPU-runnable:
 
-- :mod:`kfac_tpu.observability.trace_attrib` against the committed
-  mini-trace fixture (``tests/data/mini_trace``): device-lane filtering,
-  identifier-boundary scope matching, group-id and window-fallback step
-  mapping, args-string scope fallback, totals for out-of-window events;
 - :class:`kfac_tpu.observability.calibration.CalibrationMonitor`:
   residual-ratio math (warmup, rolling window, direction-free fold
   error), the ``calib/*`` record/annotate emission contract, the
@@ -35,14 +31,12 @@ import kfac_tpu
 from kfac_tpu.autotune import model as model_lib
 from kfac_tpu.autotune import search as search_lib
 from kfac_tpu.enums import DistributedStrategy
-from kfac_tpu.observability import calibration, trace_attrib
+from kfac_tpu.observability import calibration
 from kfac_tpu.observability import flight_recorder as flight_lib
 from kfac_tpu.observability.sinks import JSONLWriter, RateLimitedLogger
 from kfac_tpu.resilience import CheckpointManager
 from kfac_tpu.warnings import reset_fleet_warnings, reset_layout_warnings
 from testing import compile_pins, models
-
-FIXTURE = os.path.join(os.path.dirname(__file__), 'data', 'mini_trace')
 
 WORLD = 8
 
@@ -58,94 +52,6 @@ def _clean_warning_state():
     yield
     reset_fleet_warnings()
     reset_layout_warnings()
-
-
-# ----------------------------------------------------- trace attribution
-
-
-def test_fixture_step_attribution_exact():
-    """The committed mini-trace parses to pinned numbers: device lanes
-    only, boundary-checked scopes, group-id + window step mapping."""
-    out = trace_attrib.step_attribution(FIXTURE)
-    assert out['n_steps'] == 2
-    assert out['n_device_events'] == 7
-    assert len(out['trace_files']) == 1
-    # step 7: group_id events, including the dist_kfac.precondition one
-    # that must NOT be miscounted as kfac.precondition (boundary check),
-    # and the host-lane kfac.update_factors impostor that must be ignored
-    assert out['steps'][7] == {
-        'dist_kfac.precondition': 0.1,
-        'kfac.precondition': 0.2,
-        'kfac.update_factors': 0.3,
-    }
-    # step 8: window-fallback (no group_id), args long_name fallback for
-    # the fusion event, and the unattributable infeed op
-    assert out['steps'][8] == {
-        'kfac.precondition': 0.05,
-        'kfac.update_inverses': 0.4,
-        'unattributed': 0.03,
-    }
-    # the out-of-window async refresh counts toward totals only
-    assert out['total_ms'] == {
-        'dist_kfac.precondition': 0.1,
-        'kfac.async_refresh': 0.8,
-        'kfac.precondition': 0.25,
-        'kfac.update_factors': 0.3,
-        'kfac.update_inverses': 0.4,
-        'unattributed': 0.03,
-    }
-    # mean over the two annotated steps, async refresh excluded
-    assert out['per_step_ms'] == {
-        'kfac.update_factors': 0.15,
-        'kfac.precondition': 0.125,
-        'dist_kfac.precondition': 0.05,
-        'kfac.update_inverses': 0.2,
-        'unattributed': 0.015,
-    }
-
-
-def test_device_breakdown_is_per_step_view():
-    assert (trace_attrib.device_breakdown_ms(FIXTURE)
-            == trace_attrib.step_attribution(FIXTURE)['per_step_ms'])
-
-
-def test_match_scope_boundary_and_depth():
-    # identifier boundary: the kfac.* substring inside dist_kfac.* does
-    # not count as a kfac.* scope entry
-    assert (trace_attrib.match_scope('jit(f)/dist_kfac.update_factors/x')
-            == 'dist_kfac.update_factors')
-    assert trace_attrib.match_scope('a_kfac.step') is None
-    # nested scopes attribute to the innermost (deepest occurrence)
-    assert (trace_attrib.match_scope('kfac.step/kfac.precondition/fusion')
-            == 'kfac.precondition')
-    assert trace_attrib.match_scope('fusion.123') is None
-
-
-def test_find_trace_files_resolution(tmp_path):
-    files = trace_attrib.find_trace_files(FIXTURE)
-    assert len(files) == 1 and files[0].endswith('trace.json.gz')
-    # a direct file path passes through
-    assert trace_attrib.find_trace_files(files[0]) == [files[0]]
-    # a dir with no traces is empty, not an error
-    assert trace_attrib.find_trace_files(tmp_path) == []
-
-
-def test_host_only_trace_yields_empty_breakdown(tmp_path):
-    """A CPU-backend capture (no device lanes) is a graceful no-op."""
-    import gzip
-
-    doc = {'traceEvents': [
-        {'ph': 'M', 'pid': 1, 'name': 'process_name',
-         'args': {'name': '/host:CPU'}},
-        {'ph': 'X', 'pid': 1, 'name': 'kfac.update_factors',
-         'ts': 0, 'dur': 100},
-    ]}
-    path = tmp_path / 'host.trace.json.gz'
-    with gzip.open(path, 'wt') as f:
-        json.dump(doc, f)
-    out = trace_attrib.step_attribution(tmp_path)
-    assert out['n_device_events'] == 0
-    assert trace_attrib.device_breakdown_ms(tmp_path) == {}
 
 
 # ------------------------------------------------------ JSONL rotation
