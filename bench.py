@@ -571,7 +571,7 @@ def _fused_kernel_probe(d: int = 256, rows: int = 512) -> dict:
     """Within-run A/B of the fused step-path kernels vs their unfused
     XLA expressions (docs/ARCHITECTURE.md "Fused step-path kernels").
 
-    Per family (cov_ema / ns / klclip): p50 wall-clock of each variant,
+    Per family (cov_ema / klclip): p50 wall-clock of each variant,
     timed back-to-back in THIS process so the comparison shares one
     host-load regime, plus per-variant device milliseconds attributed
     from a short profiler trace when the backend has device lanes
@@ -589,8 +589,6 @@ def _fused_kernel_probe(d: int = 256, rows: int = 512) -> dict:
     a = jax.random.normal(jax.random.PRNGKey(7), (rows, d), jnp.float32)
     eye = jnp.eye(d, dtype=jnp.float32)
     cov = a.T @ a / rows + 0.003 * eye
-    x0 = eye / jnp.trace(cov)
-    mx0 = cov @ x0
     gmat = 0.5 * cov + 0.1 * eye
     beta, coeff = 0.95, 0.05 / rows
 
@@ -600,11 +598,6 @@ def _fused_kernel_probe(d: int = 256, rows: int = 512) -> dict:
             preferred_element_type=jnp.float32,
         )
         return beta * f + coeff * acc
-
-    def ns_unfused(mm, x, mx):
-        y = x @ (2.0 * eye - mx)
-        my = mm @ y
-        return y, my, jnp.linalg.norm(eye - my) / jnp.sqrt(float(d))
 
     def kl_unfused(p, g):
         return p * jnp.sum(p * g)
@@ -618,10 +611,6 @@ def _fused_kernel_probe(d: int = 256, rows: int = 512) -> dict:
                     lambda f, x: pallas_cov_ema._fused(
                         f, x, beta, coeff, interpret=interp),
                     (eye, a)),
-        'ns': (ns_unfused,
-               lambda mm, x, mx: pallas_ns.fused_ns_step(
-                   mm, x, mx, interpret=interp),
-               (cov, x0, mx0)),
         'klclip': (kl_unfused, kl_fused, (cov, gmat)),
     }
 
@@ -955,7 +944,7 @@ def _obs_probe(result, out_path, reg, run, loss, opt, params, data):
 
     # fused step-path kernel A/B: fused vs unfused, same process
     _atomic_write(out_path, result)
-    _log('  fused kernel probe (cov+EMA / NS / kl-clip, fused vs unfused)')
+    _log('  fused kernel probe (cov+EMA / kl-clip, fused vs unfused)')
     result['fused_kernel_probe'] = _fused_kernel_probe()
 
     # chaos-harness SLOs: committed storm artifact, read-only

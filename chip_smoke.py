@@ -45,22 +45,19 @@ IMAGES_PER_CHIP = 32
 # kernel's error may be at most twice XLA's plus 2^-16. Where the
 # expression leaves matmul precision at the TPU's default (the
 # covariance), kernel and XLA both round f32 operands to bf16 once and
-# their errors match up to accumulation order (the factor 2). Where it is
-# f32 (Newton-Schulz) or has no matmul (kl-clip), what is left is the
-# MXU's f32 emulation and the accumulation order over up to 4,608 terms
-# (the floor). A kernel that dropped a tile, a mask or a scale is off by
-# orders of magnitude more.
+# their errors match up to accumulation order (the factor 2). Where it
+# has no matmul (kl-clip), what is left is the accumulation order over up
+# to 4,608 terms (the floor). A kernel that dropped a tile, a mask or a
+# scale is off by orders of magnitude more.
 KERNEL_TOL_FACTOR = 2.0
 KERNEL_TOL_FLOOR = 2.0 ** -16
 
 # Real shapes from ResNet-50 at 32 images per chip (see
 # DistributedKFAC.describe()): stage3 conv2's A factor reads 32*7*7 patch
 # rows 512*9 wide; the head's A factor is 2048+1 wide (ragged); the
-# 1152-wide A bucket (stage1 conv2, 128*9) stacks 4 slots; the
 # preconditioned gradients of stage3 conv2 and of the head.
 KERNEL_SHAPES = {
     'cov': [(1568, 4608), (32, 2049)],
-    'ns': [(1152,), (4, 1152)],
     'klclip': [(512, 4608), (1000, 2049)],
 }
 
@@ -91,7 +88,7 @@ def check_kernels(shapes=KERNEL_SHAPES) -> list[dict]:
     import jax
     import jax.numpy as jnp
 
-    from kfac_tpu.ops import factors, pallas_cov, pallas_ns
+    from kfac_tpu.ops import pallas_cov, pallas_ns
 
     interpret = pallas_cov.interpret_mode()
     rows: list[dict] = []
@@ -134,27 +131,6 @@ def check_kernels(shapes=KERNEL_SHAPES) -> list[dict]:
         row('sym_cov', (n, d),
             lambda a: pallas_cov.sym_cov(a, scale=1.0, interpret=interpret),
             lambda a: a.T @ a, (normal(0, (n, d)),))
-
-    def ns_fused(m, x, mx):
-        return pallas_ns.fused_ns_step(m, x, mx, interpret=interpret)
-
-    def ns_inputs(seed, d):
-        # a damped covariance and the Gershgorin cold start the solver
-        # builds from it (ops/factors.newton_schulz_inverse_info)
-        a = normal(seed, (2 * d, d))
-        m = a.T @ a / (2 * d) + 0.003 * jnp.eye(d, dtype=jnp.float32)
-        lam = jnp.max(jnp.sum(jnp.abs(m), axis=-1))
-        return m, jnp.eye(d, dtype=jnp.float32) / lam, m / lam
-
-    for shape in shapes['ns']:
-        if len(shape) == 1:
-            row('fused_ns_step', shape, ns_fused,
-                factors.newton_schulz_step, ns_inputs(1, shape[0]))
-        else:  # the stacked engine's form: one bucket's slots under vmap
-            slots = [ns_inputs(2 + i, shape[1]) for i in range(shape[0])]
-            row('vmap(fused_ns_step)', shape, jax.vmap(ns_fused),
-                jax.vmap(factors.newton_schulz_step),
-                tuple(jnp.stack(s) for s in zip(*slots)))
 
     for r_, c in shapes['klclip']:
         p, g = normal(3, (r_, c)), normal(4, (r_, c))

@@ -252,8 +252,6 @@ def check_cost_model_parity(suite: harness.Suite) -> list[core.Finding]:
 STEP_PALLAS_ALLOWLIST = frozenset({
     '_sym_cov_kernel',
     '_sym_cov_ema_kernel',
-    '_ns_xupdate_kernel',
-    '_ns_mx_resid_kernel',
     '_klclip_dot_kernel',
     '_klclip_scale_kernel',
     '_flash_kernel',

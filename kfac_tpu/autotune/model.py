@@ -89,24 +89,6 @@ def fused_cov_ema_hbm_saved(d: int) -> float:
     return 8.0 * d * d
 
 
-def fused_ns_iter_flops(d: int) -> float:
-    """MXU FLOPs of one fused Newton-Schulz iteration: two (d, d)
-    matmuls (the X-update and the MX/residual kernel), 2 d^3 each —
-    identical to the unfused count, so :data:`NS_FLOPS_PER_ITER_DIM3`
-    and the KFL205 decomposition parity are preserved by construction
-    (the fused win is HBM traffic, not FLOPs)."""
-    d_pad = _ceil_to(d, FUSED_TILE)
-    return 4.0 * float(d_pad) ** 3
-
-
-def fused_ns_iter_hbm_saved(d: int) -> float:
-    """HBM bytes one fused NS iteration avoids: the 2I - MX residual
-    operand stays in VMEM instead of round-tripping a f32 (d, d)
-    intermediate, and the in-pass residual reduction replaces the
-    separate norm pass's full reread."""
-    return 8.0 * d * d
-
-
 def fused_klclip_flops(shape: tuple[int, int]) -> float:
     """VPU FLOPs of the fused kl-clip pair on one (r, c) tensor: the
     tiled multiply-reduce (2 r c) plus the scale apply (r c)."""

@@ -42,14 +42,13 @@ ENV_VAR = 'KFAC_TPU_DISPATCH_TABLE'
 
 #: prior thresholds (the constants the gates shipped with) — the
 #: derivation's starting point and the load-or-default fallback. The
-#: fused step-path families (cov_ema, ns, klclip) start at conservative
+#: fused step-path families (cov_ema, klclip) start at conservative
 #: priors sized off the unfused kernels' win regimes; only a clean sweep
 #: moves them (docs/ARCHITECTURE.md "Fused step-path kernels").
 DEFAULTS: dict[str, Any] = {
     'cov': {'min_dim': 256, 'dtypes': ['float32']},
     'attn': {'min_sk_dense': 2048},
     'cov_ema': {'min_dim': 256, 'dtypes': ['float32']},
-    'ns': {'min_dim': 512},
     'klclip': {'min_dim': 512},
 }
 
@@ -60,7 +59,6 @@ BASELINE_SWEEP_PREFIX: dict[str, str] = {
     'cov': 'cov_dense',
     'attn': 'attn_einsum',
     'cov_ema': 'cov_ema_unfused',
-    'ns': 'ns_unfused',
     'klclip': 'klclip_unfused',
 }
 
@@ -193,7 +191,7 @@ def flash_min_sk_dense(default: int) -> int:
 
 def family_min_dim(family: str, default: int) -> int:
     """Smallest swept dim the named fused family wins at (generic
-    accessor for the cov_ema/ns/klclip gates)."""
+    accessor for the cov_ema/klclip gates)."""
     v = _get(load_tables(), family, 'min_dim')
     return int(v) if isinstance(v, (int, float)) and v > 0 else default
 
@@ -243,16 +241,15 @@ def floor_contaminated(family: str) -> str | None:
 _COV_RE = re.compile(r'^cov_(dense|pallas)_(\d+)_(f32|bf16)$')
 _ATTN_RE = re.compile(r'^attn_(einsum|flash)_s(\d+)$')
 _FUSED_RE = re.compile(
-    r'^(cov_ema|ns|klclip)_(unfused|fused)_(\d+)(?:_f32)?$'
+    r'^(cov_ema|klclip)_(unfused|fused)_(\d+)(?:_f32)?$'
 )
 _DTYPE_NAME = {'f32': 'float32', 'bf16': 'bfloat16'}
 
 #: work ~ size**exponent for each fused family's floor verdict: the
-#: cov+EMA contraction is n·d² at fixed rows, one NS iteration is two
-#: (d,d) matmuls (d³), the kl-clip contraction+apply is elementwise d²
+#: cov+EMA contraction is n·d² at fixed rows, the kl-clip
+#: contraction+apply is elementwise d²
 FUSED_WORK_EXPONENT: dict[str, float] = {
     'cov_ema': 2.0,
-    'ns': 3.0,
     'klclip': 2.0,
 }
 
@@ -380,7 +377,7 @@ def derive_tables(
             fam, impl, d = m.group(1), m.group(2), int(m.group(3))
             fused_series.setdefault(fam, {}).setdefault(impl, {})[d] = ms
     fused_out: dict[str, dict[str, Any]] = {}
-    for fam in ('cov_ema', 'ns', 'klclip'):
+    for fam in ('cov_ema', 'klclip'):
         fam_prior = dict(prior.get(fam, DEFAULTS[fam]))
         fam_min = int(fam_prior.get('min_dim', DEFAULTS[fam]['min_dim']))
         impls = fused_series.get(fam, {})

@@ -208,20 +208,21 @@ def gershgorin_condition_bound(
 # (one MXU pass, ~2^-9 per product), and the iteration's attainable
 # residual is ~kappa times that: measured on a v5e (PR 21) on factors
 # with kappa ~300, the default stalled at 1-2e-2 where HIGHEST reaches
-# 1e-6 in as many iterations. So every product of the solve — here, in
-# the fused Pallas pair and in the engines' residual monitor — names
-# this precision.
+# 1e-6 in as many iterations. So every product of the solve — here and
+# in the engines' residual monitor — names this precision.
 NS_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def newton_schulz_step(
     m: jax.Array, x: jax.Array, mx: jax.Array
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One unfused Newton-Schulz iteration on ``m = factor + damping*I``
+    """One Newton-Schulz iteration on ``m = factor + damping*I``
     with ``mx`` the cached ``m @ x``: ``(x_new, mx_new, resid)`` where
     ``x_new = x (2I - mx)``, ``mx_new = m x_new`` and ``resid =
-    ||I - mx_new||_F / sqrt(d)``. The XLA expression the fused Pallas
-    pair (:func:`kfac_tpu.ops.pallas_ns.fused_ns_step`) replaces."""
+    ||I - mx_new||_F / sqrt(d)``. XLA's own tiling of the two products
+    runs at 27-30 TFLOP/s of the six-pass f32 peak's 32.8 on a v5e (PR
+    26), level with a Mosaic pair that fused the residual in (see
+    ``pallas_ns``'s docstring for what that pair was and cost)."""
     d = m.shape[-1]
     eye = jnp.eye(d, dtype=jnp.float32)
     x_new = jnp.matmul(x, 2.0 * eye - mx, precision=NS_PRECISION)
@@ -374,22 +375,6 @@ def newton_schulz_inverse_info(
             resid, prev, k, on_probation
         )
 
-    # trace-time dispatch of the iteration body: in the fused kernel's
-    # win regime (TPU, whole tiles, artifact-backed — see
-    # pallas_ns.use_fused_ns_for) the two matmuls and the residual
-    # reduction run as the fused Pallas pair, feeding the stopping rule
-    # an identical residual; everywhere else the XLA expressions below
-    from kfac_tpu.ops import pallas_ns
-
-    use_fused = factor.ndim == 2 and pallas_ns.use_fused_ns_for(d)
-
-    def step(x, mx):
-        if use_fused:
-            return pallas_ns.fused_ns_step(
-                m, x, mx, interpret=pallas_ns.interpret_mode()
-            )
-        return newton_schulz_step(m, x, mx)
-
     x_cold = eye / lam_max
     mx_cold = m / lam_max  # == m @ x_cold, sans the matmul
     r_cold = residual(mx_cold)
@@ -402,7 +387,7 @@ def newton_schulz_inverse_info(
         x = jnp.where(restart, x_cold, x)
         mx = jnp.where(restart, mx_cold, mx)
         resid = jnp.where(restart, r_cold, resid)
-        x_new, mx_new, r_new = step(x, mx)
+        x_new, mx_new, r_new = newton_schulz_step(m, x, mx)
         return x_new, mx_new, r_new, resid, k + 1, on_probation & ~restart
 
     if x0 is not None:

@@ -1,6 +1,6 @@
 """Dispatch gate for the Pallas TPU kernels.
 
-Each kernel family (``cov``, ``cov_ema``, ``ns``, ``klclip``, ``attn``)
+Each kernel family (``cov``, ``cov_ema``, ``klclip``, ``attn``)
 dispatches on a TPU only inside the regime its ``use_*_for`` heuristic
 accepts (shape, dtype, trace context, the thresholds in
 ``dispatch_thresholds.json``). Those thresholds were derived off-chip

@@ -1,23 +1,4 @@
-"""Fused Pallas TPU kernels for the step path: Newton-Schulz iteration
-and kl-clip.
-
-**Fused NS iteration** (:func:`fused_ns_step`): the
-``newton_schulz_inverse_info`` body costs two (d, d) matmuls plus a
-residual reduction per iteration:
-
-    x_new  = x @ (2I - mx)        # mx cached from the previous step
-    mx_new = m @ x_new
-    resid  = ||I - mx_new||_F / sqrt(d)
-
-The unfused path materializes ``2I - mx`` in HBM (one d^2 write + read)
-and runs the residual as a separate elementwise+reduce pass over
-``mx_new`` (another d^2 read). The fused pair of kernels removes both:
-the first builds each ``2I - mx`` tile in VMEM inside the matmul's
-reduction loop (the identity is synthesized from the grid indices, never
-stored), the second accumulates the identity-residual sum-of-squares in
-the epilogue of the ``m @ x_new`` tile it just produced, while the tile
-is still VMEM-resident. The stopping rule in
-``newton_schulz_inverse_info`` consumes the returned residual unchanged.
+"""Fused Pallas TPU kernels for the step path: kl-clip.
 
 **Fused kl-clip** (:func:`fused_klclip_dot` / :func:`fused_klclip_scale`):
 the second-moment contraction ``sum(pmat * gmat)`` and the scale
@@ -30,17 +11,27 @@ cross-layer, so it cannot fuse into any per-layer kernel.
 
 Equivalence contract (pinned by tests/ops/test_fused_kernels.py in the
 interpreter, and on the chip by ``chip_smoke.py``): f32 allclose to the
-unfused expressions above (``ops.factors.newton_schulz_step`` for the
-iteration, contracted at f32 precision on both sides), for dense and
-stacked (vmapped) factors.
+unfused expressions above, contracted at f32 precision on both sides,
+for dense and stacked (vmapped) tensors.
 
-Dispatch: families ``ns`` and ``klclip`` in the committed threshold
-artifact (:mod:`kfac_tpu.ops.dispatch_tables`); the NS kernels
-additionally require whole (TILE, TILE) tiling (``d % TILE == 0``) so
-the identity synthesis never needs a padding mask inside the iteration
-loop. Off-TPU, below threshold, in partial-manual trace contexts, or
-under a contaminated baseline sweep the callers fall back to the
-unfused expressions.
+Dispatch: family ``klclip`` in the committed threshold artifact
+(:mod:`kfac_tpu.ops.dispatch_tables`). Off-TPU, below threshold, in
+partial-manual trace contexts, or under a contaminated baseline sweep
+the callers fall back to the unfused expressions.
+
+The module keeps its name from the fused Newton-Schulz pair it also held
+until PR 26 (``_ns_xupdate_kernel`` / ``_ns_mx_resid_kernel``: both
+products and the residual of one iteration in two Mosaic kernels). On a
+v5e that pair in its 128^3 blocks ran at 8.9 TFLOP/s, 27% of the
+six-pass f32 peak, against 27-30 for XLA's own tiling of the same two
+``HIGHEST`` products (``ops.factors.newton_schulz_step``); in blocks of
+640 to 1,152 it reached 30-31, 4-7% ahead of XLA at four widths and level
+elsewhere, which was inside the run-to-run spread of every end-to-end
+metric and cost a block chooser, a VMEM override and megabytes of
+unrolled kernel code a bucket. So the iteration is XLA's at every width.
+One thing the pair did buy: at 3,200 wide a cold solve reached residual
+6e-6 to 8e-6 where XLA's tiling of the same product stops at 1.9e-5
+(``PERF.md`` section 6, PR 26, has the table; section 7 the question).
 """
 
 from __future__ import annotations
@@ -52,145 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kfac_tpu.ops.factors import NS_PRECISION
 from kfac_tpu.ops.pallas_cov import TILE, _pad_to, interpret_mode
-
-
-def _eye_tile(i, j):
-    """The (TILE, TILE) block (i, j) of the identity, synthesized from
-    grid indices — never read from HBM."""
-    gr = i * TILE + jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 0)
-    gc = j * TILE + jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 1)
-    return (gr == gc).astype(jnp.float32)
-
-
-def _ns_xupdate_kernel(x_ref, mx_ref, out_ref):
-    """``x_new[i,j] = sum_k x[i,k] @ (2I - mx)[k,j]`` with the
-    ``2I - mx`` tile built in VMEM inside the reduction loop."""
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    y = 2.0 * _eye_tile(k, j) - mx_ref[:]
-    out_ref[:] += jax.lax.dot_general(
-        x_ref[:], y,
-        (((1,), (0,)), ((), ())),
-        # Mosaic's default contraction rounds f32 operands to bf16 too
-        precision=NS_PRECISION,
-        preferred_element_type=jnp.float32,
-    )
-
-
-def _ns_mx_resid_kernel(m_ref, x_ref, out_ref, part_ref):
-    """``mx_new[i,j] = sum_k m[i,k] @ x_new[k,j]`` with the identity
-    residual ``(I - mx_new)^2`` reduced in the epilogue while the
-    finished tile is VMEM-resident. Each (i, j) tile writes its own
-    lane-shaped (1, TILE) partial — Mosaic cannot store a scalar to
-    VMEM — and the caller sums the partials."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    out_ref[:] += jax.lax.dot_general(
-        m_ref[:], x_ref[:],
-        (((1,), (0,)), ((), ())),
-        # Mosaic's default contraction rounds f32 operands to bf16 too
-        precision=NS_PRECISION,
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _resid():
-        delta = _eye_tile(i, j) - out_ref[:]
-        part_ref[:] = jnp.sum(delta * delta, axis=0, keepdims=True)
-
-
-@functools.partial(jax.jit, static_argnames=('interpret',))
-def fused_ns_step(
-    m: jax.Array,
-    x: jax.Array,
-    mx: jax.Array,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One fused Newton-Schulz iteration: ``(x_new, mx_new, resid)``
-    matching the unfused body of ``newton_schulz_inverse_info`` (f32).
-
-    Requires ``d % TILE == 0`` (the gate enforces it); all three inputs
-    are (d, d) f32.
-    """
-    d = m.shape[-1]
-    nb = d // TILE
-    grid = (nb, nb, nb)
-    # inside a vma-checked shard_map (the stacked engine's sharded
-    # inverse) the outputs vary over the same mesh axes as the factor
-    vma = jax.typeof(m).vma
-    tile_spec = pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, j))
-
-    x_new = pl.pallas_call(
-        _ns_xupdate_kernel,
-        out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32, vma=vma),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, k)),
-            pl.BlockSpec((TILE, TILE), lambda i, j, k: (k, j)),
-        ],
-        out_specs=tile_spec,
-        interpret=interpret,
-        name='_ns_xupdate_kernel',
-    )(x, mx)
-
-    mx_new, resid_parts = pl.pallas_call(
-        _ns_mx_resid_kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((d, d), jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct((nb, nb, 1, TILE), jnp.float32, vma=vma),
-        ],
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, k)),
-            pl.BlockSpec((TILE, TILE), lambda i, j, k: (k, j)),
-        ],
-        out_specs=[
-            tile_spec,
-            pl.BlockSpec((None, None, 1, TILE), lambda i, j, k: (i, j, 0, 0)),
-        ],
-        interpret=interpret,
-        name='_ns_mx_resid_kernel',
-    )(m, x_new)
-
-    sqrt_d = jnp.sqrt(jnp.asarray(d, jnp.float32))
-    resid = jnp.sqrt(jnp.sum(resid_parts)) / sqrt_d
-    return x_new, mx_new, resid
-
-
-def use_fused_ns_for(d: int) -> bool:
-    """Dispatch the fused NS iteration only in its artifact-backed win
-    regime (family ``ns``): TPU, whole-tile dims, a trace context a raw
-    ``pallas_call`` can execute in, and a clean backing sweep."""
-    from kfac_tpu import warnings as kfac_warnings
-    from kfac_tpu.ops import dispatch_tables, pallas_gate
-    from kfac_tpu.ops.pallas_attention import _mosaic_context_ok
-
-    if not (
-        pallas_gate.enabled('ns') and jax.default_backend() == 'tpu'
-    ):
-        return False
-    sweep = dispatch_tables.floor_contaminated('ns')
-    if sweep is not None:
-        kfac_warnings.warn_dispatch_event('ns', sweep)
-        return False
-    return (
-        d % TILE == 0
-        and d >= dispatch_tables.family_min_dim('ns', default=4 * TILE)
-        and _mosaic_context_ok()
-    )
 
 
 # ------------------------------------------------------------------ kl-clip

@@ -96,6 +96,72 @@ def test_kl_clip_scale():
     np.testing.assert_allclose(got_neg, np.sqrt(0.001 / 4.0), rtol=1e-6)
 
 
+# one Newton-Schulz iteration (the body every engine's solve runs, XLA's
+# products at every width): under one MXU tile, ragged, whole tiles
+NS_STEP_DIMS = [64, 200, 256]
+
+
+def _ns_start(d, seed):
+    """A damped SPD factor and the Gershgorin cold start
+    ``newton_schulz_inverse_info`` builds from it."""
+    m = _random_spd(d, seed)
+    x0 = np.eye(d, dtype=np.float32) / np.abs(m).sum(axis=1).max()
+    return m, x0
+
+
+@pytest.mark.parametrize('d', NS_STEP_DIMS)
+def test_newton_schulz_step_chain_matches_float64(d):
+    m, x0 = _ns_start(d, d)
+    m64, x64, eye = m.astype(np.float64), x0.astype(np.float64), np.eye(d)
+    x, mx = jnp.asarray(x0), jnp.asarray(m @ x0)
+    for _ in range(3):
+        x, mx, resid = factors.newton_schulz_step(jnp.asarray(m), x, mx)
+        x64 = x64 @ (2.0 * eye - m64 @ x64)
+        np.testing.assert_allclose(np.asarray(x), x64, rtol=1e-4, atol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(mx), m64 @ x64, rtol=1e-4, atol=1e-5
+        )
+        want = np.linalg.norm(eye - m64 @ x64) / np.sqrt(d)
+        assert float(resid) == pytest.approx(want, rel=1e-4)
+
+
+@pytest.mark.parametrize('d', NS_STEP_DIMS)
+def test_newton_schulz_step_residual_feeds_stopping_rule(d):
+    # the rule runs while the residual strictly shrinks: in the
+    # iteration's quadratic phase it must, and what is returned is the
+    # residual OF the returned iterate (the carry invariant)
+    m, x0 = _ns_start(d, d + 1)
+    m = jnp.asarray(m)
+    x, mx = jnp.asarray(x0), m @ jnp.asarray(x0)
+    resids = []
+    for _ in range(3):
+        x, mx, resid = factors.newton_schulz_step(m, x, mx)
+        own = jnp.linalg.norm(jnp.eye(d) - mx) / jnp.sqrt(float(d))
+        assert float(resid) == pytest.approx(float(own), rel=1e-6)
+        resids.append(float(resid))
+    assert resids[0] > resids[1] > resids[2]
+
+
+@pytest.mark.parametrize('slots,d', [(2, 128), (3, 200)])
+def test_newton_schulz_step_stacked_vmap(slots, d):
+    # the stacked engine runs one bucket's slots under vmap: each slot
+    # gets its own residual, equal to the slot run alone
+    starts = [_ns_start(d, 10 + i) for i in range(slots)]
+    m = jnp.stack([jnp.asarray(s[0]) for s in starts])
+    x0 = jnp.stack([jnp.asarray(s[1]) for s in starts])
+    xs, mxs, rs = jax.vmap(factors.newton_schulz_step)(m, x0, m @ x0)
+    assert rs.shape == (slots,)
+    for i in range(slots):
+        x, mx, r = factors.newton_schulz_step(m[i], x0[i], m[i] @ x0[i])
+        np.testing.assert_allclose(
+            np.asarray(xs[i]), np.asarray(x), rtol=1e-5, atol=1e-7
+        )
+        np.testing.assert_allclose(
+            np.asarray(mxs[i]), np.asarray(mx), rtol=1e-5, atol=1e-6
+        )
+        assert float(rs[i]) == pytest.approx(float(r), rel=1e-5)
+
+
 def test_newton_schulz_inverse_matches_cholesky():
     """The matmul-only solver converges to the direct damped inverse for
     well- and mildly ill-conditioned SPD factors."""

@@ -53,45 +53,6 @@ def cov_ema_pair(cov_inputs):
     return np.asarray(fused), np.asarray(unfused)
 
 
-@pytest.fixture(scope='module')
-def ns_problem(rng):
-    """Damped SPD factor + Gershgorin cold start, as
-    ``newton_schulz_inverse_info`` sets them up."""
-    d = 256
-    g = jnp.asarray(rng.standard_normal((d, d)), jnp.float32)
-    m = g @ g.T / d + 0.1 * jnp.eye(d, dtype=jnp.float32)
-    x0 = jnp.eye(d, dtype=jnp.float32) / jnp.max(
-        jnp.sum(jnp.abs(m), axis=1)
-    )
-    return m, x0
-
-
-@pytest.fixture(scope='module')
-def ns_chains(ns_problem):
-    """Three fused iterations next to the unfused body they replace."""
-    m, x0 = ns_problem
-    d = m.shape[0]
-    eye = jnp.eye(d, dtype=jnp.float32)
-
-    def unfused_step(x, mx):
-        x_new = x @ (2.0 * eye - mx)
-        mx_new = m @ x_new
-        resid = jnp.linalg.norm(eye - mx_new) / jnp.sqrt(
-            jnp.asarray(d, jnp.float32)
-        )
-        return x_new, mx_new, resid
-
-    fused, unfused = [], []
-    xf = xu = x0
-    mxf = mxu = m @ x0
-    for _ in range(3):
-        xf, mxf, rf = pallas_ns.fused_ns_step(m, xf, mxf, interpret=True)
-        fused.append((np.asarray(xf), np.asarray(mxf), float(rf)))
-        xu, mxu, ru = unfused_step(xu, mxu)
-        unfused.append((np.asarray(xu), np.asarray(mxu), float(ru)))
-    return fused, unfused
-
-
 # ----------------------------------------------------- cov+EMA fusion
 
 
@@ -154,56 +115,6 @@ def test_fused_cov_ema_cold_start_matches_ema_update(rng):
     assert np.array_equal(np.asarray(out), np.asarray(ref))
 
 
-# ----------------------------------------------------- NS fusion
-
-
-def test_fused_ns_chain_matches_unfused(ns_chains):
-    fused, unfused = ns_chains
-    for (xf, mxf, rf), (xu, mxu, ru) in zip(fused, unfused):
-        np.testing.assert_allclose(xf, xu, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(mxf, mxu, rtol=1e-5, atol=1e-5)
-        assert rf == pytest.approx(ru, rel=1e-4, abs=1e-6)
-
-
-def test_fused_ns_residual_feeds_stopping_rule(ns_chains):
-    # the stopping rule consumes a strictly-shrinking residual while the
-    # iteration is in its quadratic phase; the fused in-pass reduction
-    # must preserve that shape
-    fused, _ = ns_chains
-    resids = [r for _, _, r in fused]
-    assert resids[0] > resids[1] > resids[2]
-
-
-def test_fused_ns_stacked_vmap(rng):
-    d = 128
-    g = jnp.asarray(rng.standard_normal((2, d, d)), jnp.float32)
-    m = g @ jnp.swapaxes(g, -1, -2) / d + 0.1 * jnp.eye(d)
-    x0 = jnp.eye(d) / jnp.max(jnp.sum(jnp.abs(m), axis=-1), axis=-1)[
-        :, None, None
-    ]
-    mx0 = m @ x0
-    eye = jnp.eye(d, dtype=jnp.float32)
-    xf, mxf, rf = jax.vmap(
-        lambda mm, xx, mxmx: pallas_ns.fused_ns_step(
-            mm, xx, mxmx, interpret=True
-        )
-    )(m, x0, mx0)
-    xu = x0 @ (2.0 * eye - mx0)
-    mxu = m @ xu
-    ru = jnp.linalg.norm(eye - mxu, axis=(-2, -1)) / jnp.sqrt(
-        jnp.asarray(d, jnp.float32)
-    )
-    np.testing.assert_allclose(
-        np.asarray(xf), np.asarray(xu), rtol=1e-5, atol=1e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(mxf), np.asarray(mxu), rtol=1e-5, atol=1e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(rf), np.asarray(ru), rtol=1e-4, atol=1e-6
-    )
-
-
 # ----------------------------------------------------- kl-clip fusion
 
 
@@ -234,23 +145,19 @@ def test_fused_klclip_scale_matches(rng):
 def test_gates_stay_off_cpu_even_when_enabled(monkeypatch):
     monkeypatch.setenv('KFAC_TPU_PALLAS', '1')
     assert not pallas_cov_ema.use_fused_cov_ema_for(4096, jnp.float32)
-    assert not pallas_ns.use_fused_ns_for(4096)
     assert not pallas_ns.use_fused_klclip_for((4096, 4096))
 
 
 def test_gate_win_regimes_under_committed_artifact(monkeypatch):
     """The committed artifact holds every fused family at its prior
-    (cov_ema 256/f32, ns 512, klclip 512); faking the TPU backend pins
-    the gates to exactly those regimes."""
+    (cov_ema 256/f32, klclip 512); faking the TPU backend pins the gates
+    to exactly those regimes."""
     monkeypatch.setenv('KFAC_TPU_PALLAS', '1')
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     monkeypatch.setattr(jax, 'devices', lambda *a: [object()])
     assert pallas_cov_ema.use_fused_cov_ema_for(256, jnp.float32)
     assert not pallas_cov_ema.use_fused_cov_ema_for(128, jnp.float32)
     assert not pallas_cov_ema.use_fused_cov_ema_for(256, jnp.bfloat16)
-    assert pallas_ns.use_fused_ns_for(512)
-    assert not pallas_ns.use_fused_ns_for(384)   # below min_dim
-    assert not pallas_ns.use_fused_ns_for(520)   # not whole-tile
     assert pallas_ns.use_fused_klclip_for((512, 512))
     assert pallas_ns.use_fused_klclip_for((1024, 256))  # same traffic
     assert not pallas_ns.use_fused_klclip_for((64, 64))
@@ -265,7 +172,7 @@ def contaminated_artifact(monkeypatch, tmp_path):
     art['schema'] = dispatch_tables.SCHEMA_VERSION
     art['provenance'] = {'contaminated': {
         f'{fam}_unfused': {'contaminated': True, 'reason': 'flat'}
-        for fam in ('cov_ema', 'ns', 'klclip')
+        for fam in ('cov_ema', 'klclip')
     }}
     p = tmp_path / 'contaminated.json'
     p.write_text(json.dumps(art))
@@ -291,8 +198,6 @@ def test_gate_holds_on_contaminated_floor_and_warns_once(
         pywarnings.simplefilter('error')
         assert not pallas_cov_ema.use_fused_cov_ema_for(4096, jnp.float32)
     with pytest.warns(kfac_warnings.DispatchTableWarning):
-        assert not pallas_ns.use_fused_ns_for(4096)
-    with pytest.warns(kfac_warnings.DispatchTableWarning):
         assert not pallas_ns.use_fused_klclip_for((4096, 4096))
 
 
@@ -311,20 +216,20 @@ def _fused_sweep(fam, unfused_ms, fused_ms, sizes=(256, 512, 1024, 2048)):
 
 def test_derive_fused_holds_prior_on_contaminated_baseline():
     t = dispatch_tables.derive_tables(_fused_sweep(
-        'ns', lambda d: 50.0 + d % 5, lambda d: 1.0))
-    assert t['ns'] == dispatch_tables.DEFAULTS['ns']
-    assert 'ns_unfused' in t['provenance']['contaminated']
-    assert 'ns' in t['provenance']['held']
+        'klclip', lambda d: 50.0 + d % 5, lambda d: 1.0))
+    assert t['klclip'] == dispatch_tables.DEFAULTS['klclip']
+    assert 'klclip_unfused' in t['provenance']['contaminated']
+    assert 'klclip' in t['provenance']['held']
 
 
 def test_derive_fused_moves_threshold_on_clean_win_suffix():
     t = dispatch_tables.derive_tables(_fused_sweep(
-        'ns',
-        lambda d: 0.001 * d ** 3 / 256 ** 2,
-        lambda d: 9.0 if d < 1024 else 0.0002 * d ** 3 / 256 ** 2,
+        'klclip',
+        lambda d: 0.01 * d * d / 256,
+        lambda d: 90.0 if d < 1024 else 0.002 * d * d / 256,
     ))
-    assert t['ns']['min_dim'] == 1024
-    assert t['provenance']['derived']['ns']['win_from_dim'] == 1024
+    assert t['klclip']['min_dim'] == 1024
+    assert t['provenance']['derived']['klclip']['win_from_dim'] == 1024
 
 
 def test_derive_fused_rejects_single_point_win():
@@ -348,13 +253,43 @@ def test_derive_fused_rejects_non_suffix_wins():
     assert 'no clean win regime' in t['provenance']['held']['klclip']
 
 
+def test_derive_ignores_sweeps_of_a_family_that_left():
+    # the committed CPU sweep still holds ns_unfused_* / ns_fused_* rows
+    # of the Newton-Schulz pair: they derive nothing and hold nothing
+    t = dispatch_tables.derive_tables(_fused_sweep(
+        'ns', lambda d: 0.001 * d ** 3, lambda d: 0.0001 * d ** 3))
+    assert 'ns' not in t
+    assert not any('ns' in k for k in t['provenance']['held'])
+    assert t['provenance']['contaminated'] == {}
+    assert t['klclip'] == dispatch_tables.DEFAULTS['klclip']
+
+
+def test_artifact_with_a_row_of_a_family_that_left_still_loads(
+    monkeypatch, tmp_path
+):
+    # an artifact derived before PR 26 has an ``ns`` row: the gates that
+    # remain read their own rows from it as before
+    art = json.loads(json.dumps(dispatch_tables.DEFAULTS))
+    art.update(schema=dispatch_tables.SCHEMA_VERSION, ns={'min_dim': 512})
+    art['klclip'] = {'min_dim': 1024}
+    p = tmp_path / 'old.json'
+    p.write_text(json.dumps(art))
+    monkeypatch.setenv(dispatch_tables.ENV_VAR, str(p))
+    dispatch_tables.invalidate_cache()
+    try:
+        assert dispatch_tables.family_min_dim('klclip', default=512) == 1024
+        assert dispatch_tables.floor_contaminated('klclip') is None
+    finally:
+        dispatch_tables.invalidate_cache()
+
+
 def test_committed_artifact_is_clean_and_has_fused_families():
     """Satellite: the committed thresholds were re-derived from a clean
     one-dispatch sweep — no contaminated baselines remain, every fused
     family has a row, and provenance names its source sweep."""
     tables = dispatch_tables.load_tables()
     assert tables.get('schema') == dispatch_tables.SCHEMA_VERSION
-    for fam in ('cov_ema', 'ns', 'klclip'):
+    for fam in ('cov_ema', 'klclip'):
         assert 'min_dim' in tables[fam]
         assert dispatch_tables.floor_contaminated(fam) is None
     assert tables['provenance']['contaminated'] == {}
@@ -388,34 +323,6 @@ def test_kfl205_fused_cov_ema_flop_parity(cov_inputs):
     assert counted == model.fused_cov_ema_flops(N, D)
 
 
-def test_kfl205_fused_ns_flop_parity(ns_problem):
-    from kfac_tpu.analysis.ir import visitor
-    from kfac_tpu.autotune import model
-
-    m, x0 = ns_problem
-    d = m.shape[0]
-    jaxpr = jax.make_jaxpr(
-        lambda mm, xx, mxmx: pallas_ns.fused_ns_step(
-            mm, xx, mxmx, interpret=True
-        )
-    )(m, x0, m @ x0)
-    summaries = [
-        s for s in visitor.pallas_call_summaries(jaxpr)
-        if s['name'] in ('_ns_xupdate_kernel', '_ns_mx_resid_kernel')
-    ]
-    assert sorted(s['name'] for s in summaries) == [
-        '_ns_mx_resid_kernel', '_ns_xupdate_kernel'
-    ]
-    counted = 0.0
-    for s in summaries:
-        ni, nj, nk = s['grid']
-        counted += ni * nj * nk * s['dot_flops_per_tile']
-    # one fused iteration == the unfused 2 matmuls == 4d^3: fusing
-    # removes HBM traffic, never FLOPs, so decomp parity is preserved
-    # by construction
-    assert counted == model.fused_ns_iter_flops(d) == 4.0 * d ** 3
-
-
 def test_fused_klclip_price_pads_to_tiles():
     from kfac_tpu.autotune import model
 
@@ -427,7 +334,6 @@ def test_fused_hbm_saved_is_one_f32_roundtrip():
     from kfac_tpu.autotune import model
 
     assert model.fused_cov_ema_hbm_saved(1024) == 8.0 * 1024 * 1024
-    assert model.fused_ns_iter_hbm_saved(1024) == 8.0 * 1024 * 1024
 
 
 # ----------------------------------------------------- lint rules
@@ -447,22 +353,23 @@ def test_kfl110_detects_doc_drift(tmp_path):
         '### Fused-kernel dispatch families\n\n'
         '| family | kernel |\n|---|---|\n'
         '| `cov` | x |\n| `attn` | x |\n| `cov_ema` | x |\n'
-        '| `klclip` | x |\n| `ghost` | x |\n'
+        '| `ghost` | x |\n'
     )
     problems = drift.check_fused_dispatch_table(str(doc))
-    assert any('ns' in p and 'undocumented' in p for p in problems)
+    assert any('klclip' in p and 'undocumented' in p for p in problems)
     assert any('ghost' in p for p in problems)
 
 
-def test_kfl206_allowlist_passes_fused_kernels(ns_problem):
+def test_kfl206_allowlist_passes_fused_kernels(rng):
     from kfac_tpu.analysis.ir import rules
 
-    m, x0 = ns_problem
-    jaxpr = jax.make_jaxpr(
-        lambda mm, xx, mxmx: pallas_ns.fused_ns_step(
-            mm, xx, mxmx, interpret=True
-        )
-    )(m, x0, m @ x0)
+    p = jnp.asarray(rng.standard_normal((256, 128)), jnp.float32)
+
+    def klclip(pp, gg):
+        s = pallas_ns.fused_klclip_dot(pp, gg, interpret=True)
+        return pallas_ns.fused_klclip_scale(pp, s, interpret=True)
+
+    jaxpr = jax.make_jaxpr(klclip)(p, p)
     trace = SimpleNamespace(
         path='tests/fake.py', line=1, display='fake:step', jaxpr=jaxpr
     )
@@ -470,7 +377,12 @@ def test_kfl206_allowlist_passes_fused_kernels(ns_problem):
     assert rules.check_pallas_allowlist(suite) == []
 
 
-def test_kfl206_flags_unlisted_kernel():
+# a kernel nobody registered, and the two of the Newton-Schulz pair that
+# left the step path (PR 26): coming back takes the wiring again
+@pytest.mark.parametrize('kernel_name', [
+    '_rogue_kernel', '_ns_xupdate_kernel', '_ns_mx_resid_kernel',
+])
+def test_kfl206_flags_unlisted_kernel(kernel_name):
     from jax.experimental import pallas as pl
 
     from kfac_tpu.analysis.ir import rules
@@ -483,6 +395,7 @@ def test_kfl206_flags_unlisted_kernel():
             _rogue_kernel,
             out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
             interpret=True,
+            name=kernel_name,
         )(x)
 
     jaxpr = jax.make_jaxpr(run)(jnp.zeros((8, 128), jnp.float32))
@@ -493,4 +406,4 @@ def test_kfl206_flags_unlisted_kernel():
     findings = rules.check_pallas_allowlist(suite)
     assert len(findings) == 1
     assert findings[0].code == 'KFL206'
-    assert '_rogue_kernel' in findings[0].message
+    assert kernel_name in findings[0].message

@@ -1,13 +1,12 @@
 """Every Pallas entry point lowers for TPU from the CPU.
 
 The kernel tests elsewhere run with ``interpret=True``, which never
-reaches Mosaic's lowering rules — that is how ``fused_ns_step`` and
-``fused_klclip_dot`` shipped with a scalar store into VMEM that no TPU
-could lower. Here each entry point is traced on the CPU and lowered with
+reaches Mosaic's lowering rules — that is how ``fused_klclip_dot``
+shipped with a scalar store into VMEM that no TPU could lower. Here each entry point is traced on the CPU and lowered with
 ``lowering_platforms=('tpu',)``: the Pallas->Mosaic lowering runs for
 real and the text must carry a ``tpu_custom_call``. This is the check to
 run before spending chip time; it does not replace compiling on the chip
-(Mosaic's own compiler only runs there — ``chip_smoke.py``).
+(``chip_smoke.py``).
 """
 
 import jax
@@ -44,9 +43,6 @@ def _f32(*shape):
 COV_SHAPES = [(1024, 256), (700, 300)]
 # (rows, cols) of a preconditioned gradient
 KLCLIP_SHAPES = [(512, 512), (1000, 2049)]
-# Newton-Schulz dispatches whole tiles only (use_fused_ns_for), so its
-# second shape is a second tile count, not a ragged one
-NS_DIMS = [512, 1152]
 BATCH = 3
 
 
@@ -70,16 +66,6 @@ def test_fused_cov_ema_lowers(n, d):
 
     assert _kernels_in(fused, f, a) == 1
     assert _kernels_in(jax.vmap(fused), _stack(f), _stack(a)) == 1
-
-
-@pytest.mark.parametrize('d', NS_DIMS)
-def test_fused_ns_step_lowers(d):
-    m = _f32(d, d)
-    assert _kernels_in(pallas_ns.fused_ns_step, m, m, m) == 2
-    # the stacked engine reaches the kernel pair under vmap
-    assert _kernels_in(
-        jax.vmap(pallas_ns.fused_ns_step), _stack(m), _stack(m), _stack(m)
-    ) == 2
 
 
 @pytest.mark.parametrize('r,c', KLCLIP_SHAPES)
@@ -117,17 +103,23 @@ def test_flash_partials_lower(s_q, s_k):
     ) == 1
 
 
-def test_newton_schulz_dispatches_fused_pair_when_gates_open(monkeypatch):
-    """End of the chain the README quick-start hits on a TPU: the gate
-    (backend, thresholds, whole tiles, one device) opens at d=512 and
-    the Newton-Schulz inverse lowers with the fused pair in its loop."""
+# a bucket below every kernel's threshold, the widths the Mosaic pair
+# once took (whole tiles from 512: 896, 2,304, 3,200) and a ragged one
+@pytest.mark.parametrize('d', [256, 896, 2049, 2304, 3200])
+def test_newton_schulz_is_xla_at_every_width(monkeypatch, d):
+    """End of the chain the README quick-start hits on a TPU: with every
+    gate open (backend, thresholds, one device) the Newton-Schulz inverse
+    lowers with no Mosaic kernel in its loop, dense or stacked: its two
+    products are XLA's (``factors.newton_schulz_step``)."""
     from kfac_tpu.ops import factors
 
     one = jax.devices()[:1]
     monkeypatch.setattr(jax, 'devices', lambda *a: one)
-    assert pallas_ns.use_fused_ns_for(512)
-    f = jnp.eye(512, dtype=jnp.float32)
-    n = _kernels_in(
-        lambda f: factors.newton_schulz_inverse(f, 0.003), f
-    )
-    assert n == 2
+    assert pallas_ns.use_fused_klclip_for((512, 512))  # gates are open
+    f = jnp.eye(d, dtype=jnp.float32)
+
+    def solve(f):
+        return factors.newton_schulz_inverse(f, 0.003)
+
+    assert _kernels_in(solve, f) == 0
+    assert _kernels_in(jax.vmap(solve), _stack(f)) == 0

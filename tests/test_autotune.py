@@ -289,13 +289,16 @@ def test_measured_winner_not_worse_than_strategy_baselines():
         top_k=1, warmup=0, iters=1, granularities=(1,),
     )
     assert plan.winner['picked_by'] == 'measured'
-    measured = {
-        r['knobs']['strategy']: r['measured_step_s']
-        for r in plan.cost_table if r['measured']
-    }
+    # by row, not by strategy: the model's own pick shares its strategy
+    # with a baseline, and either may be the faster of the two
+    measured = [r for r in plan.cost_table if r['measured']]
     # all three hand-configured strategies were actually timed
-    assert {'COMM_OPT', 'HYBRID_OPT', 'MEM_OPT'} <= set(measured)
-    assert plan.winner['measured_step_s'] == min(measured.values())
+    assert {'COMM_OPT', 'HYBRID_OPT', 'MEM_OPT'} <= {
+        r['knobs']['strategy'] for r in measured
+    }
+    assert plan.winner['measured_step_s'] == min(
+        r['measured_step_s'] for r in measured
+    )
     # the plan drives a real engine end to end
     eng = DistributedKFAC(config=cfg, auto_layout=plan)
     assert eng.auto_layout_applied

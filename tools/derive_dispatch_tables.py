@@ -72,26 +72,31 @@ def selftest() -> None:
     assert not t['provenance']['contaminated']
     # fused step-path families: a flat (contaminated) unfused baseline
     # holds the prior, a clean sweep with a fused win suffix moves it
-    flat_ns = [
-        {'op': f'ns_unfused_{d}', 'ms': 50.0 + (d % 5)}
+    flat_kl = [
+        {'op': f'klclip_unfused_{d}', 'ms': 50.0 + (d % 5)}
         for d in (256, 512, 1024)
     ] + [
-        {'op': f'ns_fused_{d}', 'ms': 10.0} for d in (256, 512, 1024)
+        {'op': f'klclip_fused_{d}', 'ms': 10.0} for d in (256, 512, 1024)
     ]
-    t = dispatch_tables.derive_tables(flat_ns)
-    assert t['ns']['min_dim'] == dispatch_tables.DEFAULTS['ns']['min_dim']
-    assert 'ns_unfused' in t['provenance']['contaminated'], t['provenance']
-    clean_ns = [
-        {'op': f'ns_unfused_{d}', 'ms': 0.001 * d ** 3 / 256 ** 2}
+    t = dispatch_tables.derive_tables(flat_kl)
+    assert (
+        t['klclip']['min_dim']
+        == dispatch_tables.DEFAULTS['klclip']['min_dim']
+    )
+    assert 'klclip_unfused' in t['provenance']['contaminated'], (
+        t['provenance']
+    )
+    clean_kl = [
+        {'op': f'klclip_unfused_{d}', 'ms': 0.01 * d * d / 256}
         for d in (256, 512, 1024, 2048)
     ] + [
-        {'op': f'ns_fused_{d}',
-         'ms': 9.0 if d < 1024 else 0.0002 * d ** 3 / 256 ** 2}
+        {'op': f'klclip_fused_{d}',
+         'ms': 90.0 if d < 1024 else 0.002 * d * d / 256}
         for d in (256, 512, 1024, 2048)
     ]
-    t = dispatch_tables.derive_tables(clean_ns)
-    assert t['ns']['min_dim'] == 1024, t
-    assert 'ns' in t['provenance'].get('derived', {}), t
+    t = dispatch_tables.derive_tables(clean_kl)
+    assert t['klclip']['min_dim'] == 1024, t
+    assert 'klclip' in t['provenance'].get('derived', {}), t
     print('derive_dispatch_tables selftest: ok')
 
 

@@ -744,40 +744,6 @@ def main():
                     args.iters,
                 ))
 
-                damping = 0.003
-                m_spd = cov + damping * jnp.eye(d, dtype=jnp.float32)
-                x0 = jnp.eye(d, dtype=jnp.float32) / jnp.trace(m_spd)
-                mx0 = m_spd @ x0
-
-                def ns_unfused(mm, x, mx):
-                    eye = jnp.eye(mm.shape[-1], dtype=jnp.float32)
-                    x_new = x @ (2.0 * eye - mx)
-                    mx_new = mm @ x_new
-                    r = jnp.linalg.norm(eye - mx_new) / jnp.sqrt(
-                        jnp.float32(mm.shape[-1])
-                    )
-                    return x_new, mx_new, r
-
-                track('ns_unfused', 3.0, d, measured(
-                    f'ns_unfused_{d}',
-                    lambda n: timeit(jax.jit(ns_unfused), m_spd, x0, mx0,
-                                     iters=n),
-                    args.iters,
-                ))
-                if d % pallas_ns.TILE == 0:
-                    track('ns_fused', 3.0, d, measured(
-                        f'ns_fused_{d}',
-                        lambda n: timeit(
-                            jax.jit(
-                                lambda mm, x, mx: pallas_ns.fused_ns_step(
-                                    mm, x, mx, interpret=interp
-                                )
-                            ),
-                            m_spd, x0, mx0, iters=n,
-                        ),
-                        args.iters,
-                    ))
-
                 gmat = 0.5 * cov + 0.1 * jnp.eye(d, dtype=jnp.float32)
 
                 def kl_unfused(p, g):
