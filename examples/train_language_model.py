@@ -25,7 +25,7 @@ sys.path.insert(0, '.')
 import kfac_tpu
 from examples import common, data
 from kfac_tpu import training
-from kfac_tpu.models import TransformerLM, lm_loss
+from kfac_tpu.models import HybridLM, TransformerLM, hybrid_lm_loss, lm_loss
 from kfac_tpu.parallel import tensor_parallel, token_sharding, train_mesh
 from kfac_tpu.parallel.mesh import SEQ_AXIS
 
@@ -80,6 +80,26 @@ def main(argv=None, on_step=None) -> float:
     p.add_argument('--num-layers', type=int, default=4)
     p.add_argument('--seq-len', type=int, default=256)
     p.add_argument('--vocab-size', type=int, default=8192)
+    p.add_argument(
+        '--model', choices=['transformer', 'hybrid'], default='transformer',
+        help="'hybrid': the sparse hybrid decoder (models.HybridLM: Gated "
+        'DeltaNet layers with a gated-attention layer every fourth, top-k '
+        'routed experts with a shared expert), sized from --d-model: heads '
+        'of d/8 (attention) and d/16 (DeltaNet), experts d/4 wide',
+    )
+    p.add_argument(
+        '--num-experts', type=int, default=16,
+        help='hybrid: experts the router scores',
+    )
+    p.add_argument('--experts-per-token', type=int, default=2)
+    p.add_argument(
+        '--experts-held', type=int, nargs=2, default=None,
+        metavar=('FIRST', 'COUNT'),
+        help='hybrid: the share of every layer\'s experts that lives in '
+        'this process (an expert-parallel rank\'s); the router still scores '
+        'all of them, and what the absent ones would add is left out. '
+        'Default: all',
+    )
     p.add_argument('--model-shards', type=int, default=1)
     p.add_argument('--seq-shards', type=int, default=1)
     p.add_argument(
@@ -119,16 +139,38 @@ def main(argv=None, on_step=None) -> float:
         seq=args.seq_shards,
     )
     tokens_np, vocab = data.lm_corpus(args.data_dir, args.vocab_size)
-    model = TransformerLM(
-        vocab_size=vocab,
-        d_model=args.d_model,
-        num_heads=args.num_heads,
-        num_layers=args.num_layers,
-        max_len=args.seq_len,
-        dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
-        ring_mesh=mesh if args.seq_shards > 1 else None,
-        ring_axis=SEQ_AXIS if args.seq_shards > 1 else None,
-    )
+    dtype = jnp.bfloat16 if args.bf16 else jnp.float32
+    if args.model == 'hybrid':
+        if args.model_shards > 1 or args.seq_shards > 1:
+            raise SystemExit('--model hybrid runs data-parallel only')
+        d = args.d_model
+        model = HybridLM(
+            vocab_size=vocab, d_model=d, num_layers=args.num_layers,
+            num_heads=args.num_heads,
+            num_kv_heads=max(1, args.num_heads // 4),
+            head_dim=d // args.num_heads * 2,
+            linear_num_key_heads=args.num_heads,
+            linear_num_value_heads=2 * args.num_heads,
+            linear_key_head_dim=d // args.num_heads,
+            linear_value_head_dim=d // args.num_heads,
+            num_experts=args.num_experts, top_k=args.experts_per_token,
+            expert_width=d // 4, shared_expert_width=d // 4,
+            experts_held=(
+                tuple(args.experts_held) if args.experts_held else None
+            ),
+            attention_chunk=min(1024, args.seq_len), dtype=dtype,
+        )
+    else:
+        model = TransformerLM(
+            vocab_size=vocab,
+            d_model=args.d_model,
+            num_heads=args.num_heads,
+            num_layers=args.num_layers,
+            max_len=args.seq_len,
+            dtype=dtype,
+            ring_mesh=mesh if args.seq_shards > 1 else None,
+            ring_axis=SEQ_AXIS if args.seq_shards > 1 else None,
+        )
     sample = jnp.zeros((args.batch_size, args.seq_len), jnp.int32)
     params = model.init(jax.random.PRNGKey(args.seed), sample)['params']
     if args.model_shards > 1:
@@ -138,7 +180,7 @@ def main(argv=None, on_step=None) -> float:
     )
     print(f'registered {len(registry)} K-FAC layers; mesh {dict(mesh.shape)}')
 
-    loss = lm_loss(model)
+    loss = (hybrid_lm_loss if args.model == 'hybrid' else lm_loss)(model)
 
     def loss_fn(params, model_state, batch):
         return loss(params, batch), model_state

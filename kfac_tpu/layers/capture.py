@@ -92,6 +92,50 @@ def _make_role_gtap(
     return gtap
 
 
+def _make_stack_gtap(
+    tap: helpers_lib.ExpertStackTap,
+) -> Callable[..., jax.Array]:
+    """Identity g-tap of a stacked expert projection: its vjp emits, as
+    the cotangent of the stack's one dummy argument, every held expert's
+    G factor over its own rows pre-scaled by its capture weight (1 with
+    rows, 0 without), those weights, and the plan's traffic counters."""
+
+    @jax.custom_vjp
+    def gtap(y: jax.Array, gstat: Any, plan: Any) -> jax.Array:
+        del gstat, plan
+        return y
+
+    def fwd(y: jax.Array, gstat: Any, plan: Any):
+        del gstat
+        return y, plan
+
+    def bwd(plan: Any, ybar: jax.Array):
+        with tracing.capture_scope('g'), tracing.capture_scope('experts'):
+            # no ``* live``: an expert without rows has zero sums already
+            g = tap.g_factors(ybar, plan)
+        return ybar, (g, tap.live(plan), tap.traffic(plan)), None
+
+    gtap.defvjp(fwd, bwd)
+    return gtap
+
+
+def expand_stacks(
+    registry: registry_lib.Registry, g_stats: dict[str, Any]
+) -> tuple[dict[str, Any], dict[str, jax.Array]]:
+    """Replace each stacked projection's entry of the g-tap cotangents by
+    its experts' ``(weighted G sum, weight)`` pairs under their own layer
+    names; returns them with the stacks' traffic counters."""
+    out = {n: v for n, v in g_stats.items() if n not in registry.stacks}
+    traffic = {}
+    for name, tap in registry.stacks.items():
+        if name not in g_stats:
+            continue
+        g, live, traffic[name] = g_stats[name]
+        for j, slot in enumerate(tap.slots):
+            out[slot] = (g[j], live[j])
+    return out, traffic
+
+
 class CurvatureCapture:
     """Wraps a loss function to also emit per-layer curvature statistics.
 
@@ -108,10 +152,19 @@ class CurvatureCapture:
 
     def __init__(self, registry: registry_lib.Registry):
         self.registry = registry
+        # an expert of a stacked projection is tapped through its stack
+        self._slots = frozenset(
+            slot for tap in registry.stacks.values() for slot in tap.slots
+        )
         self._gtaps = {
             name: _make_gtap(helper)
             for name, helper in registry.layers.items()
             if not isinstance(helper, helpers_lib.LoRAHelper)
+            and name not in self._slots
+        }
+        self._stack_gtaps = {
+            name: _make_stack_gtap(tap)
+            for name, tap in registry.stacks.items()
         }
         # fused units (LoRA adapter pairs) tap at their CHILD module
         # paths; Registry.taps routes each child to (unit, role)
@@ -134,9 +187,20 @@ class CurvatureCapture:
                 return (fac, jnp.zeros((), dtype=h.factor_dtype))
             return fac
 
-        return {
+        out = {
             name: zero(h) for name, h in self.registry.layers.items()
+            if name not in self._slots
         }
+        # a stacked projection: its experts' G sums, their capture
+        # weights, and the plan's traffic counters (rows, then dropped)
+        for name, tap in self.registry.stacks.items():
+            e, d = len(tap.slots), tap.out_features
+            out[name] = (
+                jnp.zeros((e, d, d), tap.factor_dtype),
+                jnp.zeros((e,), tap.factor_dtype),
+                jnp.zeros((e + 1,), jnp.float32),
+            )
+        return out
 
     def tapped(
         self,
@@ -153,11 +217,26 @@ class CurvatureCapture:
         registry = self.registry
         gtaps = self._gtaps
         role_gtaps = self._role_gtaps
+        stack_gtaps = self._stack_gtaps
 
         def wrapped(params: Any, gstats: dict[str, jax.Array], *args: Any, **kwargs: Any):
             a_stats: dict[str, jax.Array] = {}
             counts: dict[str, jax.Array] = {}
             weights: dict[str, jax.Array] = {}
+
+            def accumulate(name, fac, weight=None):
+                # repeated invocations of one layer sum; ``counts`` (and,
+                # for weighted layers, the summed weights) divide in run()
+                if name in a_stats:
+                    a_stats[name] = a_stats[name] + fac
+                    counts[name] = counts[name] + 1
+                    if weight is not None:
+                        weights[name] = weights[name] + weight
+                else:
+                    a_stats[name] = fac
+                    counts[name] = jnp.asarray(1, dtype=jnp.int32)
+                    if weight is not None:
+                        weights[name] = weight
 
             def role_tap(name, iargs, ikwargs, next_fun):
                 # fused-unit child projection: embed this role's A block
@@ -169,20 +248,35 @@ class CurvatureCapture:
                 a = jax.lax.stop_gradient(iargs[0])
                 with tracing.capture_scope('a'):
                     a_fac = uhelper.role_a_factor(role, a)
-                if unit in a_stats:
-                    a_stats[unit] = a_stats[unit] + a_fac
-                    counts[unit] = counts[unit] + 1
-                else:
-                    a_stats[unit] = a_fac
-                    counts[unit] = jnp.asarray(1, dtype=jnp.int32)
+                accumulate(unit, a_fac)
                 y = next_fun(*iargs, **ikwargs)
                 return role_gtaps[name](y, gstats[unit])
+
+            def stack_tap(name, iargs, ikwargs, next_fun):
+                # a stacked expert projection: one tap for every held
+                # expert's A factor (over its own rows), filed under the
+                # experts' own layer names with weight 1 or, without a
+                # row, 0; one g-tap on the projection's output
+                tap = registry.stacks[name]
+                x, plan = jax.lax.stop_gradient(iargs[0]), iargs[1]
+                with tracing.capture_scope('a'), tracing.capture_scope(
+                    'experts'
+                ):
+                    # no ``* live``: zero sums without rows already
+                    live = tap.live(plan)
+                    facs = tap.a_factors(x, plan)
+                for j, slot in enumerate(tap.slots):
+                    accumulate(slot, facs[j], live[j])
+                y = next_fun(*iargs, **ikwargs)
+                return stack_gtaps[name](y, gstats[name], plan)
 
             def interceptor(next_fun, iargs, ikwargs, context):
                 mod = context.module
                 if context.method_name != '__call__' or not iargs:
                     return next_fun(*iargs, **ikwargs)
                 name = registry_lib.path_name(mod.path)
+                if name in registry.stacks:
+                    return stack_tap(name, iargs, ikwargs, next_fun)
                 helper = registry.layers.get(name)
                 if isinstance(helper, helpers_lib.LoRAHelper):
                     # the unit module itself carries no tap; its children
@@ -204,16 +298,7 @@ class CurvatureCapture:
                         # accumulate_stats/average_stats)
                         w = helper.capture_weight(a)
                         a_fac = a_fac * w
-                if name in a_stats:
-                    a_stats[name] = a_stats[name] + a_fac
-                    counts[name] = counts[name] + 1
-                    if helper.weighted:
-                        weights[name] = weights[name] + w
-                else:
-                    a_stats[name] = a_fac
-                    counts[name] = jnp.asarray(1, dtype=jnp.int32)
-                    if helper.weighted:
-                        weights[name] = w
+                accumulate(name, a_fac, w if helper.weighted else None)
                 y = next_fun(*iargs, **ikwargs)
                 return gtaps[name](y, gstats[name])
 
@@ -247,6 +332,7 @@ class CurvatureCapture:
             (loss, (aux, a_stats, counts, weights)), (grads, g_stats) = (
                 grad_fn(params, gstats_in, *args, **kwargs)
             )
+            g_stats, traffic = expand_stacks(self.registry, g_stats)
             g_sums, g_weights = split_g_stats(g_stats)
             a_avg = weighted_average(a_stats, counts, weights)
             g_avg = weighted_average(
@@ -256,7 +342,7 @@ class CurvatureCapture:
                 n: weights[n] / counts[n].astype(weights[n].dtype)
                 for n in weights
             }
-            stats = CapturedStats(a=a_avg, g=g_avg, w=w_avg)
+            stats = CapturedStats(a=a_avg, g=g_avg, w=w_avg, traffic=traffic)
             return (loss, aux), grads, stats
 
         return run
@@ -280,29 +366,38 @@ class CapturedStats:
         a: dict[str, jax.Array],
         g: dict[str, jax.Array],
         w: dict[str, jax.Array] | None = None,
+        traffic: dict[str, jax.Array] | None = None,
     ):
         self.a = a
         self.g = g
         self.w = {} if w is None else w
+        # stacked expert projection -> (E_here + 1,) float32: the live
+        # rows of each held expert at this capture, then the assignments
+        # its plan left out (``helpers.ExpertStackTap.traffic``). Counters
+        # for the engine to keep, not statistics: no factor reads them.
+        self.traffic = {} if traffic is None else traffic
 
     def tree_flatten(self):
         names = sorted(self.a)
         wnames = sorted(self.w)
+        tnames = sorted(self.traffic)
         leaves = (
             tuple(self.a[n] for n in names)
             + tuple(self.g[n] for n in names)
             + tuple(self.w[n] for n in wnames)
+            + tuple(self.traffic[n] for n in tnames)
         )
-        return leaves, (tuple(names), tuple(wnames))
+        return leaves, (tuple(names), tuple(wnames), tuple(tnames))
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        names, wnames = aux
-        n = len(names)
+        names, wnames, tnames = aux
+        n, m = len(names), len(wnames)
         a = dict(zip(names, leaves[:n]))
         g = dict(zip(names, leaves[n:2 * n]))
-        w = dict(zip(wnames, leaves[2 * n:]))
-        return cls(a=a, g=g, w=w)
+        w = dict(zip(wnames, leaves[2 * n:2 * n + m]))
+        traffic = dict(zip(tnames, leaves[2 * n + m:]))
+        return cls(a=a, g=g, w=w, traffic=traffic)
 
     def scaled(self, grad_scale: jax.Array | float) -> 'CapturedStats':
         """Unscale G stats computed under a scaled loss (AMP loss scaling).
@@ -315,6 +410,7 @@ class CapturedStats:
             a=self.a,
             g={n: v / s2 for n, v in self.g.items()},
             w=self.w,
+            traffic=self.traffic,
         )
 
 
@@ -386,6 +482,7 @@ def _traffic_scaled(stats: CapturedStats) -> CapturedStats:
             for n in stats.g
         },
         w=stats.w,
+        traffic=stats.traffic,
     )
 
 
@@ -407,6 +504,8 @@ def accumulate_stats(
         a={n: acc.a[n] + new.a[n] for n in acc.a},
         g={n: acc.g[n] + new.g[n] for n in acc.g},
         w={n: acc.w[n] + new.w[n] for n in acc.w},
+        # rows and drops of all the micro-steps together
+        traffic={n: acc.traffic[n] + new.traffic[n] for n in acc.traffic},
     )
 
 
@@ -430,4 +529,5 @@ def average_stats(acc: CapturedStats, num_steps: int | jax.Array) -> CapturedSta
         a={n: div(n, v) for n, v in acc.a.items()},
         g={n: div(n, v) for n, v in acc.g.items()},
         w={n: v / num_steps for n, v in acc.w.items()},
+        traffic=acc.traffic,
     )

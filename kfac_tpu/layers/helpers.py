@@ -339,6 +339,75 @@ class LoRAHelper(LayerHelper):
         }
 
 
+@dataclasses.dataclass(frozen=True)
+class ExpertStackTap:
+    """The one capture tap of a stacked expert projection
+    (:class:`kfac_tpu.models.moe.ExpertProjection`).
+
+    The engines see each held expert's projection as a layer of its own
+    (``slots``: routed bias-free :class:`DenseHelper` entries of the
+    registry, one size class, so they land side by side in a bucket); the
+    program runs one stacked product, and this tap computes all the
+    experts' factors from its one input and its one output cotangent:
+    ``(E_here, d, d)`` sums of ``r^T r`` over each expert's own rows
+    (:func:`kfac_tpu.ops.grouped.grouped_cov`, which walks the plan's
+    blocks in use), divided by the rows the plan counted for that expert.
+    An expert with no row gives zeros with weight 0, which the factor EMA
+    ignores: its factors stay as they were.
+
+    ``mode`` is the projection's: ``'gather'`` reads token rows and
+    returns plan blocks, ``'combine'`` reads blocks and returns tokens
+    (each row weighted by its routing weight and summed into its token:
+    the cotangent of a row of the expert's own output is the token's,
+    times that weight).
+    """
+
+    name: str
+    slots: tuple[str, ...]
+    out_features: int
+    mode: str
+    factor_dtype: Any = jnp.float32
+
+    def _factors(self, sums: jax.Array, plan: Any) -> jax.Array:
+        # one elementwise pass over the sums (each ``r^T r`` is symmetric
+        # as computed; a second pass to symmetrise would hold a second
+        # ``(E_here, d, d)`` value a stack at the step's memory peak)
+        scale = 1.0 / jnp.maximum(plan.rows, 1).astype(jnp.float32)
+        return (sums * scale[:, None, None]).astype(self.factor_dtype)
+
+    def a_factors(self, x: jax.Array, plan: Any) -> jax.Array:
+        """``(E_here, d_in, d_in)``: each expert's input second moment
+        over its own rows; zeros for an expert without rows."""
+        from kfac_tpu.ops import grouped
+
+        return self._factors(grouped.grouped_cov(
+            x, plan.block_expert, plan.n_blocks, len(self.slots),
+            row_token=plan.row_token if self.mode == 'gather' else None,
+        ), plan)
+
+    def g_factors(self, ybar: jax.Array, plan: Any) -> jax.Array:
+        """The same of the output cotangent's rows."""
+        from kfac_tpu.ops import grouped
+
+        combine = self.mode == 'combine'
+        return self._factors(grouped.grouped_cov(
+            ybar, plan.block_expert, plan.n_blocks, len(self.slots),
+            row_token=plan.row_token if combine else None,
+            row_weight=plan.row_weight if combine else None,
+        ), plan)
+
+    def live(self, plan: Any) -> jax.Array:
+        """``(E_here,)`` capture weights: 1 where the expert saw a row."""
+        return (plan.rows > 0).astype(self.factor_dtype)
+
+    def traffic(self, plan: Any) -> jax.Array:
+        """``(E_here + 1,)`` float32: each expert's live rows, then the
+        assignments to held experts that the plan left out."""
+        return jnp.concatenate(
+            [plan.rows, plan.dropped[None]]
+        ).astype(jnp.float32)
+
+
 def matrix_param_count(helper: LayerHelper) -> int:
     """Number of elements in the packed gradient matrix for a helper."""
     return helper.g_factor_shape[0] * helper.a_factor_shape[0]

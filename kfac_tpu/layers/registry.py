@@ -128,6 +128,19 @@ class Registry:
     taps: dict[str, tuple[str, str]] = dataclasses.field(
         default_factory=dict
     )
+    # capture-time module path of a stacked expert projection -> its one
+    # tap; the experts themselves are ``layers`` entries (the tap's
+    # ``slots``), so the engines need to know nothing of stacks
+    stacks: dict[str, helpers.ExpertStackTap] = dataclasses.field(
+        default_factory=dict
+    )
+    # parameter leaves that no registered layer owns, 'a/b/c' -> why: they
+    # pass the preconditioner unchanged and take the first-order update
+    # (:func:`passthrough_leaves`). Empty where registration saw no
+    # parameters (``apply_fn``).
+    passthrough: dict[str, str] = dataclasses.field(default_factory=dict)
+    # the leaves the registered layers do own, in the same form
+    kfac_leaves: tuple[str, ...] = ()
 
     def __len__(self) -> int:
         return len(self.layers)
@@ -218,7 +231,96 @@ def masked_registry(registry: Registry, mask: Any) -> Registry:
         for tap, (unit, role) in registry.taps.items()
         if unit in keep
     }
-    return Registry(layers=keep, param_paths=paths, taps=taps)
+    stacks = {}
+    for name, tap in registry.stacks.items():
+        kept = [slot in keep for slot in tap.slots]
+        if any(kept) and not all(kept):
+            raise ValueError(
+                f'mask freezes some experts of the stacked projection '
+                f'{name!r} and not others; its experts are captured by '
+                'one tap, so mask the projection whole'
+            )
+        if all(kept):
+            stacks[name] = tap
+    frozen = {
+        '/'.join(registry.param_paths[n]) for n in registry.layers
+        if n not in keep
+    }
+    passthrough = dict(registry.passthrough)
+    passthrough.update({
+        leaf: 'frozen by the mask'
+        for leaf in registry.kfac_leaves
+        if any(leaf.startswith(f + '/') for f in frozen)
+    })
+    return Registry(
+        layers=keep, param_paths=paths, taps=taps, stacks=stacks,
+        passthrough=passthrough,
+        kfac_leaves=tuple(
+            leaf for leaf in registry.kfac_leaves
+            if leaf not in passthrough
+        ),
+    )
+
+
+# Which parameters K-FAC does not factor. A leaf is K-FAC's only if it
+# belongs to a registered layer: the kernel (and bias) of an ``nn.Dense``,
+# of a 2-D undilated ungrouped ``nn.Conv``, of a LoRA adapter pair, or of an
+# expert of a stacked projection, that no ``skip_layers`` pattern names and
+# no mask freezes. Every other leaf passes the preconditioner unchanged and
+# takes the optimizer's first-order update; ``Registry.passthrough`` lists
+# them with the clause that applies:
+PASSTHROUGH_RULE = {
+    'skipped': 'its layer matches a skip_layers pattern',
+    'embedding': 'an embedding table is a look-up, not a product with an '
+                 'activation: its A factor would be as wide as the '
+                 'vocabulary',
+    'convolution': 'a convolution that is not 2-D, or is grouped '
+                   '(depthwise), dilated or padded by wrapping: no patch '
+                   'covariance is defined for it here',
+    'elementwise': 'a vector that its module applies elementwise (a norm '
+                   'weight, a gate\'s decay or bias): it has no '
+                   'Kronecker-factored curvature',
+    'unsupported': 'a matrix of a module kind that has no helper',
+}
+
+
+def passthrough_leaves(
+    params: Any,
+    param_paths: dict[str, tuple[str, ...]],
+    modules: dict[tuple[str, ...], tuple[str, bool]],
+) -> tuple[dict[str, str], tuple[str, ...]]:
+    """Sort the leaves of ``params`` by :data:`PASSTHROUGH_RULE`.
+
+    ``modules``: path -> (class name, whether ``skip_layers`` named it) of
+    every module the probe called. Returns ``({leaf: clause}, kfac
+    leaves)``, leaves as ``'a/b/c'``.
+    """
+    owned = set(param_paths.values())
+    out: dict[str, str] = {}
+    kfac: list[str] = []
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    for path, value in flat:
+        keys = tuple(str(getattr(k, 'key', k)) for k in path)
+        leaf = '/'.join(keys)
+        if any(keys[:i] in owned for i in range(1, len(keys))):
+            kfac.append(leaf)
+            continue
+        cls, skipped = 'module', False
+        for i in range(len(keys) - 1, 0, -1):
+            if keys[:i] in modules:
+                cls, skipped = modules[keys[:i]]
+                break
+        if skipped:
+            out[leaf] = 'skipped'
+        elif cls == 'embed':
+            out[leaf] = 'embedding'
+        elif 'conv' in cls:
+            out[leaf] = 'convolution'
+        elif len(value.shape) > 1:
+            out[leaf] = 'unsupported'
+        else:
+            out[leaf] = 'elementwise'
+    return out, tuple(kfac)
 
 
 def register_model(
@@ -259,13 +361,25 @@ def register_model(
     (:class:`kfac_tpu.layers.helpers.LoRAHelper`), their child taps
     recorded in ``Registry.taps``; the frozen ``base`` projection and any
     modules nested under a unit are not registered separately.
+
+    Modules declaring ``_kfac_expert_stack = True``
+    (:class:`kfac_tpu.models.moe.ExpertProjection`) register every held
+    expert as a layer of its own, ``<path>/e<j>`` (a routed bias-free
+    dense helper), and one :class:`~kfac_tpu.layers.helpers
+    .ExpertStackTap` for the projection in ``Registry.stacks``.
+
+    The registry also reports what K-FAC leaves alone:
+    ``Registry.passthrough`` maps every parameter leaf that no registered
+    layer owns to the clause of :data:`PASSTHROUGH_RULE` that applies.
     """
     skip_patterns = [re.compile(p) for p in (skip_layers or [])]
     routed_patterns = [re.compile(p) for p in (routed_layers or [])]
     found: dict[str, helpers.LayerHelper] = {}
     param_paths: dict[str, tuple[str, ...]] = {}
     taps: dict[str, tuple[str, str]] = {}
+    stacks: dict[str, helpers.ExpertStackTap] = {}
     unit_prefixes: list[tuple[str, ...]] = []
+    modules: dict[tuple[str, ...], tuple[str, bool]] = {}
 
     def interceptor(next_fun, iargs, ikwargs, context):
         mod = context.module
@@ -276,9 +390,30 @@ def register_model(
             return next_fun(*iargs, **ikwargs)
         name = path_name(mod.path)
         cls_name = type(mod).__name__.lower()
-        if any_match(name, skip_patterns) or any_match(cls_name, skip_patterns):
+        skipped = any_match(name, skip_patterns) or any_match(
+            cls_name, skip_patterns
+        )
+        modules[tuple(mod.path)] = (cls_name, skipped)
+        if skipped:
             return next_fun(*iargs, **ikwargs)
         path = tuple(mod.path)
+        if getattr(type(mod), '_kfac_expert_stack', False):
+            if name not in stacks:
+                slots = tuple(f'{name}/e{j}' for j in range(mod.experts))
+                for j, slot in enumerate(slots):
+                    found[slot] = helpers.DenseHelper(
+                        name=slot, has_bias=False,
+                        in_features=int(x.shape[-1]),
+                        out_features=int(mod.features),
+                        factor_dtype=factor_dtype, routed=True,
+                    )
+                    param_paths[slot] = path + (f'e{j}',)
+                stacks[name] = helpers.ExpertStackTap(
+                    name=name, slots=slots,
+                    out_features=int(mod.features), mode=mod.mode,
+                    factor_dtype=factor_dtype,
+                )
+            return next_fun(*iargs, **ikwargs)
         if getattr(type(mod), '_kfac_lora_unit', False):
             if name not in found:
                 found[name] = helpers.LoRAHelper(
@@ -333,7 +468,7 @@ def register_model(
                 return apply_fn(*full_args, **full_kwargs)
             return model.init(jax.random.PRNGKey(0), *full_args, **full_kwargs)
 
-    jax.eval_shape(probe, [leaves[i] for i in traced_positions])
+    probed = jax.eval_shape(probe, [leaves[i] for i in traced_positions])
     if routed_patterns:
         unmatched = [
             p.pattern
@@ -347,10 +482,18 @@ def register_model(
                 'the approximate shared-normalization capture, so it is an '
                 f'error. Registered layers: {sorted(found)}'
             )
+    passthrough, kfac_leaves = {}, ()
+    if apply_fn is None and isinstance(probed, Mapping) and 'params' in probed:
+        passthrough, kfac_leaves = passthrough_leaves(
+            probed['params'], param_paths, modules
+        )
     registry = Registry(
         layers=dict(found),
         param_paths=dict(param_paths),
         taps=dict(taps),
+        stacks=dict(stacks),
+        passthrough=passthrough,
+        kfac_leaves=kfac_leaves,
     )
     return masked_registry(registry, mask)
 
@@ -399,6 +542,9 @@ def merge_registries(*registries: Registry) -> Registry:
     layers: dict[str, helpers.LayerHelper] = {}
     paths: dict[str, tuple[str, ...]] = {}
     taps: dict[str, tuple[str, str]] = {}
+    stacks: dict[str, helpers.ExpertStackTap] = {}
+    passthrough: dict[str, str] = {}
+    kfac_leaves: tuple[str, ...] = ()
     for r in registries:
         overlap = set(layers) & set(r.layers)
         if overlap:
@@ -408,4 +554,10 @@ def merge_registries(*registries: Registry) -> Registry:
         layers.update(r.layers)
         paths.update(r.param_paths)
         taps.update(r.taps)
-    return Registry(layers=layers, param_paths=paths, taps=taps)
+        stacks.update(r.stacks)
+        passthrough.update(r.passthrough)
+        kfac_leaves += r.kfac_leaves
+    return Registry(
+        layers=layers, param_paths=paths, taps=taps, stacks=stacks,
+        passthrough=passthrough, kfac_leaves=kfac_leaves,
+    )

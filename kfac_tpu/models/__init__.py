@@ -1,4 +1,5 @@
-"""Model families: MLP, CIFAR/ImageNet ResNets, Transformer LM, MoE."""
+"""Model families: MLP, CIFAR/ImageNet ResNets, Transformer LM, MoE, and
+the sparse hybrid LM (Gated DeltaNet + gated attention + top-k experts)."""
 
 from kfac_tpu.models.lora import LoRADense
 from kfac_tpu.models.mlp import MLP
@@ -10,17 +11,32 @@ from kfac_tpu.models.resnet import (
     resnet50,
     resnet56,
 )
-from kfac_tpu.models.moe import MoEMLP, expert_tp_overrides, load_balance_loss
-from kfac_tpu.models.transformer import TransformerLM, lm_loss
+from kfac_tpu.models.deltanet import GatedDeltaNet
+from kfac_tpu.models.moe import (
+    MoEMLP,
+    SparseMoE,
+    expert_tp_overrides,
+    load_balance_loss,
+)
+from kfac_tpu.models.transformer import (
+    HybridLM,
+    TransformerLM,
+    hybrid_lm_loss,
+    lm_loss,
+)
 
 __all__ = [
     'LoRADense',
     'MLP',
     'MoEMLP',
     'CifarResNet',
+    'GatedDeltaNet',
+    'HybridLM',
     'ImageNetResNet',
+    'SparseMoE',
     'TransformerLM',
     'expert_tp_overrides',
+    'hybrid_lm_loss',
     'lm_loss',
     'load_balance_loss',
     'resnet20',

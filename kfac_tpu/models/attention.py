@@ -43,6 +43,38 @@ def dense_causal_attention(q, k, v):
     return out.astype(q.dtype)
 
 
+def blockwise_causal_attention(q, k, v, chunk: int = 1024):
+    """Causal attention by online softmax over chunks of keys, grouped
+    queries included: ``q`` ``(B, S, H, D)``, ``k``/``v`` ``(B, S, H_kv,
+    D)`` with every key/value head serving ``H / H_kv`` query heads.
+
+    The scores of one (query chunk, key chunk) pair exist at a time and
+    are not kept for the backward pass (each pair's partials are
+    rematerialised), so memory is ``O(S * chunk)`` where the dense path's
+    is ``O(S^2)``; chunk pairs above the diagonal are never formed. The
+    pairs go through :func:`_block_attend` and :func:`_merge`, as the
+    ring's do.
+    """
+    s, h, h_kv = q.shape[1], q.shape[2], k.shape[2]
+    if h % h_kv:
+        raise ValueError(f'{h} query heads over {h_kv} key/value heads')
+    if h != h_kv:
+        k, v = (jnp.repeat(x, h // h_kv, axis=2) for x in (k, v))
+    if s <= chunk or s % chunk:
+        chunk = s
+    attend = jax.checkpoint(_block_attend, static_argnums=(3, 4, 5))
+    out = []
+    for i in range(s // chunk):
+        qi = q[:, i * chunk:(i + 1) * chunk]
+        carry = None
+        for j in range(i + 1):
+            kv = slice(j * chunk, (j + 1) * chunk)
+            blk = attend(qi, k[:, kv], v[:, kv], i * chunk, j * chunk, i == j)
+            carry = blk if carry is None else _merge(carry, blk)
+        out.append(_finish(carry))
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
 def _block_attend(q, k, v, q_offset, k_offset, causal):
     """Unnormalized blockwise attention: returns (acc, row_max, row_sum).
 

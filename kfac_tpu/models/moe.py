@@ -55,7 +55,7 @@ the stacked-bucket KAISA design:
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -166,3 +166,265 @@ def expert_tp_overrides() -> list[tuple[str, str]]:
         (r'.*expert\d+_up', 'column'),
         (r'.*expert\d+_down', 'row'),
     ]
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing over a share of the experts (stacked expert weights).
+#
+# ``SparseMoE`` is the expert layer of today's sparse LMs: a router over ALL
+# the layer's experts, top-k with renormalised weights, bias-free gated-MLP
+# experts and an optional shared expert behind a sigmoid gate. It is told
+# which experts it holds (``experts_held``: first index and count, the
+# chip's share of an expert-parallel group), routes over all of them and
+# computes its own experts' part of the result; what the absent experts
+# would add is left out (no code stands in for the absent chips).
+#
+# Among the experts held no assignment is dropped: the plan's row capacity
+# is the worst load (every token choosing every held expert it can), and
+# the products loop over the blocks in use (``ops/grouped.py``), so the
+# work follows the real load. ``ExpertPlan.dropped`` counts what the plan
+# failed to place and has to read 0.
+#
+# K-FAC: every expert's three projections are K-FAC layers with their own
+# A and G factors (``<moe>/experts/<proj>/e<j>``: slots of one size class
+# in the engine's buckets), but the *program* holds one stacked product
+# and ONE capture tap a projection (``ExpertProjection`` ->
+# ``layers.helpers.ExpertStackTap``): factors are stacked ``(E_here, d,
+# d)`` sums over each expert's own rows, normalised by its live rows; an
+# expert with no row in a capture carries weight 0 and keeps its factors.
+# The parameters stay one 2-D ``kernel`` leaf an expert (stacked when the
+# layer is called), so that optimizers, checkpoints and the benchmark's
+# plain reference see ordinary dense layers.
+
+
+class ExpertPlan(NamedTuple):
+    """Where each assignment to a held expert lives, in blocks of rows.
+
+    ``blocks = ceil(tokens * min(top_k, held) / block_rows) + held``:
+    room for the worst load with every expert's rows padded to whole
+    blocks. Rows past an expert's last are padding: they read zeros,
+    weigh 0 and write nowhere.
+    """
+
+    row_token: jax.Array     # (blocks, rows) int32; ``tokens`` for padding
+    row_weight: jax.Array    # (blocks, rows) float32 routing weight
+    block_expert: jax.Array  # (blocks,) int32, local index of the expert
+    n_blocks: jax.Array      # int32 scalar: blocks in use
+    rows: jax.Array          # (held,) int32: live rows of each expert
+    dropped: jax.Array       # int32 scalar: assignments left out (0)
+
+
+@jax.custom_vjp
+def _row_weights(wts, assign, valid, dest):
+    """``wts.ravel()[assign]`` where ``valid`` (else 0), with a backward
+    pass that is a gather too (``dest``: assignment -> plan row), not a
+    scatter of tens of thousands of scalars."""
+    del dest
+    return jnp.where(valid, wts.reshape(-1)[assign], 0.0)
+
+
+def _row_weights_fwd(wts, assign, valid, dest):
+    return _row_weights(wts, assign, valid, dest), (wts.shape, dest)
+
+
+def _row_weights_bwd(res, d_rows):
+    shape, dest = res
+    flat = jnp.concatenate([d_rows.reshape(-1), jnp.zeros((1,), d_rows.dtype)])
+    return flat[dest].reshape(shape), None, None, None
+
+
+_row_weights.defvjp(_row_weights_fwd, _row_weights_bwd)
+
+
+def make_plan(
+    idx: jax.Array, wts: jax.Array, first: int, held: int, block_rows: int
+) -> ExpertPlan:
+    """The row plan of a routing: ``idx`` ``(tokens, k)`` int32 expert
+    choices, ``wts`` their weights; experts ``first .. first + held - 1``
+    live here. Two sorts and gathers, no scatter."""
+    tokens, k = idx.shape
+    n = tokens * k
+    blocks = -(-tokens * min(k, held) // block_rows) + held
+    local = (idx - first).reshape(n)
+    is_held = (local >= 0) & (local < held)
+    key = jnp.where(is_held, local, held)
+    order = jnp.argsort(key, stable=True)   # sorted position -> assignment
+    place = jnp.argsort(order)              # assignment -> sorted position
+    rows = jnp.sum(
+        key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0,
+        dtype=jnp.int32,
+    )
+    group_start = jnp.cumsum(rows) - rows   # in sorted order
+    blocks_of = -(-rows // block_rows)
+    block_end = jnp.cumsum(blocks_of)
+    n_blocks = block_end[-1]
+    row_start = (block_end - blocks_of) * block_rows
+    block_expert = jnp.minimum(
+        jnp.searchsorted(block_end, jnp.arange(blocks), side='right'),
+        held - 1,
+    ).astype(jnp.int32)
+    r = jnp.arange(blocks * block_rows, dtype=jnp.int32)
+    e_r = jnp.repeat(block_expert, block_rows)
+    off = r - row_start[e_r]
+    valid = (off < rows[e_r]) & (r < n_blocks * block_rows)
+    assign = order[jnp.clip(group_start[e_r] + off, 0, n - 1)]
+    e_n = jnp.minimum(key, held - 1)
+    dest = jnp.where(
+        is_held, row_start[e_n] + place - group_start[e_n],
+        blocks * block_rows,
+    )
+    shape = (blocks, block_rows)
+    return ExpertPlan(
+        row_token=jnp.where(valid, assign // k, tokens)
+        .astype(jnp.int32).reshape(shape),
+        row_weight=_row_weights(
+            wts.astype(jnp.float32), assign, valid, dest
+        ).reshape(shape),
+        block_expert=block_expert,
+        n_blocks=n_blocks.astype(jnp.int32),
+        rows=rows,
+        # counted from the plan as built, not from the routing: a capacity
+        # or placement fault shows here
+        dropped=(jnp.sum(is_held) - jnp.sum(valid)).astype(jnp.int32),
+    )
+
+
+class _ExpertKernel(nn.Module):
+    """One expert's ``kernel`` leaf (a dense layer's, to everything that
+    walks the parameter tree)."""
+
+    shape: tuple[int, int]
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param('kernel', nn.initializers.lecun_normal(), self.shape)
+
+
+class ExpertProjection(nn.Module):
+    """One projection of every held expert: a stacked ``(E_here, d_in,
+    d_out)`` product over the plan's blocks. ``mode``: ``'gather'`` reads
+    token rows ``(tokens, d_in)`` and returns blocks; ``'combine'`` maps
+    blocks to ``(tokens, d_out)``, each row weighted by its routing weight
+    and summed into its token.
+
+    ``register_model`` registers its experts as ``<path>/e<j>`` slots and
+    the capture layer taps it once (``_kfac_expert_stack``)."""
+
+    _kfac_expert_stack = True
+
+    experts: int
+    features: int
+    mode: str  # 'gather' | 'combine'
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self, x: jax.Array, plan: ExpertPlan, tokens: int | None = None
+    ) -> jax.Array:
+        from kfac_tpu.ops import grouped
+
+        w = jnp.stack([
+            _ExpertKernel((x.shape[-1], self.features), name=f'e{j}')()
+            .astype(self.dtype) for j in range(self.experts)
+        ])
+        x = x.astype(self.dtype)
+        if self.mode == 'gather':
+            return grouped.grouped_matmul_gather(
+                x, w, plan.row_token, plan.block_expert, plan.n_blocks
+            )
+        return grouped.grouped_matmul_combine(
+            x, w, plan.row_token, plan.row_weight, plan.block_expert,
+            plan.n_blocks, tokens,
+        )
+
+
+class Experts(nn.Module):
+    """The held experts' gated MLPs over a plan: ``down(silu(gate x) *
+    up x)``, weighted and summed into the tokens."""
+
+    experts: int
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array, plan: ExpertPlan) -> jax.Array:
+        def proj(features, mode, name):
+            return ExpertProjection(
+                self.experts, features, mode, dtype=self.dtype, name=name
+            )
+
+        g = proj(self.width, 'gather', 'gate_proj')(x, plan)
+        u = proj(self.width, 'gather', 'up_proj')(x, plan)
+        return proj(x.shape[-1], 'combine', 'down_proj')(
+            nn.silu(g) * u, plan, tokens=x.shape[0]
+        )
+
+
+class GatedMLP(nn.Module):
+    """Bias-free ``down(silu(gate x) * up x)``."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        def dense(features, name):
+            return nn.Dense(
+                features, use_bias=False, dtype=self.dtype, name=name
+            )
+
+        h = nn.silu(dense(self.width, 'gate_proj')(x)) * dense(
+            self.width, 'up_proj'
+        )(x)
+        return dense(x.shape[-1], 'down_proj')(h)
+
+
+class SparseMoE(nn.Module):
+    """Top-k routed gated-MLP experts, a share of them held here.
+
+    ``y = sum_{e in top-k, e held} w_e expert_e(x) + sigmoid(x w_s)
+    shared(x)``. Router logits and softmax in float32 over all
+    ``num_experts``; the top-k weights renormalised (``norm_topk_prob``).
+    ``experts_held = (first, count)``; ``None`` holds every expert.
+    ``shared_width`` 0 leaves the shared expert out.
+    """
+
+    num_experts: int
+    top_k: int
+    width: int
+    shared_width: int = 0
+    experts_held: tuple[int, int] | None = None
+    norm_topk_prob: bool = True
+    block_rows: int = 256
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        from kfac_tpu import tracing
+
+        lead, d = x.shape[:-1], x.shape[-1]
+        xf = x.reshape(-1, d)
+        first, held = self.experts_held or (0, self.num_experts)
+        with tracing.model_scope('moe_route'):
+            logits = nn.Dense(
+                self.num_experts, use_bias=False, dtype=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST, name='router',
+            )(xf.astype(jnp.float32))
+            probs = jax.nn.softmax(logits, axis=-1)
+            wts, idx = jax.lax.top_k(probs, self.top_k)
+            if self.norm_topk_prob:
+                wts = wts / jnp.sum(wts, axis=-1, keepdims=True)
+            plan = make_plan(idx, wts, first, held, self.block_rows)
+        with tracing.model_scope('moe_experts'):
+            y = Experts(held, self.width, dtype=self.dtype, name='experts')(
+                xf, plan
+            )
+        if self.shared_width:
+            gate = nn.Dense(
+                1, use_bias=False, dtype=jnp.float32, name='shared_gate'
+            )(xf.astype(jnp.float32))
+            shared = GatedMLP(
+                self.shared_width, dtype=self.dtype, name='shared'
+            )(xf)
+            y = y + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
+        return y.reshape(*lead, d)

@@ -283,6 +283,11 @@ class DistKFACState(NamedTuple):
     # replicated, shaped by the host-side chunk plan
     # (``_plan_compression``).
     comp_ef: Any = None
+    # what the last capture saw of the routed experts held here, where the
+    # registry has stacked expert projections (``Registry.stacks``):
+    # float32 :data:`TRAFFIC_COLUMNS`. ``None`` without them. Ephemeral
+    # like ``refresh`` below. Read with :meth:`DistributedKFAC.traffic_report`.
+    traffic: Any = None
     # what the last Newton-Schulz refresh (``update_inverses``) reported of
     # itself: a :class:`RefreshState`, ONE float32 leaf. ``None`` where no
     # synchronous Newton-Schulz refresh runs (the eigen method, the
@@ -290,6 +295,34 @@ class DistKFACState(NamedTuple):
     # metrics/flight: ``init()`` makes it, no checkpoint holds it. Read
     # with :meth:`DistributedKFAC.refresh_report`.
     refresh: Any = None
+
+
+# ``DistKFACState.traffic``: live rows of the emptiest held expert and of
+# the mean one over every stacked projection's experts at the last capture,
+# and the assignments to held experts that a plan left out (must be 0).
+TRAFFIC_COLUMNS = ('rows_min', 'rows_mean', 'dropped')
+
+# The most the engine holds of one value of a bucket's stack beside the
+# state: a wider stack is solved in equal groups of slots, one after
+# another (``_sharded_inv``), so that a Newton-Schulz solve's temporaries
+# (several values of the stack) stay a few times this, and its statistics
+# are folded into the factors row by row, never stacked
+# (``_stack_stats``, ``update_factors``). 512 MiB is above every stack of
+# the dense models run so far (12 slots 3,200 wide: 469 MiB) and a third
+# of a sparse model's 78 slots 2,048 wide.
+SOLVE_GROUP_BYTES = 512 * 2**20
+
+
+def _solve_groups(slots: int, d: int) -> int:
+    """Groups to solve ``slots`` factors ``d`` wide in: the fewest that
+    divide them with a group's float32 stack inside
+    :data:`SOLVE_GROUP_BYTES`."""
+    for groups in range(1, slots + 1):
+        if slots % groups == 0 and (
+            (slots // groups) * d * d * 4 <= SOLVE_GROUP_BYTES
+        ):
+            return groups
+    return slots
 
 
 # Columns of ``RefreshState.solved``: each slot's
@@ -302,7 +335,7 @@ _NS_SOLVERS = ('newton_schulz', 'auto')
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=['solved'], meta_fields=['buckets'],
+    data_fields=['solved'], meta_fields=['buckets', 'groups'],
 )
 @dataclasses.dataclass(frozen=True)
 class RefreshState:
@@ -313,10 +346,13 @@ class RefreshState:
     costs the device nothing): ``(side, key, padded, live)`` for every
     bucket of ``DistributedKFAC.a_store`` and then of ``g_store``, in the
     order of the rows; a bucket's first ``live`` slots hold a layer, the
-    rest identity padding."""
+    rest identity padding. ``groups``: the equal groups of slots each
+    bucket's stack is solved in, one after another (:func:`_solve_groups`;
+    1 for all but the widest stacks)."""
 
     buckets: tuple[tuple[str, str, int, int], ...]
     solved: jax.Array
+    groups: tuple[int, ...] = ()
 
 
 def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
@@ -325,21 +361,26 @@ def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
     ``warm_starts``, ``restarts`` and ``worst_residual``, and the
     ``trips`` its loop ran (the vmapped ``while_loop`` runs every slot of
     a device's block until the slowest is done: the largest
-    ``iterations`` of all its slots; identity padding converges in 0 or,
-    warm-started across a damping change, in one or two). Empty while no
-    refresh has filled the array."""
+    ``iterations`` of all its slots, summed over the groups a wide stack
+    is solved in; identity padding converges in 0 or, warm-started across
+    a damping change, in one or two). Empty while no refresh has filled
+    the array."""
     rows = np.asarray(jax.device_get(refresh.solved), np.float64)
     col = dict(zip(REFRESH_COLUMNS, rows.T))
     if not (col['iterations'] >= 0).any():
         return []
     out, start = [], 0
-    for side, key, padded, live in refresh.buckets:
+    groups = refresh.groups or (1,) * len(refresh.buckets)
+    for (side, key, padded, live), n in zip(refresh.buckets, groups):
         its = col['iterations'][start:start + live]
         out.append({
             'side': side,
             'key': key,
             'iterations': [int(v) for v in its],
-            'trips': int(col['iterations'][start:start + padded].max()),
+            'trips': int(sum(
+                group.max() for group in
+                np.split(col['iterations'][start:start + padded], n)
+            )),
             'warm_starts': int(col['warm'][start:start + live].sum()),
             'restarts': int(col['restarted'][start:start + live].sum()),
             # np.max, not max(): a NaN residual has to show
@@ -662,9 +703,12 @@ class DistributedKFAC:
             shadow=shadow_sh,
             comp_ef=comp_ef_sh,
             refresh=(
-                RefreshState(self._refresh_buckets(), rep)
+                RefreshState(
+                    self._refresh_buckets(), rep, self._refresh_groups()
+                )
                 if self._ns_refresh else None
             ),
+            traffic=rep if self.registry.stacks else None,
         )
 
     # ----------------------------------------------------------------- init
@@ -756,6 +800,10 @@ class DistributedKFAC:
                     ])
                     if self._ns_refresh else None
                 ),
+                traffic=(
+                    jnp.zeros((len(TRAFFIC_COLUMNS),), jnp.float32)
+                    if self.registry.stacks else None
+                ),
             )
 
         def build_with_shadow() -> DistKFACState:
@@ -785,7 +833,9 @@ class DistributedKFAC:
 
         Returns ``(a_stacks, g_stacks, new_comp_ef)``: the third element
         is the updated error-feedback residual dict when the compressed
-        transport carries one, else the state's ``comp_ef`` unchanged.
+        transport carries one, else the state's ``comp_ef`` unchanged. A
+        bucket whose stack is wider than :data:`SOLVE_GROUP_BYTES` comes
+        back as the list of its layers' rows, not stacked.
         """
         cfg = self.config
         bucketed = (
@@ -895,6 +945,13 @@ class DistributedKFAC:
             stacks = {}
             for sb in store:
                 r = rows[sb.key]
+                if _solve_groups(sb.padded, sb.d) > 1:
+                    # a stack too wide to hold whole beside the state and
+                    # the statistics themselves: its rows stay apart and
+                    # ``update_factors`` folds them into the state in
+                    # place, one by one (padding slots keep their identity)
+                    stacks[sb.key] = r
+                    continue
                 pad = sb.padded - len(sb.layers)
                 if pad:
                     r = r + [jnp.eye(sb.d, dtype=cfg.factor_dtype)] * pad
@@ -977,8 +1034,18 @@ class DistributedKFAC:
         def ema(store, side_state, stacks):
             out = {}
             for sb in store:
-                s = jax.lax.with_sharding_constraint(stacks[sb.key], fac)
                 av = slot_alphas(sb)
+                if isinstance(stacks[sb.key], list):
+                    # rows apart (see ``_stack_stats``): a chain of
+                    # in-place row updates of the donated stack, each
+                    # reading the row it is about to replace
+                    new = side_state[sb.key]
+                    for i, row in enumerate(stacks[sb.key]):
+                        a_i = alpha if av is None else av[i].astype(row.dtype)
+                        new = new.at[i].set(a_i * new[i] + (1 - a_i) * row)
+                    out[sb.key] = new
+                    continue
+                s = jax.lax.with_sharding_constraint(stacks[sb.key], fac)
                 if av is None:
                     out[sb.key] = alpha * side_state[sb.key] + (1 - alpha) * s
                 else:
@@ -1055,6 +1122,13 @@ class DistributedKFAC:
         state = state._replace(
             a=new_a, g=new_g, health=new_health, comp_ef=new_ef
         )
+        traffic = getattr(stats, 'traffic', None)
+        if traffic and state.traffic is not None:
+            seen = [traffic[n] for n in sorted(traffic)]
+            rows = jnp.concatenate([t[:-1] for t in seen])
+            state = state._replace(traffic=jnp.stack([
+                jnp.min(rows), jnp.mean(rows), sum(t[-1] for t in seen),
+            ]))
         if self.config.metrics is not None and state.metrics is not None:
             state = state._replace(
                 metrics=self._record_factor_metrics(state, updated, ok)
@@ -1145,6 +1219,21 @@ class DistributedKFAC:
         iters = self.config.newton_schulz_iters
 
         def local(block, prev_block, dmp_block):
+            groups = _solve_groups(block.shape[0], block.shape[-1])
+            if groups > 1:
+                def split(x):
+                    return x.reshape(groups, -1, *x.shape[1:])
+
+                out = jax.lax.map(
+                    lambda t: solve(*t),
+                    (split(block), split(prev_block), split(dmp_block)),
+                )
+                return jax.tree_util.tree_map(
+                    lambda x: x.reshape(-1, *x.shape[2:]), out
+                )
+            return solve(block, prev_block, dmp_block)
+
+        def solve(block, prev_block, dmp_block):
             if solver == 'auto':
                 # one scalar cond per device-local block: Cholesky runs
                 # at runtime only when some slot's NS residual fails —
@@ -1337,6 +1426,14 @@ class DistributedKFAC:
             for sb in store
         )
 
+    def _refresh_groups(self) -> tuple[int, ...]:
+        """``RefreshState.groups``: the groups ``_sharded_inv`` solves a
+        device's block of each bucket in, in ``_refresh_buckets``' order."""
+        return tuple(
+            _solve_groups(sb.padded // self.total_devices, sb.d)
+            for sb in self.a_store + self.g_store
+        )
+
     def _pack_refresh(self, solved: list[jax.Array]) -> RefreshState:
         """``DistKFACState.refresh`` from what ``_sharded_inv`` returned
         for every bucket of the A store and then of the G store."""
@@ -1345,6 +1442,7 @@ class DistributedKFAC:
             jax.lax.with_sharding_constraint(
                 jnp.concatenate(solved), NamedSharding(self.mesh, P())
             ),
+            self._refresh_groups(),
         )
 
     def refresh_report(self, state: DistKFACState) -> dict[str, Any]:
@@ -1374,6 +1472,17 @@ class DistributedKFAC:
             'buckets': buckets,
             'totals': {k.split('/', 1)[1]: v for k, v in totals.items()},
         }
+
+    def traffic_report(self, state: DistKFACState) -> dict[str, float]:
+        """:data:`TRAFFIC_COLUMNS` of the last capture, on the host (one
+        ``device_get``); ``{}`` for a registry without stacked expert
+        projections."""
+        if getattr(state, 'traffic', None) is None:
+            return {}
+        return dict(zip(
+            TRAFFIC_COLUMNS,
+            (float(v) for v in jax.device_get(state.traffic)),
+        ))
 
     def inverse_residuals(
         self, state: DistKFACState

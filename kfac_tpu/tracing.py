@@ -15,8 +15,9 @@ them: docs/OBSERVABILITY.md "A step that reports on itself"):
   ``op_name`` metadata; a TPU trace's events carry no scope, so a reducer
   joins the two by instruction name): the engine entry points
   (``dist_kfac.step`` / ``.update_factors`` / ``.update_inverses`` /
-  ``.precondition``, :func:`scope`) and the capture layer's two sides,
-  :data:`CAPTURE_SCOPES` (:func:`capture_scope`);
+  ``.precondition``, :func:`scope`), the capture layer's two sides,
+  :data:`CAPTURE_SCOPES` (:func:`capture_scope`), and the model parts of
+  :data:`MODEL_SCOPES` (:func:`model_scope`);
 - host spans (``jax.profiler.TraceAnnotation``: they land on the
   profile's ``/host:CPU`` line in the device's time base):
   :data:`HOST_SPANS` inside ``Trainer.step`` (:func:`host_span`).
@@ -52,11 +53,24 @@ _force_sync: bool = False
 # in the backward pass under the g-taps' vjp rule. 'patches' nests under
 # the A side ('kfac.capture_a/patches'): the convolution helper's patch
 # rows (im2col, the reshape to rows, the bias column, the scaling), all
-# of a convolution's A side but the covariance itself.
+# of a convolution's A side but the covariance itself. 'experts' nests
+# under either side ('kfac.capture_a/experts', 'kfac.capture_g/experts'):
+# the stacked per-expert covariances of a routed expert projection.
 CAPTURE_SCOPES = {
     'a': 'kfac.capture_a',
     'g': 'kfac.capture_g',
     'patches': 'patches',
+    'experts': 'experts',
+}
+
+# Device scopes of the model parts that a sparse hybrid LM adds
+# (kfac_tpu/models/deltanet.py, models/moe.py): the chunked delta-rule scan
+# (forward, and its backward pass under ``transpose(jvp(...))``), the
+# router with its top-k and row plan, and the grouped expert products.
+MODEL_SCOPES = {
+    'gdn_scan': 'model.gdn_scan',
+    'moe_route': 'model.moe_route',
+    'moe_experts': 'model.moe_experts',
 }
 
 # Host spans inside one Trainer step, in order: what runs before the jitted
@@ -169,8 +183,14 @@ def scope(name: str) -> Callable[[F], F]:
 
 def capture_scope(side: str):
     """``jax.named_scope`` of one side of the capture layer: ``'a'``,
-    ``'g'``, or ``'patches'`` inside ``'a'`` (:data:`CAPTURE_SCOPES`)."""
+    ``'g'``, or inside one of them ``'patches'`` or ``'experts'``
+    (:data:`CAPTURE_SCOPES`)."""
     return jax.named_scope(CAPTURE_SCOPES[side])
+
+
+def model_scope(part: str):
+    """``jax.named_scope`` of one of :data:`MODEL_SCOPES`."""
+    return jax.named_scope(MODEL_SCOPES[part])
 
 
 def host_span(part: str, step: int | None):

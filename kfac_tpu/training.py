@@ -653,6 +653,11 @@ class Trainer:
                 for n, h in reg.layers.items()
                 if n in executed and getattr(h, 'weighted', False)
             },
+            traffic={
+                n: jax.numpy.zeros((len(t.slots) + 1,), jax.numpy.float32)
+                for n, t in reg.stacks.items()
+                if t.slots[0] in executed
+            },
         )
 
     def _scan_body(self, state: TrainState, batch, executed: set[str]):

@@ -1,0 +1,147 @@
+"""``resnet50.kaisa-hybrid-4chip``: the benchmark's four-chip cell as data
+(a workload file, two readers, entries in ``BENCHMARK.json``), its
+collective readers on a hand-made trace, and the cell at a tiny size on
+four virtual CPU devices through ``harness.run_cell``.
+"""
+
+import os
+import sys
+import time
+
+import jax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import harness, rehearse  # noqa: E402
+from kfac_tpu import enums, preconditioner  # noqa: E402
+
+CELL = 'resnet50.kaisa-hybrid-4chip'
+ONE_CHIP = 'resnet50.kfac-10-100'
+
+
+def _op(text, start, ns):
+    return {'name': text, 'start_ns': start, 'duration_ns': ns, 'stats': {}}
+
+
+def _plane(index, ops):
+    return {'name': f'/device:TPU:{index}', 'lines': [
+        {'name': 'XLA Ops', 'events': ops},
+    ]}
+
+
+def _ctx(planes, steps):
+    return harness.LayerContext(
+        cell={}, run=None, devices=[], first_order_rows=[], rows=[],
+        traced_rows=[{'kind': 'plain'}] * steps, trace={'planes': planes},
+        windows={p['name']: (0, 10_000) for p in planes}, throughput=0.0,
+    )
+
+
+def _fusion(n, start, ns):
+    return _op(f'%fusion.{n} = f32[8]{{0}} fusion(f32[8]{{0}} %x)', start, ns)
+
+
+def test_collective_readers_on_a_synthetic_trace():
+    """Two devices, two traced steps. Device 0: an all-reduce [100,400)
+    under compute [0,200): 300 in collectives, 200 of it alone; an
+    all-gather's two halves [500,520) and [580,600) with compute between
+    them: 40 more, all alone. Device 1: one collective-permute [0,100)
+    wholly under compute. The readers take the device with most."""
+    d0 = _plane(0, [
+        _fusion(1, 0, 200),
+        _op('%all-reduce.2 = f32[8]{0} all-reduce(f32[8]{0} %g)', 100, 300),
+        _op('%all-gather-start.3 = f32[8]{0} all-gather-start(%x)', 500, 20),
+        _fusion(4, 520, 60),
+        _op('%all-gather-done.3 = f32[8]{0} all-gather-done(%y)', 580, 20),
+    ])
+    d1 = _plane(1, [
+        _fusion(1, 0, 300),
+        _op('%collective-permute.5 = f32[8]{0} collective-permute(%x)', 0, 100),
+    ])
+    ctx = _ctx([d0, d1], steps=2)
+    assert harness.read_layer_metric('collective_ms', ctx) == pytest.approx(
+        340 / 2 / 1e6
+    )
+    assert harness.read_layer_metric(
+        'collective_exposed_ms', ctx
+    ) == pytest.approx(240 / 2 / 1e6)
+
+
+def test_collectives_all_hidden_read_zero_and_none_reads_nothing():
+    hidden = _plane(0, [
+        _fusion(1, 0, 300),
+        _op('%all-reduce.2 = f32[8]{0} all-reduce(f32[8]{0} %g)', 50, 100),
+    ])
+    ctx = _ctx([hidden], steps=1)
+    assert harness.read_layer_metric('collective_ms', ctx) == pytest.approx(1e-4)
+    # nothing exposed is a reading, not a missing one
+    assert harness.read_layer_metric('collective_exposed_ms', ctx) == 0.0
+    # a one-chip program holds no collective: the line leaves both out
+    ctx = _ctx([_plane(0, [_fusion(1, 0, 300)])], steps=1)
+    assert harness.read_layer_metric('collective_ms', ctx) is None
+    assert harness.read_layer_metric('collective_exposed_ms', ctx) is None
+    # no traced step, nothing to divide by
+    assert harness.read_layer_metric('collective_ms', _ctx([hidden], 0)) is None
+
+
+def test_the_cell_is_cell_one_on_four_chips_under_hybrid_opt():
+    cell, one = harness.load_cell(CELL), harness.load_cell(ONE_CHIP)
+    assert cell['chips'] == 4 and one['chips'] == 1
+    assert cell['config'] == one['config']
+    new, old = dict(cell['workload']), dict(one['workload'])
+    assert new['kfac'].pop('strategy') == 'hybrid-opt'
+    assert old['kfac'].pop('strategy') == 'comm-opt'
+    for key in ('chips', 'traffic', 'limits', 'limits_why', 'limits_readings'):
+        new.pop(key), old.pop(key)
+    assert new == old
+    assert set(cell['workload']['limits']) == set(one['workload']['limits'])
+    listed = [w for w in cell['bench']['workloads'] if w['chips'] == 4]
+    assert [w['name'] for w in listed] == [CELL]  # the benchmark's one
+
+
+def test_benchmark_rows_of_the_four_chip_cell():
+    bench = harness.load_cell(CELL)['bench']
+    rows = {m['name']: m for m in bench['per_layer']}
+    for name in ('collective_ms', 'collective_exposed_ms'):
+        assert rows[name]['workloads'] == [CELL]
+        assert rows[name]['source'] == 'device_trace'
+    # no Mosaic kernel runs in a several-device program (the gate in
+    # ops/pallas_cov.use_pallas_for): the kernel's row names the cells
+    # that have it, and this one is not among them
+    assert CELL not in rows['dev_ms.sym_cov']['workloads']
+    assert ONE_CHIP in rows['dev_ms.sym_cov']['workloads']
+    # the feed of a sharded batch runs a slicing program on device 0
+    # between the steps, and this reader pairs steps with program runs
+    # one to one: it reads nothing here (my chip run, PR 27)
+    assert CELL not in rows['capture_dev_extra_ms']['workloads']
+    assert ONE_CHIP in rows['capture_dev_extra_ms']['workloads']
+    # what the program reports on itself it reports on four chips too
+    for name in ('dev_ms.capture_a', 'dev_ms.capture_g', 'ns_trips_refresh',
+                 'host_ms.launch', 'idle_ms.launch', 'dev_ms.capture_patches'):
+        assert CELL in rows[name]['workloads'], name
+
+
+def test_the_cell_tiny_on_four_devices_through_the_harness(monkeypatch):
+    """HYBRID-OPT on a 2x2 mesh of virtual CPU devices: the program's first
+    three K-FAC steps against the plain reference's, then a window."""
+    monkeypatch.setattr(
+        preconditioner, 'default_compute_method',
+        lambda platform=None: (enums.ComputeMethod.INVERSE, 'newton_schulz'),
+    )
+    cell = rehearse.tiny_cell(harness.load_cell(CELL))
+    cell['workload']['limits'] = {
+        'loss_gap': 1e-4, 'first_grad_norm_gap': 2e-3,
+        'update_norm_gap': 2e-3, 'inverse_residual': 3e-6,
+    }
+    lines = []
+    result = harness.run_cell(
+        cell, 2_147_483_659, 0.5, False, jax.devices()[:4],
+        time.perf_counter(), lines.append,
+    )
+    assert result['correct'] is True, lines
+    assert result['device']['count'] == 4
+    assert set(result['metrics']) == {
+        m['name'] for m in cell['bench']['end_to_end']
+    }
