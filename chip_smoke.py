@@ -10,7 +10,9 @@ synthetic data from a seed), with every library default for method,
 solver, kernels and granularity, and a cadence that puts every step
 variant inside ten steps. Before that it compiles every Pallas kernel the
 default configuration can dispatch at a real ResNet-50 shape and holds it
-against the XLA expression it replaces.
+against the XLA expression it replaces, and holds the covariance product
+(``ops.cov.get_cov``) at two of the benchmark's shapes against a float64
+product of the same values on the host.
 
 It fails — non-zero, no result line — unless ``jax.devices()[0]`` is a
 TPU (it never pins or falls back to the CPU), if any phase raises, if a
@@ -42,22 +44,26 @@ IMAGES_PER_CHIP = 32
 
 # A kernel may not be less accurate than the XLA expression it replaces.
 # Both are measured against that expression at precision=HIGHEST, and the
-# kernel's error may be at most twice XLA's plus 2^-16. Where the
-# expression leaves matmul precision at the TPU's default (the
-# covariance), kernel and XLA both round f32 operands to bf16 once and
-# their errors match up to accumulation order (the factor 2). Where it
-# has no matmul (kl-clip), what is left is the accumulation order over up
-# to 4,608 terms (the floor). A kernel that dropped a tile, a mask or a
-# scale is off by orders of magnitude more.
+# kernel's error may be at most twice XLA's plus 2^-16. Kl-clip has no
+# matmul: what is left is the accumulation order over up to 4,608 terms
+# (the floor). A kernel that dropped a tile, a mask or a scale is off by
+# orders of magnitude more.
 KERNEL_TOL_FACTOR = 2.0
 KERNEL_TOL_FLOOR = 2.0 ** -16
 
-# Real shapes from ResNet-50 at 32 images per chip (see
-# DistributedKFAC.describe()): stage3 conv2's A factor reads 32*7*7 patch
-# rows 512*9 wide; the head's A factor is 2048+1 wide (ragged); the
-# preconditioned gradients of stage3 conv2 and of the head.
+# The covariance product against float64 (relative Frobenius norm): what
+# float32 accumulation over the rows leaves, for bfloat16 rows (exact
+# products) and for float32 rows (HIGHEST). An operand rounded to
+# bfloat16 on the way reads 2^-9, two hundred times this.
+COV_TOL = 2.0 ** -16
+
+# Covariance rows (rows, width) at the size of the benchmark's widest
+# factors, one column past whole tiles: ResNet-50's 3x3 patch rows of the
+# last stage at 128 images, GPT-2 small's MLP tap at 8 sequences. Kl-clip:
+# the preconditioned gradients of ResNet-50's stage3 conv2 and of its
+# head (ragged).
 KERNEL_SHAPES = {
-    'cov': [(1568, 4608), (32, 2049)],
+    'cov': [(6272, 4609), (8192, 3073)],
     'klclip': [(512, 4608), (1000, 2049)],
 }
 
@@ -82,15 +88,17 @@ def resnet50_argv(n_devices: int) -> list[str]:
 
 def check_kernels(shapes=KERNEL_SHAPES) -> list[dict]:
     """Compile each Pallas kernel the default path dispatches on a TPU
-    and compare it with the XLA expression it replaces. One row per
-    kernel and shape; raises if a row misses its tolerance. Off a TPU
-    the same calls run the Pallas interpreter (tests only)."""
+    and compare it with the XLA expression it replaces, and the
+    covariance product with float64. One row per kernel and shape;
+    raises if a row misses its tolerance. Off a TPU the same calls run
+    the Pallas interpreter (tests only)."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
-    from kfac_tpu.ops import pallas_cov, pallas_ns
+    from kfac_tpu.ops import cov, pallas_gate, pallas_ns
 
-    interpret = pallas_cov.interpret_mode()
+    interpret = pallas_gate.interpret_mode()
     rows: list[dict] = []
 
     @jax.jit
@@ -128,9 +136,24 @@ def check_kernels(shapes=KERNEL_SHAPES) -> list[dict]:
         return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
 
     for n, d in shapes['cov']:
-        row('sym_cov', (n, d),
-            lambda a: pallas_cov.sym_cov(a, scale=1.0, interpret=interpret),
-            lambda a: a.T @ a, (normal(0, (n, d)),))
+        for dtype in (jnp.bfloat16, jnp.float32):
+            a = normal(0, (n, d)).astype(dtype)
+            a64 = np.asarray(a.astype(jnp.float32), np.float64)
+            ref = a64.T @ a64 / n
+            got = np.asarray(jax.jit(cov.get_cov)(a), np.float64)
+            r = {
+                'kernel': 'get_cov', 'shape': [n, d],
+                'dtype': jnp.dtype(dtype).name,
+                'max_err': float(
+                    np.linalg.norm(got - ref) / np.linalg.norm(ref)
+                ),
+                'tol': COV_TOL,
+            }
+            log(f"get_cov {r['shape']} {r['dtype']}: err {r['max_err']:.2e} "
+                f"against float64 (tol {COV_TOL:.2e})")
+            if not r['max_err'] <= r['tol']:
+                raise RuntimeError(f'covariance check failed: {r}')
+            rows.append(r)
 
     for r_, c in shapes['klclip']:
         p, g = normal(3, (r_, c)), normal(4, (r_, c))

@@ -245,12 +245,11 @@ def check_cost_model_parity(suite: harness.Suite) -> list[core.Finding]:
 
 #: kernel function names allowed to appear as ``pallas_call`` eqns in
 #: traced engine programs — the registry the fused step-path kernels pin
-#: themselves to (kfac_tpu/ops/pallas_{cov,cov_ema,ns,attention}.py).
+#: themselves to (kfac_tpu/ops/pallas_{cov_ema,ns,attention}.py).
 #: An unlisted kernel on the step path is either a new kernel that
 #: skipped its pricing/equivalence/dispatch wiring, or a renamed one
 #: whose autotune price and docs now point at nothing.
 STEP_PALLAS_ALLOWLIST = frozenset({
-    '_sym_cov_kernel',
     '_sym_cov_ema_kernel',
     '_klclip_dot_kernel',
     '_klclip_scale_kernel',

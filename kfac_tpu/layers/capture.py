@@ -31,6 +31,23 @@ from kfac_tpu.layers import helpers as helpers_lib
 from kfac_tpu.layers import registry as registry_lib
 
 
+def layer_input(module: nn.Module, a: jax.Array) -> jax.Array:
+    """The A-side tap as the layer's own product sees it: rounded to the
+    dtype the module computes in (flax: its ``dtype``, or without one the
+    promotion of input and parameters). A norm in float32 feeding a
+    bfloat16 layer hands over float32 values the layer then rounds, and
+    the covariance multiplies what the layer multiplies
+    (:func:`kfac_tpu.ops.cov.get_cov`). The G side needs no such step: a
+    cotangent arrives in the dtype of the layer's output.
+    """
+    dtype = getattr(module, 'dtype', None)
+    if dtype is None:
+        dtype = jnp.promote_types(
+            a.dtype, getattr(module, 'param_dtype', a.dtype)
+        )
+    return jax.lax.stop_gradient(a).astype(dtype)
+
+
 def _make_gtap(helper: helpers_lib.LayerHelper) -> Callable[..., jax.Array]:
     """Identity on ``y`` whose vjp emits the layer G factor into ``gstat``."""
 
@@ -238,15 +255,15 @@ class CurvatureCapture:
                     if weight is not None:
                         weights[name] = weight
 
-            def role_tap(name, iargs, ikwargs, next_fun):
+            def role_tap(mod, name, iargs, ikwargs, next_fun):
                 # fused-unit child projection: embed this role's A block
                 # into the unit's block-diagonal accumulator and g-tap the
                 # child output into the unit's shared G dummy (cotangents
                 # of the two roles sum there)
                 unit, role = registry.taps[name]
                 uhelper = registry.layers[unit]
-                a = jax.lax.stop_gradient(iargs[0])
                 with tracing.capture_scope('a'):
+                    a = layer_input(mod, iargs[0])
                     a_fac = uhelper.role_a_factor(role, a)
                 accumulate(unit, a_fac)
                 y = next_fun(*iargs, **ikwargs)
@@ -284,10 +301,10 @@ class CurvatureCapture:
                     return next_fun(*iargs, **ikwargs)
                 if helper is None:
                     if name in registry.taps:
-                        return role_tap(name, iargs, ikwargs, next_fun)
+                        return role_tap(mod, name, iargs, ikwargs, next_fun)
                     return next_fun(*iargs, **ikwargs)
-                a = jax.lax.stop_gradient(iargs[0])
                 with tracing.capture_scope('a'):
+                    a = layer_input(mod, iargs[0])
                     a_fac = helper.get_a_factor(a)
                     if helper.weighted:
                         # traffic-weighted accumulation: sum w_i * F_i

@@ -131,16 +131,16 @@ class DenseHelper(LayerHelper):
         return (self.out_features, self.out_features)
 
     def get_a_factor(self, a: jax.Array) -> jax.Array:
-        if self.routed:
-            return cov.routed_linear_a_factor(
-                a, self.has_bias, dtype=self.factor_dtype
-            )
-        return cov.linear_a_factor(a, self.has_bias, dtype=self.factor_dtype)
+        factor = (
+            cov.routed_linear_a_factor if self.routed else cov.linear_a_factor
+        )
+        return factor(a, self.has_bias).astype(self.factor_dtype)
 
     def get_g_factor(self, g: jax.Array) -> jax.Array:
-        if self.routed:
-            return cov.routed_linear_g_factor(g, dtype=self.factor_dtype)
-        return cov.linear_g_factor(g, dtype=self.factor_dtype)
+        factor = (
+            cov.routed_linear_g_factor if self.routed else cov.linear_g_factor
+        )
+        return factor(g).astype(self.factor_dtype)
 
     @property
     def weighted(self) -> bool:
@@ -155,7 +155,7 @@ class DenseHelper(LayerHelper):
         # routed G x its live fraction == the plain total-rows
         # normalization: get_cov(g)*(rows/n) * (n/rows) = g^T g / rows
         if self.routed:
-            return cov.linear_g_factor(g, dtype=self.factor_dtype)
+            return cov.linear_g_factor(g).astype(self.factor_dtype)
         return self.get_g_factor(g)
 
     def g_capture_weight(self, g: jax.Array) -> jax.Array | None:
@@ -212,11 +212,10 @@ class Conv2dHelper(LayerHelper):
             strides=self.strides,
             padding=self.padding,
             has_bias=self.has_bias,
-            dtype=self.factor_dtype,
-        )
+        ).astype(self.factor_dtype)
 
     def get_g_factor(self, g: jax.Array) -> jax.Array:
-        return cov.conv2d_g_factor(g, dtype=self.factor_dtype)
+        return cov.conv2d_g_factor(g).astype(self.factor_dtype)
 
     def grads_to_matrix(self, grads: dict[str, jax.Array]) -> jax.Array:
         k = grads['kernel']  # (kh, kw, in, out)
@@ -302,13 +301,15 @@ class LoRAHelper(LayerHelper):
 
     def role_a_factor(self, role: str, a: jax.Array) -> jax.Array:
         dim = self.a_factor_shape[0]
-        fac = cov.linear_a_factor(a, has_bias=False, dtype=self.factor_dtype)
+        fac = cov.linear_a_factor(a, has_bias=False).astype(
+            self.factor_dtype
+        )
         lo = 0 if role == 'down' else self.in_features
         return self._embed(fac, dim, lo)
 
     def role_g_factor(self, role: str, g: jax.Array) -> jax.Array:
         dim = self.g_factor_shape[0]
-        fac = cov.routed_linear_g_factor(g, dtype=self.factor_dtype)
+        fac = cov.routed_linear_g_factor(g).astype(self.factor_dtype)
         lo = 0 if role == 'down' else self.rank
         return self._embed(fac, dim, lo)
 

@@ -27,6 +27,15 @@ def append_bias_ones(x: jax.Array) -> jax.Array:
     return jnp.concatenate([x, jnp.ones(shape, dtype=x.dtype)], axis=-1)
 
 
+def _operand_precision(dtype) -> jax.lax.Precision | None:
+    """``HIGHEST`` for operands the MXU would otherwise round (float32
+    and wider); ``None`` for 16-bit operands, whose products are exact
+    in the float32 accumulator at the default."""
+    return (
+        jax.lax.Precision.HIGHEST if jnp.dtype(dtype).itemsize >= 4 else None
+    )
+
+
 def get_cov(
     a: jax.Array,
     b: jax.Array | None = None,
@@ -34,16 +43,26 @@ def get_cov(
 ) -> jax.Array:
     """Empirical second moment of a 2D tensor: ``a^T @ (b or a) / scale``.
 
-    The self-covariance is symmetrized ``(C + C^T)/2`` to guard against
+    One ``dot_general`` that contracts the row axis and accumulates in
+    float32, the same on one device, under GSPMD (local rows, then the
+    partitioner's sum) and on the local rows inside a ``shard_map``.
+    The rule for its operands, for every caller:
+
+    1. The covariance sees what the layer's own product sees. The
+       operands are multiplied in the dtype they arrive in, which the
+       capture taps make the dtype the layer rounds its input to (the
+       flax module's ``dtype``; a cotangent already has it). Products of
+       16-bit operands are exact in the float32 accumulator; float32
+       (or wider) operands are multiplied at ``Precision.HIGHEST``.
+    2. Scales leave the operands: ``scale`` (default: the row count)
+       divides the ``d x d`` result, never the rows, so a 16-bit operand
+       stays the exact value the layer multiplied (a bias column of ones
+       is exact in any dtype).
+
+    The result is float32 (or wider, with the operands). The
+    self-covariance is symmetrized ``(C + C^T)/2`` to guard against
     floating-point asymmetry before eigh. Reference:
     kfac/layers/utils.py:18-59.
-
-    On TPU, f32 self-covariances with factor dims spanning ≥ 2 MXU tiles
-    dispatch to the triangular Pallas kernel (exactly symmetric by
-    construction, half the MXU FLOPs; bf16 inputs stay on XLA) where a
-    raw Mosaic call can run: in a one-device process, or on the local
-    rows inside a fully-manual ``shard_map``
-    (:func:`kfac_tpu.ops.pallas_cov.use_pallas_for`).
     """
     if a.ndim != 2:
         raise ValueError(f'expected 2D tensor, got shape {a.shape}')
@@ -51,19 +70,17 @@ def get_cov(
         raise ValueError(f'shape mismatch: {a.shape} vs {b.shape}')
     if scale is None:
         scale = a.shape[0]
+    rhs = a if b is None else b
+    dtype = jnp.result_type(a, rhs)
+    cov = jax.lax.dot_general(
+        a, rhs,
+        (((0,), (0,)), ((), ())),  # contract over the row (sample) dim
+        precision=_operand_precision(dtype),
+        preferred_element_type=jnp.promote_types(dtype, jnp.float32),
+    ) / scale
     if b is None:
-        from kfac_tpu.ops import pallas_cov
-
-        if pallas_cov.use_pallas_for(a.shape[1], a.dtype):
-            # the gate has checked the trace context: one device, or a
-            # fully-manual shard_map where the rows are device-local
-            c = pallas_cov.sym_cov(
-                a, scale=1.0, interpret=pallas_cov.interpret_mode()
-            )
-            return c / scale
-        cov = a.T @ (a / scale)
-        return (cov + cov.T) / 2.0
-    return a.T @ (b / scale)
+        cov = (cov + cov.T) / 2.0
+    return cov
 
 
 def reshape_data(
@@ -93,7 +110,10 @@ def extract_patches_nhwc(
     ``lax.conv_general_dilated_patches`` and the (out, in*kh*kw) weight
     matricization used by the conv helper. TPU-native replacement for the
     reference's ``Tensor.unfold`` chain
-    (kfac/layers/modules.py:210-237).
+    (kfac/layers/modules.py:210-237). The identity-kernel convolution
+    behind it copies 16-bit values exactly; float32 values go at
+    ``Precision.HIGHEST``, as in :func:`get_cov`, or the MXU would round
+    them on the way.
     """
     if isinstance(padding, str):
         pad = padding
@@ -105,48 +125,47 @@ def extract_patches_nhwc(
         window_strides=strides,
         padding=pad,
         dimension_numbers=('NHWC', 'HWIO', 'NHWC'),
+        precision=_operand_precision(x.dtype),
     )
     return patches
 
 
-def linear_a_factor(
-    a: jax.Array,
-    has_bias: bool,
-    dtype: jnp.dtype | None = None,
-) -> jax.Array:
+def _rows(x: jax.Array) -> jax.Array:
+    """Leading dims flattened into covariance rows."""
+    return x.reshape(-1, x.shape[-1])
+
+
+def _live_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(mask, count)``: which rows hold any nonzero entry, and how many
+    they are (float32: a 16-bit count would round)."""
+    live = jnp.max(jnp.abs(rows), axis=-1) > 0
+    return live, jnp.sum(live, dtype=jnp.float32)
+
+
+def linear_a_factor(a: jax.Array, has_bias: bool) -> jax.Array:
     """A factor for a dense layer from its input activations.
 
     Flattens leading dims into rows ((batch, seq, d) -> (batch*seq, d)),
-    appends the bias column of ones, and returns the scaled covariance.
-    Reference: kfac/layers/modules.py:123-132.
+    appends the bias column of ones, and returns the covariance,
+    multiplied as :func:`get_cov` states: the caller hands the input in
+    the dtype the layer rounds it to. Reference:
+    kfac/layers/modules.py:123-132.
     """
-    if dtype is not None:
-        a = a.astype(dtype)
-    a = a.reshape(-1, a.shape[-1])
+    rows = _rows(a)
     if has_bias:
-        a = append_bias_ones(a)
-    return get_cov(a)
+        rows = append_bias_ones(rows)
+    return get_cov(rows)
 
 
-def linear_g_factor(
-    g: jax.Array,
-    dtype: jnp.dtype | None = None,
-) -> jax.Array:
+def linear_g_factor(g: jax.Array) -> jax.Array:
     """G factor for a dense layer from the loss gradient w.r.t. its output.
 
     Reference: kfac/layers/modules.py:134-141.
     """
-    if dtype is not None:
-        g = g.astype(dtype)
-    g = g.reshape(-1, g.shape[-1])
-    return get_cov(g)
+    return get_cov(_rows(g))
 
 
-def routed_linear_a_factor(
-    a: jax.Array,
-    has_bias: bool,
-    dtype: jnp.dtype | None = None,
-) -> jax.Array:
+def routed_linear_a_factor(a: jax.Array, has_bias: bool) -> jax.Array:
     """A factor over only the NONZERO rows — exact per-expert statistics
     for row-masked (MoE-routed) dense layers.
 
@@ -159,8 +178,8 @@ def routed_linear_a_factor(
     bias one only to live rows, and normalizes by the live count: the
     result equals the covariance computed from just the routed tokens
     (the per-expert oracle). An all-zero input returns zeros (count
-    floors at one). The covariance still rides :func:`get_cov` (Pallas
-    on TPU); the correction is one mask reduction plus a scalar rescale.
+    floors at one). The covariance is :func:`get_cov`'s, scaled by the
+    live count; the correction is one mask reduction.
 
     Caveat (same as :func:`routed_linear_g_factor`'s): a ROUTED token
     whose layer input is exactly all-zero — e.g. a fully-dead ReLU hidden
@@ -183,14 +202,13 @@ def routed_linear_a_factor(
     micro-steps average factors equally and carry the mean live fraction
     as the combined weight.
     """
-    if dtype is not None:
-        a = a.astype(dtype)
-    a = a.reshape(-1, a.shape[-1])
-    nz = (jnp.max(jnp.abs(a), axis=-1) > 0).astype(a.dtype)
-    n = jnp.maximum(jnp.sum(nz), 1.0)
+    rows = _rows(a)
+    live, n = _live_rows(rows)
     if has_bias:
-        a = jnp.concatenate([a, nz[:, None]], axis=-1)
-    return get_cov(a) * (a.shape[0] / n)
+        rows = jnp.concatenate(
+            [rows, live[:, None].astype(rows.dtype)], axis=-1
+        )
+    return get_cov(rows, scale=jnp.maximum(n, 1.0))
 
 
 def routed_live_fraction(a: jax.Array) -> jax.Array:
@@ -204,27 +222,19 @@ def routed_live_fraction(a: jax.Array) -> jax.Array:
     which makes the engines' weighted EMA leave its running factor
     untouched instead of diluting it toward zero.
     """
-    a = a.reshape(-1, a.shape[-1])
-    nz = jnp.max(jnp.abs(a), axis=-1) > 0
-    return jnp.mean(nz.astype(jnp.float32))
+    rows = _rows(a)
+    return _live_rows(rows)[1] / rows.shape[0]
 
 
-def routed_linear_g_factor(
-    g: jax.Array,
-    dtype: jnp.dtype | None = None,
-) -> jax.Array:
+def routed_linear_g_factor(g: jax.Array) -> jax.Array:
     """G factor normalized by the nonzero-cotangent row count (the routed
     tokens: non-routed rows have exactly-zero output cotangents). Caveat:
     a ROUTED row whose cotangent happens to vanish is miscounted as
     unrouted — generically measure-zero, and the resulting overnormalize
     is bounded by 1/n_e per such row.
     """
-    if dtype is not None:
-        g = g.astype(dtype)
-    g = g.reshape(-1, g.shape[-1])
-    nz = (jnp.max(jnp.abs(g), axis=-1) > 0).astype(g.dtype)
-    n = jnp.maximum(jnp.sum(nz), 1.0)
-    return get_cov(g) * (g.shape[0] / n)
+    rows = _rows(g)
+    return get_cov(rows, scale=jnp.maximum(_live_rows(rows)[1], 1.0))
 
 
 def conv2d_a_factor(
@@ -233,40 +243,33 @@ def conv2d_a_factor(
     strides: tuple[int, int],
     padding: str | Sequence[tuple[int, int]],
     has_bias: bool,
-    dtype: jnp.dtype | None = None,
 ) -> jax.Array:
     """A factor for a 2D conv layer (NHWC input).
 
     Patch rows are normalized by the spatial output size, mirroring the
-    reference's KFC normalization (kfac/layers/modules.py:173-182).
+    reference's KFC normalization (kfac/layers/modules.py:173-182):
+    ``cov(rows / s) = rows^T rows / (N s^2)``, the scale on the result
+    (:func:`get_cov`, rule 2), so the rows are extracted from the
+    activations in the dtype the layer multiplies them in.
     """
-    if dtype is not None:
-        a = a.astype(dtype)
     # the scope holds everything between the activations and the
     # covariance's operand: XLA rewrites most of the identity-kernel
-    # convolutions into window copies fused under the division's root, so
-    # the extraction alone keeps the name for a few layers only
+    # convolutions into window copies, so the extraction alone keeps the
+    # name for a few layers only
     with tracing.capture_scope('patches'):
         patches = extract_patches_nhwc(a, kernel_size, strides, padding)
         spatial_size = patches.shape[1] * patches.shape[2]
-        rows = patches.reshape(-1, patches.shape[-1])
+        rows = _rows(patches)
         if has_bias:
             rows = append_bias_ones(rows)
-        rows = rows / spatial_size
-    return get_cov(rows)
+    return get_cov(rows, scale=float(rows.shape[0] * spatial_size**2))
 
 
-def conv2d_g_factor(
-    g: jax.Array,
-    dtype: jnp.dtype | None = None,
-) -> jax.Array:
+def conv2d_g_factor(g: jax.Array) -> jax.Array:
     """G factor for a 2D conv layer from NHWC output gradients.
 
     Reference (NCHW variant): kfac/layers/modules.py:184-194.
     """
-    if dtype is not None:
-        g = g.astype(dtype)
     spatial_size = g.shape[1] * g.shape[2]
-    rows = g.reshape(-1, g.shape[-1])
-    rows = rows / spatial_size
-    return get_cov(rows)
+    rows = _rows(g)
+    return get_cov(rows, scale=float(rows.shape[0] * spatial_size**2))

@@ -1,12 +1,11 @@
 """Measured dispatch thresholds for the Pallas kernels, as a versioned
 artifact instead of folklore constants.
 
-The ``use_pallas_for`` / ``use_flash_for`` gates used to hard-code their
-win-regime thresholds from one microbench run. That is dangerous: the
-cov sweep behind them was dispatch-latency contaminated (dense f32 flat
-at 72-83 ms across d=256-2048 — a latency floor, not a measurement), so
-the thresholds it justified rest on numbers that never touched the work
-being timed. This module makes the derivation itself an artifact:
+Win-regime thresholds hard-coded from one microbench run are dangerous:
+a sweep can be dispatch-latency contaminated (flat timings across an 8x
+size range — a latency floor, not a measurement), and the thresholds it
+justifies then rest on numbers that never touched the work being timed.
+This module makes the derivation itself an artifact:
 
 - :func:`latency_floor_verdict` flags a size sweep whose timings are
   flat while the underlying work scales — the signature of measuring
@@ -46,7 +45,6 @@ ENV_VAR = 'KFAC_TPU_DISPATCH_TABLE'
 #: priors sized off the unfused kernels' win regimes; only a clean sweep
 #: moves them (docs/ARCHITECTURE.md "Fused step-path kernels").
 DEFAULTS: dict[str, Any] = {
-    'cov': {'min_dim': 256, 'dtypes': ['float32']},
     'attn': {'min_sk_dense': 2048},
     'cov_ema': {'min_dim': 256, 'dtypes': ['float32']},
     'klclip': {'min_dim': 512},
@@ -56,16 +54,14 @@ DEFAULTS: dict[str, Any] = {
 #: what :func:`floor_contaminated` scans the artifact provenance for, and
 #: what :func:`derive_tables` writes verdicts under
 BASELINE_SWEEP_PREFIX: dict[str, str] = {
-    'cov': 'cov_dense',
     'attn': 'attn_einsum',
     'cov_ema': 'cov_ema_unfused',
     'klclip': 'klclip_unfused',
 }
 
-#: a dtype must win at this many distinct sweep sizes before the
-#: derivation will flip its gate (one anomalous point — e.g. the single
-#: 2722 ms cov_dense_2048_bf16 outlier in the committed evidence — must
-#: not re-open a measured-loss regime)
+#: a kernel must win at this many distinct sweep sizes before the
+#: derivation will flip its gate (one anomalous point must not re-open a
+#: measured-loss regime)
 MIN_WIN_POINTS = 2
 
 _cache: dict[str, dict[str, Any]] = {}
@@ -167,21 +163,6 @@ def _get(table: Mapping[str, Any], section: str, key: str) -> Any:
     return None
 
 
-def cov_min_dim(default: int) -> int:
-    """Smallest factor dim the triangular cov kernel wins at."""
-    v = _get(load_tables(), 'cov', 'min_dim')
-    return int(v) if isinstance(v, (int, float)) and v > 0 else default
-
-
-def cov_dtypes(default: Sequence[str] = ('float32',)) -> tuple[str, ...]:
-    """Input dtype names (``jnp.dtype(...).name``) the cov kernel wins
-    at."""
-    v = _get(load_tables(), 'cov', 'dtypes')
-    if isinstance(v, (list, tuple)) and all(isinstance(s, str) for s in v):
-        return tuple(v)
-    return tuple(default)
-
-
 def flash_min_sk_dense(default: int) -> int:
     """Minimum s_k at which dense-path flash beats XLA's fused
     attention."""
@@ -238,12 +219,10 @@ def floor_contaminated(family: str) -> str | None:
 
 # ---------------------------------------------------------------- derivation
 
-_COV_RE = re.compile(r'^cov_(dense|pallas)_(\d+)_(f32|bf16)$')
 _ATTN_RE = re.compile(r'^attn_(einsum|flash)_s(\d+)$')
 _FUSED_RE = re.compile(
     r'^(cov_ema|klclip)_(unfused|fused)_(\d+)(?:_f32)?$'
 )
-_DTYPE_NAME = {'f32': 'float32', 'bf16': 'bfloat16'}
 
 #: work ~ size**exponent for each fused family's floor verdict: the
 #: cov+EMA contraction is n·d² at fixed rows, the kl-clip
@@ -293,60 +272,6 @@ def derive_tables(
     best = _best_ms(ops)
     provenance: dict[str, Any] = {'held': {}, 'contaminated': {}}
 
-    # --- cov: pallas vs dense per dtype ---------------------------------
-    series: dict[str, dict[str, dict[int, float]]] = {}
-    for name, ms in best.items():
-        m = _COV_RE.match(name)
-        if m:
-            impl, d, tag = m.group(1), int(m.group(2)), m.group(3)
-            series.setdefault(tag, {}).setdefault(impl, {})[d] = ms
-    cov_prior = prior.get('cov', DEFAULTS['cov'])
-    min_dim = int(cov_prior.get('min_dim', DEFAULTS['cov']['min_dim']))
-    dtypes = set(cov_prior.get('dtypes', DEFAULTS['cov']['dtypes']))
-    for tag, impls in sorted(series.items()):
-        dense, pallas = impls.get('dense', {}), impls.get('pallas', {})
-        both = sorted(set(dense) & set(pallas))
-        dtype = _DTYPE_NAME[tag]
-        verdict = latency_floor_verdict(
-            both, [dense[d] * 1e-3 for d in both], flat_tol=flat_tol
-        )
-        if verdict and verdict['contaminated']:
-            provenance['contaminated'][f'cov_dense_{tag}'] = verdict
-            provenance['held'][f'cov/{dtype}'] = (
-                'baseline sweep is latency-floor contaminated; threshold '
-                'held at prior'
-            )
-            continue
-        wins = [d for d in both if pallas[d] < dense[d]]
-        if len(wins) < min_win_points:
-            if dtype in dtypes:
-                provenance['held'][f'cov/{dtype}'] = (
-                    f'only {len(wins)} winning size(s) < {min_win_points}; '
-                    'prior stands'
-                )
-            else:
-                provenance['held'][f'cov/{dtype}'] = (
-                    f'{len(wins)} winning size(s) — not enough evidence to '
-                    'open a measured-loss regime'
-                )
-            continue
-        # smallest size from which the kernel wins at every larger
-        # measured size (a clean win regime is a suffix of the sweep)
-        suffix = None
-        for d in sorted(both, reverse=True):
-            if d in wins:
-                suffix = d
-            else:
-                break
-        if suffix is None:
-            dtypes.discard(dtype)
-            continue
-        dtypes.add(dtype)
-        if dtype == 'float32':
-            min_dim = suffix
-        provenance.setdefault('derived', {})[f'cov/{dtype}'] = {
-            'win_from_dim': suffix, 'sizes': both,
-        }
     # --- attn: flash vs einsum per sequence length ----------------------
     attn: dict[str, dict[int, float]] = {}
     for name, ms in best.items():
@@ -424,7 +349,6 @@ def derive_tables(
         fused_out[fam] = fam_prior
     return {
         'schema': SCHEMA_VERSION,
-        'cov': {'min_dim': min_dim, 'dtypes': sorted(dtypes)},
         'attn': {'min_sk_dense': min_sk},
         **fused_out,
         'provenance': provenance,

@@ -4,10 +4,11 @@ Every engine's capture path runs ``get_cov`` (a^T a / scale) immediately
 followed by ``ema_update`` (F <- beta*F + (1-beta)*cov) — two kernels
 with a full (d, d) f32 round-trip through HBM between them, plus the
 defensive symmetrization the unfused contraction needs. This module
-extends the triangular :mod:`pallas_cov` kernel with an EMA epilogue:
-at the last reduction step of each on-or-above-diagonal output tile the
-kernel reads the matching tile of the running factor and blends in
-place, so the covariance intermediate never exists in HBM
+is a triangular covariance kernel (the MXU runs only for output tiles on
+or above the diagonal) with an EMA epilogue: at the last reduction step
+of each such tile the kernel reads the matching tile of the running
+factor and blends in place, so the covariance intermediate never exists
+in HBM
 (``F <- beta*F + (1-beta)*a^T a/scale`` in one pass) and the result is
 exactly symmetric by the same mirror-the-upper-triangle construction —
 no ``(C + C^T)/2`` needed.
@@ -17,8 +18,8 @@ f32 inputs, ``fused_cov_ema(F, a, alpha, scale)`` is allclose to
 ``ema_update(F, get_cov(a, scale), alpha)`` and exactly symmetric for
 symmetric ``F``.
 
-Like :mod:`pallas_cov` it runs only where a raw Mosaic call can: a
-one-device process or a fully-manual ``shard_map``.
+It runs only where a raw Mosaic call can: a one-device process or a
+fully-manual ``shard_map``.
 
 Dispatch (:func:`use_fused_cov_ema_for`) follows the family's row in the
 committed threshold artifact (:mod:`kfac_tpu.ops.dispatch_tables`,
@@ -34,9 +35,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from kfac_tpu.ops.pallas_cov import (
-    K_BLOCK, TILE, _pad_to, interpret_mode,
-)
+from kfac_tpu.ops.pallas_gate import interpret_mode
+
+TILE = 128       # lane-aligned C-block edge
+K_BLOCK = 512    # rows of `a` consumed per reduction step
+
+
+def _pad_to(x: jax.Array, rows: int, cols: int) -> jax.Array:
+    pr, pc = rows - x.shape[0], cols - x.shape[1]
+    if pr or pc:
+        x = jnp.pad(x, ((0, pr), (0, pc)))
+    return x
 
 
 def _sym_cov_ema_kernel(a_i_ref, a_j_ref, f_ref, out_ref, *, beta, coeff):

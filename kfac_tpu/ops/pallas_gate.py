@@ -1,6 +1,6 @@
 """Dispatch gate for the Pallas TPU kernels.
 
-Each kernel family (``cov``, ``cov_ema``, ``klclip``, ``attn``)
+Each kernel family (``cov_ema``, ``klclip``, ``attn``)
 dispatches on a TPU only inside the regime its ``use_*_for`` heuristic
 accepts (shape, dtype, trace context, the thresholds in
 ``dispatch_thresholds.json``). Those thresholds were derived off-chip
@@ -12,12 +12,12 @@ The gate defaults ON. Override via the ``KFAC_TPU_PALLAS`` environment
 variable:
 
     KFAC_TPU_PALLAS=1 (default)  kernels dispatch in their regimes
-    KFAC_TPU_PALLAS=cov          enable only the covariance kernel
+    KFAC_TPU_PALLAS=klclip       enable only the kl-clip pair
     KFAC_TPU_PALLAS=attn         enable only the flash-attention kernel
-    KFAC_TPU_PALLAS=cov,attn     comma-separated combination
+    KFAC_TPU_PALLAS=klclip,attn  comma-separated combination
     KFAC_TPU_PALLAS=0            XLA paths only
 
-The gate is read at trace time (each ``get_cov`` / attention dispatch),
+The gate is read at trace time (each kl-clip / attention dispatch),
 so flipping the variable between jit traces takes effect without a
 process restart; already-compiled programs are unaffected.
 
@@ -35,13 +35,28 @@ _FALSE = frozenset({'', '0', 'false', 'off', 'none'})
 
 
 def enabled(kernel: str) -> bool:
-    """Whether the named Pallas kernel ('cov', 'attn') may dispatch on TPU."""
+    """Whether the named Pallas kernel family may dispatch on TPU."""
     val = os.environ.get('KFAC_TPU_PALLAS', '1').strip().lower()
     if val in _TRUE:
         return True
     if val in _FALSE:
         return False
     return kernel in {t.strip() for t in val.split(',')}
+
+
+def interpret_mode() -> bool:
+    """Run the kernels in interpret mode off-TPU (tests, CPU meshes).
+
+    Every Pallas family routes through this, so off a TPU the kernels
+    silently become the Pallas interpreter — right for tests, and never
+    a measurement. Nothing here proves Mosaic compiled anything:
+    ``chip_smoke.py`` does, by refusing to run unless
+    ``jax.devices()[0].platform == 'tpu'`` and by listing the
+    ``tpu_custom_call`` kernels found in the compiled step programs.
+    """
+    import jax
+
+    return jax.default_backend() != 'tpu'
 
 
 def manual_context() -> tuple[bool, bool, bool]:

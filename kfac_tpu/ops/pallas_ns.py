@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kfac_tpu.ops.pallas_cov import TILE, _pad_to, interpret_mode
+from kfac_tpu.ops.pallas_cov_ema import TILE, _pad_to
+from kfac_tpu.ops.pallas_gate import interpret_mode
 
 
 # ------------------------------------------------------------------ kl-clip

@@ -362,7 +362,7 @@ class PipelinedLM:
             helper = registry.layers.get(name)
             if helper is None:
                 return next_fun(*iargs, **ikwargs)
-            a = jax.lax.stop_gradient(iargs[0])
+            a = capture_lib.layer_input(mod, iargs[0])
             tick_a[name] = tick_a.get(name, 0.0) + (
                 helper.get_a_factor(a) * valid
             )

@@ -20,7 +20,7 @@ import pytest
 from kfac_tpu import warnings as kfac_warnings
 from kfac_tpu.ops import dispatch_tables, factors, pallas_cov_ema, pallas_ns
 from kfac_tpu.ops.cov import get_cov
-from kfac_tpu.ops.pallas_cov import K_BLOCK, TILE
+from kfac_tpu.ops.pallas_cov_ema import K_BLOCK, TILE
 
 BETA = 0.95
 N, D = 512, 256

@@ -15,7 +15,6 @@ import pytest
 
 from kfac_tpu.ops import (
     pallas_attention,
-    pallas_cov,
     pallas_cov_ema,
     pallas_ns,
 )
@@ -48,13 +47,6 @@ BATCH = 3
 
 def _stack(x):
     return jnp.stack([x] * BATCH)
-
-
-@pytest.mark.parametrize('n,d', COV_SHAPES)
-def test_sym_cov_lowers(n, d):
-    a = _f32(n, d)
-    assert _kernels_in(pallas_cov.sym_cov, a) == 1
-    assert _kernels_in(jax.vmap(pallas_cov.sym_cov), _stack(a)) == 1
 
 
 @pytest.mark.parametrize('n,d', COV_SHAPES)

@@ -89,8 +89,12 @@ TINY_KERNEL_SHAPES = {
 def test_kernel_checks_run_in_the_interpreter():
     rows = chip_smoke.check_kernels(TINY_KERNEL_SHAPES)
     assert {r['kernel'] for r in rows} == {
-        'sym_cov', 'fused_klclip_dot', 'fused_klclip_scale',
+        'get_cov', 'fused_klclip_dot', 'fused_klclip_scale',
     }
+    assert sorted(
+        (r['shape'], r['dtype']) for r in rows if r['kernel'] == 'get_cov'
+    ) == [([40, 130], 'bfloat16'), ([40, 130], 'float32'),
+          ([256, 256], 'bfloat16'), ([256, 256], 'float32')]
     for r in rows:
         assert r['max_err'] <= r['tol']
 

@@ -107,9 +107,9 @@ def test_benchmark_rows_of_the_four_chip_cell():
     for name in ('collective_ms', 'collective_exposed_ms'):
         assert rows[name]['workloads'] == [CELL]
         assert rows[name]['source'] == 'device_trace'
-    # no Mosaic kernel runs in a several-device program (the gate in
-    # ops/pallas_cov.use_pallas_for): the kernel's row names the cells
-    # that have it, and this one is not among them
+    # the row names a Mosaic kernel that never ran in a several-device
+    # program (and since PR 28 runs in none: the covariance is XLA's
+    # product): the accepted row lists the one-chip cells, not this one
     assert CELL not in rows['dev_ms.sym_cov']['workloads']
     assert ONE_CHIP in rows['dev_ms.sym_cov']['workloads']
     # the feed of a sharded batch runs a slicing program on device 0

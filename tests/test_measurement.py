@@ -181,14 +181,14 @@ def test_report_floor_verdicts_emits_lines(capsys):
 def test_committed_artifact_loads_from_a_clean_sweep():
     doc = dispatch_tables.load_tables(dispatch_tables.ARTIFACT_PATH)
     assert doc['schema'] == dispatch_tables.SCHEMA_VERSION
-    assert doc['cov']['min_dim'] == 256
-    assert doc['cov']['dtypes'] == ['float32']
+    assert doc['cov_ema']['min_dim'] == 256
+    assert doc['cov_ema']['dtypes'] == ['float32']
     assert doc['attn']['min_sk_dense'] == 2048
     # re-derived from the clean one-dispatch sweep: no contaminated
     # baselines remain (the latency-floor-contaminated v1 numbers are
     # retired), and everything still at its prior says why
     assert doc['provenance']['contaminated'] == {}
-    assert 'cov/float32' in doc['provenance']['held']
+    assert 'cov_ema' in doc['provenance']['held']
     assert doc['provenance']['source']['records'] > 0
 
 
@@ -197,84 +197,50 @@ def test_accessors_fall_back_on_missing_artifact(monkeypatch, tmp_path):
                        str(tmp_path / 'does_not_exist.json'))
     dispatch_tables.invalidate_cache()
     assert dispatch_tables.load_tables() == {}
-    assert dispatch_tables.cov_min_dim(default=321) == 321
-    assert dispatch_tables.cov_dtypes() == ('float32',)
+    assert dispatch_tables.family_min_dim('cov_ema', default=321) == 321
+    assert dispatch_tables.family_dtypes('cov_ema') == ('float32',)
     assert dispatch_tables.flash_min_sk_dense(default=4096) == 4096
 
 
 def test_accessors_fall_back_on_schema_mismatch(monkeypatch, tmp_path):
     p = tmp_path / 'future.json'
-    p.write_text(json.dumps({'schema': 99, 'cov': {'min_dim': 1}}))
+    p.write_text(json.dumps({'schema': 99, 'cov_ema': {'min_dim': 1}}))
     monkeypatch.setenv(dispatch_tables.ENV_VAR, str(p))
     dispatch_tables.invalidate_cache()
     assert dispatch_tables.load_tables() == {}
-    assert dispatch_tables.cov_min_dim(default=256) == 256
+    assert dispatch_tables.family_min_dim('cov_ema', default=256) == 256
 
 
 def test_env_override_redirects_the_gates(monkeypatch, tmp_path):
     p = tmp_path / 'tuned.json'
     p.write_text(json.dumps({
         'schema': 1,
-        'cov': {'min_dim': 512, 'dtypes': ['float32', 'bfloat16']},
+        'cov_ema': {'min_dim': 512, 'dtypes': ['float32', 'bfloat16']},
         'attn': {'min_sk_dense': 1024},
     }))
     monkeypatch.setenv(dispatch_tables.ENV_VAR, str(p))
     dispatch_tables.invalidate_cache()
-    assert dispatch_tables.cov_min_dim(default=256) == 512
-    assert dispatch_tables.cov_dtypes() == ('float32', 'bfloat16')
+    assert dispatch_tables.family_min_dim('cov_ema', default=256) == 512
+    assert dispatch_tables.family_dtypes('cov_ema') == (
+        'float32', 'bfloat16')
     assert dispatch_tables.flash_min_sk_dense(default=2048) == 1024
 
 
 def test_gate_functions_consume_the_tables(monkeypatch, tmp_path):
-    """use_pallas_for / use_flash_for read the artifact through the
-    accessors (off-TPU both still return False — backend check — so this
-    pins the plumbing via the accessors the gates call)."""
-    from kfac_tpu.ops import pallas_attention, pallas_cov
+    """use_fused_cov_ema_for / use_flash_for read the artifact through
+    the accessors (off-TPU both still return False — backend check — so
+    this pins the plumbing via the accessors the gates call)."""
+    from kfac_tpu.ops import pallas_attention, pallas_cov_ema
 
-    assert pallas_cov.use_pallas_for(1024, jnp.float32) is False  # cpu
+    assert pallas_cov_ema.use_fused_cov_ema_for(1024, jnp.float32) is False
     assert pallas_attention.use_flash_for(128, 2048, 128, dense=True) is False
     # and the threshold values they would compare against come from the
     # committed artifact
-    assert dispatch_tables.cov_min_dim(default=0) == 256
+    assert dispatch_tables.family_min_dim('cov_ema', default=0) == 256
     assert dispatch_tables.flash_min_sk_dense(default=0) == 2048
 
 
 # -------------------------------------------------------------- derivation
-
-
-def _cov_sweep(dense_ms, pallas_ms, tag='f32', sizes=(256, 512, 1024, 2048)):
-    return (
-        [{'op': f'cov_dense_{d}_{tag}', 'ms': dense_ms(d)} for d in sizes]
-        + [{'op': f'cov_pallas_{d}_{tag}', 'ms': pallas_ms(d)}
-           for d in sizes]
-    )
-
-
-def test_derive_holds_prior_on_contaminated_baseline():
-    t = dispatch_tables.derive_tables(
-        _cov_sweep(lambda d: 75.0 + d % 7, lambda d: 15.0))
-    assert t['cov'] == dispatch_tables.DEFAULTS['cov']
-    assert 'cov_dense_f32' in t['provenance']['contaminated']
-
-
-def test_derive_moves_threshold_on_clean_win_suffix():
-    t = dispatch_tables.derive_tables(_cov_sweep(
-        lambda d: 0.01 * d * d / 256,
-        lambda d: 15.0 if d < 1024 else 0.001 * d * d / 256,
-    ))
-    assert t['cov']['min_dim'] == 1024
-    assert 'float32' in t['cov']['dtypes']
-    assert t['provenance']['derived']['cov/float32']['win_from_dim'] == 1024
-
-
-def test_derive_rejects_single_point_win():
-    """One anomalous winning size (the committed bf16 2048 outlier
-    pattern) must not re-open a measured-loss regime."""
-    ops = _cov_sweep(
-        lambda d: 80.0 if d < 2048 else 2722.0, lambda d: 150.0, tag='bf16')
-    t = dispatch_tables.derive_tables(ops)
-    assert 'bfloat16' not in t['cov']['dtypes']
-    assert 'cov/bfloat16' in t['provenance']['held']
 
 
 def test_derive_attn_needs_min_win_points():

@@ -6,8 +6,8 @@ Usage:
 
 Reads one or more ``tools/tpu_microbench.py`` JSONL sweeps, runs the
 latency-floor check on every baseline series, and writes the versioned
-threshold table the gate modules (``use_pallas_for`` /
-``use_flash_for``) load-or-default. Contaminated or thin evidence HOLDS
+threshold table the gate modules (``use_fused_cov_ema_for`` /
+``use_fused_klclip_for`` / ``use_flash_for``) load-or-default. Contaminated or thin evidence HOLDS
 the prior thresholds and says so in the artifact's ``provenance`` —
 this tool can only move a gate on clean numbers.
 
@@ -46,30 +46,9 @@ def read_jsonl(path: str) -> list[dict]:
 
 
 def selftest() -> None:
-    """Synthetic derivation: a flat (contaminated) f32 sweep must hold
-    the prior, a cleanly scaling sweep with a kernel win regime must
+    """Synthetic derivation: a flat (contaminated) baseline sweep must
+    hold the prior, a cleanly scaling sweep with a kernel win regime must
     move the threshold."""
-    flat = [
-        {'op': f'cov_dense_{d}_f32', 'ms': 75.0 + (d % 7)}
-        for d in (256, 512, 1024, 2048)
-    ] + [
-        {'op': f'cov_pallas_{d}_f32', 'ms': 15.0}
-        for d in (256, 512, 1024, 2048)
-    ]
-    t = dispatch_tables.derive_tables(flat)
-    assert t['cov']['min_dim'] == dispatch_tables.DEFAULTS['cov']['min_dim']
-    assert t['provenance']['contaminated'], t['provenance']
-    clean = [
-        {'op': f'cov_dense_{d}_f32', 'ms': 0.01 * d * d / 256}
-        for d in (256, 512, 1024, 2048)
-    ] + [
-        {'op': f'cov_pallas_{d}_f32',
-         'ms': 15.0 if d < 1024 else 0.001 * d * d / 256}
-        for d in (256, 512, 1024, 2048)
-    ]
-    t = dispatch_tables.derive_tables(clean)
-    assert t['cov']['min_dim'] == 1024, t
-    assert not t['provenance']['contaminated']
     # fused step-path families: a flat (contaminated) unfused baseline
     # holds the prior, a clean sweep with a fused win suffix moves it
     flat_kl = [
@@ -134,7 +113,6 @@ def main() -> int:
             f.write(doc)
         held = table['provenance'].get('held', {})
         print(f'wrote {args.out} (held: {len(held)}, '
-              f'cov.min_dim={table["cov"]["min_dim"]}, '
               f'attn.min_sk_dense={table["attn"]["min_sk_dense"]})')
     else:
         print(doc, end='')

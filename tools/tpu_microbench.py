@@ -1,6 +1,6 @@
 """TPU microbenchmarks for the K-FAC hot ops: run on the real chip to pick
 factor-op implementations (eigh vs Cholesky vs Newton-Schulz) and validate
-the Pallas triangular covariance against XLA's dense contraction.
+the Pallas kernels against the XLA expressions they replace.
 
 Usage: python tools/tpu_microbench.py [--sizes 512 2048] [--iters 20]
 Prints one JSON line per measurement.
@@ -498,7 +498,7 @@ def main():
                    'skip the attention A/B so the sweep runs in seconds '
                    'on a CPU host (make prof)')
     p.add_argument('--no-pallas', action='store_true',
-                   help='skip the Pallas kernels (cov + flash attention): '
+                   help='skip the Pallas kernels (fused pairs + flash attention): '
                    'measure only validated XLA ops — the safe first pass '
                    'on an untested chip')
     p.add_argument('--pallas-only', action='store_true',
@@ -674,40 +674,6 @@ def main():
                       measured(f'newton_schulz_warm_{d}',
                                lambda n: timeit(warm, drift, iters=n),
                                qiters, post=warm_iters))
-
-            # covariance: XLA dense contraction vs Pallas triangular kernel
-            for dt, tag in ((jnp.float32, 'f32'), (jnp.bfloat16, 'bf16')):
-                md = m.astype(dt)
-                dense = jax.jit(
-                    lambda a: jax.lax.dot_general(
-                        a, a, (((0,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    ) / a.shape[0]
-                )
-                announce(f'cov_dense_{d}_{tag}')
-                t = timeit(dense, md, iters=args.iters)
-                report(f'cov_dense_{d}_{tag}', t)
-                track(f'cov_dense_{tag}', 2.0, d, t)
-                if run_pallas:
-                    from kfac_tpu.ops import pallas_cov
-
-                    def cov_check(_t, _md=md, _dense=dense):
-                        got = pallas_cov.sym_cov(_md)
-                        want = _dense(_md).astype(got.dtype)
-                        err = float(jnp.abs(
-                            got.astype(jnp.float32)
-                            - want.astype(jnp.float32)
-                        ).max())
-                        return {'max_err': round(err, 5)}
-
-                    track(f'cov_pallas_{tag}', 2.0, d, measured(
-                        f'cov_pallas_{d}_{tag}',
-                        lambda n, _md=md: timeit(
-                            jax.jit(lambda a: pallas_cov.sym_cov(a)), _md,
-                            iters=n,
-                        ),
-                        args.iters, post=cov_check,
-                    ))
 
             # fused step-path kernels vs their unfused XLA expressions
             # (interpret mode off-TPU: numerics-true, and the derivation
