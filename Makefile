@@ -15,7 +15,7 @@ PY ?= python
 TEST_ENV = JAX_PLATFORMS=cpu \
 	XLA_FLAGS="--xla_force_host_platform_device_count=8"
 
-.PHONY: test test-fast test-unit test-integration faults async compress fleet chaos compilewatch ledger serve obs prof tune resilience lint lint-ir lint-pod inspect bench bench-acc native
+.PHONY: test test-fast test-unit test-integration faults async compress fleet chaos compilewatch ledger serve obs prof tune resilience lint lint-ir lint-pod inspect native
 
 test:
 	$(TEST_ENV) $(PY) -m pytest tests/ -q
@@ -66,14 +66,10 @@ chaos:
 # measurement-truth layer (docs/OBSERVABILITY.md "Measurement truth"):
 # a real microbench smoke sweep on the CPU backend (fori_loop one-
 # dispatch provenance + latency-floor verdicts over an actual size
-# sweep), the threshold-derivation selftest, a derivation run over the
-# smoke sweep's output, and the measurement + calibration test suites
+# sweep) and the measurement + calibration test suites
 prof:
 	$(TEST_ENV) $(PY) tools/tpu_microbench.py --smoke --no-pallas \
 		--sizes 128 256 --iters 2 --rows 512 > /tmp/kfac_prof_micro.jsonl
-	$(TEST_ENV) $(PY) tools/derive_dispatch_tables.py --selftest
-	$(TEST_ENV) $(PY) tools/derive_dispatch_tables.py \
-		/tmp/kfac_prof_micro.jsonl --out /tmp/kfac_prof_tables.json
 	$(TEST_ENV) $(PY) -m pytest tests/test_measurement.py \
 		tests/test_calibration.py -q
 
@@ -106,8 +102,8 @@ serve:
 # compression/offload suite (its wire-bytes accounting is part of the
 # comms report contract), the self-driving fleet suite (its drift
 # detector consumes the flight recorder's skew columns), the
-# measurement-truth layer (prof: dispatch-free microbench, threshold
-# derivation, calibration), the compile & memory truth layer
+# measurement-truth layer (prof: dispatch-free microbench,
+# calibration), the compile & memory truth layer
 # (compilewatch: recompile attribution, XLA memory accounting,
 # mid-compile heartbeats), the unified static-analysis pass (which
 # includes the named-scope, metric-key, plan-schema, compression-knob,
@@ -169,12 +165,6 @@ resilience:
 #   make inspect BUNDLE=postmortems/postmortem-step00000042-skip
 inspect:
 	$(PY) tools/kfac_inspect.py $(BUNDLE)
-
-bench:
-	$(PY) bench.py
-
-bench-acc:
-	$(TEST_ENV) $(PY) tools/bench_accuracy.py
 
 # the loader self-builds (and caches) on first use; this just forces it
 native:

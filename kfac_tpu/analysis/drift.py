@@ -496,48 +496,6 @@ def _topology_knobs() -> list[core.Finding]:
     return _doc_findings('KFL109', AUTOTUNE_DOC, line, problems)
 
 
-# -------------------------------------------- KFL110 fused dispatch families
-
-
-def check_fused_dispatch_table(doc_path: str = ARCHITECTURE_DOC) -> list[str]:
-    """Drift between the docs/ARCHITECTURE.md "Fused-kernel dispatch
-    families" table and the ``ops.dispatch_tables`` registry: every
-    family in ``DEFAULTS`` needs a doc row naming it, and every family
-    needs a baseline-sweep prefix so :func:`floor_contaminated` can find
-    its floor verdict."""
-    section, _ = doc_section(doc_path, '### Fused-kernel dispatch families')
-    documented = table_first_cells(section)
-    from kfac_tpu.ops import dispatch_tables
-
-    actual = set(dispatch_tables.DEFAULTS)
-    problems = []
-    for k in sorted(actual - documented):
-        problems.append(
-            f'undocumented dispatch family (add to {doc_path}): {k}'
-        )
-    for k in sorted(documented - actual):
-        problems.append(
-            f'documented family is not in dispatch_tables.DEFAULTS: {k}'
-        )
-    for k in sorted(actual - set(dispatch_tables.BASELINE_SWEEP_PREFIX)):
-        problems.append(
-            f'family {k} has no BASELINE_SWEEP_PREFIX entry — its floor '
-            'verdict is unfindable and the contamination guard is blind'
-        )
-    return problems
-
-
-def _fused_dispatch_table() -> list[core.Finding]:
-    try:
-        _, line = doc_section(
-            ARCHITECTURE_DOC, '### Fused-kernel dispatch families'
-        )
-        problems = check_fused_dispatch_table()
-    except (OSError, ValueError) as exc:
-        return _doc_findings('KFL110', ARCHITECTURE_DOC, 1, [str(exc)])
-    return _doc_findings('KFL110', ARCHITECTURE_DOC, line, problems)
-
-
 # ------------------------------------------------------ KFL111 chaos knobs
 
 
@@ -799,20 +757,6 @@ core.register(core.Rule(
         'trigger; an undocumented (or phantom) knob means the drift '
         'threshold that re-layouts a live job is configured by folklore',
     check=_calibration_knobs,
-    kind='project',
-))
-
-core.register(core.Rule(
-    code='KFL110',
-    name='fused-dispatch-doc',
-    what='drift between the docs/ARCHITECTURE.md "Fused-kernel dispatch '
-         'families" table and the ops.dispatch_tables registry '
-         '(DEFAULTS families and their baseline-sweep prefixes)',
-    why='the fused step-path kernels dispatch through artifact-backed '
-        'thresholds; a family missing from the doc table (or the sweep-'
-        'prefix registry) is a kernel whose win regime and fallback '
-        'story exist only in folklore',
-    check=_fused_dispatch_table,
     kind='project',
 ))
 

@@ -244,13 +244,12 @@ def check_cost_model_parity(suite: harness.Suite) -> list[core.Finding]:
 # ------------------------------------------------------------------ KFL206
 
 #: kernel function names allowed to appear as ``pallas_call`` eqns in
-#: traced engine programs — the registry the fused step-path kernels pin
-#: themselves to (kfac_tpu/ops/pallas_{cov_ema,ns,attention}.py).
+#: traced engine programs — the registry the step-path kernels pin
+#: themselves to (kfac_tpu/ops/pallas_{ns,attention}.py).
 #: An unlisted kernel on the step path is either a new kernel that
-#: skipped its pricing/equivalence/dispatch wiring, or a renamed one
-#: whose autotune price and docs now point at nothing.
+#: skipped its equivalence test and its ``use_*_for`` choice, or a
+#: renamed one whose docs and trace readers now point at nothing.
 STEP_PALLAS_ALLOWLIST = frozenset({
-    '_sym_cov_ema_kernel',
     '_klclip_dot_kernel',
     '_klclip_scale_kernel',
     '_flash_kernel',
@@ -271,7 +270,7 @@ def check_pallas_allowlist(suite: harness.Suite) -> list[core.Finding]:
                     f'{summary["grid"]}) is not on the step-path kernel '
                     'allowlist; register it in '
                     'analysis/ir/rules.STEP_PALLAS_ALLOWLIST alongside '
-                    'its autotune price and dispatch-table family',
+                    'its equivalence test and its use_*_for choice',
                 ))
     return findings
 
@@ -335,8 +334,8 @@ core.register(core.Rule(
     code='KFL206', name='ir-pallas-kernel-allowlist',
     what='pallas_call eqns in traced engine programs whose kernel name '
          'is not on the registered step-path allowlist',
-    why='a fused kernel that bypasses the allowlist also bypassed its '
-        'autotune price, equivalence test, and dispatch-table gate — '
-        'the contract that keeps hand-written Mosaic honest',
+    why='a kernel that bypasses the allowlist also bypassed its '
+        'equivalence test and its use_*_for choice — the contract that '
+        'keeps hand-written Mosaic honest',
     check=_bind(check_pallas_allowlist), kind='ir',
 ))

@@ -54,47 +54,6 @@ from kfac_tpu import enums
 EIGH_FLOPS_PER_DIM3 = 30.0  # batched symmetric eigh ~= 30 d^3
 NS_FLOPS_PER_ITER_DIM3 = 4.0  # two (d, d) matmuls per Newton-Schulz iter
 
-# Fused step-path kernel geometry (kfac_tpu/ops/pallas_cov_ema.py and
-# pallas_ns.py). Mirrored here instead of imported: this module must
-# stay jax-free, and the KFL205 IR parity test diffs these prices
-# against the kernels' actual jaxprs — drift either way and the lint
-# says so.
-FUSED_TILE = 128  # MXU tile of every fused kernel's BlockSpec
-FUSED_K_BLOCK = 512  # cov+EMA row-panel depth per grid k-step
-
-
-def _ceil_to(x: int, q: int) -> int:
-    return -(-int(x) // q) * q
-
-
-def fused_cov_ema_flops(n: int, d: int) -> float:
-    """Exact MXU FLOPs of one fused cov+EMA launch on (n, d) rows.
-
-    The kernel computes the upper-triangle tile block only —
-    nblk*(nblk+1)/2 of the nblk^2 (i, j) grid points run the
-    (K_BLOCK, TILE)^T @ (K_BLOCK, TILE) dot per k-step, 2*K*T^2 FLOPs
-    each — which telescopes to ``n_pad * d_pad * (d_pad + TILE)``.
-    The KFL205 parity test counts the same number out of the lowered
-    jaxpr (grid product x per-tile dot FLOPs x triangular multiplicity).
-    """
-    n_pad = _ceil_to(n, FUSED_K_BLOCK)
-    d_pad = _ceil_to(d, FUSED_TILE)
-    return float(n_pad) * d_pad * (d_pad + FUSED_TILE)
-
-
-def fused_cov_ema_hbm_saved(d: int) -> float:
-    """HBM bytes the fused EMA epilogue avoids per factor update: the
-    unfused path writes the f32 (d, d) covariance then rereads it for
-    the blend (one round trip the epilogue keeps in VMEM)."""
-    return 8.0 * d * d
-
-
-def fused_klclip_flops(shape: tuple[int, int]) -> float:
-    """VPU FLOPs of the fused kl-clip pair on one (r, c) tensor: the
-    tiled multiply-reduce (2 r c) plus the scale apply (r c)."""
-    r, c = shape
-    return 3.0 * _ceil_to(r, FUSED_TILE) * _ceil_to(c, FUSED_TILE)
-
 
 @dataclasses.dataclass(frozen=True)
 class Candidate:

@@ -70,7 +70,7 @@ class OffloadManager:
 
     Holds the numpy copies while the device state carries placeholders,
     runs the asynchronous prefetch, and keeps the traffic/hit counters
-    ``comms_report()`` and bench's ``_compression_probe`` read. Purely
+    ``comms_report()`` reads. Purely
     host state — construction touches no device.
     """
 

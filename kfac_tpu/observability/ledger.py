@@ -808,7 +808,7 @@ DEFAULT_SENTINEL_KEYS: dict[str, dict[str, Any]] = {
     'mfu': {'direction': 'higher', 'tolerance': 0.15},
     'acc_step_ratio': {'direction': 'lower', 'tolerance': 0.25},
     'acc_time_ratio': {'direction': 'lower', 'tolerance': 0.25},
-    # serving-probe headline keys (bench.py _serving_probe): latency is
+    # serving headline keys: latency is
     # lower-is-better, throughput higher; 0.25 absorbs shared-host
     # timing jitter like the acc ratios above
     'serving_mc_p50_ms': {'direction': 'lower', 'tolerance': 0.25},

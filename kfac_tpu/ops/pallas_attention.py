@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from kfac_tpu.ops import pallas_gate
+
 NEG_INF = -1e30
 
 BLOCK_Q = 128
@@ -274,81 +276,38 @@ def _call(kern, offs, q, k, v, b, h, s_q, s_k, d, block_q, n_q, interpret):
 _VMEM_KV_BYTES = 8 * 1024 * 1024
 
 
-# dispatch regimes (priors from one 2026-07-31 chip session whose records
-# are gone; not measured on today's code — ROADMAP S5):
+# Where the dense path's floor came from: one 2026-07-31 chip session
+# whose records are gone, never re-derived on the chip since (ROADMAP S6).
 # - DENSE single-device attention competes against XLA's fused
-#   softmax(QK^T)V: the flagship with kernels enabled ran slower at
-#   s=512, so the dense path only dispatches flash at s_k >= 2048 where
-#   the S x S HBM materialization the kernel eliminates is large.
-# - The BLOCKWISE-PARTIALS form (ring/zigzag steps) competes against
-#   attend_partials_einsum, which must materialize unfused (acc, m, l)
-#   partials, so no length floor applies there.
+#   softmax(QK^T)V: the flagship of that session ran slower with the
+#   kernel at s=512, so the dense path only dispatches flash at
+#   s_k >= 2048 where the S x S HBM materialization the kernel
+#   eliminates is large.
+# - The BLOCKWISE-PARTIALS form (ring/zigzag steps, the chunks of
+#   blockwise_causal_attention) competes against attend_partials_einsum,
+#   which must materialize unfused (acc, m, l) partials, so no length
+#   floor applies there.
 _MIN_FLASH_SK_DENSE = 2048
-
-
-def _mosaic_context_ok() -> bool:
-    """Whether the current trace context can execute a raw ``pallas_call``.
-
-    Mosaic kernels cannot be automatically partitioned (measured on-chip:
-    ``NotImplementedError: Mosaic kernels cannot be automatically
-    partitioned`` from a flash dispatch inside the pipeline's
-    partial shard_map, whose model axis stays automatic). Safe contexts:
-
-    - a FULLY-manual shard_map region: every mesh axis manual, so the
-      kernel sees device-local blocks and GSPMD never touches it;
-    - no surrounding mesh AND a single-device process: with more than
-      one device, inputs placed via ``device_put(NamedSharding)`` can
-      arrive sharded without any mesh context and would still need GSPMD
-      to partition the kernel.
-
-    Partial-manual regions (pipeline manual over pipe+data with TP
-    automatic) and plain pjit meshes fall back to the einsum partials,
-    which XLA partitions fine.
-    """
-    from kfac_tpu.ops import pallas_gate
-
-    has_mesh, _any_manual, all_manual = pallas_gate.manual_context()
-    if has_mesh:
-        return all_manual
-    return len(jax.devices()) == 1
 
 
 def use_flash_for(
     s_q: int, s_k: int, d: int, itemsize: int = 4, dense: bool = False
 ) -> bool:
-    """Dispatch heuristic: the kernel needs whole lane-aligned tiles, the
-    staged K+V chunks must fit the VMEM budget, and a trace context GSPMD
-    won't auto-partition (:func:`_mosaic_context_ok`); the single-device
-    dense path (``dense=True``) additionally requires the measured
-    on-chip win length — loaded from the committed derivation artifact
-    (:mod:`kfac_tpu.ops.dispatch_tables`) with ``_MIN_FLASH_SK_DENSE``
-    as the load-or-default fallback — because its alternative is XLA's
-    fully-fused attention rather than the unfused einsum partials.
-    Overridable via ``KFAC_TPU_PALLAS``
-    (:mod:`kfac_tpu.ops.pallas_gate`). A latency-floor-contaminated
-    baseline sweep in the artifact provenance voids the dense-path
-    threshold: the gate holds the conservative XLA default for the dense
-    path and warns once, naming the sweep (the blockwise-partials path
-    has no length floor and stays available)."""
-    from kfac_tpu import warnings as kfac_warnings
-    from kfac_tpu.ops import dispatch_tables, pallas_gate
-
-    if not (
-        pallas_gate.enabled('attn') and jax.default_backend() == 'tpu'
-    ):
-        return False
-    if dense:
-        sweep = dispatch_tables.floor_contaminated('attn')
-        if sweep is not None:
-            kfac_warnings.warn_dispatch_event('attn', sweep)
-            return False
+    """Whether the flash kernel computes this attend, from what can be
+    observed here: a TPU backend; whole lane-aligned tiles (``s_q``,
+    ``s_k`` on their blocks, ``d`` on 128); the staged K+V chunks of
+    ``itemsize`` bytes an element inside ``_VMEM_KV_BYTES``; a trace
+    context GSPMD won't auto-partition
+    (:func:`pallas_gate.mosaic_context_ok`); and on the single-device
+    dense path (``dense=True``) ``s_k >= _MIN_FLASH_SK_DENSE``, because
+    its alternative is XLA's fully-fused attention rather than the
+    unfused einsum partials."""
     return (
-        s_q % BLOCK_Q == 0
+        jax.default_backend() == 'tpu'
+        and s_q % BLOCK_Q == 0
         and s_k % BLOCK_K == 0
-        and (not dense or s_k >= dispatch_tables.flash_min_sk_dense(
-            default=_MIN_FLASH_SK_DENSE
-        ))
+        and (not dense or s_k >= _MIN_FLASH_SK_DENSE)
         and d % 128 == 0
         and 2 * s_k * d * itemsize <= _VMEM_KV_BYTES
-        and _mosaic_context_ok()
+        and pallas_gate.mosaic_context_ok()
     )

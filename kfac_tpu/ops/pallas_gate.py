@@ -1,47 +1,18 @@
-"""Dispatch gate for the Pallas TPU kernels.
+"""What both Pallas TPU kernel families ask of the trace they are in.
 
-Each kernel family (``cov_ema``, ``klclip``, ``attn``)
-dispatches on a TPU only inside the regime its ``use_*_for`` heuristic
-accepts (shape, dtype, trace context, the thresholds in
-``dispatch_thresholds.json``). Those thresholds were derived off-chip
-and no kernel's speed has been measured on today's code; what IS
-checked on the chip is that every kernel compiles under Mosaic and
+Which kernel runs is decided in the kernel's own module, from what it
+can observe: ``pallas_attention.use_flash_for`` and
+``pallas_ns.use_fused_klclip_for`` (backend, shape, dtype, a constant of
+their own). This module holds the questions the two share: whether a raw
+Mosaic call may run in the current trace context
+(:func:`mosaic_context_ok`), and whether the kernels run in the Pallas
+interpreter (:func:`interpret_mode`). No kernel's speed has been
+measured against its XLA expression on today's code (ROADMAP S6); what
+IS checked on the chip is that every kernel compiles under Mosaic and
 agrees with the XLA expression it replaces (``chip_smoke.py``).
-
-The gate defaults ON. Override via the ``KFAC_TPU_PALLAS`` environment
-variable:
-
-    KFAC_TPU_PALLAS=1 (default)  kernels dispatch in their regimes
-    KFAC_TPU_PALLAS=klclip       enable only the kl-clip pair
-    KFAC_TPU_PALLAS=attn         enable only the flash-attention kernel
-    KFAC_TPU_PALLAS=klclip,attn  comma-separated combination
-    KFAC_TPU_PALLAS=0            XLA paths only
-
-The gate is read at trace time (each kl-clip / attention dispatch),
-so flipping the variable between jit traces takes effect without a
-process restart; already-compiled programs are unaffected.
-
-Off-TPU backends are unaffected by the gate: the dispatch heuristics
-already return False there, and interpret-mode tests call the kernels
-directly.
 """
 
 from __future__ import annotations
-
-import os
-
-_TRUE = frozenset({'1', 'true', 'on', 'all'})
-_FALSE = frozenset({'', '0', 'false', 'off', 'none'})
-
-
-def enabled(kernel: str) -> bool:
-    """Whether the named Pallas kernel family may dispatch on TPU."""
-    val = os.environ.get('KFAC_TPU_PALLAS', '1').strip().lower()
-    if val in _TRUE:
-        return True
-    if val in _FALSE:
-        return False
-    return kernel in {t.strip() for t in val.split(',')}
 
 
 def interpret_mode() -> bool:
@@ -74,3 +45,30 @@ def manual_context() -> tuple[bool, bool, bool]:
     am = jax.sharding.get_abstract_mesh()
     manual = [t == jax.sharding.AxisType.Manual for t in am.axis_types]
     return bool(am.axis_names), any(manual), bool(manual) and all(manual)
+
+
+def mosaic_context_ok() -> bool:
+    """Whether the current trace context can execute a raw ``pallas_call``.
+
+    Mosaic kernels cannot be automatically partitioned (measured on-chip:
+    ``NotImplementedError: Mosaic kernels cannot be automatically
+    partitioned`` from a flash dispatch inside the pipeline's
+    partial shard_map, whose model axis stays automatic). Safe contexts:
+
+    - a FULLY-manual shard_map region: every mesh axis manual, so the
+      kernel sees device-local blocks and GSPMD never touches it;
+    - no surrounding mesh AND a single-device process: with more than
+      one device, inputs placed via ``device_put(NamedSharding)`` can
+      arrive sharded without any mesh context and would still need GSPMD
+      to partition the kernel.
+
+    Partial-manual regions (pipeline manual over pipe+data with TP
+    automatic) and plain pjit meshes fall back to the XLA expressions,
+    which XLA partitions fine.
+    """
+    import jax
+
+    has_mesh, _any_manual, all_manual = manual_context()
+    if has_mesh:
+        return all_manual
+    return len(jax.devices()) == 1

@@ -14,10 +14,10 @@ interpreter, and on the chip by ``chip_smoke.py``): f32 allclose to the
 unfused expressions above, contracted at f32 precision on both sides,
 for dense and stacked (vmapped) tensors.
 
-Dispatch: family ``klclip`` in the committed threshold artifact
-(:mod:`kfac_tpu.ops.dispatch_tables`). Off-TPU, below threshold, in
-partial-manual trace contexts, or under a contaminated baseline sweep
-the callers fall back to the unfused expressions.
+Dispatch: :func:`use_fused_klclip_for`, from backend, shape and trace
+context. Off-TPU, below ``_MIN_KLCLIP_DIM`` squared elements or in a
+partial-manual trace context the callers fall back to the unfused
+expressions.
 
 The module keeps its name from the fused Newton-Schulz pair it also held
 until PR 26 (``_ns_xupdate_kernel`` / ``_ns_mx_resid_kernel``: both
@@ -43,8 +43,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kfac_tpu.ops.pallas_cov_ema import TILE, _pad_to
-from kfac_tpu.ops.pallas_gate import interpret_mode
+from kfac_tpu.ops.pallas_gate import interpret_mode, mosaic_context_ok
+
+TILE = 128       # lane-aligned block edge
+
+# The pair runs from this many elements squared. An off-chip prior sized
+# off the unfused expressions' sweep, never re-derived on the chip; the
+# on-chip A/B of the pair against XLA's two passes is ROADMAP S6's.
+_MIN_KLCLIP_DIM = 4 * TILE
+
+
+def _pad_to(x: jax.Array, rows: int, cols: int) -> jax.Array:
+    pr, pc = rows - x.shape[0], cols - x.shape[1]
+    if pr or pc:
+        x = jnp.pad(x, ((0, pr), (0, pc)))
+    return x
 
 
 # ------------------------------------------------------------------ kl-clip
@@ -126,25 +139,15 @@ def fused_klclip_scale(
 
 
 def use_fused_klclip_for(shape: tuple[int, ...]) -> bool:
-    """Dispatch the fused kl-clip kernels only in their artifact-backed
-    win regime (family ``klclip``): the gate compares the tensor's
-    element count against ``min_dim**2`` (the family's sweep is over
-    square (d, d) preconditioned gradients), so rectangular weights with
-    equivalent traffic dispatch consistently."""
-    from kfac_tpu import warnings as kfac_warnings
-    from kfac_tpu.ops import dispatch_tables, pallas_gate
-    from kfac_tpu.ops.pallas_attention import _mosaic_context_ok
-
-    if not (
-        pallas_gate.enabled('klclip')
-        and jax.default_backend() == 'tpu'
-    ):
-        return False
-    sweep = dispatch_tables.floor_contaminated('klclip')
-    if sweep is not None:
-        kfac_warnings.warn_dispatch_event('klclip', sweep)
-        return False
-    if len(shape) != 2:
-        return False
-    min_dim = dispatch_tables.family_min_dim('klclip', default=4 * TILE)
-    return shape[0] * shape[1] >= min_dim * min_dim and _mosaic_context_ok()
+    """Whether the fused kl-clip pair runs on a tensor of this shape,
+    from what can be observed here: a TPU backend, a 2-D tensor of at
+    least ``_MIN_KLCLIP_DIM ** 2`` elements (by element count, so
+    rectangular weights with the traffic of a square one decide alike),
+    and a trace context a raw Mosaic call can run in
+    (:func:`pallas_gate.mosaic_context_ok`)."""
+    return (
+        jax.default_backend() == 'tpu'
+        and len(shape) == 2
+        and shape[0] * shape[1] >= _MIN_KLCLIP_DIM ** 2
+        and mosaic_context_ok()
+    )

@@ -8,8 +8,7 @@ two pipeline scans — the 2-slot 1F1B (:class:`parallel.pipeline
 8-virtual-device CPU mesh under the one-dispatch microbench harness
 (:mod:`tools.tpu_microbench`), and committing the measured-vs-predicted
 table as a versioned artifact (``planner/bubble_table.json``), loaded
-with the same load-or-default discipline as
-``ops/dispatch_thresholds.json``.
+load-or-default (:func:`load_bubble_table`).
 
 Measurement protocol (per ``(schedule, p, v)`` row): the scan is timed
 at two microbatch counts ``m`` and ``2m``. Since fill/drain depth does
@@ -18,9 +17,9 @@ not depend on ``m``, the per-slot time is the SLOPE
 fraction is ``1 - executed·t / W(m)`` — on the collectively-synchronized
 mesh every tick costs one slot time whether or not this rank is idle, so
 this converges to the simulator's ``idle/total`` slot fraction. Rows
-whose sweep is flat under :func:`ops.dispatch_tables
-.latency_floor_verdict` (work doubled, wall clock didn't move) are
-marked ``contaminated`` and excluded from the agreement gate.
+whose sweep is flat under the harness's ``latency_floor_verdict`` (work
+doubled, wall clock didn't move) are marked ``contaminated`` and
+excluded from the agreement gate.
 
 Executed-tick counts are not inferred: the interleaved rows read the
 per-rank ``(F, B, idle)`` counters the scan carry itself accumulates
@@ -176,24 +175,31 @@ def _build(schedule: str, p: int, v: int, m: int):
     return model, params, (tokens, targets)
 
 
-def _time_point(
-    schedule: str, p: int, v: int, m: int, iters: int, repeats: int = 1
-):
-    """(seconds-per-step Timing, executed-tick evidence) for one
-    ``(schedule, p, v, m)`` point under the one-dispatch harness."""
+def _microbench():
+    """The one-dispatch harness, ``tools/tpu_microbench.py``. tools/ is
+    not a package: it is imported the same way
+    tests/test_measurement.py does."""
     import sys
 
-    import jax
-    import numpy as np
-
-    # tools/ is not a package; the microbench harness is imported the
-    # same way tests/test_measurement.py does.
     _tools = os.path.join(
         os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))), 'tools')
     if _tools not in sys.path:
         sys.path.insert(0, _tools)
     import tpu_microbench
+
+    return tpu_microbench
+
+
+def _time_point(
+    schedule: str, p: int, v: int, m: int, iters: int, repeats: int = 1
+):
+    """(seconds-per-step Timing, executed-tick evidence) for one
+    ``(schedule, p, v, m)`` point under the one-dispatch harness."""
+    import jax
+    import numpy as np
+
+    tpu_microbench = _microbench()
 
     model, params, batch = _build(schedule, p, v, m)
     sim = schedule_terms_checked(schedule, p, v, m)
@@ -266,8 +272,6 @@ def measure_row(
     the measured bubble fraction next to the simulator's exact slot
     fraction plus the harness provenance and the latency-floor verdict.
     """
-    from kfac_tpu.ops import dispatch_tables
-
     m_lo = int(m_lo) if m_lo else 2 * p
     if m_lo % p:
         raise ValueError(f'm_lo ({m_lo}) must be a multiple of p ({p})')
@@ -283,7 +287,7 @@ def measure_row(
         1.0 - (e_lo * slot_s) / float(t_lo) if slot_s > 0 and t_lo > 0
         else None
     )
-    floor = dispatch_tables.latency_floor_verdict(
+    floor = _microbench().latency_floor_verdict(
         [e_lo, e_hi], [float(t_lo), float(t_hi)],
         work_exponent=1.0, min_work_ratio=1.5,
     )

@@ -55,7 +55,7 @@ def default_compute_method(
     (kfac/preconditioner.py:245-256) because cuSOLVER makes eigh cheap on
     GPU. On TPU, eigh/cholesky lower to sequential panel algorithms that are
     MXU-hostile: a single distinct-shape EIGEN step was measured never to
-    finish compiling inside a 20-minute budget on v5e (see bench.py), while
+    finish compiling inside a 20-minute budget on v5e, while
     the Newton-Schulz damped inverse is 2*iters large matmuls. So:
 
     - ``tpu`` -> (INVERSE, ``'newton_schulz'``)

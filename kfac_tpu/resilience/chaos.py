@@ -97,8 +97,7 @@ REPO_ROOT = os.path.dirname(
 DEFAULT_WORKER = os.path.join(REPO_ROOT, 'testing', 'chaos_worker.py')
 
 #: committed SLO artifact (written by ``tools/kfac_chaos.py --out``):
-#: the canonical scripted storm's reconciled report, folded read-only
-#: into bench rounds by ``bench.py``'s ``_chaos_probe``
+#: the canonical scripted storm's reconciled report
 ARTIFACT_PATH = os.path.join(os.path.dirname(__file__), 'chaos_slo.json')
 
 

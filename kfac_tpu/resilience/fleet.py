@@ -252,7 +252,7 @@ class FleetController:
         self._last_event_step: int | None = None
         #: chronological fleet events ({'event', 'step', 'detail'})
         self.events: list[dict[str, Any]] = []
-        #: headline counters/timings (bench.py's _fleet_probe reads these)
+        #: headline counters/timings
         self.stats: dict[str, Any] = {
             'retunes': 0, 'migrations': 0, 'aborts': 0,
             'retune_s': None, 'migration_s': None, 'downtime_steps': None,

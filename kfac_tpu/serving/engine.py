@@ -28,8 +28,7 @@ Three design points carry the engine:
   is orders of magnitude cheaper than Monte-Carlo sampling; the
   ``auto`` path computes it first and escalates only the requests
   whose variance clears ``ServingConfig.variance_threshold`` to the
-  ``escalated_n_samples`` MC predictive — the calibrated-abstention
-  loop gated in ``tools/bench_accuracy.py``.
+  ``escalated_n_samples`` MC predictive.
 
 See docs/SERVING.md for the walkthrough.
 """

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point (``chip_smoke.py``, the example trainers,
-``bench.py`` stages, ``tests/conftest.py``): if
+``benchmark/run.py``, ``tests/conftest.py``): if
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no code
 sets another directory; otherwise the cache is ``<checkout>/.jax_cache``
 (git-ignored). The path is part of the cache key, so it is fixed — never

@@ -30,13 +30,6 @@ class LayoutPlanWarning(Warning):
     engine fell back to its explicit/default configuration."""
 
 
-class DispatchTableWarning(Warning):
-    """A Pallas dispatch gate held its conservative (XLA) default because
-    the committed threshold artifact's backing sweep is latency-floor
-    contaminated (kfac_tpu/ops/dispatch_tables.py) — the threshold it
-    would have used never measured the op."""
-
-
 class FleetWarning(Warning):
     """A self-driving fleet event (kfac_tpu/resilience/fleet.py) an
     operator should know about: a topology-change retune, a drift-
@@ -129,32 +122,3 @@ def warn_fleet_event(cause: str, detail: str = '') -> bool:
 def reset_fleet_warnings() -> None:
     """Forget emitted fleet events (tests)."""
     _fleet_events_emitted.clear()
-
-
-# families already warned about — once per process per family: the gates
-# run at trace time, so a contaminated artifact would otherwise warn on
-# every jit trace while saying nothing new.
-_dispatch_events_emitted: set[str] = set()
-
-
-def warn_dispatch_event(family: str, sweep: str) -> bool:
-    """Emit a rate-limited :class:`DispatchTableWarning` (once per
-    ``family``) naming the contaminated sweep the gate refused to trust.
-
-    Returns True when a warning was actually emitted."""
-    if family in _dispatch_events_emitted:
-        return False
-    _dispatch_events_emitted.add(family)
-    _warnings.warn(
-        f'kfac-tpu dispatch: {family!r} threshold held at the conservative '
-        f'XLA default — backing sweep {sweep!r} is latency-floor '
-        'contaminated (re-derive kfac_tpu/ops/dispatch_thresholds.json '
-        'from a clean one-dispatch sweep)',
-        DispatchTableWarning, stacklevel=2,
-    )
-    return True
-
-
-def reset_dispatch_warnings() -> None:
-    """Forget emitted dispatch-gate events (tests)."""
-    _dispatch_events_emitted.clear()

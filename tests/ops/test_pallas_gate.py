@@ -1,51 +1,19 @@
-"""The Pallas dispatch gate: default ON since the round-5 on-chip
-validation, with dispatch restricted to each kernel's measured win
-regime; KFAC_TPU_PALLAS=0 restores the pure-XLA paths."""
+"""What the flash kernel's choice asks of the backend and of the trace
+context (``pallas_gate.mosaic_context_ok``); the decision tables are in
+tests/ops/test_kernel_choice.py."""
 
-import pytest
-
-from kfac_tpu.ops import pallas_attention, pallas_gate
+from kfac_tpu.ops import pallas_attention
 
 
-@pytest.mark.parametrize(
-    'val,klclip,attn',
-    [
-        (None, True, True),       # unset: default ON (validated on-chip r5)
-        ('0', False, False),
-        ('', False, False),
-        ('off', False, False),
-        ('1', True, True),
-        ('true', True, True),
-        ('all', True, True),
-        ('klclip', True, False),
-        ('attn', False, True),
-        ('klclip,attn', True, True),
-        (' klclip , attn ', True, True),
-        ('bogus', False, False),
-    ],
-)
-def test_enabled_parsing(monkeypatch, val, klclip, attn):
-    if val is None:
-        monkeypatch.delenv('KFAC_TPU_PALLAS', raising=False)
-    else:
-        monkeypatch.setenv('KFAC_TPU_PALLAS', val)
-    assert pallas_gate.enabled('klclip') is klclip
-    assert pallas_gate.enabled('attn') is attn
-
-
-def test_dispatch_stays_off_cpu_even_when_enabled(monkeypatch):
-    # the gate only ever ADDS a restriction: enabling it off-TPU must not
-    # flip the backend check
-    monkeypatch.setenv('KFAC_TPU_PALLAS', '1')
+def test_flash_stays_off_cpu():
+    # every other condition holds (whole tiles, d on 128, K+V inside the
+    # VMEM budget): the CPU test backend alone keeps the kernel off
     assert not pallas_attention.use_flash_for(1024, 1024, 128)
 
 
-def test_dispatch_default_on_but_cpu_backend_off(monkeypatch):
-    # default gate is ON since the round-5 on-chip validation, but the
-    # CPU test backend still never dispatches
-    monkeypatch.delenv('KFAC_TPU_PALLAS', raising=False)
-    assert pallas_gate.enabled('klclip') and pallas_gate.enabled('attn')
-    assert not pallas_attention.use_flash_for(1024, 1024, 128)
+def test_dense_flash_stays_off_cpu():
+    # the dense path at its floor length: still the backend that decides
+    assert not pallas_attention.use_flash_for(2048, 2048, 128, dense=True)
 
 
 def test_dispatch_win_regimes(monkeypatch):
@@ -53,15 +21,14 @@ def test_dispatch_win_regimes(monkeypatch):
     faking the TPU backend check."""
     import jax as _jax
 
-    monkeypatch.setenv('KFAC_TPU_PALLAS', '1')
     monkeypatch.setattr(_jax, 'default_backend', lambda: 'tpu')
     # single-device process (one chip): mesh-less dispatch allowed
     monkeypatch.setattr(_jax, 'devices', lambda *a: [object()])
-    # dense path: XLA's fused attention wins below s=2048 (measured)
+    # dense path: XLA's fused attention below s=2048 (an off-chip prior)
     assert pallas_attention.use_flash_for(2048, 2048, 128, dense=True)
     assert not pallas_attention.use_flash_for(512, 512, 128, dense=True)
     # blockwise-partials path (ring steps): no length floor — the
-    # alternative is the unfused einsum partials the kernel beat 300x
+    # alternative is the unfused einsum partials
     assert pallas_attention.use_flash_for(512, 512, 128)
 
 
@@ -74,7 +41,6 @@ def test_mosaic_context_guard(monkeypatch):
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    monkeypatch.setenv('KFAC_TPU_PALLAS', '1')
     monkeypatch.setattr(_jax, 'default_backend', lambda: 'tpu')
 
     mesh = Mesh(np.array(_jax.devices()).reshape(4, 2), ('a', 'b'))

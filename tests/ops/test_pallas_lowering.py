@@ -13,11 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from kfac_tpu.ops import (
-    pallas_attention,
-    pallas_cov_ema,
-    pallas_ns,
-)
+from kfac_tpu.ops import pallas_attention, pallas_ns
 
 
 @pytest.fixture(autouse=True)
@@ -38,8 +34,6 @@ def _f32(*shape):
     return jnp.ones(shape, jnp.float32)
 
 
-# (rows, d): one whole-tile shape, one ragged in both dims
-COV_SHAPES = [(1024, 256), (700, 300)]
 # (rows, cols) of a preconditioned gradient
 KLCLIP_SHAPES = [(512, 512), (1000, 2049)]
 BATCH = 3
@@ -47,17 +41,6 @@ BATCH = 3
 
 def _stack(x):
     return jnp.stack([x] * BATCH)
-
-
-@pytest.mark.parametrize('n,d', COV_SHAPES)
-def test_fused_cov_ema_lowers(n, d):
-    f, a = _f32(d, d), _f32(n, d)
-
-    def fused(f, a):
-        return pallas_cov_ema._fused(f, a, 0.95, 0.05 / n)
-
-    assert _kernels_in(fused, f, a) == 1
-    assert _kernels_in(jax.vmap(fused), _stack(f), _stack(a)) == 1
 
 
 @pytest.mark.parametrize('r,c', KLCLIP_SHAPES)

@@ -258,17 +258,6 @@ def test_load_slo_artifact_tolerates_absence(tmp_path):
     assert chaos.load_slo_artifact(str(bad)) is None
 
 
-def test_bench_chaos_probe_folds_artifact():
-    import bench
-
-    probe = bench._chaos_probe()
-    assert probe['status'] == 'ok'
-    assert {'sigterm_wave', 'torn_checkpoint', 'shrink'} <= set(
-        probe['rows']
-    )
-    assert probe['blown'] == []
-
-
 def test_chaos_cli_selftest():
     import subprocess
     import sys

@@ -19,16 +19,13 @@ Pins PR 18's acceptance criteria:
   re-stamped after rotation, never duplicated on append),
   ``PostmortemWriter`` MANIFESTs, and the Trainer -> compile-watch
   thread;
-- KFL113 pins the doc tables to the live registries;
-- ``bench._ledger_probe`` folds the same verdict into round JSON
-  without ever killing the round.
+- KFL113 pins the doc tables to the live registries.
 
 Compile budget: everything here is host-side parsing — the one Trainer
 test only constructs (never steps) the engine, so the module adds zero
 XLA compiles.
 """
 
-import copy
 import json
 import os
 import subprocess
@@ -367,14 +364,6 @@ def test_load_baseline_rejects_foreign_artifacts(tmp_path):
         ledger.load_baseline(p)
 
 
-def test_committed_bench_baseline_is_loadable():
-    base = ledger.load_baseline(os.path.join(REPO, 'bench_runs',
-                                             'LEDGER.json'))
-    assert base['platform'] == 'cpu'  # rounds 2-5 are CPU-fallback
-    assert base['n_dropped_provenance'] == 1  # r1 has parsed: null
-    assert set(base['keys']) <= set(ledger.DEFAULT_SENTINEL_KEYS)
-
-
 # -------------------------------------------------------- run-id threading
 
 
@@ -489,34 +478,3 @@ def test_kfl113_catches_doc_drift(tmp_path):
 def test_kfl113_registered():
     rules = {r.code for r in drift.core.all_rules()}
     assert 'KFL113' in rules
-
-
-# ------------------------------------------------------------- bench probe
-
-
-def test_bench_ledger_probe_statuses(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.chdir(REPO)
-    monkeypatch.setenv('BENCH_RUNS_DIR', FIXTURE)
-    probe = bench._ledger_probe(_fixture_round())
-    assert probe['status'] == 'ok'
-    assert probe['keys']['value'] == 'ok'
-
-    doctored = _fixture_round()
-    doctored['parsed']['value'] /= 1.5
-    probe = bench._ledger_probe(doctored)
-    assert probe['status'] == 'regressed'
-    assert probe['regressed_keys'] == ['value']
-
-    cpu = copy.deepcopy(_fixture_round())
-    cpu['parsed']['platform'] = 'cpu'
-    probe = bench._ledger_probe(cpu)
-    assert probe['status'] == 'refused'
-
-    monkeypatch.setenv('BENCH_RUNS_DIR', str(tmp_path))  # no LEDGER.json
-    probe = bench._ledger_probe(_fixture_round())
-    assert probe['status'] == 'no_baseline'
-    # the probe never kills the round
-    assert bench._ledger_probe({'parsed': 'garbage'})['status'] in (
-        'no_baseline', 'error')

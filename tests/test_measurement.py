@@ -1,5 +1,5 @@
-"""Measurement truth layer: the one-dispatch microbench harness, the
-latency-floor detector, and the dispatch-threshold artifact.
+"""Measurement truth layer: the one-dispatch microbench harness and its
+latency-floor detector.
 
 All CPU-runnable: the harness's fori_loop and legacy dispatch modes are
 the SAME chained math (pinned by equivalence here), so everything but
@@ -15,24 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kfac_tpu.ops import dispatch_tables
-
 sys.path.insert(
     0, os.path.abspath(os.path.join(os.path.dirname(__file__), '..', 'tools'))
 )
 import tpu_microbench as mb  # noqa: E402
-
-import bench  # noqa: E402
-
-
-@pytest.fixture(autouse=True)
-def _fresh_tables(monkeypatch):
-    """Each test sees the real committed artifact unless it overrides
-    the env var itself; the cache never leaks across tests."""
-    monkeypatch.delenv(dispatch_tables.ENV_VAR, raising=False)
-    dispatch_tables.invalidate_cache()
-    yield
-    dispatch_tables.invalidate_cache()
 
 
 # ------------------------------------------------------ harness equivalence
@@ -123,18 +109,11 @@ def test_report_lifts_provenance(capsys):
                    'dispatch_mode': 'fori_loop', 'dispatches': 1}
 
 
-def test_bench_measurement_block_matches_harness():
-    """bench.py hardcodes the provenance block (it must not import jax
-    via tpu_microbench at orchestrator scope) — pin the copies."""
-    assert bench._MEASUREMENT['harness_version'] == mb.HARNESS_VERSION
-    assert bench._MEASUREMENT['dispatch_mode'] == mb._dispatch_mode()
-
-
 # -------------------------------------------------------- floor detector
 
 
 def test_floor_detector_flags_flat_sweep():
-    verdict = dispatch_tables.latency_floor_verdict(
+    verdict = mb.latency_floor_verdict(
         [256, 512, 1024, 2048], [0.0716, 0.0756, 0.0828, 0.0753],
     )
     assert verdict is not None and verdict['contaminated']
@@ -145,7 +124,7 @@ def test_floor_detector_flags_flat_sweep():
 
 def test_floor_detector_passes_scaling_sweep():
     sizes = [256, 512, 1024, 2048]
-    verdict = dispatch_tables.latency_floor_verdict(
+    verdict = mb.latency_floor_verdict(
         sizes, [0.001 * (s / 256) ** 2 for s in sizes],
     )
     assert verdict is not None and not verdict['contaminated']
@@ -153,12 +132,12 @@ def test_floor_detector_passes_scaling_sweep():
 
 def test_floor_detector_abstains_without_evidence():
     # one point: nothing to compare
-    assert dispatch_tables.latency_floor_verdict([512], [0.01]) is None
+    assert mb.latency_floor_verdict([512], [0.01]) is None
     # the sweep never leaves the latency-bound regime (work ratio < 4x)
-    assert dispatch_tables.latency_floor_verdict(
+    assert mb.latency_floor_verdict(
         [128, 160], [0.01, 0.0101]) is None
     # None entries (errored ops) are dropped before judging
-    assert dispatch_tables.latency_floor_verdict(
+    assert mb.latency_floor_verdict(
         [128, 256, 512], [None, 0.01, None]) is None
 
 
@@ -173,92 +152,3 @@ def test_report_floor_verdicts_emits_lines(capsys):
     assert [ln['op'] for ln in lines] == ['floor/cov_dense_f32']
     assert lines[0]['contaminated'] is True
     assert set(verdicts) == {'cov_dense_f32'}
-
-
-# --------------------------------------------------------- artifact loading
-
-
-def test_committed_artifact_loads_from_a_clean_sweep():
-    doc = dispatch_tables.load_tables(dispatch_tables.ARTIFACT_PATH)
-    assert doc['schema'] == dispatch_tables.SCHEMA_VERSION
-    assert doc['cov_ema']['min_dim'] == 256
-    assert doc['cov_ema']['dtypes'] == ['float32']
-    assert doc['attn']['min_sk_dense'] == 2048
-    # re-derived from the clean one-dispatch sweep: no contaminated
-    # baselines remain (the latency-floor-contaminated v1 numbers are
-    # retired), and everything still at its prior says why
-    assert doc['provenance']['contaminated'] == {}
-    assert 'cov_ema' in doc['provenance']['held']
-    assert doc['provenance']['source']['records'] > 0
-
-
-def test_accessors_fall_back_on_missing_artifact(monkeypatch, tmp_path):
-    monkeypatch.setenv(dispatch_tables.ENV_VAR,
-                       str(tmp_path / 'does_not_exist.json'))
-    dispatch_tables.invalidate_cache()
-    assert dispatch_tables.load_tables() == {}
-    assert dispatch_tables.family_min_dim('cov_ema', default=321) == 321
-    assert dispatch_tables.family_dtypes('cov_ema') == ('float32',)
-    assert dispatch_tables.flash_min_sk_dense(default=4096) == 4096
-
-
-def test_accessors_fall_back_on_schema_mismatch(monkeypatch, tmp_path):
-    p = tmp_path / 'future.json'
-    p.write_text(json.dumps({'schema': 99, 'cov_ema': {'min_dim': 1}}))
-    monkeypatch.setenv(dispatch_tables.ENV_VAR, str(p))
-    dispatch_tables.invalidate_cache()
-    assert dispatch_tables.load_tables() == {}
-    assert dispatch_tables.family_min_dim('cov_ema', default=256) == 256
-
-
-def test_env_override_redirects_the_gates(monkeypatch, tmp_path):
-    p = tmp_path / 'tuned.json'
-    p.write_text(json.dumps({
-        'schema': 1,
-        'cov_ema': {'min_dim': 512, 'dtypes': ['float32', 'bfloat16']},
-        'attn': {'min_sk_dense': 1024},
-    }))
-    monkeypatch.setenv(dispatch_tables.ENV_VAR, str(p))
-    dispatch_tables.invalidate_cache()
-    assert dispatch_tables.family_min_dim('cov_ema', default=256) == 512
-    assert dispatch_tables.family_dtypes('cov_ema') == (
-        'float32', 'bfloat16')
-    assert dispatch_tables.flash_min_sk_dense(default=2048) == 1024
-
-
-def test_gate_functions_consume_the_tables(monkeypatch, tmp_path):
-    """use_fused_cov_ema_for / use_flash_for read the artifact through
-    the accessors (off-TPU both still return False — backend check — so
-    this pins the plumbing via the accessors the gates call)."""
-    from kfac_tpu.ops import pallas_attention, pallas_cov_ema
-
-    assert pallas_cov_ema.use_fused_cov_ema_for(1024, jnp.float32) is False
-    assert pallas_attention.use_flash_for(128, 2048, 128, dense=True) is False
-    # and the threshold values they would compare against come from the
-    # committed artifact
-    assert dispatch_tables.family_min_dim('cov_ema', default=0) == 256
-    assert dispatch_tables.flash_min_sk_dense(default=0) == 2048
-
-
-# -------------------------------------------------------------- derivation
-
-
-def test_derive_attn_needs_min_win_points():
-    ops = [{'op': f'attn_einsum_s{s}', 'ms': m}
-           for s, m in [(512, 1.0), (1024, 4.0), (2048, 290.0)]]
-    ops += [{'op': f'attn_flash_s{s}', 'ms': m}
-            for s, m in [(512, 5.0), (1024, 6.0), (2048, 0.9)]]
-    t = dispatch_tables.derive_tables(ops)
-    assert t['attn']['min_sk_dense'] == (
-        dispatch_tables.DEFAULTS['attn']['min_sk_dense'])
-    assert 'attn/min_sk_dense' in t['provenance']['held']
-    # two winning lengths flips it
-    ops[-2]['ms'] = 2.0
-    t = dispatch_tables.derive_tables(ops)
-    assert t['attn']['min_sk_dense'] == 1024
-
-
-def test_derive_tool_selftest_runs():
-    import derive_dispatch_tables
-
-    derive_dispatch_tables.selftest()

@@ -4,8 +4,8 @@
 Drives :class:`kfac_tpu.resilience.chaos.ChaosConductor` — a real
 multi-process gloo pod under scripted or seeded preemption storms —
 and writes the reconciled :class:`ChaosReport` JSON. The committed
-artifact (``kfac_tpu/resilience/chaos_slo.json``) is what ``bench.py``'s
-``_chaos_probe`` and the docs/ROBUSTNESS.md SLO table fold in.
+artifact (``kfac_tpu/resilience/chaos_slo.json``) is what the
+docs/ROBUSTNESS.md SLO table folds in.
 
 Usage:
 

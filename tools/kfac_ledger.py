@@ -8,7 +8,8 @@ ledger"):
     # correlated anomaly timeline over a run directory of stream files
     python tools/kfac_ledger.py --timeline runs/2026-08-06/
 
-    # rebuild the committed perf baseline from bench round records
+    # build a perf baseline from round records (the paths are the
+    # defaults; the repository commits neither rounds nor a baseline)
     python tools/kfac_ledger.py --build-baseline bench_runs/run_*.json \\
         --out bench_runs/LEDGER.json
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Posterior serving CLI: selftest and latency bench for the serving tier.
+"""Posterior serving CLI: selftest of the serving tier.
 
 Drives :class:`kfac_tpu.serving.ServingEngine` — the jitted batched
 uncertainty-inference engine over a Laplace export (docs/SERVING.md) —
@@ -13,18 +13,11 @@ Usage:
         padding buckets, routing/escalation semantics, and the
         zero-recompiles steady-state pin. Exits 0 on success (seconds,
         runs in CI — `make serve`).
-
-    python tools/kfac_serve.py --bench
-        The bench.py serving probe standalone: per-bucket p50/p95
-        latency + requests/s on both paths and the cold-vs-warm AOT
-        warmup A/B over a fresh persistent compile cache, printed as a
-        table.
 """
 
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 import tempfile
@@ -167,38 +160,12 @@ def selftest() -> int:
     return 0
 
 
-def bench() -> int:
-    """Standalone run of the bench.py serving probe, as a table."""
-    import bench as bench_lib
-
-    out = bench_lib._serving_probe()
-    print(json.dumps({k: v for k, v in out.items() if k != 'shapes'},
-                     indent=2, default=str))
-    print()
-    print(f'{"path.bucket":<18}{"batch":>6}{"p50 ms":>9}{"p95 ms":>9}'
-          f'{"req/s":>12}')
-    for name, row in out['shapes'].items():
-        print(f'{name:<18}{row["batch"]:>6}{row["p50_ms"]:>9}'
-              f'{row["p95_ms"]:>9}{row["requests_per_sec"]:>12}')
-    warm = out['warmup']
-    print(
-        f'\nwarmup: {warm["seconds"]}s '
-        f'({warm["persistent_cache"]["hits"]} cache hits, '
-        f'{warm["persistent_cache"]["misses"]} misses); '
-        f'recompiles after warmup: {out["recompiles_after_warmup"]}'
-    )
-    return 1 if out['recompiles_after_warmup'] else 0
-
-
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    g = p.add_mutually_exclusive_group(required=True)
-    g.add_argument('--selftest', action='store_true',
+    p.add_argument('--selftest', action='store_true', required=True,
                    help='end-to-end parity + recompile pin (exit 0 on ok)')
-    g.add_argument('--bench', action='store_true',
-                   help='per-bucket latency table + cold/warm warmup A/B')
-    args = p.parse_args(argv)
-    return selftest() if args.selftest else bench()
+    p.parse_args(argv)
+    return selftest()
 
 
 if __name__ == '__main__':

@@ -14,8 +14,7 @@ Training then picks the plan up with
 ``Trainer(..., auto_layout='plan.json')`` or
 ``DistributedKFAC(config, auto_layout='plan.json')`` — applied only when
 the topology+model fingerprint matches, ignored with a rate-limited
-warning otherwise. ``bench.py`` records the active plan (set
-``KFAC_TUNE_PLAN=plan.json``) into its run JSON.
+warning otherwise.
 
 ``--selftest`` (wired into ``make tune``) runs the whole pipeline on a
 tiny config and asserts the plan round-trips, is deterministic, applies,

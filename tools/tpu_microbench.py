@@ -26,9 +26,8 @@ import jax.numpy as jnp
 
 #: measurement-harness version stamped on every reported line. v1 was the
 #: per-iteration host dispatch loop; v2 is the one-dispatch in-jit
-#: fori_loop chain (bench.py's round records carry this so a number is
-#: attributable to the harness that produced it — keep bench.py's
-#: _MEASUREMENT copy in sync, tests/test_measurement.py pins the pair).
+#: fori_loop chain (a reported line carries this so a number is
+#: attributable to the harness that produced it).
 HARNESS_VERSION = 2
 
 #: env override for the dispatch mode ('fori_loop' | 'legacy'); the
@@ -230,24 +229,67 @@ def report(name, seconds, **extra):
     print(json.dumps(rec), flush=True)
 
 
+def latency_floor_verdict(
+    sizes,
+    seconds,
+    work_exponent: float = 2.0,
+    flat_tol: float = 0.25,
+    min_work_ratio: float = 4.0,
+):
+    """Flag a size sweep whose timings are flat while the work scales.
+
+    A real op timed across sizes spanning a ``min_work_ratio``-fold work
+    range (work ~ size**work_exponent) cannot be flat; measurements
+    whose max/min spread stays within ``flat_tol`` over such a range are
+    dominated by a fixed per-dispatch latency (host round-trip, queue
+    depth), and every number in the sweep is the floor, not the op.
+
+    Returns None when the series is too short or spans too little work
+    to judge; otherwise a verdict dict with ``contaminated`` (bool),
+    the measured ``spread``, the ``expected_ratio`` of work, and the
+    implied ``floor_ms``.
+    """
+    pts = [
+        (float(s), float(t))
+        for s, t in zip(sizes, seconds)
+        if t is not None and t > 0.0
+    ]
+    if len(pts) < 2:
+        return None
+    pts.sort()
+    lo_s, hi_s = pts[0][0], pts[-1][0]
+    if lo_s <= 0 or hi_s <= lo_s:
+        return None
+    expected = (hi_s / lo_s) ** work_exponent
+    if expected < min_work_ratio:
+        return None  # the sweep never leaves the latency-bound regime
+    times = [t for _, t in pts]
+    spread = max(times) / min(times)
+    flat = spread <= 1.0 + flat_tol
+    return {
+        'contaminated': bool(flat),
+        'spread': round(spread, 3),
+        'expected_ratio': round(expected, 1),
+        'n': len(pts),
+        'floor_ms': round(min(times) * 1e3, 3),
+    }
+
+
 def report_floor_verdicts(sweeps):
     """Latency-floor check per sweep family, one ``floor/<family>`` JSON
     line each: a family whose timings stayed flat while the sweep's work
     scaled is contaminated — every number in it is the dispatch floor,
     not the op (measured: cov_dense f32 flat at 72-83 ms across
-    d=256-2048 under the v1 host-loop harness). bench.py lifts these
-    verdicts into the round record so contaminated numbers self-label.
+    d=256-2048 under the v1 host-loop harness).
 
     ``sweeps``: family -> (work_exponent, [(size, seconds|None), ...]).
     Returns the verdicts keyed by family.
     """
-    from kfac_tpu.ops import dispatch_tables
-
     verdicts = {}
     for family, (exponent, points) in sorted(sweeps.items()):
         sizes = [s for s, t in points if t is not None]
         times = [t for _, t in points if t is not None]
-        verdict = dispatch_tables.latency_floor_verdict(
+        verdict = latency_floor_verdict(
             sizes, times, work_exponent=exponent
         )
         if verdict is not None:
@@ -675,41 +717,12 @@ def main():
                                lambda n: timeit(warm, drift, iters=n),
                                qiters, post=warm_iters))
 
-            # fused step-path kernels vs their unfused XLA expressions
-            # (interpret mode off-TPU: numerics-true, and the derivation
-            # can only HOLD priors on a losing or contaminated sweep —
-            # committed CPU evidence never opens a fused gate)
+            # the kl-clip pair vs its unfused XLA expression (interpret
+            # mode off-TPU: numerics-true, never a measurement)
             if run_pallas:
-                from kfac_tpu.ops import pallas_cov_ema, pallas_ns
+                from kfac_tpu.ops import pallas_ns
 
                 interp = pallas_ns.interpret_mode()
-                beta = 0.95
-                coeff = (1.0 - beta) / args.rows
-                f0 = cov + jnp.eye(d, dtype=jnp.float32)
-
-                def ema_unfused(f, a, _beta=beta, _coeff=coeff):
-                    acc = jax.lax.dot_general(
-                        a, a, (((0,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    return _beta * f + _coeff * acc
-
-                track('cov_ema_unfused', 2.0, d, measured(
-                    f'cov_ema_unfused_{d}_f32',
-                    lambda n: timeit(jax.jit(ema_unfused), f0, m, iters=n),
-                    args.iters,
-                ))
-                track('cov_ema_fused', 2.0, d, measured(
-                    f'cov_ema_fused_{d}_f32',
-                    lambda n: timeit(
-                        jax.jit(lambda f, a: pallas_cov_ema._fused(
-                            f, a, beta, coeff, interpret=interp
-                        )),
-                        f0, m, iters=n,
-                    ),
-                    args.iters,
-                ))
-
                 gmat = 0.5 * cov + 0.1 * jnp.eye(d, dtype=jnp.float32)
 
                 def kl_unfused(p, g):
