@@ -175,6 +175,9 @@ def _dense_slice(engine, state, units):
             return damping
         return damping * h.damping_mult[name]
 
+    floor = factors_lib.identity_floor(
+        state.step, engine.factor_decay, engine.factor_update_steps
+    )
     for side, name in units:
         if eigen:
             if side in ('a', 'ag'):
@@ -203,13 +206,13 @@ def _dense_slice(engine, state, units):
                 upd['a_inv'][name] = factors_lib.damped_inverse(
                     state.a[name], eff(name), engine.inv_dtype,
                     engine.inverse_solver, engine.newton_schulz_iters,
-                    x0=state.a_inv[name],
+                    x0=state.a_inv[name], floor=floor,
                 )
             else:
                 upd['g_inv'][name] = factors_lib.damped_inverse(
                     state.g[name], eff(name), engine.inv_dtype,
                     engine.inverse_solver, engine.newton_schulz_iters,
-                    x0=state.g_inv[name],
+                    x0=state.g_inv[name], floor=floor,
                 )
     return state._replace(shadow=sh._replace(
         progress=sh.progress + 1,
@@ -476,7 +479,10 @@ def _kaisa_slice(engine, state, units):
             factor = state.a[key] if side == 'a' else state.g[key]
             prev = state.a_inv[key] if side == 'a' else state.g_inv[key]
             cand = engine._sharded_inv(
-                factor, slot_damping(sb.layers, sb.padded), prev=prev
+                factor, slot_damping(sb.layers, sb.padded), prev=prev,
+                floor=factors_lib.identity_floor(
+                    state.step, cfg.factor_decay, cfg.factor_update_steps
+                ),
             )[0].astype(cfg.inv_dtype)
             upd['a_inv' if side == 'a' else 'g_inv'][key] = (
                 jax.lax.with_sharding_constraint(cand, dec)

@@ -8,7 +8,7 @@ the reference (kfac/layers/eigen.py:295-348, kfac/layers/inverse.py:186-213).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,37 @@ def effective_alpha(
     (w=0) capture, the plain EMA step at w=1.
     """
     return 1.0 - (1.0 - alpha) * w
+
+
+def identity_floor(
+    step: jax.Array,
+    factor_decay: Any,
+    factor_update_steps: Any,
+) -> jax.Array | float:
+    """What an engine knows under a factor's smallest eigenvalue at
+    ``step``: the weight of the identity initialisation still in it.
+
+    A factor is ``decay^n I + (PSD)`` after ``n`` EMA updates from
+    :func:`ema_update`'s identity, and an engine updates at most once every
+    ``factor_update_steps`` steps, so ``n <= step // factor_update_steps
+    + 1`` by the time it inverts at ``step`` and every eigenvalue is at
+    least ``decay^(that)``. Whatever holds an update back leaves more of
+    the identity in, never less: a step without statistics, a layer the
+    loss did not run, a quarantined update rolled back, a routed layer's
+    evidence-weighted decay (:func:`effective_alpha` >= alpha). The count
+    travels with the factors (``step`` is checkpointed beside them). It
+    is not known, and this returns 0, where either hyperparameter is a
+    schedule of the step. It can overstate the floor where factors were
+    made by another cadence than this engine's (an engine swapped in
+    mid-run with a longer ``factor_update_steps``) or are not a sum of
+    PSD terms (a lossy statistics transport): that is safe, see
+    :func:`newton_schulz_inverse_info`, and costs the trips the bound
+    would have saved.
+    """
+    if callable(factor_decay) or callable(factor_update_steps):
+        return 0.0
+    n = step // factor_update_steps + 1
+    return jnp.asarray(factor_decay, jnp.float32) ** n
 
 
 class EigenDecomp(NamedTuple):
@@ -177,7 +208,8 @@ def gershgorin_condition_bound(
     Gershgorin's max absolute row sum bounds ``lambda_max``; damping floors
     ``lambda_min``, so ``kappa <= ||M||_inf / damping``. One reduction —
     usable inside jit to size Newton-Schulz iteration budgets
-    (``log2(kappa) + 5`` iterations reach the fp32 floor) or to flag factors
+    (``log4(kappa) + 5`` iterations of a cold solve reach the fp32 floor when
+    the bound is tight, ``log2(kappa) + 5`` at worst) or to flag factors
     whose fp32 inverse (by ANY solver — Cholesky's backward-stable solve
     also has forward error ``O(kappa * eps)``) cannot be trusted.
 
@@ -212,6 +244,24 @@ def gershgorin_condition_bound(
 # in the engines' residual monitor — names this precision.
 NS_PRECISION = jax.lax.Precision.HIGHEST
 
+# A cold solve's scaled phase (``newton_schulz_inverse_info``) runs while
+# its lower bound on the eigenvalues of ``M X`` is under NS_SCALED_UNTIL.
+# From 0.99 two plain steps reach a 1e-6 residual (errors 1e-2, 1e-4, 1e-8).
+# Chosen on a v5e from the trips (PR 30): gpt2-small's 72 A factors after 11
+# EMA updates, every bucket solved cold from ``l`` of 4e-4 to 4e-3, took
+# 54 trips plain and 36 / 38 / 38 / 35 / 34 / 34 with the switch at 0.5 /
+# 0.8 / 0.9 / 0.95 / 0.99 / 0.999 (1,055 ms; 697 / 704 / 706 / 694 / 640 /
+# 639): a late scaled step squares and quarters the worst error where a
+# plain one only squares it, and what is left is the stopping rule's own
+# one to four trips at the float32 floor.
+# It starts from a bound no smaller than NS_SCALE_FROM: under it float32
+# cannot invert the factor by any iteration, and measured on ill-conditioned
+# factors (kappa 1e8 and up, CPU float32, PR 30) a phase started from 1e-7
+# ended on a residual a quarter worse than the plain iteration's and one
+# from the true bound on inf, where one from 1e-6 ended level with it.
+NS_SCALED_UNTIL = 0.99
+NS_SCALE_FROM = 1e-6
+
 
 def newton_schulz_step(
     m: jax.Array, x: jax.Array, mx: jax.Array
@@ -219,7 +269,11 @@ def newton_schulz_step(
     """One Newton-Schulz iteration on ``m = factor + damping*I``
     with ``mx`` the cached ``m @ x``: ``(x_new, mx_new, resid)`` where
     ``x_new = x (2I - mx)``, ``mx_new = m x_new`` and ``resid =
-    ||I - mx_new||_F / sqrt(d)``. XLA's own tiling of the two products
+    ||I - mx_new||_F / sqrt(d)``. A scaled step ``a x (2I - a mx)``
+    (:func:`newton_schulz_inverse_info`) is this step on ``(a x, a mx)``:
+    the pair stays consistent (``m (a x) = a mx``), so the step has one
+    form and three arguments (the benchmark's control swaps it for one
+    that rounds its operands). XLA's own tiling of the two products
     runs at 27-30 TFLOP/s of the six-pass f32 peak's 32.8 on a v5e (PR
     26), level with a Mosaic pair that fused the residual in (see
     ``pallas_ns``'s docstring for what that pair was and cost)."""
@@ -244,7 +298,9 @@ class NewtonSchulzInfo(NamedTuple):
     iteration began from it (bool scalar; False without an ``x0``);
     ``restarted``: that warm start was then abandoned, and the solve
     started over from the cold init (bool scalar: ``warm & ~restarted``
-    is a warm start that paid off).
+    is a warm start that paid off);
+    ``scaled``: the iterations among ``iterations`` that were scaled
+    steps of a cold start (int32 scalar; 0 for a warm start that held).
     """
 
     inverse: jax.Array
@@ -252,6 +308,7 @@ class NewtonSchulzInfo(NamedTuple):
     iterations: jax.Array
     warm: jax.Array
     restarted: jax.Array
+    scaled: jax.Array
 
 
 def newton_schulz_inverse_info(
@@ -262,6 +319,7 @@ def newton_schulz_inverse_info(
     tol: float = 1e-6,
     differentiable: bool = False,
     x0: jax.Array | None = None,
+    floor: float | jax.Array = 0.0,
 ) -> NewtonSchulzInfo:
     """Tikhonov-damped inverse by Newton-Schulz — matmuls only, with a
     residual-based stopping rule and convergence diagnostics.
@@ -270,7 +328,7 @@ def newton_schulz_inverse_info(
     PREVIOUS inverse at each ``inv_update_steps`` refresh: the factor EMA
     moves slowly, so the old inverse sits deep inside the quadratic
     convergence basin and the refresh needs a handful of iterations
-    instead of the cold ~log2(kappa)+5. Safeguarded twice. Up front, the
+    instead of the cold ~log4(kappa)+5. Safeguarded twice. Up front, the
     warm init is used only when its own residual
     ``||I - M X0||_F/sqrt(d) < 0.5``, else the Gershgorin cold start
     runs — an all-zeros x0 (a fresh engine state) therefore falls back
@@ -287,10 +345,40 @@ def newton_schulz_inverse_info(
     ``X_0 = I / ||M||_inf`` guarantees that for symmetric PSD ``M``
     (Gershgorin: the max absolute row sum bounds lambda_max — much tighter
     than trace, whose overshoot costs log2(d) extra iterations). Per
-    eigenvalue the error is ``(1 - lam/||M||_inf)^(2^k)``, so convergence
-    needs ~``log2(kappa) + 5`` iterations: the default cap of 40 covers
-    condition numbers beyond 1e9 — far past the fp32 accuracy floor, so in
-    practice the *stopping rule* ends the loop, not the cap.
+    eigenvalue the plain step's error is ``(1 - lam/||M||_inf)^(2^k)``: the
+    smallest eigenvalue of ``M X`` only doubles an iteration until it is
+    near 1, ~``log2(kappa) + 5`` iterations in all. The default cap of 40
+    covers condition numbers beyond 1e9 — far past the fp32 accuracy floor,
+    so in practice the *stopping rule* ends the loop, not the cap.
+
+    Scaled phase (cold starts only; Pan & Schreiber 1991). With the
+    eigenvalues of ``M X`` known to lie in ``[l, 1]``, the step
+    ``X <- a X (2I - a M X)`` with ``a = 2/(1 + l)`` maps them into
+    ``[l', 1]``, ``l' = 4l/(1 + l)^2``: the bound *quadruples* an
+    iteration, for the same two products, and ``a -> 1`` as ``l -> 1``. A
+    cold start knows ``l = (floor + damping) / ||M||_inf``, where ``floor``
+    is what the caller knows under the *factor's* smallest eigenvalue (0 if
+    nothing; the engines pass :func:`identity_floor`; a per-slot vector
+    under ``vmap``), or ``1 - r_0 sqrt(d)`` where its own residual says
+    more (no eigenvalue's error exceeds the root of the sum of their
+    squares: a factor that is nearly a multiple of the identity is not
+    folded down to a loose ``damping / ||M||_inf`` and brought back), and
+    carries ``l`` through the loop: while ``l < NS_SCALED_UNTIL`` the step
+    is scaled, after that it is the plain one, ~``log4(kappa) + 5``
+    iterations in all when the bound is tight. No
+    margin is taken off the bound, because none is needed: ``a < 2`` and
+    eigenvalues ``<= 1`` keep every eigenvalue in ``(0, 1]`` whatever ``l``
+    is, so a bound that is wrong or loose costs iterations (one per factor
+    of 4 too low; and too high by ``c`` ends the phase ``log4(c)`` steps
+    early, the plain steps doubling from there), never convergence, and a
+    relative error in it does not grow. Two limits: a bound under
+    ``NS_SCALE_FROM`` is read as that (it claims a condition number float32
+    cannot invert, and a scaled step has no slack for the rounding of
+    ``M X`` above 1 beyond ``l`` itself), and a bound at or above
+    ``NS_SCALED_UNTIL`` means no scaled step at all. A warm start that
+    passes its test runs the plain iteration from its first step (nothing
+    bounds its eigenvalues from below); one that restarts from probation
+    re-enters the scaled phase from the cold ``l``.
 
     The loop (``lax.while_loop``) monitors the relative identity residual
     ``r_k = ||I - M X_k||_F / sqrt(d)`` — computed from the ``M @ X``
@@ -301,7 +389,11 @@ def newton_schulz_inverse_info(
     - ``r_k >= r_{k-1}`` (stagnation: the iteration hit its fp32 limiting
       accuracy ``O(kappa * eps)`` — quadratic convergence means the
       residual strictly shrinks until roundoff takes over, so the first
-      non-improving step marks the floor; continuing would only oscillate);
+      non-improving step marks the floor; continuing would only oscillate).
+      Not applied against a scaled step's residual, which need not fall:
+      the directions already converged are pushed out to ``(1-l)/(1+l)``
+      on purpose. The plain steps after the phase decide the returned
+      residual, by this rule as before;
     - ``k == max_iters`` (cap — a backstop, see above).
 
     The returned ``residual`` is the honest quality statement: callers that
@@ -370,7 +462,7 @@ def newton_schulz_inverse_info(
         )
 
     def cond(carry):
-        _, _, resid, prev, k, on_probation = carry
+        _, _, resid, prev, k, on_probation, _, _ = carry
         return running(resid, prev, k) | needs_restart(
             resid, prev, k, on_probation
         )
@@ -378,17 +470,38 @@ def newton_schulz_inverse_info(
     x_cold = eye / lam_max
     mx_cold = m / lam_max  # == m @ x_cold, sans the matmul
     r_cold = residual(mx_cold)
+    # the cold start's eigenvalue bound (see "Scaled phase" above); the
+    # schedule of step sizes is no part of what a caller differentiates
+    l_cold = jax.lax.stop_gradient(
+        jnp.clip(
+            jnp.maximum((floor + damping) / lam_max, 1.0 - r_cold * sqrt_d),
+            NS_SCALE_FROM, NS_SCALED_UNTIL,
+        )
+    )
+    inf = lam_max * 0.0 + jnp.inf
 
     def body(carry):
         """One iteration — from the cold init instead, if the warm start
         just failed."""
-        x, mx, resid, prev, k, on_probation = carry
+        x, mx, resid, prev, k, on_probation, l, scaled = carry
         restart = needs_restart(resid, prev, k, on_probation)
         x = jnp.where(restart, x_cold, x)
         mx = jnp.where(restart, mx_cold, mx)
         resid = jnp.where(restart, r_cold, resid)
-        x_new, mx_new, r_new = newton_schulz_step(m, x, mx)
-        return x_new, mx_new, r_new, resid, k + 1, on_probation & ~restart
+        l = jnp.where(restart, l_cold, l)
+        scaling = l < NS_SCALED_UNTIL
+        # alpha = 1 leaves both operands as they are, to the bit
+        alpha = jnp.where(scaling, 2.0 / (1.0 + l), 1.0)
+        x_new, mx_new, r_new = newton_schulz_step(m, alpha * x, alpha * mx)
+        l_new = jnp.where(scaling, 4.0 * l / jnp.square(1.0 + l), l)
+        # a scaled step's residual need not fall (the converged directions
+        # are pushed out to (1-l)/(1+l) on purpose): no stagnation test
+        # against it, which is what an infinite ``prev`` says
+        return (
+            x_new, mx_new, r_new, jnp.where(scaling, inf, resid), k + 1,
+            on_probation & ~restart, l_new,
+            scaled + scaling.astype(jnp.int32),
+        )
 
     if x0 is not None:
         # safeguarded warm start: keep the caller's init only if it is
@@ -402,15 +515,21 @@ def newton_schulz_inverse_info(
         use_warm = residual(m_warm) < 0.5
         x0 = jnp.where(use_warm, warm, x_cold)
         mx0 = jnp.where(use_warm, m_warm, mx_cold)
+        # a warm start runs the plain iteration: nothing bounds its
+        # eigenvalues from below
+        l0 = jnp.where(use_warm, NS_SCALED_UNTIL, l_cold)
     else:
-        x0, mx0 = x_cold, mx_cold
+        x0, mx0, l0 = x_cold, mx_cold, l_cold
         use_warm = lam_max < 0.0  # False, typed like the rest of the carry
 
-    # prev starts at inf so the first step always runs; it derives from
-    # lam_max (not a fresh constant) so that under shard_map the carry init
-    # has the same varying-manual-axes type as the residuals the body
-    # computes from ``m``.
-    init = (x0, mx0, residual(mx0), lam_max * 0.0 + jnp.inf, 0, use_warm)
+    # prev starts at inf so the first step always runs; it and the
+    # counter derive from lam_max (not a fresh constant) so that under
+    # shard_map the carry init has the same varying-manual-axes type as
+    # what the body computes from ``m``.
+    init = (
+        x0, mx0, residual(mx0), inf, 0, use_warm, l0,
+        (lam_max * 0.0).astype(jnp.int32),
+    )
     if differentiable:
         # fixed-trip scan with where-frozen lanes: same outputs as the
         # while_loop (frozen lanes never change), reverse-differentiable
@@ -420,11 +539,11 @@ def newton_schulz_inverse_info(
                 lambda n, c: jnp.where(active, n, c), body(carry), carry
             ), None
 
-        (x, _, resid, _, k, on_probation), _ = jax.lax.scan(
+        (x, _, resid, _, k, on_probation, _, scaled), _ = jax.lax.scan(
             scan_body, init, None, length=max_iters
         )
     else:
-        x, _, resid, _, k, on_probation = jax.lax.while_loop(
+        x, _, resid, _, k, on_probation, _, scaled = jax.lax.while_loop(
             cond, body, init
         )
     return NewtonSchulzInfo(
@@ -434,6 +553,7 @@ def newton_schulz_inverse_info(
         warm=use_warm,
         # probation starts as ``use_warm`` and ends only at a restart
         restarted=use_warm & ~on_probation,
+        scaled=scaled,
     )
 
 
@@ -445,14 +565,15 @@ def newton_schulz_inverse(
     tol: float = 1e-6,
     differentiable: bool = False,
     x0: jax.Array | None = None,
+    floor: float | jax.Array = 0.0,
 ) -> jax.Array:
     """Newton-Schulz damped inverse (see ``newton_schulz_inverse_info`` for
-    the iteration, stopping rule, accuracy, warm start, and the
-    ``differentiable`` fixed-trip variant for callers that differentiate
-    through it)."""
+    the iteration, stopping rule, accuracy, warm start, the ``floor`` of
+    a cold start's scaled phase, and the ``differentiable`` fixed-trip
+    variant for callers that differentiate through it)."""
     return newton_schulz_inverse_info(
         factor, damping, inv_dtype, max_iters=iters, tol=tol,
-        differentiable=differentiable, x0=x0,
+        differentiable=differentiable, x0=x0, floor=floor,
     ).inverse
 
 
@@ -472,6 +593,7 @@ def damped_inverse(
     solver: str = 'cholesky',
     iters: int = 40,
     x0: jax.Array | None = None,
+    floor: float | jax.Array = 0.0,
 ) -> jax.Array:
     """Solver-dispatched damped inverse — the single place the
     ``inverse_solver`` config option is interpreted (dense, KAISA, and
@@ -485,15 +607,18 @@ def damped_inverse(
     lowers the cond to a select that executes BOTH branches batched; for
     stacked/batched callers use :func:`batched_damped_inverse_auto_info`,
     whose single scalar cond pays the Cholesky only when some slot
-    actually needs it (the stacked KAISA engine does this).
+    actually needs it (the stacked KAISA engine does this). ``floor``
+    (what the engine knows under the factor's smallest eigenvalue:
+    :func:`identity_floor`) only speeds a cold Newton-Schulz solve; the
+    Cholesky solver ignores it.
     """
     if solver == 'newton_schulz':
         return newton_schulz_inverse(
-            factor, damping, inv_dtype, iters=iters, x0=x0
+            factor, damping, inv_dtype, iters=iters, x0=x0, floor=floor
         )
     if solver == 'auto':
         info = newton_schulz_inverse_info(
-            factor, damping, jnp.float32, max_iters=iters, x0=x0
+            factor, damping, jnp.float32, max_iters=iters, x0=x0, floor=floor
         )
         bad = ~(info.residual <= NS_FALLBACK_RESIDUAL)  # NaN residual -> bad
         out = jax.lax.cond(
@@ -511,6 +636,7 @@ def batched_damped_inverse_auto_info(
     inv_dtype: jnp.dtype = jnp.float32,
     iters: int = 40,
     x0: jax.Array | None = None,
+    floor: float | jax.Array = 0.0,
 ) -> NewtonSchulzInfo:
     """Batched ``'auto'`` inverse paying Cholesky only when NS fails.
 
@@ -525,28 +651,30 @@ def batched_damped_inverse_auto_info(
     (well-conditioned) case costs pure MXU matmuls.
 
     ``damping`` may be a scalar or a per-slot ``(n,)`` vector (per-layer
-    escalated damping under factor quarantine) — broadcast into the vmap.
+    escalated damping under factor quarantine) — broadcast into the vmap,
+    as ``floor`` is (``newton_schulz_inverse_info``).
 
     Returns the batched Newton-Schulz pass's :class:`NewtonSchulzInfo`
     with ``inverse`` replaced by the served stack: the other fields stay
     the iteration's own (a slot served by Cholesky keeps the residual
     that condemned it).
     """
-    dmp = jnp.broadcast_to(
-        jnp.asarray(damping, jnp.float32), stack.shape[:-2]
+    dmp, flr = (
+        jnp.broadcast_to(jnp.asarray(v, jnp.float32), stack.shape[:-2])
+        for v in (damping, floor)
     )
     if x0 is None:
         infos = jax.vmap(
-            lambda m, dm: newton_schulz_inverse_info(
-                m, dm, jnp.float32, max_iters=iters
+            lambda m, dm, fl: newton_schulz_inverse_info(
+                m, dm, jnp.float32, max_iters=iters, floor=fl
             )
-        )(stack, dmp)
+        )(stack, dmp, flr)
     else:
         infos = jax.vmap(
-            lambda m, dm, w: newton_schulz_inverse_info(
-                m, dm, jnp.float32, max_iters=iters, x0=w
+            lambda m, dm, fl, w: newton_schulz_inverse_info(
+                m, dm, jnp.float32, max_iters=iters, x0=w, floor=fl
             )
-        )(stack, dmp, x0)
+        )(stack, dmp, flr, x0)
     bad = ~(infos.residual <= NS_FALLBACK_RESIDUAL)  # (n,); NaN -> bad
 
     def fallback(_):

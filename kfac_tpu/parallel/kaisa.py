@@ -328,8 +328,9 @@ def _solve_groups(slots: int, d: int) -> int:
 # Columns of ``RefreshState.solved``: each slot's
 # ``factors.NewtonSchulzInfo`` without the inverse (``iterations``; final
 # ``residual``; ``warm``: the previous inverse was accepted as the start;
-# ``restarted``: and then abandoned for the cold start).
-REFRESH_COLUMNS = ('iterations', 'residual', 'warm', 'restarted')
+# ``restarted``: and then abandoned for the cold start; ``scaled``: the
+# iterations of a cold start's scaled phase, a part of ``iterations``).
+REFRESH_COLUMNS = ('iterations', 'residual', 'warm', 'restarted', 'scaled')
 _NS_SOLVERS = ('newton_schulz', 'auto')
 
 
@@ -339,7 +340,7 @@ _NS_SOLVERS = ('newton_schulz', 'auto')
 )
 @dataclasses.dataclass(frozen=True)
 class RefreshState:
-    """``DistKFACState.refresh``. ``solved``: ``(slots, 4)`` float32, a
+    """``DistKFACState.refresh``. ``solved``: ``(slots, 5)`` float32, a
     row a slot and :data:`REFRESH_COLUMNS` across, ``iterations`` -1 in
     every row until a refresh has filled it. ``buckets`` is static aux
     data (as ``MetricsState.keys``: the layout travels with the state and
@@ -363,9 +364,13 @@ def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
     a device's block until the slowest is done: the largest
     ``iterations`` of all its slots, summed over the groups a wide stack
     is solved in; identity padding converges in 0 or, warm-started across
-    a damping change, in one or two). Empty while no refresh has filled
-    the array."""
+    a damping change, in one or two) with ``scaled_trips``, those of them
+    that some slot took scaled (a cold start's first phase,
+    ``factors.newton_schulz_inverse_info``), formed the same way. Empty
+    while no refresh has filled the array."""
     rows = np.asarray(jax.device_get(refresh.solved), np.float64)
+    # (the benchmark's hand-made states predate ``scaled``: it reads 0)
+    rows = np.pad(rows, ((0, 0), (0, len(REFRESH_COLUMNS) - rows.shape[1])))
     col = dict(zip(REFRESH_COLUMNS, rows.T))
     if not (col['iterations'] >= 0).any():
         return []
@@ -373,14 +378,19 @@ def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
     groups = refresh.groups or (1,) * len(refresh.buckets)
     for (side, key, padded, live), n in zip(refresh.buckets, groups):
         its = col['iterations'][start:start + live]
+
+        def slowest(column):  # of each group's slots, summed over groups
+            return int(sum(
+                group.max()
+                for group in np.split(col[column][start:start + padded], n)
+            ))
+
         out.append({
             'side': side,
             'key': key,
             'iterations': [int(v) for v in its],
-            'trips': int(sum(
-                group.max() for group in
-                np.split(col['iterations'][start:start + padded], n)
-            )),
+            'trips': slowest('iterations'),
+            'scaled_trips': slowest('scaled'),
             'warm_starts': int(col['warm'][start:start + live].sum()),
             'restarts': int(col['restarted'][start:start + live].sum()),
             # np.max, not max(): a NaN residual has to show
@@ -403,6 +413,9 @@ def refresh_totals(refresh: RefreshState) -> dict[str, float]:
     - ``refresh/trips``: loop trips the device executed: buckets are
       solved one after another, each until its slowest slot is done, so
       the sum over buckets of the largest ``iterations`` of each;
+    - ``refresh/scaled_trips``: those of them in which some slot took a
+      scaled step (cold starts' first phase; 0 where every slot started
+      warm): the sum over buckets of the largest ``scaled`` of each;
     - ``refresh/warm_starts``, ``refresh/restarts``: slots whose previous
       inverse was accepted as the start, and those of them that were
       restarted cold;
@@ -421,6 +434,9 @@ def _refresh_totals(buckets: list[dict[str, Any]]) -> dict[str, float]:
             sum(sum(b['iterations']) for b in buckets)
         ),
         'refresh/trips': float(sum(b['trips'] for b in buckets)),
+        'refresh/scaled_trips': float(
+            sum(b['scaled_trips'] for b in buckets)
+        ),
         'refresh/warm_starts': float(sum(b['warm_starts'] for b in buckets)),
         'refresh/restarts': float(sum(b['restarts'] for b in buckets)),
         'refresh/worst_residual': float(
@@ -795,7 +811,9 @@ class DistributedKFAC:
                 refresh=(
                     self._pack_refresh([
                         # iterations -1: no refresh has filled the row
-                        jnp.zeros((sb.padded, 4), jnp.float32).at[:, 0].set(-1)
+                        jnp.zeros(
+                            (sb.padded, len(REFRESH_COLUMNS)), jnp.float32
+                        ).at[:, 0].set(-1)
                         for sb in self.a_store + self.g_store
                     ])
                     if self._ns_refresh else None
@@ -1199,41 +1217,45 @@ class DistributedKFAC:
         return q, d
 
     def _sharded_inv(
-        self, stack: jax.Array, damping, prev: jax.Array | None = None
+        self, stack: jax.Array, damping, prev: jax.Array | None = None,
+        floor=0.0,
     ) -> tuple[jax.Array, jax.Array | None]:
         """Batched sharded damped inverse; ``prev`` (the resident inverse
         stack) warm-starts Newton-Schulz per slot — safeguarded inside
         the solver, so a fresh state's zero inverses cold-start.
         ``damping`` may be a scalar or a per-slot (L,) vector (per-layer
         escalated damping under factor quarantine) — the vector rides the
-        shard_map with the same slot sharding as the stack.
+        shard_map with the same slot sharding as the stack, and so does
+        ``floor`` (``factors.identity_floor``: what a cold Newton-Schulz
+        solve may assume under every slot's smallest eigenvalue; identity
+        padding has all of them at 1).
 
         Returns the inverse stack and, of a Newton-Schulz solve, what each
-        slot's solve reported: an ``(L, 4)`` float32 array,
+        slot's solve reported: an ``(L, 5)`` float32 array,
         :data:`REFRESH_COLUMNS` across (``None`` under the Cholesky
         solver)."""
-        dmp = jnp.broadcast_to(
-            jnp.asarray(damping, jnp.float32), stack.shape[:1]
+        dmp, flr = (
+            jnp.broadcast_to(jnp.asarray(v, jnp.float32), stack.shape[:1])
+            for v in (damping, floor)
         )
         solver = self.config.inverse_solver
         iters = self.config.newton_schulz_iters
 
-        def local(block, prev_block, dmp_block):
-            groups = _solve_groups(block.shape[0], block.shape[-1])
+        def local(*blocks):
+            groups = _solve_groups(blocks[0].shape[0], blocks[0].shape[-1])
             if groups > 1:
                 def split(x):
                     return x.reshape(groups, -1, *x.shape[1:])
 
                 out = jax.lax.map(
-                    lambda t: solve(*t),
-                    (split(block), split(prev_block), split(dmp_block)),
+                    lambda t: solve(*t), tuple(split(b) for b in blocks)
                 )
                 return jax.tree_util.tree_map(
                     lambda x: x.reshape(-1, *x.shape[2:]), out
                 )
-            return solve(block, prev_block, dmp_block)
+            return solve(*blocks)
 
-        def solve(block, prev_block, dmp_block):
+        def solve(block, prev_block, dmp_block, flr_block):
             if solver == 'auto':
                 # one scalar cond per device-local block: Cholesky runs
                 # at runtime only when some slot's NS residual fails —
@@ -1241,13 +1263,17 @@ class DistributedKFAC:
                 # pay-both-branches select
                 info = factors_lib.batched_damped_inverse_auto_info(
                     block, dmp_block, jnp.float32, iters, x0=prev_block,
+                    floor=flr_block,
                 )
             elif solver == 'newton_schulz':
                 info = jax.vmap(
-                    lambda m, w, dm: factors_lib.newton_schulz_inverse_info(
-                        m, dm, jnp.float32, max_iters=iters, x0=w,
+                    lambda m, w, dm, fl: (
+                        factors_lib.newton_schulz_inverse_info(
+                            m, dm, jnp.float32, max_iters=iters, x0=w,
+                            floor=fl,
+                        )
                     )
-                )(block, prev_block, dmp_block)
+                )(block, prev_block, dmp_block, flr_block)
             else:
                 return jax.vmap(
                     lambda m, w, dm: factors_lib.damped_inverse(
@@ -1256,10 +1282,8 @@ class DistributedKFAC:
                 )(block, prev_block, dmp_block)
             return info.inverse, jnp.stack(
                 [
-                    v.astype(jnp.float32) for v in (
-                        info.iterations, info.residual, info.warm,
-                        info.restarted,
-                    )
+                    getattr(info, c).astype(jnp.float32)
+                    for c in REFRESH_COLUMNS
                 ],
                 axis=-1,
             )
@@ -1272,9 +1296,9 @@ class DistributedKFAC:
         # eps_bf16 * kappa and reject the warm start exactly in the
         # high-kappa regime where it saves the most
         out = jax.shard_map(
-            local, mesh=self.mesh, in_specs=(spec, spec, spec),
+            local, mesh=self.mesh, in_specs=(spec,) * 4,
             out_specs=(spec, spec) if solver in _NS_SOLVERS else spec,
-        )(stack, prev, dmp)
+        )(stack, prev, dmp, flr)
         return out if solver in _NS_SOLVERS else (out, None)
 
     @tracing.scope('dist_kfac.update_inverses')
@@ -1366,14 +1390,20 @@ class DistributedKFAC:
             )
         else:
             a_inv, g_inv = {}, {}
-            solved = []  # per bucket, A store then G store: (L, 4)
+            solved = []  # per bucket, A store then G store: (L, 5)
+            # what is left of the identity the factors started from: a
+            # cold Newton-Schulz solve's lower bound (the stores' padding
+            # slots are identity itself)
+            floor = factors_lib.identity_floor(
+                state.step, cfg.factor_decay, cfg.factor_update_steps
+            )
 
             def side(store, side_state, prev, out, ok_slots):
                 for sb in store:
                     cand, told = self._sharded_inv(
                         side_state[sb.key],
                         slot_damping(sb.layers, sb.padded),
-                        prev=prev[sb.key],
+                        prev=prev[sb.key], floor=floor,
                     )
                     cand = cand.astype(cfg.inv_dtype)
                     solved.append(told)

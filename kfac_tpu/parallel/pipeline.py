@@ -927,7 +927,7 @@ class PipelineKFAC:
             idx = idx * int(self.mesh.shape[ax]) + jax.lax.axis_index(ax)
         return idx
 
-    def _make_decomp(self, damping, a_mat, g_mat, like, li):
+    def _make_decomp(self, damping, floor, a_mat, g_mat, like, li):
         """Decomposition of one stage-local layer (inside shard_map).
 
         Returns ``compute(operand) -> (qa, qg, da, dg)``: eigendecomposition
@@ -935,7 +935,9 @@ class PipelineKFAC:
         Newton-Schulz solver keeps this matmul-only on TPU). With DP peers
         present the work round-robins over them by layer index ``li`` and
         psum-shares, dividing decomposition wall-clock by the DP world.
-        ``like`` supplies zero templates for the non-owner branch.
+        ``like`` supplies zero templates for the non-owner branch; ``floor``
+        is ``factors.identity_floor`` at this step, for a cold
+        Newton-Schulz solve.
         """
         cfg = self.config
 
@@ -950,7 +952,7 @@ class PipelineKFAC:
             # Newton-Schulz from them (safeguarded; zeros cold-start)
             inv = lambda f, prev: factors_lib.damped_inverse(
                 f, damping, cfg.inv_dtype, cfg.inverse_solver,
-                cfg.newton_schulz_iters, x0=prev,
+                cfg.newton_schulz_iters, x0=prev, floor=floor,
             )
             return (
                 inv(a_mat, like[0]), inv(g_mat, like[1]),
@@ -983,6 +985,9 @@ class PipelineKFAC:
         checkpoint restore: only step + factors are durable)."""
         cfg = self.config
         damping = _resolve(cfg.damping, state['step'])
+        floor = factors_lib.identity_floor(
+            state['step'], cfg.factor_decay, cfg.factor_update_steps
+        )
         names = list(self.registry.layers)
 
         def body(a, g, qa, qg, da, dg):
@@ -991,7 +996,7 @@ class PipelineKFAC:
             new_qa, new_qg, new_da, new_dg = {}, {}, {}, {}
             for li, name in enumerate(names):
                 compute = self._make_decomp(
-                    damping, a[name], g[name],
+                    damping, floor, a[name], g[name],
                     (qa[name], qg[name], da[name], dg[name]), li,
                 )
                 (
@@ -1114,6 +1119,9 @@ class PipelineKFAC:
 
         do_factors = step % _resolve(cfg.factor_update_steps, step) == 0
         do_inverses = step % _resolve(cfg.inv_update_steps, step) == 0
+        floor = factors_lib.identity_floor(
+            step, cfg.factor_decay, cfg.factor_update_steps
+        )
 
         def body(a, g, qa, qg, da, dg, sa, sg, stage_grads):
             # stage-local views: leading dim = stages per rank (1 for the
@@ -1158,7 +1166,7 @@ class PipelineKFAC:
                     # round-robin owner over DP peers: offset by chunk so
                     # multi-chunk ranks spread decompositions too
                     compute = self._make_decomp(
-                        damping, na_, ng_,
+                        damping, floor, na_, ng_,
                         (qa_c[name], qg_c[name], da_c[name], dg_c[name]),
                         ci * len(names) + li,
                     )

@@ -828,10 +828,15 @@ class KFACPreconditioner:
             # EMA drifts slowly between inv_update_steps refreshes, so the
             # old inverse is deep in the quadratic basin (the safeguard
             # inside newton_schulz_inverse_info falls back to the Gershgorin
-            # cold start for the all-zeros inverses of a fresh state)
+            # cold start for the all-zeros inverses of a fresh state, whose
+            # scaled phase starts from what is left of the factors'
+            # identity initialisation)
+            floor = factors_lib.identity_floor(
+                state.step, self.factor_decay, self.factor_update_steps
+            )
             inv = lambda f, prev, dmp: factors_lib.damped_inverse(
                 f, dmp, self.inv_dtype, self.inverse_solver,
-                self.newton_schulz_iters, x0=prev,
+                self.newton_schulz_iters, x0=prev, floor=floor,
             )
             a_inv, g_inv = dict(state.a_inv), dict(state.g_inv)
             for name in state.a:

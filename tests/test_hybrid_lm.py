@@ -573,9 +573,11 @@ def test_grouped_solve_is_the_whole_solve(monkeypatch):
 
 def test_refresh_report_counts_trips_a_group():
     """A stack solved in two groups ran each group's loop to its own
-    slowest slot: 9 + 5 trips, not the bucket's 9."""
-    solved = np.zeros((6, 4), np.float32)
+    slowest slot: 9 + 5 trips, not the bucket's 9; and so with the
+    scaled steps among them."""
+    solved = np.zeros((6, len(kaisa.REFRESH_COLUMNS)), np.float32)
     solved[:, 0] = [9, 3, 4, 5, 2, 0]
+    solved[:, 4] = [6, 0, 0, 4, 0, 0]
     refresh = kaisa.RefreshState(
         (('a', '16x16', 4, 4), ('g', '16x16', 2, 2)), jnp.asarray(solved),
         (2, 1),
@@ -583,6 +585,8 @@ def test_refresh_report_counts_trips_a_group():
     by_bucket = kaisa._refresh_by_bucket(refresh)
     assert [b['trips'] for b in by_bucket] == [9 + 5, 2]
     assert kaisa.refresh_totals(refresh)['refresh/trips'] == 16.0
+    assert [b['scaled_trips'] for b in by_bucket] == [6 + 4, 0]
+    assert kaisa.refresh_totals(refresh)['refresh/scaled_trips'] == 10.0
     # a state from before the groups were recorded reads as one group each
     old = kaisa.RefreshState(refresh.buckets, refresh.solved)
     assert [b['trips'] for b in kaisa._refresh_by_bucket(old)] == [9, 2]

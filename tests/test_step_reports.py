@@ -122,15 +122,19 @@ def test_newton_schulz_info_says_warm_and_restarted():
     ema = _drifted_factors()
     cold = factors.newton_schulz_inverse_info(ema(3), 0.003)
     assert not bool(cold.warm) and not bool(cold.restarted)
+    # a cold start's first iterations are scaled ones
+    assert 0 < int(cold.scaled) < int(cold.iterations)
     near = factors.newton_schulz_inverse(ema(3), 0.004)
     kept = factors.newton_schulz_inverse_info(ema(3), 0.003, x0=near)
     assert bool(kept.warm) and not bool(kept.restarted)
+    assert int(kept.scaled) == 0  # a warm start that holds runs plain
     assert int(kept.iterations) < int(cold.iterations)
     # zeros fail the up-front test: never warm, so never restarted
     refused = factors.newton_schulz_inverse_info(
         ema(3), 0.003, x0=jnp.zeros_like(near)
     )
     assert not bool(refused.warm) and not bool(refused.restarted)
+    assert int(refused.scaled) == int(cold.scaled)
     old = factors.newton_schulz_inverse(ema(1), 0.003)
     for kwargs in ({}, {'differentiable': True}):
         poisoned = factors.newton_schulz_inverse_info(
@@ -138,6 +142,8 @@ def test_newton_schulz_info_says_warm_and_restarted():
         )
         assert bool(poisoned.warm) and bool(poisoned.restarted)
         assert int(poisoned.iterations) > int(cold.iterations)
+        # the restart re-enters the scaled phase from the cold bound
+        assert int(poisoned.scaled) == int(cold.scaled)
 
 
 def test_batched_auto_info_keeps_the_iterations_own_fields():
@@ -153,6 +159,8 @@ def test_batched_auto_info_keeps_the_iterations_own_fields():
     assert (np.asarray(info.iterations) > 0).all()
     assert not np.asarray(info.warm).any()
     assert not np.asarray(info.restarted).any()
+    assert info.scaled.shape == (2,)
+    assert (np.asarray(info.scaled) > 0).all()
 
 
 def _dense_engine(solver='newton_schulz', method='inverse', frac=1.0, dim=255):
@@ -209,11 +217,16 @@ def test_refresh_report_cold_then_warm_then_poisoned(solver):
         b['trips'] for side in cold['buckets'].values() for b in side.values()
     )
     assert cold['totals']['trips'] <= cold['totals']['iterations']
+    # the wide factor's cold solve took scaled steps; the others (a
+    # fresh state's identities) took none, being solved where they start
+    assert 0 < bucket['scaled_trips'] < bucket['trips']
+    assert cold['totals']['scaled_trips'] == bucket['scaled_trips']
 
     # the same factors again: every slot starts from its own inverse
     warm = engine.refresh_report(refresh(state))
     assert warm['totals']['warm_starts'] == warm['totals']['slots']
     assert warm['totals']['restarts'] == 0
+    assert warm['totals']['scaled_trips'] == 0
     assert warm['totals']['iterations'] < cold['totals']['iterations']
     assert warm['totals']['worst_residual'] < 1e-5
 
@@ -222,6 +235,10 @@ def test_refresh_report_cold_then_warm_then_poisoned(solver):
     poisoned = engine.refresh_report(state)
     assert poisoned['totals']['restarts'] == 1
     assert poisoned['buckets']['a'][key]['restarts'] == 1
+    # the restarted slot's second attempt is a cold one: scaled steps
+    assert poisoned['totals']['scaled_trips'] == (
+        poisoned['buckets']['a'][key]['scaled_trips']
+    ) > 0
     assert poisoned['totals']['warm_starts'] == poisoned['totals']['slots']
     assert (poisoned['buckets']['a'][key]['iterations'][slot]
             > cold['buckets']['a'][key]['iterations'][slot])
@@ -248,6 +265,7 @@ def test_refresh_report_on_a_sharded_mesh():
             # a fresh state's factors are the identity: solved in 0 trips,
             # and still a refresh that filled the counters
             assert bucket['trips'] == max(bucket['iterations']) == 0
+            assert bucket['scaled_trips'] == 0
 
 
 def test_refresh_field_is_one_ephemeral_leaf():
@@ -255,7 +273,10 @@ def test_refresh_field_is_one_ephemeral_leaf():
     state = engine.init()
     assert kaisa.DistKFACState._fields[-1] == 'refresh'
     stores = engine.a_store + engine.g_store
-    # the device holds the solve's four columns; the layout is static
+    # the device holds the solve's five columns; the layout is static
+    assert kaisa.REFRESH_COLUMNS == (
+        'iterations', 'residual', 'warm', 'restarted', 'scaled'
+    )
     assert state.refresh.solved.shape == (
         sum(sb.padded for sb in stores), len(kaisa.REFRESH_COLUMNS)
     )
@@ -349,8 +370,8 @@ def test_collector_folds_the_refresh_totals():
         f'refresh/{k}': v for k, v in totals.items()
     }
     assert set(totals) == {
-        'slots', 'iterations', 'trips', 'warm_starts', 'restarts',
-        'worst_residual',
+        'slots', 'iterations', 'trips', 'scaled_trips', 'warm_starts',
+        'restarts', 'worst_residual',
     }
 
 
