@@ -51,12 +51,18 @@ def load_json(*parts: str) -> Any:
 
 def load_cell(name: str) -> dict:
     """A cell by its name: its entry in ``BENCHMARK.json``, its workload
-    file and its configuration's file."""
+    file and its configuration's file. ``bench['end_to_end']`` holds the
+    end-to-end metrics this cell reports: one that lists ``workloads``
+    without the cell is left out (``per_layer`` stays whole; which of its
+    rows the cell reads is :func:`layer_rows`'s to say)."""
     with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
         bench = json.load(f)
     if not any(w['name'] == name for w in bench['workloads']):
         known = [w['name'] for w in bench['workloads']]
         raise SystemExit(f'unknown workload {name!r}; BENCHMARK.json has {known}')
+    bench['end_to_end'] = [
+        m for m in bench['end_to_end'] if name in m.get('workloads', (name,))
+    ]
     workload = load_json('workloads', f'{name}.json')
     listed = next(c for c in bench['configs'] if c['name'] == workload['config'])
     with open(os.path.join(ROOT, listed['file'])) as f:
@@ -82,7 +88,10 @@ class BuildCounter:
 
 @dataclasses.dataclass
 class Run:
-    """A job with its trainers and the state they step."""
+    """A job with its trainers and the state they step. Of ``state`` and
+    ``first_order_state`` one at most is on the device at a time, and the
+    plain reference is unloaded before either exists: a user holds the
+    K-FAC trainer's state alone, and ``footprint`` reads that job."""
 
     job: jobs.Job
     ring: list
@@ -97,7 +106,9 @@ class Run:
     inv_every: int
     fed: int = 0  # batches fed so far: the ring index
     fresh_variables: Any = None  # the seed's weights, made anew each call
-    footprint: int = 0  # most device memory seen after a step of the program
+    first_order_step_s: float = 0.0  # an untimed first-order step, fed
+    footprint: int = 0  # most device memory seen after a step of the window
+    memory_after_kfac: Any = None  # the first device's, its last K-FAC step done
     leaf_gaps: Any = None  # every leaf's norm gaps of the last check
 
     def sample_memory(self) -> None:
@@ -237,26 +248,24 @@ def build_run(cell: dict, devices) -> Run:
     )
 
 
-def check_first_steps(run: Run, seed: int, log) -> tuple[dict, float]:
-    """From the seed: the ring, the plain reference's first three steps,
-    then the program's, through the window's own call and feed, and the
-    comparison. The state that leaves here is the one the window steps.
-    Returns the verdict and the seconds the reference took."""
+def reference_steps(run: Run, seed: int, log) -> tuple[dict, float]:
+    """From the seed: the ring and the weights' maker, then the plain
+    reference's first three steps on the first three batches, before any
+    state of the program exists. The reference is unloaded when this
+    returns, so that no step of the program is sampled beside its programs
+    (the runtime's reserved scratch is that of the largest loaded program,
+    and has to be the job's own). Returns the reference's numbers and the
+    seconds it took."""
     job = run.job
-    replicated = NamedSharding(job.mesh, PartitionSpec())
+    make = weights.maker(
+        job.variable_shapes, NamedSharding(job.mesh, PartitionSpec())
+    )
     key = weights.seed_key(seed)
-
-    def fresh_variables():
-        return weights.make(job.variable_shapes, key, replicated)
-
-    run.fresh_variables = fresh_variables
+    run.fresh_variables = lambda: make(key)
     run.ring = job.make_ring(seed, run.ring_size)
-    run.fed = 0
 
-    # the reference first, before the program's state exists, so that the
-    # peak of device memory stays the program's
     began = time.perf_counter()
-    variables = fresh_variables()
+    variables = run.fresh_variables()
     ref = run.reference.first_steps(
         variables['params'],
         [run.put(b) for b in run.ring[:reference.STEPS]],
@@ -265,8 +274,23 @@ def check_first_steps(run: Run, seed: int, log) -> tuple[dict, float]:
     reference_s = time.perf_counter() - began
     log(f'reference: {reference.STEPS} steps in {reference_s:.1f}s, of '
         f'which {run.reference.seconds}')
+    # the leaves K-FAC preconditions: names only, no program
+    ref['kfac_layers'] = run.reference.ref.kfac_layers(
+        job.variable_shapes['params']
+    )
+    run.reference = None
+    ref_kfac.unload()
+    gc.collect()
+    return ref, reference_s
 
-    variables = fresh_variables()
+
+def check_first_steps(run: Run, ref: dict, log) -> dict:
+    """The program's first three steps from the seed's weights, through
+    the window's own call and feed, on the batches the reference took
+    (:func:`reference_steps` gave ``ref``), and the comparison. The state
+    that leaves here is the one the window steps. Returns the verdict."""
+    run.fed = 0
+    variables = run.fresh_variables()
     state = run.trainer.init(variables['params'], variables.get('batch_stats'))
     del variables
     run.trainer.resume(state)
@@ -280,7 +304,7 @@ def check_first_steps(run: Run, seed: int, log) -> tuple[dict, float]:
                 ref_kfac.leaf_norms(_momentum_trace(state.opt_state))
             )
     update = jax.device_get(ref_kfac.leaf_norms(jax.tree_util.tree_map(
-        jnp.subtract, state.params, fresh_variables()['params']
+        jnp.subtract, state.params, run.fresh_variables()['params']
     )))
     run.state = state
 
@@ -289,7 +313,7 @@ def check_first_steps(run: Run, seed: int, log) -> tuple[dict, float]:
     # embeddings) pass the preconditioner unchanged, and in bfloat16 the
     # first stages' BatchNorm gradients, sums of a million cancelling terms,
     # read 0.1 to 0.5 off the float32 reference in sound runs (PERF.md 2)
-    layers = run.reference.ref.kfac_layers(job.variable_shapes['params'])
+    layers = ref['kfac_layers']
     first_grad = {n: float(v) for n, v in first_grad.items()}
     update = {n: float(v) for n, v in update.items()}
 
@@ -323,39 +347,63 @@ def check_first_steps(run: Run, seed: int, log) -> tuple[dict, float]:
         name, gap = max(gaps.items(), key=lambda kv: kv[1])
         log(f'check: {what}, every leaf (not judged): worst {gap:.3g} at '
             f'{name}, median {statistics.median(gaps.values()):.3g}')
-    return {'ok': ok, 'numbers': numbers, 'rows': rows}, reference_s
+    return {'ok': ok, 'numbers': numbers, 'rows': rows}
 
 
-def set_up(cell: dict, seed: int, devices, log) -> tuple[Run, dict, float]:
-    """Build the job, check its first three steps against the plain
-    reference, warm the first-order baseline. Returns the run, the check's
-    verdict and the seconds the reference took (not part of set-up)."""
-    run = build_run(cell, devices)
-    verdict, reference_s = check_first_steps(run, seed, log)
-    # unload the reference's programs: the runtime's reserved scratch is
-    # that of the largest loaded program, and has to be the job's own
-    run.reference = None
-    gc.collect()
+def first_order_stretch(run: Run, steps: int) -> list:
+    """The first-order baseline from the seed's weights: its state made on
+    the device, ``reference.STEPS`` untimed steps (their first builds the
+    programs in set-up; momentum exists after them), then ``steps`` timed
+    ones, whose rows it returns. The state is gone when this returns. Only
+    while the K-FAC trainer's state is off the device."""
     variables = run.fresh_variables()
-    fo_state = run.first_order.init(
+    run.first_order_state = run.first_order.init(
         variables['params'], variables.get('batch_stats')
     )
     del variables
-    run.first_order_state, _ = timed_rows(
-        run, run.first_order, fo_state, reference.STEPS
+    run.first_order_state, warm = timed_rows(
+        run, run.first_order, run.first_order_state, reference.STEPS
     )
-    return run, verdict, reference_s
+    # what the window keeps free for each first-order step, feed included
+    # (in set-up the first of the three built the programs)
+    run.first_order_step_s = min(r['end'] - r['begin'] for r in warm)
+    run.first_order_state, rows = timed_rows(
+        run, run.first_order, run.first_order_state, steps
+    )
+    run.first_order_state = None
+    return rows
+
+
+def drop_kfac_state(run: Run) -> None:
+    """The K-FAC steps are done: note what the device holds with their
+    state on it, then free it for the first-order trainer's. A full
+    collection, as set-up ran one ahead of these steps while they came
+    first: what a cycle holds of that state goes before the next is made,
+    and no full collection (0.08 s of such a heap) falls due in 40 steps."""
+    run.memory_after_kfac = run.job.mesh.devices.flat[0].memory_stats()
+    run.state = None
+    gc.collect()
+
+
+def set_up(cell: dict, seed: int, devices, log) -> tuple[Run, dict, float]:
+    """Build the job; run and unload the plain reference; build and warm
+    the first-order baseline's programs and drop its state; then the
+    program's first three steps, checked against the reference's. Returns
+    the run, the check's verdict and the seconds the reference took (not
+    part of set-up)."""
+    run = build_run(cell, devices)
+    ref, reference_s = reference_steps(run, seed, log)
+    first_order_stretch(run, 0)
+    return run, check_first_steps(run, ref, log), reference_s
 
 
 def window(run: Run, seconds: float, first_order_steps: int):
-    """The measured window: the first-order steps, then whole periods of
-    K-FAC steps while another fits, never fewer than one."""
+    """The measured window: whole periods of K-FAC steps while another
+    fits beside the first-order stretch, never fewer than one; then, with
+    the K-FAC trainer's state off the device, the first-order steps."""
     began = time.perf_counter()
-    state, fo_rows = timed_rows(
-        run, run.first_order, run.first_order_state, first_order_steps
-    )
-    del state
-    run.first_order_state = None
+    run.footprint = 0  # set-up's samples go: see device_report
+    kept = first_order_steps * run.first_order_step_s
     periods = []
     next_step = reference.STEPS
     while True:
@@ -365,17 +413,22 @@ def window(run: Run, seconds: float, first_order_steps: int):
         next_step += run.inv_every
         periods.append(rows)
         elapsed = time.perf_counter() - began
-        if elapsed + schedule.wall(rows) > seconds:
+        if elapsed + kept + schedule.wall(rows) > seconds:
             break
-    return fo_rows, periods
+    drop_kfac_state(run)
+    return first_order_stretch(run, first_order_steps), periods
 
 
 def device_report(run: Run, devices) -> dict:
     """The device as JAX reports it. ``memory_peak_bytes``: the most the
-    fullest chip held after any step of the program (set-up's and the
-    window's), buffers and reserved program scratch together: see
-    :func:`footprint_bytes`. The plain reference's memory is not in it: it
-    is gone before the program's first step."""
+    fullest chip held after any step of the window, buffers and reserved
+    program scratch together: see :func:`footprint_bytes`. The plain
+    reference's memory is not in it (it is unloaded before the program's
+    first step), nor a second trainer's state (the K-FAC trainer's and the
+    first-order one's take turns), nor set-up's steps: between the checked
+    steps the check's own programs run and its own buffers live, and what
+    of them was still held at a sample followed the clock, not the seed
+    (Qwen: 240 MB there in seven runs of nine; my chip runs, PR 31)."""
     return {
         'platform': devices[0].platform,
         'kind': devices[0].device_kind,
@@ -415,10 +468,14 @@ def run_cell(
         rows, extra = fo_rows + kfac_rows, {}
         e2e = schedule.end_to_end(fo_rows, periods, run.job.global_batch)
         log(f'window: {len(fo_rows)} first-order steps, {len(periods)} '
-            f'periods of {run.inv_every}; by kind {schedule.by_kind(kfac_rows)}')
+            f'periods of {run.inv_every}; by kind {schedule.by_kind(kfac_rows)}'
+            f'; a first-order step '
+            f"{statistics.median(r['seconds'] for r in fo_rows)}"
+            f"; the longest K-FAC step {e2e['stall_ms']} ms")
     built_in_window = builds.count - built_before
     device = device_report(run, devices)
-    log(f'memory: {devices[0].memory_stats()}')
+    log(f'memory: {run.memory_after_kfac} after the K-FAC steps; '
+        f'{devices[0].memory_stats()} at the end')
     if not trace:
         e2e.update(
             peak_hbm_gb=device['memory_peak_bytes'] / 1e9, setup_s=setup_s
@@ -440,7 +497,25 @@ def run_cell(
         'metrics': metrics,
         'device': device,
         **extra,
+        'compared': compared(verdict['rows'], built_in_window, failed),
     }
+
+
+def compared(check_rows, built_in_window: int, failed: int) -> dict:
+    """Every number ``correct`` rests on beside its limit, for the result
+    line's last key and the run's last lines on standard error. A number
+    that is not finite goes as its name ('nan'), which strict JSON holds."""
+    out = {
+        r['number']: {
+            'value': r['value'] if math.isfinite(r['value'])
+            else repr(r['value']),
+            'limit': r['limit'],
+        }
+        for r in check_rows
+    }
+    out['programs_built_in_window'] = {'value': built_in_window, 'limit': 0}
+    out['non_finite_losses'] = {'value': failed, 'limit': 0}
+    return out
 
 
 # ------------------------------------------------------------- traced run
@@ -448,12 +523,16 @@ def run_cell(
 
 @dataclasses.dataclass
 class LayerContext:
-    """What a per-layer metric's reader may read."""
+    """What a per-layer metric's reader may read. Most readers read while
+    the K-FAC trainer's state (``run.state``) is on the device, before the
+    first-order stretch has run; one whose module says
+    ``AFTER_FIRST_ORDER = True`` reads after it, with ``first_order_rows``
+    there and ``run.state`` gone."""
 
     cell: dict
     run: Run
     devices: list
-    first_order_rows: list
+    first_order_rows: list | None
     rows: list            # untraced K-FAC rows (host clock)
     traced_rows: list     # the profiled stretch's rows
     trace: dict           # neutral trace (benchmark.trace_reduce)
@@ -479,15 +558,58 @@ def trace_scopes() -> tuple:
     return tuple(sorted(found))
 
 
+def layer_rows(cell: dict) -> list:
+    """The rows of ``per_layer`` that a traced run of ``cell`` reads: those
+    that list the cell under ``workloads``, and of those that list nothing
+    the ones whose ``moves`` names an end-to-end metric the cell reports.
+    A quantity whose cells report different end-to-end metrics has a row
+    for each (``refresh_extra_ms`` moves ``stall_ms``;
+    ``refresh_extra_ms.overhead`` moves ``kfac_overhead`` where no
+    ``stall_ms`` is reported). A row that does not list the cell finds
+    nothing to read there, and some take a pass over the trace to say so."""
+    name = cell['name']
+    reported = {m['name'] for m in cell['bench']['end_to_end']}
+    return [
+        m for m in cell['bench']['per_layer']
+        if name in m.get('workloads', (name,)) and m['moves'] in reported
+    ]
+
+
+def _row_file(name: str) -> str:
+    return os.path.join(HERE, 'layer_metrics', name + '.json')
+
+
+def _read_under(name: str) -> str:
+    """The metric whose reader reads ``name``: itself, or the one a row
+    ``layer_metrics/<name>.json`` of the form ``{"reads": "<metric>"}``
+    names (the same quantity under another name, for cells that report
+    another end-to-end metric)."""
+    while os.path.exists(_row_file(name)):
+        other = load_json('layer_metrics', name + '.json').get('reads')
+        if other is None:
+            break
+        name = other
+    return name
+
+
+def layer_reader(name: str):
+    """A per-layer metric's reader by the metric's name: the module
+    ``layer_metrics/<name>.py``, or ``None`` where the metric is a row
+    ``layer_metrics/<name>.json`` of scopes."""
+    name = _read_under(name)
+    if os.path.exists(_row_file(name)):
+        return None
+    return importlib.import_module(f'benchmark.layer_metrics.{name}')
+
+
 def read_layer_metric(name: str, ctx: LayerContext):
-    """A per-layer metric by its name: ``layer_metrics/<name>.py``'s
-    ``read(ctx)``, or a row ``layer_metrics/<name>.json`` for device
-    milliseconds per step of a kind on the worst device, under the row's
-    ``scopes`` or in its ``ops`` (kernels by name). ``None`` where there
-    is nothing to read."""
-    path = os.path.join(HERE, 'layer_metrics', name)
-    if not os.path.exists(path + '.json'):
-        module = importlib.import_module(f'benchmark.layer_metrics.{name}')
+    """A per-layer metric by its name: its module's ``read(ctx)``, or a
+    row ``layer_metrics/<name>.json`` for device milliseconds per step of
+    a kind on the worst device under the row's ``scopes``. ``None`` where
+    there is nothing to read."""
+    name = _read_under(name)
+    module = layer_reader(name)
+    if module is not None:
         return module.read(ctx)
     row = load_json('layer_metrics', name + '.json')
     steps = ctx.count(row.get('per'))
@@ -496,13 +618,10 @@ def read_layer_metric(name: str, ctx: LayerContext):
     scopes = trace_scopes()
     worst = 0.0
     for plane in trace_reduce.device_planes(ctx.trace):
-        window = ctx.windows[plane['name']]
-        if 'ops' in row:
-            got = trace_reduce.named_ops_ns(plane, window, row['ops'])
-        else:
-            under = trace_reduce.scope_ns(plane, window, scopes)
-            got = sum(under.get(s, 0.0) for s in row['scopes'])
-        worst = max(worst, got)
+        under = trace_reduce.scope_ns(
+            plane, ctx.windows[plane['name']], scopes
+        )
+        worst = max(worst, sum(under.get(s, 0.0) for s in row['scopes']))
     return worst / 1e6 / steps if worst else None
 
 
@@ -522,17 +641,14 @@ def _program_op_names(trainer) -> dict:
 
 
 def traced_run(cell, run: Run, devices, log):
-    """The ``--trace 1`` run: the first-order steps and at least one whole
-    period untraced, for the numbers read off the host clock (tracing
-    slows the host); then a profiled stretch that holds a capture step, a
-    refresh step and the plain steps between them."""
+    """The ``--trace 1`` run: at least one whole period untraced, for the
+    numbers read off the host clock (tracing slows the host); a profiled
+    stretch that holds a capture step, a refresh step and the plain steps
+    between them; the readers of what the K-FAC steps left; then, with
+    that state off the device, the first-order steps (untraced) and the
+    readers that compare with them."""
     workload = cell['workload']
-    state, fo_rows = timed_rows(
-        run, run.first_order, run.first_order_state,
-        workload['first_order_steps'],
-    )
-    del state
-    run.first_order_state = None
+    run.footprint = 0  # set-up's samples go: see device_report
     first, last = schedule.traced_stretch(
         reference.STEPS + run.inv_every, run.factor_every, run.inv_every
     )
@@ -540,10 +656,6 @@ def traced_run(cell, run: Run, devices, log):
         run, run.trainer, run.state, first - reference.STEPS,
         kfac_step=reference.STEPS,
     )
-    periods = schedule.whole_periods(rows, run.inv_every)
-    throughput = schedule.end_to_end(
-        fo_rows, periods, run.job.global_batch
-    )['throughput']
 
     logdir = tempfile.mkdtemp(prefix='kfac_bench_trace_')
     try:
@@ -580,15 +692,27 @@ def traced_run(cell, run: Run, devices, log):
             max(e['start_ns'] + e['duration_ns'] for e in events),
         )
     ctx = LayerContext(
-        cell=cell, run=run, devices=list(devices),
-        first_order_rows=fo_rows, rows=rows, traced_rows=traced, trace=trace,
-        windows=windows, throughput=throughput,
+        cell=cell, run=run, devices=list(devices), first_order_rows=None,
+        rows=rows, traced_rows=traced, trace=trace, windows=windows,
+        throughput=schedule.throughput(
+            schedule.whole_periods(rows, run.inv_every), run.job.global_batch
+        ),
     )
-    metrics = {}
-    for m in cell['bench']['per_layer']:
-        value = read_layer_metric(m['name'], ctx)
-        if value is not None:
-            metrics[m['name']] = {'value': float(value), 'unit': m['unit']}
+    listed = layer_rows(cell)
+    names = [m['name'] for m in listed]
+    late = {
+        n for n in names
+        if getattr(layer_reader(n), 'AFTER_FIRST_ORDER', False)
+    }
+    values = {n: read_layer_metric(n, ctx) for n in names if n not in late}
+    drop_kfac_state(run)
+    fo_rows = first_order_stretch(run, workload['first_order_steps'])
+    ctx = dataclasses.replace(ctx, first_order_rows=fo_rows)
+    values.update((n, read_layer_metric(n, ctx)) for n in late)
+    metrics = {
+        m['name']: {'value': float(values[m['name']]), 'unit': m['unit']}
+        for m in listed if values[m['name']] is not None
+    }
 
     busy = [
         trace_reduce.busy_ns(p, windows[p['name']]) / 1e9 for p in planes

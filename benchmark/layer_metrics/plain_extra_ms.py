@@ -5,6 +5,10 @@ import statistics
 
 from benchmark import schedule
 
+# compares with the first-order stretch, which runs once the K-FAC
+# trainer's state has left the device
+AFTER_FIRST_ORDER = True
+
 
 def read(ctx):
     kinds = schedule.by_kind(ctx.rows)

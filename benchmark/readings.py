@@ -27,12 +27,19 @@ def log(msg: str) -> None:
     print(f'[readings +{time.perf_counter() - BEGAN:6.1f}s] {msg}', flush=True)
 
 
-def read(run, seeds, label):
-    from benchmark import harness
+def read(cell, devices, seeds, label):
+    from benchmark import harness, reference
 
+    run = harness.build_run(cell, devices)
+    config, workload = cell['config'], cell['workload']
     rows = []
     for seed in seeds:
-        verdict, ref_s = harness.check_first_steps(run, seed, lambda m: None)
+        # a run unloads its reference before the program's first step
+        run.reference = run.reference or reference.Reference(
+            config['kind'], config, workload
+        )
+        ref, ref_s = harness.reference_steps(run, seed, lambda m: None)
+        verdict = harness.check_first_steps(run, ref, lambda m: None)
         run.state = None
         rows.append({'seed': seed, **verdict['numbers']})
         log(f'{label} ' + json.dumps(rows[-1]) + f' (reference {ref_s:.1f}s)')
@@ -69,7 +76,7 @@ def main() -> None:
         args.seeds, args.control_seeds
     ))]
     sound = read(
-        harness.build_run(cell, devices), seeds[:args.seeds], 'sound'
+        cell, devices, seeds[:args.seeds], 'sound'
     ) if args.seeds else []
 
     precision = {
@@ -81,8 +88,7 @@ def main() -> None:
     # first trace, at the sound precision, would serve the control too
     jax.clear_caches()
     control = read(
-        harness.build_run(cell, devices), seeds[:args.control_seeds],
-        f'control[{args.control}]',
+        cell, devices, seeds[:args.control_seeds], f'control[{args.control}]'
     ) if args.control_seeds else []
 
     names = list(cell['workload']['limits'])

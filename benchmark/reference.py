@@ -27,8 +27,9 @@ def _flat_layers(factors: dict, layers) -> dict:
 
 
 class Reference:
-    """The plain reference of one cell; its programs are traced once and
-    serve every seed a process reads."""
+    """The plain reference of one cell. Its programs are built at their
+    first call; a run drops it, and they unload, before the program's
+    first step (``harness.reference_steps``)."""
 
     def __init__(self, kind: str, config: dict, workload: dict) -> None:
         self.ref = importlib.import_module(f'benchmark.refs.{kind}')

@@ -244,3 +244,13 @@ def leaf_norms(tree) -> dict:
             jnp.sqrt(jnp.sum(jnp.square(leaf.astype(jnp.float32))))
         for path, leaf in flat
     }
+
+
+def unload() -> None:
+    """Drop the compiled update programs of this module, which a run's
+    reference leaves loaded (they are jitted at module level): the
+    harness samples device memory only with no program of the reference
+    on the device. ``leaf_norms`` stays: the check takes the program's
+    norms with it."""
+    precondition.clear_cache()
+    sgd_step.clear_cache()

@@ -5,7 +5,8 @@
 Fails, with no result line, unless JAX finds a TPU with the chips the cell
 asks for: there is no CPU path (the tests import the arithmetic instead).
 The last line of standard output is the result object the benchmark's
-contract fixes; the lines above it say what was compared with what.
+contract fixes; the lines above it say what was compared with what, and so
+do the object's last key (``compared``) and the last lines of standard error.
 """
 
 from __future__ import annotations
@@ -61,6 +62,9 @@ def main(argv=None) -> None:
     result = harness.run_cell(
         cell, args.seed, args.seconds, bool(args.trace), devices, BEGAN, log
     )
+    for name, c in result['compared'].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
 
 

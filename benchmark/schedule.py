@@ -36,6 +36,15 @@ def wall(rows: list) -> float:
     return rows[-1]['end'] - rows[0]['begin']
 
 
+def throughput(periods: list, batch: int) -> float:
+    """Samples a second over all the steps and all the wall-clock of whole
+    K-FAC periods."""
+    if not periods:
+        raise ValueError('a window needs one whole period')
+    steps = sum(len(p) for p in periods)
+    return batch * steps / sum(wall(p) for p in periods)
+
+
 def end_to_end(first_order: list, periods: list, batch: int) -> dict:
     """The window's numbers. ``first_order``: the first-order stretch's
     rows; ``periods``: whole K-FAC periods; a row has ``begin`` (its feed
@@ -51,7 +60,7 @@ def end_to_end(first_order: list, periods: list, batch: int) -> dict:
     period_wall = sum(wall(p) for p in periods)
     first_order_step = wall(first_order) / len(first_order)
     return {
-        'throughput': batch * steps / period_wall,
+        'throughput': throughput(periods, batch),
         'kfac_overhead': (period_wall / steps) / first_order_step,
         'stall_ms': 1e3 * max(r['seconds'] for p in periods for r in p),
     }

@@ -55,7 +55,9 @@ def sound():
 
 def test_result_line_has_the_contract_keys(sound):
     result, lines = sound
-    assert list(result) == ['correct', 'attempted', 'failed', 'metrics', 'device']
+    assert list(result) == [
+        'correct', 'attempted', 'failed', 'metrics', 'device', 'compared'
+    ]
     assert result['correct'] is True
     assert result['failed'] == 0 and result['attempted'] > 0
     bench = harness.load_cell(CELL)['bench']
@@ -75,6 +77,18 @@ def test_result_line_has_the_contract_keys(sound):
                 if l.startswith('check: {')]
     assert [c['number'] for c in compared] == list(_cell()['workload']['limits'])
     assert all(c['ok'] and c['value'] <= c['limit'] for c in compared)
+    # and once more under the result line's last key, the window's two
+    # counts after the check's numbers
+    assert list(result['compared']) == [c['number'] for c in compared] + [
+        'programs_built_in_window', 'non_finite_losses'
+    ]
+    for c in compared:
+        assert result['compared'][c['number']] == {
+            'value': c['value'], 'limit': c['limit']
+        }
+    assert result['compared']['programs_built_in_window'] == {
+        'value': 0, 'limit': 0
+    }
 
 
 def test_programs_built_are_counted():
@@ -108,6 +122,8 @@ def test_a_step_that_leaves_the_state_unchanged_is_not_correct(monkeypatch):
     failed = [json.loads(l[len('check: '):]) for l in lines
               if l.startswith('check: {') and '"ok": false' in l]
     assert {f['number'] for f in failed} >= {'update_norm_gap'}
+    gap = result['compared']['update_norm_gap']
+    assert gap['value'] > gap['limit']
 
 
 def test_a_part_of_the_batch_left_out_is_not_correct(monkeypatch):

@@ -2,6 +2,8 @@
 refresh state, and nothing where the program's report has no such total
 (the parent commit's has ``trips`` and not ``scaled_trips``)."""
 
+import json
+import os
 import types
 
 import numpy as np
@@ -77,12 +79,26 @@ def test_reader_returns_none_where_the_program_reports_nothing(report):
         assert harness.read_layer_metric('ns_trips_refresh', _ctx(report)) == 33.0
 
 
-def test_benchmark_json_lists_it_last_in_every_cell():
-    bench = harness.load_cell('gpt2-small.kfac-10-100')['bench']
-    row = bench['per_layer'][-1]
-    assert row == {
+def test_benchmark_json_lists_it_in_every_cell():
+    """By name, wherever the row stands. It moves ``stall_ms`` in the cells
+    that report ``stall_ms`` and ``kfac_overhead`` (as
+    ``ns_scaled_trips_refresh.overhead``, the same reader) in the others."""
+    with open(os.path.join(harness.ROOT, 'BENCHMARK.json')) as f:
+        bench = json.load(f)
+    rows = {m['name']: m for m in bench['per_layer']}
+    (stall,) = [m for m in bench['end_to_end'] if m['name'] == 'stall_ms']
+    cells = [w['name'] for w in bench['workloads']]
+    steady = stall.get('workloads', cells)
+    assert rows[NAME] == {
         'name': NAME, 'unit': 'count', 'better': 'lower',
         'source': 'program_counter', 'layer': 'factor math',
-        'moves': 'stall_ms',
-        'workloads': [w['name'] for w in bench['workloads']],
+        'moves': 'stall_ms', 'workloads': steady,
     }
+    others = [c for c in cells if c not in steady]
+    if others:
+        assert rows[NAME + '.overhead'] == {
+            **rows[NAME], 'name': NAME + '.overhead',
+            'moves': 'kfac_overhead', 'workloads': others,
+        }
+        ctx = _ctx(_report)
+        assert harness.read_layer_metric(NAME + '.overhead', ctx) == 12.0

@@ -50,11 +50,14 @@ def _leaf(name: str, shape, key) -> jax.Array:
     return 0.02 * normal
 
 
-def make(shapes, key, sharding=None):
-    """Values for a tree of ``ShapeDtypeStruct`` leaves, from ``key``.
+def maker(shapes, sharding=None):
+    """The jitted function that makes values for a tree of
+    ``ShapeDtypeStruct`` leaves from a key. Kept and called again it
+    builds no second program: the harness makes a run's weights anew
+    inside the measured window, where none may be built.
 
-    One jitted call; ``sharding`` (one for every leaf, e.g. replicated on
-    the mesh) places the result where the trainer's state lives."""
+    ``sharding`` (one for every leaf, e.g. replicated on the mesh) places
+    the result where the trainer's state lives."""
     paths = jax.tree_util.tree_flatten_with_path(shapes)[0]
     treedef = jax.tree_util.tree_structure(shapes)
 
@@ -65,4 +68,10 @@ def make(shapes, key, sharding=None):
             leaves.append(_leaf(name, leaf.shape, jax.random.fold_in(key, i)))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
-    return jax.jit(build, out_shardings=sharding)(key)
+    return jax.jit(build, out_shardings=sharding)
+
+
+def make(shapes, key, sharding=None):
+    """Values for a tree of ``ShapeDtypeStruct`` leaves, from ``key``, in
+    one jitted call: :func:`maker`'s function, called once."""
+    return maker(shapes, sharding)(key)
