@@ -25,7 +25,13 @@ sys.path.insert(0, '.')
 import kfac_tpu
 from examples import common, data
 from kfac_tpu import training
-from kfac_tpu.models import HybridLM, TransformerLM, hybrid_lm_loss, lm_loss
+from kfac_tpu.models import (
+    ConvMoELM,
+    HybridLM,
+    TransformerLM,
+    hybrid_lm_loss,
+    lm_loss,
+)
 from kfac_tpu.parallel import tensor_parallel, token_sharding, train_mesh
 from kfac_tpu.parallel.mesh import SEQ_AXIS
 
@@ -81,21 +87,28 @@ def main(argv=None, on_step=None) -> float:
     p.add_argument('--seq-len', type=int, default=256)
     p.add_argument('--vocab-size', type=int, default=8192)
     p.add_argument(
-        '--model', choices=['transformer', 'hybrid'], default='transformer',
+        '--model', choices=['transformer', 'hybrid', 'conv-moe'],
+        default='transformer',
         help="'hybrid': the sparse hybrid decoder (models.HybridLM: Gated "
         'DeltaNet layers with a gated-attention layer every fourth, top-k '
         'routed experts with a shared expert), sized from --d-model: heads '
-        'of d/8 (attention) and d/16 (DeltaNet), experts d/4 wide',
+        'of d/8 (attention) and d/16 (DeltaNet), experts d/4 wide. '
+        "'conv-moe': the conv-hybrid sparse decoder (models.ConvMoELM: "
+        'gated short convolutions with a grouped-query attention layer '
+        'every fourth, one leading dense MLP 4 d wide, then sigmoid-routed '
+        'experts d/2 wide with a selection bias); name the dense MLP in '
+        "--kfac-skip-layers ('block0/mlp/.*') to leave it to the "
+        'first-order update',
     )
     p.add_argument(
         '--num-experts', type=int, default=16,
-        help='hybrid: experts the router scores',
+        help='hybrid, conv-moe: experts the router scores',
     )
     p.add_argument('--experts-per-token', type=int, default=2)
     p.add_argument(
         '--experts-held', type=int, nargs=2, default=None,
         metavar=('FIRST', 'COUNT'),
-        help='hybrid: the share of every layer\'s experts that lives in '
+        help='hybrid, conv-moe: the share of every layer\'s experts that lives in '
         'this process (an expert-parallel rank\'s); the router still scores '
         'all of them, and what the absent ones would add is left out. '
         'Default: all',
@@ -140,9 +153,26 @@ def main(argv=None, on_step=None) -> float:
     )
     tokens_np, vocab = data.lm_corpus(args.data_dir, args.vocab_size)
     dtype = jnp.bfloat16 if args.bf16 else jnp.float32
-    if args.model == 'hybrid':
-        if args.model_shards > 1 or args.seq_shards > 1:
-            raise SystemExit('--model hybrid runs data-parallel only')
+    sparse = args.model in ('hybrid', 'conv-moe')
+    if sparse and (args.model_shards > 1 or args.seq_shards > 1):
+        raise SystemExit(f'--model {args.model} runs data-parallel only')
+    experts_held = tuple(args.experts_held) if args.experts_held else None
+    if args.model == 'conv-moe':
+        d = args.d_model
+        model = ConvMoELM(
+            vocab_size=vocab, d_model=d,
+            layer_types=tuple(
+                'full_attention' if i % 4 == 1 else 'conv'
+                for i in range(args.num_layers)
+            ),
+            num_dense_layers=1, dense_width=4 * d, num_heads=args.num_heads,
+            num_kv_heads=max(1, args.num_heads // 4),
+            head_dim=d // args.num_heads,
+            num_experts=args.num_experts, top_k=args.experts_per_token,
+            expert_width=d // 2, experts_held=experts_held,
+            attention_chunk=min(1024, args.seq_len), dtype=dtype,
+        )
+    elif args.model == 'hybrid':
         d = args.d_model
         model = HybridLM(
             vocab_size=vocab, d_model=d, num_layers=args.num_layers,
@@ -155,9 +185,7 @@ def main(argv=None, on_step=None) -> float:
             linear_value_head_dim=d // args.num_heads,
             num_experts=args.num_experts, top_k=args.experts_per_token,
             expert_width=d // 4, shared_expert_width=d // 4,
-            experts_held=(
-                tuple(args.experts_held) if args.experts_held else None
-            ),
+            experts_held=experts_held,
             attention_chunk=min(1024, args.seq_len), dtype=dtype,
         )
     else:
@@ -180,7 +208,7 @@ def main(argv=None, on_step=None) -> float:
     )
     print(f'registered {len(registry)} K-FAC layers; mesh {dict(mesh.shape)}')
 
-    loss = (hybrid_lm_loss if args.model == 'hybrid' else lm_loss)(model)
+    loss = (hybrid_lm_loss if sparse else lm_loss)(model)
 
     def loss_fn(params, model_state, batch):
         return loss(params, batch), model_state

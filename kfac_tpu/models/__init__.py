@@ -1,5 +1,9 @@
-"""Model families: MLP, CIFAR/ImageNet ResNets, Transformer LM, MoE, and
-the sparse hybrid LM (Gated DeltaNet + gated attention + top-k experts)."""
+"""Model families: MLP, CIFAR/ImageNet ResNets, Transformer LM, MoE, the
+sparse hybrid LM (Gated DeltaNet + gated attention + top-k experts) and the
+conv-hybrid sparse LM (gated short convolutions + grouped-query attention
++ sigmoid-routed experts behind leading dense layers)."""
+
+from kfac_tpu.models.conv_moe import ConvMoELM
 
 from kfac_tpu.models.lora import LoRADense
 from kfac_tpu.models.mlp import MLP
@@ -30,6 +34,7 @@ __all__ = [
     'MLP',
     'MoEMLP',
     'CifarResNet',
+    'ConvMoELM',
     'GatedDeltaNet',
     'HybridLM',
     'ImageNetResNet',

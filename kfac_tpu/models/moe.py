@@ -383,10 +383,19 @@ class SparseMoE(nn.Module):
     """Top-k routed gated-MLP experts, a share of them held here.
 
     ``y = sum_{e in top-k, e held} w_e expert_e(x) + sigmoid(x w_s)
-    shared(x)``. Router logits and softmax in float32 over all
+    shared(x)``. Router logits and scores in float32 over all
     ``num_experts``; the top-k weights renormalised (``norm_topk_prob``).
     ``experts_held = (first, count)``; ``None`` holds every expert.
     ``shared_width`` 0 leaves the shared expert out.
+
+    ``scoring``: ``'softmax'`` scores the experts by the softmax of the
+    logits and takes the top-k of the scores; ``'sigmoid'`` by each
+    logit's sigmoid (the scores do not sum to one). ``selection_bias``
+    adds ``expert_bias`` (one value an expert; no K-FAC layer's) to the
+    scores *in the selection only*: the chosen experts' weights are their
+    unbiased scores and no gradient reaches the bias (an aux-loss-free
+    balancer would set it from the load; nothing here moves it).
+    ``renorm_eps`` is added to the sum the top-k weights are divided by.
     """
 
     num_experts: int
@@ -397,6 +406,31 @@ class SparseMoE(nn.Module):
     norm_topk_prob: bool = True
     block_rows: int = 256
     dtype: Any = jnp.float32
+    scoring: str = 'softmax'
+    selection_bias: bool = False
+    renorm_eps: float = 0.0
+
+    def _route(self, logits: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """The top-k experts of every token and their weights, before
+        renormalisation."""
+        if self.scoring == 'softmax':
+            scores = jax.nn.softmax(logits, axis=-1)
+        elif self.scoring == 'sigmoid':
+            scores = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f'scoring {self.scoring!r}')
+        if not self.selection_bias:
+            return jax.lax.top_k(scores, self.top_k)
+        bias = self.param(
+            'expert_bias', nn.initializers.zeros, (self.num_experts,)
+        )
+        _, idx = jax.lax.top_k(
+            jax.lax.stop_gradient(scores + bias), self.top_k
+        )
+        # the chosen scores by a one-hot sum: its backward pass is a
+        # product too, not a scatter of tokens * k scalars
+        chosen = jax.nn.one_hot(idx, self.num_experts, dtype=scores.dtype)
+        return jnp.sum(scores[:, None, :] * chosen, axis=-1), idx
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -410,10 +444,12 @@ class SparseMoE(nn.Module):
                 self.num_experts, use_bias=False, dtype=jnp.float32,
                 precision=jax.lax.Precision.HIGHEST, name='router',
             )(xf.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)
-            wts, idx = jax.lax.top_k(probs, self.top_k)
+            wts, idx = self._route(logits)
             if self.norm_topk_prob:
-                wts = wts / jnp.sum(wts, axis=-1, keepdims=True)
+                total = jnp.sum(wts, axis=-1, keepdims=True)
+                if self.renorm_eps:
+                    total = total + self.renorm_eps
+                wts = wts / total
             plan = make_plan(idx, wts, first, held, self.block_rows)
         with tracing.model_scope('moe_experts'):
             y = Experts(held, self.width, dtype=self.dtype, name='experts')(

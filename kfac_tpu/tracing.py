@@ -63,14 +63,17 @@ CAPTURE_SCOPES = {
     'experts': 'experts',
 }
 
-# Device scopes of the model parts that a sparse hybrid LM adds
-# (kfac_tpu/models/deltanet.py, models/moe.py): the chunked delta-rule scan
-# (forward, and its backward pass under ``transpose(jvp(...))``), the
-# router with its top-k and row plan, and the grouped expert products.
+# Device scopes of the model parts that the sparse hybrid LMs add
+# (kfac_tpu/models/deltanet.py, models/moe.py, models/conv_moe.py): the
+# chunked delta-rule scan (forward, and its backward pass under
+# ``transpose(jvp(...))``), the router with its top-k and row plan, the
+# grouped expert products, and the gated short convolution between its
+# projections (``B * x~``, the depthwise taps, ``C * conv``).
 MODEL_SCOPES = {
     'gdn_scan': 'model.gdn_scan',
     'moe_route': 'model.moe_route',
     'moe_experts': 'model.moe_experts',
+    'short_conv': 'model.short_conv',
 }
 
 # Host spans inside one Trainer step, in order: what runs before the jitted
