@@ -176,6 +176,8 @@ def _specs(profile: str, world: int) -> list[_ConfigSpec]:
         _ConfigSpec('kaisa-eigen-bucketed-int8-f0.5', 'kaisa', 16, 0.5,
                     {**bucketed, 'stat_compression': 'int8'}),
         _ConfigSpec('kaisa-ns-dense-f0.125', 'kaisa', 16, 0.125, ns),
+        # COMM-OPT with explicit inverses: precondition builds no stack
+        _ConfigSpec('kaisa-ns-dense-f1.0', 'kaisa', 16, 1.0, ns),
         _ConfigSpec('kaisa-eigen-prediv-f0.5', 'kaisa', 16, 0.5,
                     dict(prediv_eigenvalues=True)),
         _ConfigSpec('dense-eigh-host', 'dense', 16, None,

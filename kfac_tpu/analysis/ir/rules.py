@@ -179,6 +179,21 @@ def _decomp_in_jit(cfg) -> bool:
     return getattr(cfg, 'eigh_impl', 'xla') not in ('host', 'eig_host')
 
 
+def _stack_pins(strategy: str, cfg) -> int:
+    """How often ``precondition`` pins a gradient stack to replicated.
+    Sharded by column: once, the KAISA gradient broadcast. COMM_OPT keeps
+    the decompositions replicated (spec == P()): with explicit inverses
+    each layer multiplies against its resident slots and no stack exists;
+    the eigen methods still stack, and the gstack pin duplicates the
+    broadcast pin byte-for-byte — a counting artifact, priced once by the
+    model."""
+    import kfac_tpu
+
+    if strategy != 'COMM_OPT':
+        return 1
+    return 2 if cfg.compute_method == kfac_tpu.ComputeMethod.EIGEN else 0
+
+
 def check_cost_model_parity(suite: harness.Suite) -> list[core.Finding]:
     """Bytes/FLOPs counted from the lowered IR must equal the autotuner
     model's predictions (``StaticLayout``/``comms_report``)."""
@@ -202,11 +217,8 @@ def check_cost_model_parity(suite: harness.Suite) -> list[core.Finding]:
             what = 'decomposition reshard bytes'
         elif t.entry == 'precondition':
             got = visitor.rank3_replicated_pin_bytes(pins)
-            # COMM_OPT keeps the eigenbasis replicated (spec == P()), so
-            # the gstack pin duplicates the broadcast pin byte-for-byte —
-            # a counting artifact, priced once by the model
-            want = t.comms['grad_broadcast_bytes'] * (
-                2 if strategy == 'COMM_OPT' else 1
+            want = t.comms['grad_broadcast_bytes'] * _stack_pins(
+                strategy, t.cfg
             )
             what = 'grad-broadcast bytes'
         else:

@@ -17,6 +17,9 @@ import jax.numpy as jnp
 
 from kfac_tpu.ops import cov
 
+#: the one key of a gradient view in matrix form (:func:`matrix_view`)
+MATRIX = 'matrix'
+
 
 @dataclasses.dataclass(frozen=True)
 class LayerHelper:
@@ -101,6 +104,54 @@ class LayerHelper:
         """Unpack a preconditioned matrix back into flax param layout."""
         raise NotImplementedError
 
+    # ---- the gradient as the explicit-inverse product reads it ----------
+    #
+    # A *view* is a dict of arrays over which the engines' per-layer
+    # reductions (norms, the kl-clip ``sum(P * G)``) are plain sums of
+    # elementwise products, so they do not care about its layout. The
+    # matrix form (:func:`matrix_view`) serves every helper; a helper whose
+    # leaves can be multiplied as they lie (``in_layout``) hands those out
+    # instead and saves the packing and the unpacking.
+
+    #: whether :meth:`grad_view` is in the parameters' own layout
+    in_layout = False
+
+    def grad_view(self, grads: dict[str, jax.Array]) -> dict[str, jax.Array]:
+        """This layer's gradient as :meth:`inverse_precondition` reads it:
+        the packed matrix, unless the helper can multiply its leaves as
+        they lie."""
+        return matrix_view(self, grads)
+
+    def inverse_precondition(
+        self,
+        view: dict[str, jax.Array],
+        a_inv: jax.Array,
+        g_inv: jax.Array,
+    ) -> dict[str, jax.Array]:
+        """A view of the preconditioned gradient from :meth:`grad_view`
+        and the layer's two explicit (symmetric) inverses at their true
+        dims: ``(G^-1 M) A^-1`` on the matrix form, in the inverses' dtype
+        (reference: kfac/layers/inverse.py:215-234)."""
+        mat = view[MATRIX].astype(a_inv.dtype)
+        return {MATRIX: (g_inv @ mat) @ a_inv}
+
+
+def matrix_view(
+    helper: LayerHelper, grads: dict[str, jax.Array]
+) -> dict[str, jax.Array]:
+    """A layer's gradient as a view in matrix form."""
+    return {MATRIX: helper.grads_to_matrix(grads)}
+
+
+def view_to_grads(
+    helper: LayerHelper, view: dict[str, jax.Array]
+) -> dict[str, jax.Array]:
+    """A view back in flax param layout: a matrix unpacked, leaves that
+    were viewed as they lie handed back."""
+    if MATRIX in view:
+        return helper.matrix_to_grads(view[MATRIX])
+    return dict(view)
+
 
 @dataclasses.dataclass(frozen=True)
 class DenseHelper(LayerHelper):
@@ -173,6 +224,44 @@ class DenseHelper(LayerHelper):
         if self.has_bias:
             return {'kernel': mat[:, :-1].T, 'bias': mat[:, -1]}
         return {'kernel': mat.T}
+
+    in_layout = True
+
+    def grad_view(self, grads: dict[str, jax.Array]) -> dict[str, jax.Array]:
+        return {
+            k: grads[k] for k in ('kernel', 'bias')[: 1 + self.has_bias]
+        }
+
+    def inverse_precondition(
+        self,
+        view: dict[str, jax.Array],
+        a_inv: jax.Array,
+        g_inv: jax.Array,
+    ) -> dict[str, jax.Array]:
+        """On the leaves as they lie: with symmetric inverses
+        ``((G^-1 K^T) A^-1)^T = A^-1 (K G^-1)`` for the ``(d_in, d_out)``
+        kernel gradient ``K``, the matrix form's two products in the same
+        association, with no transposed copy on the way in or out.
+
+        A bias is the last row of ``[K; b^T]``; it enters as the exact
+        rank-one terms of the bordered ``A^-1 = [[A11, a], [a^T, alpha]]``
+        rather than by joining that row to ``K`` (a copy of ``K``):
+        ``P_K = A11 (K G^-1) + a (b^T G^-1)`` and
+        ``P_b = a^T (K G^-1) + alpha (b^T G^-1)``. ``a`` is read as the
+        border's row both times: sliced as a column out of a stack of
+        inverses it made the TPU compiler re-lay the whole stack
+        batch-minor on every step (offline compile, PR 34).
+        """
+        kg = view['kernel'].astype(a_inv.dtype) @ g_inv
+        if not self.has_bias:
+            return {'kernel': a_inv @ kg}
+        d = self.in_features
+        bg = view['bias'].astype(a_inv.dtype) @ g_inv
+        border = a_inv[d, :d]
+        return {
+            'kernel': a_inv[:d, :d] @ kg + jnp.outer(border, bg),
+            'bias': border @ kg + a_inv[d, d] * bg,
+        }
 
 
 @dataclasses.dataclass(frozen=True)

@@ -16,9 +16,15 @@ same strategy space is expressed as *data layout*:
 - Decompositions are then resharded to the strategy's resident layout:
   replicated for COMM-OPT (the "inverse broadcast"), sharded over the column
   axis for HYBRID/MEM-OPT. Preconditioned gradients are computed under that
-  layout and resharded to replicated (the "gradient broadcast"). XLA inserts
-  exactly the all-gathers KAISA prescribes; grad_worker_fraction is the mesh
-  aspect ratio (kfac_tpu/assignment.py:mesh_shape).
+  layout: sharded by column, gradient stacks laid out like the
+  decompositions are multiplied batched and resharded to replicated (the
+  "gradient broadcast"); replicated, every device holds every inverse, so
+  with explicit inverses each layer multiplies its own gradient against its
+  two inverse slots, a Dense kernel as it lies (same-shaped neighbours of a
+  bucket joined as they lie for one batched product), and no padded,
+  transposed stack is built or sliced. XLA inserts exactly the all-gathers
+  KAISA prescribes; grad_worker_fraction is the mesh aspect ratio
+  (kfac_tpu/assignment.py:mesh_shape).
 
 Memory matches the strategy: MEM-OPT keeps 1/world of the second-order state
 per device, COMM-OPT replicates it — the same trade the gradient worker
@@ -46,6 +52,7 @@ from kfac_tpu.async_inverse import slots as async_slots
 from kfac_tpu.compression import offload as offload_lib
 from kfac_tpu.compression import quant as quant_lib
 from kfac_tpu.layers import capture as capture_lib
+from kfac_tpu.layers import helpers as helpers_lib
 from kfac_tpu.layers import registry as registry_lib
 from kfac_tpu.observability import comms as comms_lib
 from kfac_tpu.observability import compile_watch as compile_watch_lib
@@ -54,7 +61,11 @@ from kfac_tpu.observability import metrics as metrics_lib
 from kfac_tpu.ops import factors as factors_lib
 from kfac_tpu.parallel import collectives
 from kfac_tpu.parallel import mesh as mesh_lib
-from kfac_tpu.preconditioner import KFACPreconditioner, _resolve
+from kfac_tpu.preconditioner import (
+    KFACPreconditioner,
+    _resolve,
+    finish_precondition,
+)
 
 
 def size_class(d: int, granularity: int) -> int:
@@ -545,6 +556,24 @@ class DistributedKFAC:
                 'method; ignoring',
                 stacklevel=2,
             )
+        # Which operand layout ``precondition`` runs in, read off what the
+        # engine holds: with explicit inverses resident on every device
+        # (COMM-OPT: ``_decomp_spec`` replicated, and so on any one-device
+        # mesh) each layer multiplies its own gradient against its inverse
+        # slots, a Dense kernel as it lies. Sharded by column, the stack
+        # IS the placement; the eigen methods keep it too.
+        self._in_layout = (
+            not self._eigen and self._decomp_spec() == P()
+        )
+        # the share of preconditioned gradient elements that are multiplied
+        # in their parameter's own layout, packed and unpacked by nobody
+        sizes = [
+            (helpers_lib.matrix_param_count(h), h.in_layout)
+            for h in self.registry.layers.values()
+        ]
+        self.in_layout_share = sum(
+            n for n, own in sizes if own and self._in_layout
+        ) / max(1, sum(n for n, _ in sizes))
         # inverse_solver='auto' is served by
         # factors.batched_damped_inverse_auto_info: one scalar runtime cond
         # per device-local block, so the batched Cholesky runs only when some
@@ -1575,28 +1604,114 @@ class DistributedKFAC:
         grads: Any,
         metrics_out: dict[str, jax.Array] | None = None,
     ) -> Any:
-        """Precondition a params-shaped grad pytree via batched stacked math.
+        """Precondition a params-shaped grad pytree.
 
-        Gradient stacks are laid out like the decompositions, so each column
-        preconditions only its layers (its devices are the layer's "grad
-        workers"); the final replication constraint is the KAISA gradient
-        broadcast (reference kfac/layers/base.py:224-252).
+        One algorithm, two operand layouts, chosen by what the engine
+        holds (``_in_layout``). Where every device holds every inverse
+        (COMM-OPT), each layer multiplies its own gradient against its two
+        inverse slots, in the layout it has, and no padded stack is built
+        (:meth:`_resident_views`).
+        Where the decompositions are sharded by column (HYBRID/MEM-OPT)
+        the gradients are stacked like them, so each column preconditions
+        only its layers (its devices are the layer's "grad workers"), and
+        the final replication constraint is the KAISA gradient broadcast
+        (:meth:`_stacked_views`; reference kfac/layers/base.py:224-252).
 
         ``metrics_out``, when given, collects this phase's telemetry
         scalars at the replicated per-layer true-dim level (the same
         place degradation/KL run — stack-level reductions would hit the
-        GSPMD partial-sum hazard described below); ``step`` merges them
-        into ``state.metrics``.
+        GSPMD partial-sum hazard described in :meth:`_stacked_views`);
+        ``step`` merges them into ``state.metrics``.
         """
+        layer_grads = registry_lib.slice_layer_grads(grads, self.registry)
+        views = (
+            self._resident_views if self._in_layout else self._stacked_views
+        )(state, layer_grads)
+        out = finish_precondition(self.config, state, views, metrics_out)
+        return registry_lib.merge_layer_grads(grads, out, self.registry)
+
+    def _resident_views(
+        self, state: DistKFACState, layer_grads: dict[str, Any]
+    ) -> dict[str, tuple[dict[str, jax.Array], dict[str, jax.Array]]]:
+        """Each layer's gradient and preconditioned gradient from the
+        inverses as they are resident on every device: the layer's own
+        view (``LayerHelper.grad_view``: a Dense kernel as it lies, a
+        convolution's packed matrix) against its A and G slots at their
+        true dims. A class slot's padding rows and columns meet only the
+        stacked gradient's zero padding, so the true-dims block gives the
+        stack path's number.
+
+        Layers of one bucket that follow each other in its slots with the
+        same view (helper type, shapes, true dims) are a *run*: one
+        batched product of their views, joined along a new leading axis
+        in the layout they have, against that slice of the inverse
+        stacks. The numbers are the per-layer products'; the program is
+        not: the TPU compiler emits each product's code anew, ~1 MB a
+        layer at LFM2's widths (offline compile, PR 34), which a run
+        shares."""
+        rep = NamedSharding(self.mesh, P())
+        # ((form, first A slot, first G slot), [(layer, its view), ...])
+        runs: list[tuple[tuple, list[tuple[str, dict[str, jax.Array]]]]] = []
+        for b in self.buckets:
+            for name, dims in zip(b.layers, b.dims):
+                helper = self.registry.layers[name]
+                # pinned to replicated like the stack path's matrices:
+                # TP/SP leaves per-layer grads model-sharded
+                gview = {
+                    k: jax.lax.with_sharding_constraint(v, rep)
+                    for k, v in helper.grad_view(layer_grads[name]).items()
+                }
+                (a_key, a_i), (g_key, g_i) = (
+                    self._a_slot[name], self._g_slot[name]
+                )
+                form = (
+                    type(helper), a_key, g_key, dims,
+                    tuple((k, v.shape, v.dtype) for k, v in gview.items()),
+                )
+                if runs and runs[-1][0] == (
+                    form, a_i - len(runs[-1][1]), g_i - len(runs[-1][1])
+                ):
+                    runs[-1][1].append((name, gview))
+                else:
+                    runs.append(((form, a_i, g_i), [(name, gview)]))
+
+        views = {}
+        for (form, a_i, g_i), members in runs:
+            _, a_key, g_key, (da, dg), _ = form
+            n = len(members)
+            product = self.registry.layers[members[0][0]].inverse_precondition
+            if n == 1:
+                name, gview = members[0]
+                views[name] = (gview, product(
+                    gview,
+                    state.a_inv[a_key][a_i, :da, :da],
+                    state.g_inv[g_key][g_i, :dg, :dg],
+                ))
+                continue
+            joined = jax.vmap(product)(
+                {
+                    k: jnp.stack([gview[k] for _, gview in members])
+                    for k in members[0][1]
+                },
+                state.a_inv[a_key][a_i:a_i + n, :da, :da],
+                state.g_inv[g_key][g_i:g_i + n, :dg, :dg],
+            )
+            for i, (name, gview) in enumerate(members):
+                views[name] = (gview, {k: v[i] for k, v in joined.items()})
+        return views
+
+    def _stacked_views(
+        self, state: DistKFACState, layer_grads: dict[str, Any]
+    ) -> dict[str, tuple[dict[str, jax.Array], dict[str, jax.Array]]]:
+        """Each layer's gradient and preconditioned gradient in matrix
+        form, by batched math on gradient stacks laid out like the
+        decompositions."""
         cfg = self.config
         damping = _resolve(cfg.damping, state.step)
-        lr = _resolve(cfg.lr, state.step)
         dec = NamedSharding(self.mesh, self._decomp_spec())
         rep = NamedSharding(self.mesh, P())
-        layer_grads = registry_lib.slice_layer_grads(grads, self.registry)
 
         pmats: dict[str, jax.Array] = {}
-        vg = jnp.zeros((), jnp.float32)
         for b in self.buckets:
             # pin each matrix to replicated before inserting: TP/SP leaves
             # per-layer grads model-sharded, and mixed shardings force
@@ -1681,81 +1796,26 @@ class DistributedKFAC:
                 )
             pmats[b.key] = pstack
 
-        # Extraction, graceful degradation, and KL clipping all happen on
-        # replicated per-layer true-dim matrices — NOT at stack level.
-        # Mixing gstack into outputs or reductions at stack level flips its
-        # row-axis replication to partial-sum under GSPMD at fractional
-        # grad-worker meshes and inflates values by the grad-worker count;
-        # the per-layer form also matches the dense engine's vg semantics
-        # exactly (kfac_tpu/preconditioner.py:precondition).
-        mcfg = cfg.metrics if metrics_out is not None else None
-        mats: dict[str, jax.Array] = {}
+        # Extraction happens on replicated per-layer true-dim matrices, and
+        # so do graceful degradation and KL clipping (finish_precondition)
+        # — NOT at stack level. Mixing gstack into outputs or reductions at
+        # stack level flips its row-axis replication to partial-sum under
+        # GSPMD at fractional grad-worker meshes and inflates values by
+        # the grad-worker count; the per-layer form also matches the dense
+        # engine's vg semantics exactly.
+        views = {}
         for b in self.buckets:
             # KAISA gradient broadcast: replicate the preconditioned stack.
             pstack = jax.lax.with_sharding_constraint(pmats[b.key], rep)
             for i, name in enumerate(b.layers):
-                helper = self.registry.layers[name]
                 dag, dgg = b.dims[i]
-                pmat = pstack[i][:dgg, :dag]
-                gmat = helper.grads_to_matrix(layer_grads[name])
-                if mcfg is not None:
-                    if mcfg.grad_norms:
-                        g32 = gmat.astype(jnp.float32)
-                        metrics_out[f'grad_norm/{name}'] = jnp.sqrt(
-                            jnp.sum(g32 * g32))
-                    eff = (
-                        damping * state.health.damping_mult[name]
-                        if cfg.health is not None else damping
-                    )
-                    metrics_out[f'damping_eff/{name}'] = jnp.asarray(
-                        eff, jnp.float32)
-                if cfg.health is not None:
-                    # graceful degradation: a layer past degrade_after
-                    # consecutive quarantined inversions bypasses its
-                    # preconditioner — the raw gradient flows through
-                    # (still KL-clipped with the rest), first-order per
-                    # layer
-                    pmat = jnp.where(
-                        health_lib.is_degraded(
-                            cfg.health, state.health.bad_inv[name]
-                        ),
-                        gmat.astype(pmat.dtype),
-                        pmat,
-                    )
-                if mcfg is not None and mcfg.grad_norms:
-                    # pre-scale norm, next to the kl_clip reduction's read
-                    # of pmat (one fused pass); rescaled by kl_clip_scale
-                    # below instead of re-reading the scaled tensor
-                    p32 = pmat.astype(jnp.float32)
-                    metrics_out[f'precond_grad_norm/{name}'] = jnp.sqrt(
-                        jnp.sum(p32 * p32))
-                if cfg.kl_clip is not None:
-                    vg = vg + factors_lib.kl_clip_terms(pmat, gmat, lr)
-                mats[name] = pmat
-
-        if cfg.kl_clip is not None:
-            kl_clip = _resolve(cfg.kl_clip, state.step)
-            scale = factors_lib.kl_clip_scale(vg, kl_clip)
-        else:
-            scale = None
-        if mcfg is not None:
-            metrics_out['kl_clip_scale'] = (
-                scale.astype(jnp.float32) if scale is not None
-                else jnp.ones((), jnp.float32)
-            )
-
-        out: dict[str, dict[str, jax.Array]] = {}
-        for name, pmat in mats.items():
-            helper = self.registry.layers[name]
-            ref_dtype = layer_grads[name][next(iter(layer_grads[name]))].dtype
-            if scale is not None:
-                pmat = factors_lib.kl_clip_apply(pmat, scale)
-                if mcfg is not None and mcfg.grad_norms:
-                    metrics_out[f'precond_grad_norm/{name}'] = (
-                        metrics_out[f'precond_grad_norm/{name}']
-                        * jnp.abs(scale.astype(jnp.float32)))
-            out[name] = helper.matrix_to_grads(pmat.astype(ref_dtype))
-        return registry_lib.merge_layer_grads(grads, out, self.registry)
+                views[name] = (
+                    helpers_lib.matrix_view(
+                        self.registry.layers[name], layer_grads[name]
+                    ),
+                    {helpers_lib.MATRIX: pstack[i][:dgg, :dag]},
+                )
+        return views
 
     # ------------------------------------------------------------------ step
 
@@ -1913,6 +1973,13 @@ class DistributedKFAC:
             f'(grid {self.grad_workers}x{mesh_lib.n_cols(self.mesh)}), '
             f'strategy={self.strategy.name}, colocate={self.colocate}, '
             f'method={self.config.compute_method.name}',
+            'preconditioned in the parameters\' own layout: '
+            f'{self.in_layout_share:.1%} of the gradient elements ('
+            + (
+                'each layer against its inverse slots, resident on every '
+                'device' if self._in_layout else
+                'gradient stacks laid out like the decompositions'
+            ) + ')',
             self.config.describe(),
             'stat transport buckets (stacked batched decompositions):',
         ]

@@ -20,6 +20,8 @@ Distribution model (vs reference L1/L4/L5):
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
 import warnings
 from typing import Any, Callable, NamedTuple
 
@@ -37,6 +39,7 @@ from kfac_tpu.async_inverse import slots as async_slots
 from kfac_tpu.compression import config as compression_config_lib
 from kfac_tpu.compression import offload as offload_lib
 from kfac_tpu.layers import capture as capture_lib
+from kfac_tpu.layers import helpers as helpers_lib
 from kfac_tpu.layers import registry as registry_lib
 from kfac_tpu.observability import compile_watch as compile_watch_lib
 from kfac_tpu.observability import flight_recorder as flight_lib
@@ -77,6 +80,106 @@ def _resolve(value: ScalarOrSchedule, step: jax.Array) -> jax.Array | float:
     if callable(value):
         return value(step)
     return value
+
+
+def _view_sum(fn: Callable[..., jax.Array], *views: dict) -> jax.Array:
+    """``fn`` of the views' matching arrays, summed over a view's keys."""
+    terms = [fn(*(v[k] for v in views)) for k in views[0]]
+    return functools.reduce(operator.add, terms)
+
+
+def _view_norm(view: dict[str, jax.Array]) -> jax.Array:
+    def sumsq(x):
+        x32 = x.astype(jnp.float32)
+        return jnp.sum(x32 * x32)
+
+    return jnp.sqrt(_view_sum(sumsq, view))
+
+
+def finish_precondition(
+    cfg: 'KFACPreconditioner',
+    state: Any,
+    views: dict[str, tuple[dict[str, jax.Array], dict[str, jax.Array]]],
+    metrics_out: dict[str, jax.Array] | None,
+) -> dict[str, dict[str, jax.Array]]:
+    """What every engine does with its layers' preconditioned gradients,
+    once it has them: telemetry, graceful degradation, the one kl-clip
+    scale across layers, and the way back to flax param layout.
+
+    ``views`` maps each layer to its gradient and its preconditioned
+    gradient as matching views (``LayerHelper.grad_view``): every
+    reduction here is a sum of elementwise products, so a view's layout
+    does not matter to it. ``state`` is the engine's (``step``,
+    ``health``). Returns each layer's leaves, in the gradient's dtype.
+    """
+    damping = _resolve(cfg.damping, state.step)
+    lr = _resolve(cfg.lr, state.step)
+    mcfg = cfg.metrics if metrics_out is not None else None
+    vg = jnp.zeros((), jnp.float32)
+    kept: dict[str, dict[str, jax.Array]] = {}
+    for name, (gview, pview) in views.items():
+        if mcfg is not None:
+            if mcfg.grad_norms:
+                metrics_out[f'grad_norm/{name}'] = _view_norm(gview)
+            eff = (
+                damping * state.health.damping_mult[name]
+                if cfg.health is not None else damping
+            )
+            metrics_out[f'damping_eff/{name}'] = jnp.asarray(
+                eff, jnp.float32)
+        if cfg.health is not None:
+            # graceful degradation: a layer past degrade_after consecutive
+            # quarantined inversions bypasses its preconditioner — the raw
+            # gradient flows through (still KL-clipped with the rest),
+            # first-order for this layer only
+            degraded = health_lib.is_degraded(
+                cfg.health, state.health.bad_inv[name]
+            )
+            pview = {
+                k: jnp.where(degraded, gview[k].astype(p.dtype), p)
+                for k, p in pview.items()
+            }
+        if mcfg is not None and mcfg.grad_norms:
+            # pre-scale norm, next to the kl_clip reduction's read of the
+            # same arrays (one fused pass); rescaled by kl_clip_scale below
+            # instead of re-reading the scaled tensor
+            metrics_out[f'precond_grad_norm/{name}'] = _view_norm(pview)
+        if cfg.kl_clip is not None:
+            vg = vg + _view_sum(
+                lambda p, g: factors_lib.kl_clip_terms(p, g, lr),
+                pview, gview,
+            )
+        kept[name] = pview
+
+    if cfg.kl_clip is not None and kept:
+        scale = factors_lib.kl_clip_scale(
+            vg, _resolve(cfg.kl_clip, state.step)
+        )
+    else:
+        scale = None
+    if mcfg is not None:
+        metrics_out['kl_clip_scale'] = (
+            scale.astype(jnp.float32) if scale is not None
+            else jnp.ones((), jnp.float32)
+        )
+
+    out: dict[str, dict[str, jax.Array]] = {}
+    for name, pview in kept.items():
+        gview = views[name][0]
+        if scale is not None:
+            pview = {
+                k: factors_lib.kl_clip_apply(p, scale)
+                for k, p in pview.items()
+            }
+            if mcfg is not None and mcfg.grad_norms:
+                metrics_out[f'precond_grad_norm/{name}'] = (
+                    metrics_out[f'precond_grad_norm/{name}']
+                    * jnp.abs(scale.astype(jnp.float32)))
+        out[name] = helpers_lib.view_to_grads(
+            cfg.registry.layers[name],
+            {k: p.astype(gview[k].dtype) for k, p in pview.items()},
+        )
+    return out
 
 
 class KFACState(NamedTuple):
@@ -868,22 +971,29 @@ class KFACPreconditioner:
         self,
         state: KFACState,
         name: str,
-        grad_mat: jax.Array,
+        gview: dict[str, jax.Array],
         damping: jax.Array | float,
-    ) -> jax.Array:
+    ) -> dict[str, jax.Array]:
+        """One layer's preconditioned gradient, as a view like ``gview``:
+        the matrix form (``helpers.matrix_view``) for the eigen methods,
+        the helper's own (``LayerHelper.grad_view``) for explicit
+        inverses."""
         if self.compute_method == enums.ComputeMethod.EIGEN:
+            grad_mat = gview[helpers_lib.MATRIX]
             if self.prediv_eigenvalues:
                 v1 = state.qg[name].T @ grad_mat.astype(self.inv_dtype) @ state.qa[name]
                 v2 = v1 * state.dgda[name]
-                return (state.qg[name] @ v2 @ state.qa[name].T).astype(grad_mat.dtype)
-            return factors_lib.eigen_preconditioned_grad(
-                grad_mat,
-                factors_lib.EigenDecomp(q=state.qa[name], d=state.da[name]),
-                factors_lib.EigenDecomp(q=state.qg[name], d=state.dg[name]),
-                damping,
-            )
-        return factors_lib.inverse_preconditioned_grad(
-            grad_mat, state.a_inv[name], state.g_inv[name]
+                pmat = (state.qg[name] @ v2 @ state.qa[name].T).astype(grad_mat.dtype)
+            else:
+                pmat = factors_lib.eigen_preconditioned_grad(
+                    grad_mat,
+                    factors_lib.EigenDecomp(q=state.qa[name], d=state.da[name]),
+                    factors_lib.EigenDecomp(q=state.qg[name], d=state.dg[name]),
+                    damping,
+                )
+            return {helpers_lib.MATRIX: pmat}
+        return self.registry.layers[name].inverse_precondition(
+            gview, state.a_inv[name], state.g_inv[name]
         )
 
     @tracing.scope('kfac.precondition')
@@ -900,6 +1010,10 @@ class KFACPreconditioner:
         (cf. reference's ``.item()`` loop,
         kfac/base_preconditioner.py:411-435).
 
+        With explicit inverses a Dense layer is multiplied on its leaves as
+        they lie (``DenseHelper.inverse_precondition``); the eigen methods
+        and the other helpers go through the packed matrix.
+
         ``metrics_out``, when given, is filled in-place with this phase's
         telemetry scalars (grad/preconditioned-grad norms, effective
         damping, kl_clip scale) — values the preconditioning math already
@@ -908,67 +1022,24 @@ class KFACPreconditioner:
         """
         damping = _resolve(self.damping, state.step)
         layer_grads = registry_lib.slice_layer_grads(grads, self.registry)
-        precond: dict[str, dict[str, jax.Array]] = {}
-        vg_terms = []
-        lr = _resolve(self.lr, state.step)
-        cfg = self.health
-        h = state.health
-        mcfg = self.metrics if metrics_out is not None else None
+        eigen = self.compute_method == enums.ComputeMethod.EIGEN
+        views = {}
         for name, helper in self.registry.layers.items():
-            gmat = helper.grads_to_matrix(layer_grads[name])
+            gview = (
+                helpers_lib.matrix_view(helper, layer_grads[name]) if eigen
+                else helper.grad_view(layer_grads[name])
+            )
             # per-layer escalated damping bites here for the non-prediv
             # EIGEN method (its damping enters at precondition time); the
             # other methods bake it into update_inverses
             eff = (
-                damping * h.damping_mult[name] if cfg is not None else damping
+                damping * state.health.damping_mult[name]
+                if self.health is not None else damping
             )
-            if mcfg is not None:
-                if mcfg.grad_norms:
-                    g32 = gmat.astype(jnp.float32)
-                    metrics_out[f'grad_norm/{name}'] = jnp.sqrt(
-                        jnp.sum(g32 * g32))
-                metrics_out[f'damping_eff/{name}'] = jnp.asarray(
-                    eff, jnp.float32)
-            pmat = self._precondition_one(state, name, gmat, eff)
-            if cfg is not None:
-                # graceful degradation: a layer past degrade_after
-                # consecutive quarantined inversions is bypassed — the raw
-                # gradient direction flows through (still KL-clipped with
-                # the rest), first-order for this layer only
-                degraded = health_lib.is_degraded(cfg, h.bad_inv[name])
-                pmat = jnp.where(degraded, gmat.astype(pmat.dtype), pmat)
-            if mcfg is not None and mcfg.grad_norms:
-                # pre-scale norm, next to the kl_clip reduction's read of
-                # pmat (one fused pass); the scalar is rescaled by
-                # kl_clip_scale below instead of re-reading the scaled
-                # tensor in the output loop
-                p32 = pmat.astype(jnp.float32)
-                metrics_out[f'precond_grad_norm/{name}'] = jnp.sqrt(
-                    jnp.sum(p32 * p32))
-            if self.kl_clip is not None:
-                vg_terms.append(factors_lib.kl_clip_terms(pmat, gmat, lr))
-            precond[name] = (pmat, helper)
-        if self.kl_clip is not None and vg_terms:
-            kl_clip = _resolve(self.kl_clip, state.step)
-            scale = factors_lib.kl_clip_scale(
-                sum(vg_terms), kl_clip
+            views[name] = (
+                gview, self._precondition_one(state, name, gview, eff)
             )
-        else:
-            scale = None
-        if mcfg is not None:
-            metrics_out['kl_clip_scale'] = (
-                scale.astype(jnp.float32) if scale is not None
-                else jnp.ones((), jnp.float32)
-            )
-        out: dict[str, dict[str, jax.Array]] = {}
-        for name, (pmat, helper) in precond.items():
-            if scale is not None:
-                pmat = factors_lib.kl_clip_apply(pmat, scale)
-                if mcfg is not None and mcfg.grad_norms:
-                    metrics_out[f'precond_grad_norm/{name}'] = (
-                        metrics_out[f'precond_grad_norm/{name}']
-                        * jnp.abs(scale.astype(jnp.float32)))
-            out[name] = helper.matrix_to_grads(pmat)
+        out = finish_precondition(self, state, views, metrics_out)
         return registry_lib.merge_layer_grads(grads, out, self.registry)
 
     # ------------------------------------------------------------------ step

@@ -281,6 +281,24 @@ def test_kfl205_byte_parity_three_canonical_strategies(
     )
 
 
+def test_kfl205_comm_opt_with_inverses_pins_no_stack():
+    # explicit inverses resident on every device: each layer multiplies
+    # against its own slots, so precondition holds no rank-3 pin at all
+    import kfac_tpu
+
+    spec = harness._ConfigSpec(
+        'parity-ns-comm', 'kaisa', 16, 1.0,
+        dict(compute_method=kfac_tpu.ComputeMethod.INVERSE,
+             inverse_solver='newton_schulz', newton_schulz_iters=6),
+    )
+    traces = harness._trace_config(spec, len(jax.devices()))
+    t = next(x for x in traces if x.entry == 'precondition')
+    assert t.comms['strategy'] == 'COMM_OPT'
+    pins = visitor.constraint_pins(t.jaxpr)
+    assert visitor.rank3_replicated_pin_bytes(pins) == 0
+    assert rules.check_cost_model_parity(suite_of(*traces)) == []
+
+
 def test_kfl205_eigh_flop_parity(canonical_traces):
     t = canonical_traces[0.5]['update_inverses']
     got = visitor.eigh_flops(t.jaxpr) * t.world
