@@ -8,9 +8,10 @@ Drives the normal training path once — ``register_model`` ->
 full width of ResNet-50 (224 px, 1000 classes, bf16, 32 images per chip,
 synthetic data from a seed), with every library default for method,
 solver, kernels and granularity, and a cadence that puts every step
-variant inside ten steps. Before that it compiles every Pallas kernel the
-default configuration can dispatch at a real ResNet-50 shape and holds it
-against the XLA expression it replaces, and holds the covariance product
+variant inside ten steps. Before that it compiles the kl-clip pair of
+``ops/pallas_ns.py`` (a standalone kernel: the step path runs XLA's
+expressions since PR 37) at a real ResNet-50 shape and holds it against
+the XLA expression it stands for, and holds the covariance product
 (``ops.cov.get_cov``) at two of the benchmark's shapes against a float64
 product of the same values on the host.
 
@@ -87,8 +88,8 @@ def resnet50_argv(n_devices: int) -> list[str]:
 
 
 def check_kernels(shapes=KERNEL_SHAPES) -> list[dict]:
-    """Compile each Pallas kernel the default path dispatches on a TPU
-    and compare it with the XLA expression it replaces, and the
+    """Compile the kl-clip pair (``ops/pallas_ns.py``) on a TPU and
+    compare it with the XLA expression it stands for, and the
     covariance product with float64. One row per kernel and shape;
     raises if a row misses its tolerance. Off a TPU the same calls run
     the Pallas interpreter (tests only)."""

@@ -756,29 +756,21 @@ def kl_clip_terms(
     gmat: jax.Array,
     lr: float | jax.Array,
 ) -> jax.Array:
-    """One layer's term of the kl-clip second moment:
-    ``sum(pmat * gmat) * lr^2`` in f32.
+    """One layer's (or one run of layers') term of the kl-clip second
+    moment: ``sum(pmat * gmat) * lr^2`` in f32, over every axis.
 
     This is the contraction every engine sums across layers before
-    :func:`kl_clip_scale`. In the fused kernel's win regime
-    (:func:`kfac_tpu.ops.pallas_ns.use_fused_klclip_for`) the
-    multiply-reduce runs tiled in VMEM; everywhere else it is the plain
-    XLA expression — bitwise-identical inputs either way.
+    :func:`kl_clip_scale`: XLA's multiply-reduce for every shape, backend
+    and device count. It reads its operands where they lie (a slice of a
+    batched product is an operand of the fusion, not a copy), which the
+    Mosaic pair in :mod:`kfac_tpu.ops.pallas_ns` that ran here until
+    PR 37 could not: a custom call takes whole buffers. On a v5e eight
+    2,048 x 1,536 slices of a stack reduce at 732 GB/s this way and at
+    231 GB/s through the pair's 128 x 128 tiles; alone, with its
+    operands in fast memory, the pair is 2.1-2.9x slower at every shape
+    the benchmark's cells have (``PERF.md`` section 6, PR 37).
     """
-    from kfac_tpu.ops import pallas_ns
-
-    if (
-        pmat.ndim == 2
-        and pmat.shape == gmat.shape
-        and pallas_ns.use_fused_klclip_for(pmat.shape)
-    ):
-        dot = pallas_ns.fused_klclip_dot(
-            pmat, gmat, interpret=pallas_ns.interpret_mode()
-        )
-    else:
-        dot = jnp.sum(
-            pmat.astype(jnp.float32) * gmat.astype(jnp.float32)
-        )
+    dot = jnp.sum(pmat.astype(jnp.float32) * gmat.astype(jnp.float32))
     return dot * (lr ** 2)
 
 
@@ -786,15 +778,8 @@ def kl_clip_apply(pmat: jax.Array, scale: jax.Array) -> jax.Array:
     """Apply the kl-clip scale to one preconditioned gradient:
     ``(pmat_f32 * scale)`` cast back to ``pmat``'s dtype.
 
-    The fused Pallas form runs the f32 upcast + scale tiled in VMEM in
-    its win regime; the fallback is the engines' original expression.
+    An elementwise multiply the compiler fuses into whatever reads the
+    gradient next (the cast to the leaf's dtype, the optimizer's update):
+    it writes no buffer of its own.
     """
-    from kfac_tpu.ops import pallas_ns
-
-    if pmat.ndim == 2 and pallas_ns.use_fused_klclip_for(pmat.shape):
-        out = pallas_ns.fused_klclip_scale(
-            pmat, scale, interpret=pallas_ns.interpret_mode()
-        )
-    else:
-        out = pmat.astype(jnp.float32) * scale
-    return out.astype(pmat.dtype)
+    return (pmat.astype(jnp.float32) * scale).astype(pmat.dtype)

@@ -6,10 +6,13 @@ can observe: ``pallas_attention.use_flash_for`` and
 their own). This module holds the questions the two share: whether a raw
 Mosaic call may run in the current trace context
 (:func:`mosaic_context_ok`), and whether the kernels run in the Pallas
-interpreter (:func:`interpret_mode`). No kernel's speed has been
-measured against its XLA expression on today's code (ROADMAP S6); what
-IS checked on the chip is that every kernel compiles under Mosaic and
-agrees with the XLA expression it replaces (``chip_smoke.py``).
+interpreter (:func:`interpret_mode`). The kl-clip pair was measured
+against its XLA expressions on a v5e and lost at every shape, so nothing
+dispatches it (``PERF.md`` section 6, PR 37); the flash partials' speed
+against the einsum path has not been measured on today's code
+(ROADMAP D5). What IS checked on the chip is that every kernel compiles
+under Mosaic and agrees with the XLA expression it stands for
+(``chip_smoke.py``).
 """
 
 from __future__ import annotations

@@ -1,23 +1,28 @@
-"""Fused Pallas TPU kernels for the step path: kl-clip.
+"""Pallas TPU kernels for kl-clip: a standalone pair, off the step path.
 
 **Fused kl-clip** (:func:`fused_klclip_dot` / :func:`fused_klclip_scale`):
 the second-moment contraction ``sum(pmat * gmat)`` and the scale
-application ``pmat * scale`` are each a full d^2 read the XLA path runs
-as separate elementwise passes; the Pallas forms run them tiled with the
-scalar reduction accumulated across the grid, which keeps the
-contraction's f32 upcast in VMEM. The scalar *decision*
-(``kl_clip_scale``: ``min(1, sqrt(kl/|vg|))``) is unchanged — it is
-cross-layer, so it cannot fuse into any per-layer kernel.
+application ``pmat * scale``, tiled 128 x 128 with the scalar reduction
+accumulated across the grid. Until PR 37 ``factors.kl_clip_terms`` /
+``kl_clip_apply`` dispatched them from 512^2 elements on a one-device
+TPU; since then those two are XLA's expressions everywhere and nothing
+in the library calls this module. Measured on a v5e (``PERF.md``
+section 6, PR 37): XLA's multiply-reduce and multiply are 2.1-2.9x
+faster than the pair at every shape the benchmark's cells have, 3.2x
+where the operands are slices of a stack in HBM (a custom call takes
+whole buffers, so each slice is copied out for it; 64 KB an operand a
+grid step is less than a grid step's own overhead), and the opaque
+scale kept the compiler from fusing ``p * scale`` into the optimizer's
+update. The module stays until ``benchmark/readings.py`` stops
+importing it (ROADMAP D5); its tests hold it as a kernel of its own.
 
 Equivalence contract (pinned by tests/ops/test_fused_kernels.py in the
 interpreter, and on the chip by ``chip_smoke.py``): f32 allclose to the
 unfused expressions above, contracted at f32 precision on both sides,
 for dense and stacked (vmapped) tensors.
 
-Dispatch: :func:`use_fused_klclip_for`, from backend, shape and trace
-context. Off-TPU, below ``_MIN_KLCLIP_DIM`` squared elements or in a
-partial-manual trace context the callers fall back to the unfused
-expressions.
+:func:`use_fused_klclip_for` says where the pair *can* run (backend,
+shape, trace context); no caller asks it any more.
 
 The module keeps its name from the fused Newton-Schulz pair it also held
 until PR 26 (``_ns_xupdate_kernel`` / ``_ns_mx_resid_kernel``: both
@@ -47,9 +52,11 @@ from kfac_tpu.ops.pallas_gate import interpret_mode, mosaic_context_ok
 
 TILE = 128       # lane-aligned block edge
 
-# The pair runs from this many elements squared. An off-chip prior sized
-# off the unfused expressions' sweep, never re-derived on the chip; the
-# on-chip A/B of the pair against XLA's two passes is ROADMAP S6's.
+# The size from which the pair dispatched until PR 37: an off-chip prior
+# the chip did not bear out. XLA's two expressions beat the pair at every
+# shape measured (4 MB to 16 MB an operand, and slices of a 100 MB
+# stack), so there is no size from which it wins and nothing reads this
+# but ``use_fused_klclip_for``.
 _MIN_KLCLIP_DIM = 4 * TILE
 
 
@@ -139,7 +146,8 @@ def fused_klclip_scale(
 
 
 def use_fused_klclip_for(shape: tuple[int, ...]) -> bool:
-    """Whether the fused kl-clip pair runs on a tensor of this shape,
+    """Whether the fused kl-clip pair can run on a tensor of this shape
+    (no caller dispatches on it since PR 37),
     from what can be observed here: a TPU backend, a 2-D tensor of at
     least ``_MIN_KLCLIP_DIM ** 2`` elements (by element count, so
     rectangular weights with the traffic of a square one decide alike),

@@ -1648,7 +1648,17 @@ class DistributedKFAC:
         stacks. The numbers are the per-layer products'; the program is
         not: the TPU compiler emits each product's code anew, ~1 MB a
         layer at LFM2's widths (offline compile, PR 34), which a run
-        shares."""
+        shares.
+
+        The gradient view handed on for ``finish_precondition``'s
+        reductions is held as a value of its own
+        (``optimization_barrier``), the product's operand is not: the
+        backward pass then writes each leaf once in its own dtype beside
+        its slot of the run's stack, as it did when a kernel read the
+        leaf. Left free, the TPU compiler carries two bfloat16 copies of
+        every gradient instead, holds less across the refresh
+        conditional, and plans that branch's scan buffers 0.43 GB wider
+        (LFM2's capture program, offline compile, PR 37)."""
         rep = NamedSharding(self.mesh, P())
         # ((form, first A slot, first G slot), [(layer, its view), ...])
         runs: list[tuple[tuple, list[tuple[str, dict[str, jax.Array]]]]] = []
@@ -1675,6 +1685,7 @@ class DistributedKFAC:
                 else:
                     runs.append(((form, a_i, g_i), [(name, gview)]))
 
+        held = jax.lax.optimization_barrier
         views = {}
         for (form, a_i, g_i), members in runs:
             _, a_key, g_key, (da, dg), _ = form
@@ -1682,7 +1693,7 @@ class DistributedKFAC:
             product = self.registry.layers[members[0][0]].inverse_precondition
             if n == 1:
                 name, gview = members[0]
-                views[name] = (gview, product(
+                views[name] = (held(gview), product(
                     gview,
                     state.a_inv[a_key][a_i, :da, :da],
                     state.g_inv[g_key][g_i, :dg, :dg],
@@ -1697,7 +1708,9 @@ class DistributedKFAC:
                 state.g_inv[g_key][g_i:g_i + n, :dg, :dg],
             )
             for i, (name, gview) in enumerate(members):
-                views[name] = (gview, {k: v[i] for k, v in joined.items()})
+                views[name] = (
+                    held(gview), {k: v[i] for k, v in joined.items()}
+                )
         return views
 
     def _stacked_views(

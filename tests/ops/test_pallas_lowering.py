@@ -9,10 +9,12 @@ run before spending chip time; it does not replace compiling on the chip
 (``chip_smoke.py``).
 """
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import pytest
 
+from kfac_tpu.models import moe
 from kfac_tpu.ops import pallas_attention, pallas_ns
 
 
@@ -98,3 +100,49 @@ def test_newton_schulz_is_xla_at_every_width(monkeypatch, d):
 
     assert _kernels_in(solve, f) == 0
     assert _kernels_in(jax.vmap(solve), _stack(f)) == 0
+
+
+class _DenseConvExperts(nn.Module):
+    """A Dense layer with bias, a convolution and an 8-expert stack, each
+    gradient over 512^2 elements: the size from which the kl-clip pair
+    dispatched until PR 37."""
+
+    @nn.compact
+    def __call__(self, x):
+        x = nn.relu(nn.Conv(512, (3, 3), name='conv')(x))
+        x = nn.relu(nn.Dense(512, name='fc')(x.mean((1, 2))))
+        return moe.SparseMoE(8, 2, 512, block_rows=8, name='moe')(x)
+
+
+@pytest.mark.parametrize('kl_clip', [None, 0.001], ids=['noclip', 'klclip'])
+@pytest.mark.parametrize('engine', ['dense', 'comm_opt'])
+def test_precondition_is_xla_with_every_gate_open(
+    monkeypatch, engine, kl_clip
+):
+    """With every gate open (TPU lowering, one device) the step path's
+    preconditioning lowers with no Mosaic kernel, kl-clip or not:
+    ``finish_precondition``'s contraction and scale are XLA's
+    (``factors.kl_clip_terms``, ``kl_clip_apply``), through the dense
+    engine and through ``DistributedKFAC`` with every inverse resident."""
+    import kfac_tpu
+    from kfac_tpu.parallel import DistributedKFAC, kaisa_mesh
+
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, 'devices', lambda *a: one)
+    assert pallas_ns.use_fused_klclip_for((512, 512))  # gates are open
+    model = _DenseConvExperts()
+    x = jnp.ones((16, 8, 8, 64))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)['params']
+    reg = kfac_tpu.register_model(model, x)
+    assert {'conv', 'fc'} < set(reg.layers)
+    assert sum('/experts/down_proj/e' in n for n in reg.layers) == 8
+    cfg = kfac_tpu.KFACPreconditioner(
+        registry=reg, compute_method='inverse', kl_clip=kl_clip,
+    )
+    if engine == 'comm_opt':
+        eng = DistributedKFAC(config=cfg, mesh=kaisa_mesh(devices=one))
+        assert eng._in_layout
+    else:
+        eng = cfg
+    state = jax.eval_shape(eng.init)
+    assert _kernels_in(eng.precondition, state, params) == 0

@@ -115,6 +115,16 @@ def _stacked_state(dk, state, inv):
     )
 
 
+def _grads(params, dtype=jnp.float32):
+    """A seeded gradient for every leaf of ``params``."""
+    return jax.tree_util.tree_map(
+        lambda p: jax.random.normal(
+            jax.random.PRNGKey(p.size % 97), p.shape
+        ).astype(dtype),
+        params,
+    )
+
+
 def _degrade(state, layer):
     if layer is None:
         return state
@@ -148,10 +158,7 @@ def test_in_layout_stack_and_dense_engine_agree(case, kl_clip):
     assert resident._in_layout and stacked._in_layout
     stacked._in_layout = False  # the test's switch, not an option
     inv = _seeded(reg)
-    grads = jax.tree_util.tree_map(
-        lambda p: jax.random.normal(jax.random.PRNGKey(p.size % 97), p.shape),
-        params,
-    )
+    grads = _grads(params)
 
     dstate = _degrade(_stacked_state(resident, resident.init(), inv), degraded)
     dense_state = cfg.init()
@@ -207,6 +214,86 @@ def test_in_layout_stack_and_dense_engine_agree(case, kl_clip):
                 np.asarray(got[path][0][degraded]['kernel']),
                 np.asarray(grads[degraded]['kernel']),
             )
+
+
+@pytest.mark.parametrize(
+    'dtype', [jnp.float32, jnp.bfloat16], ids=['f32', 'bf16']
+)
+@pytest.mark.parametrize('case', ['expert_slots', 'biased_run', 'bias_free'])
+def test_kl_clip_scale_against_float64(case, dtype):
+    """The one scale across layers and the leaves it scales, against
+    numpy in float64: ``min(1, sqrt(kl / |sum_layers sum(p * g) * lr^2|))``
+    with ``p = G^-1 g A^-1`` in matrix form, for a run of stacked experts,
+    a run of biased layers and lone layers, float32 and bfloat16
+    gradients. The contraction is XLA's multiply-reduce on each product
+    where it lies (``factors.kl_clip_terms``)."""
+    model, x, kw, _ = _case(case)
+    params = model.init(jax.random.PRNGKey(0), x)['params']
+    reg = kfac_tpu.register_model(model, x)
+    lr, kl = 0.5, 0.001
+    cfg = kfac_tpu.KFACPreconditioner(
+        registry=reg, compute_method='inverse', kl_clip=kl, lr=lr,
+        damping=0.01, metrics=True, **kw,
+    )
+    dk = DistributedKFAC(
+        config=cfg, mesh=kaisa_mesh(devices=jax.devices()[:1])
+    )
+    assert dk._in_layout
+    inv = _seeded(reg)
+    grads = _grads(params, dtype)
+
+    def fn(state, grads):
+        scal = {}
+        return dk.precondition(state, grads, metrics_out=scal), scal
+
+    tree, scal = jax.jit(fn)(_stacked_state(dk, dk.init(), inv), grads)
+
+    def f64(a):
+        return np.asarray(a.astype(jnp.float32), np.float64)
+
+    layer_grads = registry_lib.slice_layer_grads(grads, reg)
+    pmats, vg = {}, 0.0
+    for name, helper in reg.layers.items():
+        gm = f64(helper.grads_to_matrix(layer_grads[name]))
+        pmats[name] = f64(inv[name][1]) @ gm @ f64(inv[name][0])
+        vg += float(np.sum(pmats[name] * gm)) * lr ** 2
+    want_scale = min(1.0, np.sqrt(kl / abs(vg)))
+    assert want_scale < 0.5  # the clip bites
+    np.testing.assert_allclose(
+        float(scal['kl_clip_scale']), want_scale, rtol=2e-5
+    )
+    tol = 2e-5 if dtype == jnp.float32 else 2 ** -7
+    for name, leaves in registry_lib.slice_layer_grads(tree, reg).items():
+        want = reg.layers[name].matrix_to_grads(
+            jnp.asarray(pmats[name] * want_scale, jnp.float32)
+        )
+        for k, leaf in leaves.items():
+            assert leaf.dtype == dtype
+            np.testing.assert_allclose(
+                f64(leaf), f64(want[k]), rtol=tol,
+                atol=tol * float(np.max(np.abs(f64(want[k])))),
+                err_msg=f'{name} {k}',
+            )
+
+
+def test_kl_clip_terms_of_a_run_is_the_sum_of_its_layers():
+    """``kl_clip_terms`` sums over every axis: a run's stacked products
+    against its stacked gradients give the per-layer terms' sum."""
+    from kfac_tpu.ops import factors
+
+    kp, kg = jax.random.split(jax.random.PRNGKey(7))
+    # same-signed terms: float32's summation order moves the sum in its
+    # seventh digit, not by the terms' cancellation
+    p = jnp.abs(jax.random.normal(kp, (8, 96, 40), jnp.float32))
+    g = jnp.abs(jax.random.normal(kg, (8, 96, 40), jnp.bfloat16))
+    run = factors.kl_clip_terms(p, g, 0.5)
+    layers = sum(factors.kl_clip_terms(p[i], g[i], 0.5) for i in range(8))
+    want = 0.25 * np.sum(
+        np.asarray(p, np.float64)
+        * np.asarray(g.astype(jnp.float32), np.float64)
+    )
+    np.testing.assert_allclose(float(run), float(layers), rtol=1e-6)
+    np.testing.assert_allclose(float(run), want, rtol=1e-6)
 
 
 def test_comm_opt_matches_hybrid_opt_on_the_cpu_mesh():
