@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import optax
 
 import kfac_tpu
+from kfac_tpu import tracing
 
 
 def distributed_init() -> None:
@@ -142,17 +143,21 @@ def make_lr_schedule(base_lr, steps_per_epoch, epochs, warmup_epochs, decay_at):
 def label_smoothing_loss(logits, labels, num_classes, smoothing=0.1):
     """Label-smoothed cross entropy (reference examples/utils.py:41-63),
     via the optax built-ins."""
-    soft = optax.smooth_labels(jax.nn.one_hot(labels, num_classes), smoothing)
-    return optax.softmax_cross_entropy(
-        logits.astype(jnp.float32), soft
-    ).mean()
+    with tracing.model_scope('loss'):
+        soft = optax.smooth_labels(
+            jax.nn.one_hot(labels, num_classes), smoothing
+        )
+        return optax.softmax_cross_entropy(
+            logits.astype(jnp.float32), soft
+        ).mean()
 
 
 def cross_entropy_loss(logits, labels, num_classes):
     del num_classes
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), labels
-    ).mean()
+    with tracing.model_scope('loss'):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), labels
+        ).mean()
 
 
 class MetricsWriter:

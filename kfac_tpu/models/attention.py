@@ -32,15 +32,17 @@ def dense_causal_attention(q, k, v):
     kernel (ops/pallas_attention): scores stay in VMEM and above-diagonal
     K tiles are skipped. Elsewhere the dense einsum path runs.
     """
+    from kfac_tpu import tracing
     from kfac_tpu.ops import pallas_attention as pa
 
-    if pa.use_flash_for(
-        q.shape[1], k.shape[1], q.shape[-1], q.dtype.itemsize, dense=True
-    ):
-        out = _finish(pa.flash_attention_partials(q, k, v, causal=True))
+    with tracing.model_scope('attention'):
+        if pa.use_flash_for(
+            q.shape[1], k.shape[1], q.shape[-1], q.dtype.itemsize, dense=True
+        ):
+            out = _finish(pa.flash_attention_partials(q, k, v, causal=True))
+            return out.astype(q.dtype)
+        out = _finish(pa.attend_partials_einsum(q, k, v, 0, 0, True))
         return out.astype(q.dtype)
-    out = _finish(pa.attend_partials_einsum(q, k, v, 0, 0, True))
-    return out.astype(q.dtype)
 
 
 def blockwise_causal_attention(q, k, v, chunk: int = 1024):

@@ -135,16 +135,18 @@ def _attend(q, k, v, q_scale, k_scale, heads, kv_heads, head_dim, theta, eps,
     def heads_of(t, n):
         return t.reshape(*t.shape[:-1], n, head_dim)
 
-    q = transformer.rotary(
-        plain_rms_norm(heads_of(q, heads), q_scale, eps), head_dim, theta
-    ).astype(dtype)
-    k = transformer.rotary(
-        plain_rms_norm(heads_of(k, kv_heads), k_scale, eps), head_dim, theta
-    ).astype(dtype)
-    out = attention_lib.blockwise_causal_attention(
-        q, k, heads_of(v, kv_heads), chunk
-    )
-    return out.reshape(*out.shape[:-2], heads * head_dim)
+    with tracing.model_scope('attention'):
+        q = transformer.rotary(
+            plain_rms_norm(heads_of(q, heads), q_scale, eps), head_dim, theta
+        ).astype(dtype)
+        k = transformer.rotary(
+            plain_rms_norm(heads_of(k, kv_heads), k_scale, eps), head_dim,
+            theta,
+        ).astype(dtype)
+        out = attention_lib.blockwise_causal_attention(
+            q, k, heads_of(v, kv_heads), chunk
+        )
+        return out.reshape(*out.shape[:-2], heads * head_dim)
 
 
 class GroupedQueryAttention(nn.Module):
@@ -190,9 +192,12 @@ class ConvMoEBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        y = PlainRMSNorm(self.eps, name='norm1')(x)
-        x = x + self.make_mixer(name='mixer')(y).astype(x.dtype)
-        y = PlainRMSNorm(self.eps, name='norm2')(x)
+        with tracing.model_scope('norm'):
+            y = PlainRMSNorm(self.eps, name='norm1')(x)
+        with tracing.model_scope('mixer'):
+            x = x + self.make_mixer(name='mixer')(y).astype(x.dtype)
+        with tracing.model_scope('norm'):
+            y = PlainRMSNorm(self.eps, name='norm2')(x)
         return x + self.make_ffn()(y).astype(x.dtype)
 
 
@@ -238,7 +243,8 @@ class ConvMoELM(nn.Module):
         if unknown:
             raise ValueError(f'layer types {sorted(unknown)}: not {LAYER_TYPES}')
         embed = nn.Embed(self.vocab_size, self.d_model, name='embed')
-        x = embed(tokens).astype(jnp.float32)
+        with tracing.model_scope('embed'):
+            x = embed(tokens).astype(jnp.float32)
         for i, kind in enumerate(self.layer_types):
             if kind == 'conv':
                 mixer = functools.partial(
@@ -265,18 +271,19 @@ class ConvMoELM(nn.Module):
                     name='moe',
                 )
             x = ConvMoEBlock(mixer, ffn, self.norm_eps, name=f'block{i}')(x)
-        x = PlainRMSNorm(self.norm_eps, name='norm_f')(x)
-        table = embed.embedding.astype(self.dtype)
+        with tracing.model_scope('head'):
+            x = PlainRMSNorm(self.norm_eps, name='norm_f')(x)
+            table = embed.embedding.astype(self.dtype)
 
-        def head(x):
-            return jnp.dot(x.astype(self.dtype), table.T)
+            def head(x):
+                return jnp.dot(x.astype(self.dtype), table.T)
 
-        if targets is None:
-            return head(x)
-        seq = x.shape[1]
-        step = self.loss_chunk if seq % self.loss_chunk == 0 else seq
-        nll = jax.checkpoint(losses.vocab_parallel_nll)
-        return jnp.concatenate([
-            nll(head(x[:, i:i + step]), targets[:, i:i + step])
-            for i in range(0, seq, step)
-        ], axis=1)
+            if targets is None:
+                return head(x)
+            seq = x.shape[1]
+            step = self.loss_chunk if seq % self.loss_chunk == 0 else seq
+            nll = jax.checkpoint(losses.vocab_parallel_nll)
+            return jnp.concatenate([
+                nll(head(x[:, i:i + step]), targets[:, i:i + step])
+                for i in range(0, seq, step)
+            ], axis=1)

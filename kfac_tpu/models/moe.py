@@ -368,15 +368,18 @@ class GatedMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
+        from kfac_tpu import tracing
+
         def dense(features, name):
             return nn.Dense(
                 features, use_bias=False, dtype=self.dtype, name=name
             )
 
-        h = nn.silu(dense(self.width, 'gate_proj')(x)) * dense(
-            self.width, 'up_proj'
-        )(x)
-        return dense(x.shape[-1], 'down_proj')(h)
+        with tracing.model_scope('mlp'):
+            h = nn.silu(dense(self.width, 'gate_proj')(x)) * dense(
+                self.width, 'up_proj'
+            )(x)
+            return dense(x.shape[-1], 'down_proj')(h)
 
 
 class SparseMoE(nn.Module):
@@ -456,11 +459,12 @@ class SparseMoE(nn.Module):
                 xf, plan
             )
         if self.shared_width:
-            gate = nn.Dense(
-                1, use_bias=False, dtype=jnp.float32, name='shared_gate'
-            )(xf.astype(jnp.float32))
-            shared = GatedMLP(
-                self.shared_width, dtype=self.dtype, name='shared'
-            )(xf)
-            y = y + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
+            with tracing.model_scope('mlp'):
+                gate = nn.Dense(
+                    1, use_bias=False, dtype=jnp.float32, name='shared_gate'
+                )(xf.astype(jnp.float32))
+                shared = GatedMLP(
+                    self.shared_width, dtype=self.dtype, name='shared'
+                )(xf)
+                y = y + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
         return y.reshape(*lead, d)

@@ -17,6 +17,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from kfac_tpu import tracing
+
 ModuleDef = Any
 
 
@@ -101,18 +103,23 @@ class CifarResNet(nn.Module):
             nn.BatchNorm, use_running_average=not train, momentum=0.9,
             dtype=jnp.float32,
         )
-        x = nn.Conv(16, (3, 3), padding='SAME', use_bias=False, dtype=self.dtype, name='conv0')(x)
-        x = norm(name='bn0')(x)
-        x = nn.relu(x)
+        with tracing.model_scope('stem'):
+            x = nn.Conv(16, (3, 3), padding='SAME', use_bias=False, dtype=self.dtype, name='conv0')(x)
+            x = norm(name='bn0')(x)
+            x = nn.relu(x)
         for stage, filters in enumerate((16, 32, 64)):
             for block in range(n):
                 strides = 2 if stage > 0 and block == 0 else 1
-                x = BasicBlock(
-                    filters, strides=strides, norm=norm, dtype=self.dtype,
-                    name=f'stage{stage}_block{block}',
-                )(x)
-        x = jnp.mean(x, axis=(1, 2))
-        return nn.Dense(self.num_classes, name='head')(x.astype(jnp.float32))
+                with tracing.model_scope(f'stage{stage}'):
+                    x = BasicBlock(
+                        filters, strides=strides, norm=norm, dtype=self.dtype,
+                        name=f'stage{stage}_block{block}',
+                    )(x)
+        with tracing.model_scope('head'):
+            x = jnp.mean(x, axis=(1, 2))
+            return nn.Dense(self.num_classes, name='head')(
+                x.astype(jnp.float32)
+            )
 
 
 class ImageNetResNet(nn.Module):
@@ -128,24 +135,31 @@ class ImageNetResNet(nn.Module):
             nn.BatchNorm, use_running_average=not train, momentum=0.9,
             dtype=jnp.float32,
         )
-        x = nn.Conv(
-            64, (7, 7), strides=2, padding=[(3, 3), (3, 3)], use_bias=False,
-            dtype=self.dtype, name='conv0',
-        )(x)
-        x = norm(name='bn0')(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)])
+        with tracing.model_scope('stem'):
+            x = nn.Conv(
+                64, (7, 7), strides=2, padding=[(3, 3), (3, 3)],
+                use_bias=False, dtype=self.dtype, name='conv0',
+            )(x)
+            x = norm(name='bn0')(x)
+            x = nn.relu(x)
+            x = nn.max_pool(
+                x, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)]
+            )
         for stage, (blocks, filters) in enumerate(
             zip(self.stage_sizes, (64, 128, 256, 512))
         ):
             for block in range(blocks):
                 strides = 2 if stage > 0 and block == 0 else 1
-                x = BottleneckBlock(
-                    filters, strides=strides, norm=norm, dtype=self.dtype,
-                    name=f'stage{stage}_block{block}',
-                )(x)
-        x = jnp.mean(x, axis=(1, 2))
-        return nn.Dense(self.num_classes, name='head')(x.astype(jnp.float32))
+                with tracing.model_scope(f'stage{stage}'):
+                    x = BottleneckBlock(
+                        filters, strides=strides, norm=norm, dtype=self.dtype,
+                        name=f'stage{stage}_block{block}',
+                    )(x)
+        with tracing.model_scope('head'):
+            x = jnp.mean(x, axis=(1, 2))
+            return nn.Dense(self.num_classes, name='head')(
+                x.astype(jnp.float32)
+            )
 
 
 def resnet20(**kw) -> CifarResNet:

@@ -19,6 +19,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from kfac_tpu import tracing
 from kfac_tpu.models import moe as moe_lib
 from kfac_tpu.ops import losses
 
@@ -73,21 +74,25 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         d = x.shape[-1]
-        y = nn.LayerNorm(dtype=jnp.float32, name='ln1')(x)
-        x = x + CausalSelfAttention(
-            self.num_heads, dtype=self.dtype, ring_mesh=self.ring_mesh,
-            ring_axis=self.ring_axis, name='attn',
-        )(y)
-        y = nn.LayerNorm(dtype=jnp.float32, name='ln2')(x)
+        with tracing.model_scope('norm'):
+            y = nn.LayerNorm(dtype=jnp.float32, name='ln1')(x)
+        with tracing.model_scope('mixer'):
+            x = x + CausalSelfAttention(
+                self.num_heads, dtype=self.dtype, ring_mesh=self.ring_mesh,
+                ring_axis=self.ring_axis, name='attn',
+            )(y)
+        with tracing.model_scope('norm'):
+            y = nn.LayerNorm(dtype=jnp.float32, name='ln2')(x)
         if self.num_experts > 0:
             return x + moe_lib.MoEMLP(
                 self.num_experts, self.mlp_ratio, dtype=self.dtype,
                 capacity_factor=self.moe_capacity_factor,
                 name='moe',
             )(y)
-        h = nn.Dense(self.mlp_ratio * d, dtype=self.dtype, name='mlp_up')(y)
-        h = nn.gelu(h)
-        x = x + nn.Dense(d, dtype=self.dtype, name='mlp_down')(h)
+        with tracing.model_scope('mlp'):
+            h = nn.Dense(self.mlp_ratio * d, dtype=self.dtype, name='mlp_up')(y)
+            h = nn.gelu(h)
+            x = x + nn.Dense(d, dtype=self.dtype, name='mlp_down')(h)
         return x
 
 
@@ -117,13 +122,14 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens: jax.Array) -> jax.Array:
         seq = tokens.shape[-1]
-        x = nn.Embed(self.vocab_size, self.d_model, name='embed')(tokens)
-        pos = self.param(
-            'pos_embed',
-            nn.initializers.normal(0.02),
-            (self.max_len, self.d_model),
-        )
-        x = (x + pos[:seq]).astype(self.dtype)
+        with tracing.model_scope('embed'):
+            x = nn.Embed(self.vocab_size, self.d_model, name='embed')(tokens)
+            pos = self.param(
+                'pos_embed',
+                nn.initializers.normal(0.02),
+                (self.max_len, self.d_model),
+            )
+            x = (x + pos[:seq]).astype(self.dtype)
         block_cls = Block
         if self.remat:
             block_cls = nn.remat(Block)
@@ -141,9 +147,11 @@ class TransformerLM(nn.Module):
                 moe_capacity_factor=self.moe_capacity_factor,
                 name=f'block{i}',
             )(x)
-        x = nn.LayerNorm(dtype=jnp.float32, name='ln_f')(x.astype(jnp.float32))
-        logits = nn.Dense(self.vocab_size, use_bias=False, name='lm_head')(x)
-        return logits
+        with tracing.model_scope('head'):
+            x = nn.LayerNorm(dtype=jnp.float32, name='ln_f')(
+                x.astype(jnp.float32)
+            )
+            return nn.Dense(self.vocab_size, use_bias=False, name='lm_head')(x)
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
@@ -198,18 +206,19 @@ def _gated_attend(
     def heads_of(t, n):
         return t.reshape(*t.shape[:-1], n, head_dim)
 
-    q = rotary(
-        rms_norm(heads_of(q, heads), q_weight, eps), rotary_dim, theta
-    ).astype(dtype)
-    k = rotary(
-        rms_norm(heads_of(k, kv_heads), k_weight, eps), rotary_dim, theta
-    ).astype(dtype)
-    out = attention_lib.blockwise_causal_attention(
-        q, k, heads_of(v, kv_heads), chunk
-    )
-    return out.reshape(gate.shape) * jax.nn.sigmoid(
-        gate.astype(jnp.float32)
-    ).astype(dtype)
+    with tracing.model_scope('attention'):
+        q = rotary(
+            rms_norm(heads_of(q, heads), q_weight, eps), rotary_dim, theta
+        ).astype(dtype)
+        k = rotary(
+            rms_norm(heads_of(k, kv_heads), k_weight, eps), rotary_dim, theta
+        ).astype(dtype)
+        out = attention_lib.blockwise_causal_attention(
+            q, k, heads_of(v, kv_heads), chunk
+        )
+        return out.reshape(gate.shape) * jax.nn.sigmoid(
+            gate.astype(jnp.float32)
+        ).astype(dtype)
 
 
 class GatedAttention(nn.Module):
@@ -257,9 +266,12 @@ class HybridBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        y = RMSNorm(self.eps, name='norm1')(x)
-        x = x + self.make_mixer(name='mixer')(y).astype(x.dtype)
-        y = RMSNorm(self.eps, name='norm2')(x)
+        with tracing.model_scope('norm'):
+            y = RMSNorm(self.eps, name='norm1')(x)
+        with tracing.model_scope('mixer'):
+            x = x + self.make_mixer(name='mixer')(y).astype(x.dtype)
+        with tracing.model_scope('norm'):
+            y = RMSNorm(self.eps, name='norm2')(x)
         return x + self.make_moe(name='moe')(y).astype(x.dtype)
 
 
@@ -313,8 +325,9 @@ class HybridLM(nn.Module):
         logits of the whole batch never exist at once."""
         from kfac_tpu.models import deltanet
 
-        x = nn.Embed(self.vocab_size, self.d_model, name='embed')(tokens)
-        x = x.astype(jnp.float32)
+        with tracing.model_scope('embed'):
+            x = nn.Embed(self.vocab_size, self.d_model, name='embed')(tokens)
+            x = x.astype(jnp.float32)
         for i in range(self.num_layers):
             if (i + 1) % self.full_attention_interval == 0:
                 mixer = functools.partial(
@@ -337,19 +350,21 @@ class HybridLM(nn.Module):
                 dtype=self.dtype,
             )
             x = HybridBlock(mixer, moe, self.rms_eps, name=f'block{i}')(x)
-        x = RMSNorm(self.rms_eps, name='norm_f')(x)
-        head = nn.Dense(
-            self.vocab_size, use_bias=False, dtype=self.dtype, name='lm_head'
-        )
-        if targets is None:
-            return head(x)
-        seq = x.shape[1]
-        step = self.loss_chunk if seq % self.loss_chunk == 0 else seq
-        nll = jax.checkpoint(losses.vocab_parallel_nll)
-        return jnp.concatenate([
-            nll(head(x[:, i:i + step]), targets[:, i:i + step])
-            for i in range(0, seq, step)
-        ], axis=1)
+        with tracing.model_scope('head'):
+            x = RMSNorm(self.rms_eps, name='norm_f')(x)
+            head = nn.Dense(
+                self.vocab_size, use_bias=False, dtype=self.dtype,
+                name='lm_head',
+            )
+            if targets is None:
+                return head(x)
+            seq = x.shape[1]
+            step = self.loss_chunk if seq % self.loss_chunk == 0 else seq
+            nll = jax.checkpoint(losses.vocab_parallel_nll)
+            return jnp.concatenate([
+                nll(head(x[:, i:i + step]), targets[:, i:i + step])
+                for i in range(0, seq, step)
+            ], axis=1)
 
 
 def lm_loss(model: TransformerLM):
@@ -361,7 +376,8 @@ def lm_loss(model: TransformerLM):
         # fused NLL: no gather over the vocab axis, so a TP-sharded lm_head
         # (TRANSFORMER_TP_RULES marks it vocab-parallel) keeps the matmul
         # and softmax 1/tp per device (ops/losses.vocab_parallel_nll)
-        return jnp.mean(losses.vocab_parallel_nll(logits, targets))
+        with tracing.model_scope('loss'):
+            return jnp.mean(losses.vocab_parallel_nll(logits, targets))
 
     return loss_fn
 
@@ -373,6 +389,8 @@ def hybrid_lm_loss(model: HybridLM):
 
     def loss_fn(params, batch):
         tokens, targets = batch
-        return jnp.mean(model.apply({'params': params}, tokens, targets))
+        nll = model.apply({'params': params}, tokens, targets)
+        with tracing.model_scope('loss'):
+            return jnp.mean(nll)
 
     return loss_fn

@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from kfac_tpu import tracing
+
 
 def vocab_parallel_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Per-token negative log-likelihood, safe for vocab-sharded logits.
@@ -37,15 +39,16 @@ def vocab_parallel_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
     The backward is the textbook ``softmax - one_hot`` (autodiff of this
     form produces exactly that), so gradients are partitioned the same way.
     """
-    logits = logits.astype(jnp.float32)
-    # stop_gradient: the max-shift is a numerical offset whose gradient
-    # contributions cancel; detaching it saves the transpose ops.
-    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
-    shifted = logits - m
-    onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=logits.dtype)
-    # Both terms stay in shifted space (the m's cancel algebraically):
-    # adding m back before subtracting would cost ~ulp(|m|) of absolute
-    # precision at large logit magnitudes.
-    lse_shifted = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-    target_shifted = jnp.sum(shifted * onehot, axis=-1)
-    return lse_shifted - target_shifted
+    with tracing.model_scope('loss'):
+        logits = logits.astype(jnp.float32)
+        # stop_gradient: the max-shift is a numerical offset whose gradient
+        # contributions cancel; detaching it saves the transpose ops.
+        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+        shifted = logits - m
+        onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=logits.dtype)
+        # Both terms stay in shifted space (the m's cancel algebraically):
+        # adding m back before subtracting would cost ~ulp(|m|) of absolute
+        # precision at large logit magnitudes.
+        lse_shifted = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+        target_shifted = jnp.sum(shifted * onehot, axis=-1)
+        return lse_shifted - target_shifted

@@ -16,8 +16,11 @@ them: docs/OBSERVABILITY.md "A step that reports on itself"):
   joins the two by instruction name): the engine entry points
   (``dist_kfac.step`` / ``.update_factors`` / ``.update_inverses`` /
   ``.precondition``, :func:`scope`), the capture layer's two sides,
-  :data:`CAPTURE_SCOPES` (:func:`capture_scope`), and the model parts of
-  :data:`MODEL_SCOPES` (:func:`model_scope`);
+  :data:`CAPTURE_SCOPES` (:func:`capture_scope`), the model parts of
+  :data:`MODEL_SCOPES` (:func:`model_scope`), and the trainer's own part
+  of a step, :data:`TRAINER_SCOPES` (:func:`trainer_scope`). Together
+  they are one map of a step program: every operation lies under the
+  deepest of them on its ``op_name``, or under none;
 - host spans (``jax.profiler.TraceAnnotation``: they land on the
   profile's ``/host:CPU`` line in the device's time base):
   :data:`HOST_SPANS` inside ``Trainer.step`` (:func:`host_span`).
@@ -63,18 +66,46 @@ CAPTURE_SCOPES = {
     'experts': 'experts',
 }
 
-# Device scopes of the model parts that the sparse hybrid LMs add
-# (kfac_tpu/models/deltanet.py, models/moe.py, models/conv_moe.py): the
-# chunked delta-rule scan (forward, and its backward pass under
-# ``transpose(jvp(...))``), the router with its top-k and row plan, the
-# grouped expert products, and the gated short convolution between its
-# projections (``B * x~``, the depthwise taps, ``C * conv``).
+# Device scopes of the model's parts, one vocabulary over the model
+# families (kfac_tpu/models/): an operation belongs to the deepest scope on
+# its path, so what is under ``model.mixer`` and under nothing deeper is a
+# token mixer's projections and glue, ``model.attention`` the attention
+# core between them (QK-norm, rotary, scores, softmax or the flash
+# partials, values, the gate), ``model.gdn_scan`` the chunked delta-rule
+# scan and ``model.short_conv`` the gated short convolution (``B * x~``,
+# the depthwise taps, ``C * conv``). ``model.mlp`` is a dense or gated MLP
+# (a shared expert with its gate too), ``model.moe_route`` the router with
+# its top-k and row plan, ``model.moe_experts`` the grouped expert
+# products. ``model.norm`` is a block's norms (QK-norm is the attention
+# core's, the final norm the head's), ``model.head`` the final norm with
+# the (tied, chunked) head, or a ResNet's pool and classifier,
+# ``model.loss`` the loss, inside the head where the head computes it in
+# chunks. A ResNet reads by ``model.stem`` and ``model.stage<n>``. The
+# backward pass carries the same names under ``transpose(jvp(...))``, and
+# a rematerialised forward carries them again.
 MODEL_SCOPES = {
+    'embed': 'model.embed',
+    'mixer': 'model.mixer',
+    'attention': 'model.attention',
     'gdn_scan': 'model.gdn_scan',
+    'short_conv': 'model.short_conv',
+    'mlp': 'model.mlp',
     'moe_route': 'model.moe_route',
     'moe_experts': 'model.moe_experts',
-    'short_conv': 'model.short_conv',
+    'norm': 'model.norm',
+    'head': 'model.head',
+    'loss': 'model.loss',
+    'stem': 'model.stem',
+    'stage0': 'model.stage0',
+    'stage1': 'model.stage1',
+    'stage2': 'model.stage2',
+    'stage3': 'model.stage3',
 }
+
+# Device scope of the trainer's own part of a step program
+# (kfac_tpu/training.py): the optimizer's update and its application to
+# the parameters. Every step program a Trainer lowers carries it.
+TRAINER_SCOPES = {'optimizer': 'trainer.optimizer'}
 
 # Host spans inside one Trainer step, in order: what runs before the jitted
 # call (async-inverse and offload pumps, the cadence decision), the call
@@ -194,6 +225,11 @@ def capture_scope(side: str):
 def model_scope(part: str):
     """``jax.named_scope`` of one of :data:`MODEL_SCOPES`."""
     return jax.named_scope(MODEL_SCOPES[part])
+
+
+def trainer_scope(part: str):
+    """``jax.named_scope`` of one of :data:`TRAINER_SCOPES`."""
+    return jax.named_scope(TRAINER_SCOPES[part])
 
 
 def host_span(part: str, step: int | None):

@@ -230,10 +230,11 @@ class Trainer:
         )
 
     def _apply_update(self, state: TrainState, grads, new_model_state):
-        updates, opt_state = self.optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        with tracing.trainer_scope('optimizer'):
+            updates, opt_state = self.optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         return params, opt_state, new_model_state
 
     def _health_cfg(self):

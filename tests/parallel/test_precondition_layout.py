@@ -475,7 +475,8 @@ def test_benchmark_row_reads_the_engines_counter():
     assert harness.read_layer_metric(name, ctx(types.SimpleNamespace())) is None
     with open(os.path.join(harness.ROOT, 'BENCHMARK.json')) as f:
         bench = json.load(f)
-    assert bench['per_layer'][-1] == {
+    # by name, wherever the row stands: later PRs append rows behind it
+    assert next(m for m in bench['per_layer'] if m['name'] == name) == {
         'name': name, 'unit': '%', 'better': 'higher',
         'source': 'program_counter', 'layer': 'engine',
         'moves': 'kfac_overhead',
