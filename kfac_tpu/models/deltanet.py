@@ -18,6 +18,26 @@ chunk boundaries, in a ``lax.scan``. Its backward pass is the scan's own
 reverse-mode rule: the g-taps of the projections around the mixer see
 exactly the cotangents that flow through it.
 
+How the system is solved (:func:`unit_lower_solve`): ``I + A`` is cut into
+diagonal blocks of ``SOLVE_BLOCK`` rows (the chunk itself where it is
+shorter), every block of every chunk and head is inverted at once by the
+unrolled row substitution, the systems in the vectors' lanes, neighbouring
+blocks merge to the whole inverse (``T21 = -T22 L21 T11``, two levels for a
+chunk of 64), and ``U = T rhs`` is one product at ``HIGHEST``. The backward
+pass is a ``jax.custom_vjp``: ``rhs_bar = T^T U_bar`` and ``A_bar =
+-tril(rhs_bar U^T, -1)``, two products with the inverse the forward pass
+built, so no second solve runs and none is differentiated through. Not
+``jax.scipy.linalg.solve_triangular``: on the TPU it becomes XLA's
+``InvertDiagBlocksLowerTriangular`` custom call, which inverts each 64 x 64
+block whole by a serial row algorithm while the MXU idles (688 us for the
+512 systems of a head group, four times a group and layer with its
+transposed twin in the backward pass: 40 ms of Qwen's 203 ms step). Not the
+Neumann doubling ``(I - A)(I + A^2)(I + A^4)...`` on the whole block
+either: it is exact only in exact arithmetic, the powers of ``A`` reach
+C(63, 31) on correlated keys (neighbouring tokens behind the causal
+convolution are), and float32 then errs by 1e9 where the blocked form and
+``solve_triangular`` err by 2e-7 (PERF.md section 6, PR 39).
+
 What K-FAC does not factor here (``layers/registry.py`` pass-through
 rule): the depthwise ``conv1d``, ``A_log``, ``dt_bias`` and the gated
 norm's ``scale``. The six input projections and the output projection are
@@ -36,6 +56,91 @@ from jax import lax
 from kfac_tpu import tracing
 
 HI = lax.Precision.HIGHEST
+
+
+# Rows of a diagonal block of a chunk's system: a block is inverted by row
+# substitution, one elementwise step a row, and blocks merge in pairs.
+SOLVE_BLOCK = 16
+
+
+def _product(x, y):
+    """``x @ y`` for ``(r, k, M)`` and ``(k, c, M)`` with the systems in the
+    last (lane) axis: ``k`` multiply-adds over ``(r, c, M)`` on the vector
+    unit, exact float32. The blocks are too small for the MXU."""
+    return jnp.sum(x[:, :, None] * y, axis=1)
+
+
+def _substitute(d):
+    """``(I + d)^-1`` for ``d`` ``(b, b, M)`` strictly lower triangular, by
+    rows: row ``i`` of the inverse is ``e_i - sum_{k<i} d_ik (row k)``."""
+    b = d.shape[0]
+    t = jnp.broadcast_to(jnp.eye(b, dtype=d.dtype)[:, :, None], d.shape)
+    d = d[:, :, None]
+    for i in range(1, b):
+        t = t.at[i].add(-jnp.sum(d[i, :i] * t[:i], axis=0))
+    return t
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for ``a`` ``(..., C, C)`` strictly lower triangular."""
+    lead, c = a.shape[:-2], a.shape[-1]
+    blk = min(SOLVE_BLOCK, c)
+    nb = 1 << (-(-c // blk) - 1).bit_length()
+    size = nb * blk
+    # the systems go to the last axis: every step below is elementwise
+    # over them, and C alone would fill an eighth of a vector's lanes
+    a = jnp.moveaxis(a.reshape(-1, c, c), 0, -1)
+    a = jnp.pad(a, ((0, size - c), (0, size - c), (0, 0)))
+    m = a.shape[-1]
+
+    def blocks(below, step):  # the diagonal's, or those under it: side by side
+        return jnp.concatenate([
+            a[i + below:i + below + blk, i:i + blk]
+            for i in range(0, size, step)
+        ], axis=-1)
+
+    t = _substitute(blocks(0, blk))
+    while blk < size:
+        # [[T11, 0], [-T22 L21 T11, T22]] of each pair of neighbours
+        t11, t22 = (
+            jnp.concatenate([
+                t[:, :, i:i + m] for i in range(first, t.shape[-1], 2 * m)
+            ], axis=-1)
+            for first in (0, m)
+        )
+        t21 = -_product(t22, _product(blocks(blk, 2 * blk), t11))
+        t = jnp.concatenate([
+            jnp.concatenate([t11, jnp.zeros_like(t11)], axis=1),
+            jnp.concatenate([t21, t22], axis=1),
+        ])
+        blk *= 2
+    return jnp.moveaxis(t[:c, :c], -1, 0).reshape(*lead, c, c)
+
+
+@jax.custom_vjp
+def unit_lower_solve(a, rhs):
+    """``U`` of ``(I + a) U = rhs`` for ``a`` ``(..., C, C)`` strictly
+    lower triangular and ``rhs`` ``(..., C, n)``, float32: the inverse by
+    blocks (module docstring), then one product at ``HIGHEST``."""
+    return _unit_lower_solve_fwd(a, rhs)[0]
+
+
+def _unit_lower_solve_fwd(a, rhs):
+    t = _unit_lower_inverse(a)
+    u = jnp.matmul(t, rhs, precision=HI)
+    return u, (t, u)
+
+
+def _unit_lower_solve_bwd(res, u_bar):
+    # the transposed solve is a product with the inverse the forward pass
+    # built: no second substitution, and none differentiated
+    t, u = res
+    rhs_bar = jnp.einsum('...ji,...jn->...in', t, u_bar, precision=HI)
+    a_bar = -jnp.einsum('...in,...jn->...ij', rhs_bar, u, precision=HI)
+    return jnp.tril(a_bar, -1), rhs_bar
+
+
+unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
@@ -80,11 +185,7 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
     rhs = jnp.concatenate(
         [v * beta[..., None], kb * jnp.exp(b)[..., None]], axis=-1
     )
-    with jax.default_matmul_precision('float32'):
-        sol = jax.scipy.linalg.solve_triangular(
-            a + jnp.eye(chunk, dtype=a.dtype), rhs, lower=True,
-            unit_diagonal=True,
-        )
+    sol = unit_lower_solve(a, rhs)
     w, kc = sol[..., :dv], sol[..., dv:]
     qk = jnp.einsum('...id,...jd->...ij', q, k) * decay
 

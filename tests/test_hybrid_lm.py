@@ -423,6 +423,77 @@ def test_chunked_scan_is_the_recurrence(t, chunk):
         np.testing.assert_allclose(got_g, want_g, rtol=2e-4, atol=2e-5)
 
 
+def _chunk_system(keys, c, seed=0):
+    """One chunk's ``a`` and right-hand side as the scan builds them, for
+    three kinds of keys: drawn apart; neighbours 0.9-correlated with
+    ``beta`` 0.98 and hardly any decay; one key repeated with ``beta`` 1
+    and no decay, where the inverse's entries grow fastest."""
+    lead, d, n = (3, 2), 16, 12
+    key = jax.random.split(jax.random.PRNGKey(seed), 4)
+    k = jax.random.normal(key[0], lead + (c, d))
+    beta = jax.nn.sigmoid(jax.random.normal(key[1], lead + (c,)))
+    g = -jax.nn.softplus(jax.random.normal(key[2], lead + (c,)))
+    if keys == 'correlated':
+        k = 0.9 * k[..., :1, :] + (1 - 0.81) ** 0.5 * k
+        beta, g = jnp.full_like(beta, 0.98), jnp.full_like(g, -1e-3)
+    elif keys == 'repeated':
+        k = jnp.broadcast_to(k[..., :1, :], k.shape)
+        beta, g = jnp.ones_like(beta), jnp.zeros_like(g)
+    k = deltanet.l2norm(k, eps=0.0)
+    b = jnp.cumsum(g, axis=-1)
+    a = jnp.einsum('...id,...jd->...ij', k * beta[..., None], k)
+    a = jnp.tril(a * jnp.exp(b[..., :, None] - b[..., None, :]), -1)
+    return a, jax.random.normal(key[3], lead + (c, n))
+
+
+@pytest.mark.parametrize('c', [64, 8, 5])
+@pytest.mark.parametrize('keys', ['random', 'correlated', 'repeated'])
+def test_unit_lower_solve_is_the_float64_solve(keys, c):
+    """Forward within 2e-6 of the largest entry and both cotangents within
+    2e-5, at the cell's chunk, at one block and at a chunk no block
+    divides."""
+    a, rhs = _chunk_system(keys, c)
+    probe = jax.random.normal(jax.random.PRNGKey(7), rhs.shape)
+
+    def value_and_cotangents(solve, *args):
+        return solve(*args), jax.grad(
+            lambda *z: jnp.sum(solve(*z) * probe), (0, 1)
+        )(*args)
+
+    with jax.enable_x64():
+        want, want_bar = value_and_cotangents(
+            lambda a, rhs: jax.scipy.linalg.solve_triangular(
+                a + jnp.eye(c), rhs, lower=True, unit_diagonal=True
+            ),
+            np.asarray(a, np.float64), np.asarray(rhs, np.float64),
+        )
+    got, got_bar = value_and_cotangents(deltanet.unit_lower_solve, a, rhs)
+    assert got.dtype == jnp.float32
+    for g, w, tol in zip(
+        (got, *got_bar), (want, *want_bar), (2e-6, 2e-5, 2e-5)
+    ):
+        g, w = np.asarray(g, np.float64), np.asarray(w)
+        assert w.dtype == np.float64
+        assert np.abs(g - w).max() <= tol * np.abs(w).max()
+    assert not np.triu(got_bar[0]).any()
+
+
+def test_no_triangular_solve_in_the_scan_or_its_gradient():
+    """XLA lowers ``triangular_solve`` on the TPU to a custom call that
+    inverts each block whole on one serial row algorithm (688 us for the
+    cell's 512 systems, 48 times a step: PERF.md section 6, PR 39), and a
+    CPU run cannot see it come back: the jaxpr can."""
+    inputs = _scan_inputs(16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(deltanet.chunk_gated_delta_rule(*a, chunk=8)),
+        argnums=range(5),
+    ))(*inputs)
+    assert 'triangular_solve' not in str(jaxpr)
+    assert 'custom_vjp_call' in str(jax.make_jaxpr(
+        lambda *a: deltanet.chunk_gated_delta_rule(*a, chunk=8)
+    )(*inputs))
+
+
 def test_blockwise_attention_is_dense_attention_with_grouped_queries():
     key = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(key[0], (2, 32, 4, 8))

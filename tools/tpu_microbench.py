@@ -513,6 +513,131 @@ def bench_bubble() -> None:
             }), flush=True)
 
 
+def _gdn_system(chunks, heads, c, n):
+    """A chunk's system as ``chunk_gated_delta_rule`` builds it: ``a``
+    strictly lower ``(chunks, 1, heads, c, c)`` from normalised keys,
+    ``rhs`` ``(..., c, n)``. The keys are 0.9-correlated with ``beta`` 0.98
+    and hardly any decay: what neighbouring tokens behind the causal
+    convolution give, and the hard case for the solve."""
+    from kfac_tpu.models import deltanet
+
+    lead = (chunks, 1, heads)
+    key = jax.random.split(jax.random.PRNGKey(11), 2)
+    k = jax.random.normal(key[0], lead + (c, 128), jnp.float32)
+    k = deltanet.l2norm(0.9 * k[..., :1, :] + (1 - 0.81) ** 0.5 * k, 0.0)
+    b = -1e-3 * jnp.arange(1, c + 1, dtype=jnp.float32)
+    a = 0.98 * jnp.einsum('...id,...jd->...ij', k, k, precision='highest')
+    a = jnp.tril(a * jnp.exp(b[:, None] - b[None, :]), -1)
+    return a, jax.random.normal(key[1], lead + (c, n), jnp.float32)
+
+
+def bench_gdn_solve(iters: int) -> None:
+    """The DeltaNet chunk's unit-triangular system ``(I + a) U = rhs`` at
+    the Qwen cell's shape (64 chunks x 8 heads of 64 x 64, 256 columns),
+    forward and forward + backward, one dispatch a measurement:
+
+    - ``xla``: ``jax.scipy.linalg.solve_triangular`` (the
+      ``InvertDiagBlocksLowerTriangular`` custom call and a product),
+    - ``merged_b<k>``: ``deltanet.unit_lower_solve`` (diagonal blocks of k
+      rows by substitution, merged to the whole inverse, one product; its
+      backward pass reuses the inverse),
+    - ``rows_b16``: the four block rows of the substitution run on the
+      right-hand side itself, products 16 deep, backward the same
+      transposed,
+    - ``floor``: one pass that reads both operands and writes the result.
+
+    Each line carries the worst error of the forward result against a
+    float64 solve on the host, over the largest entry."""
+    import numpy as np
+
+    from kfac_tpu.models import deltanet
+
+    hi = jax.lax.Precision.HIGHEST
+    c, n = 64, 256
+    a, rhs = _gdn_system(64, 8, c, n)
+    probe = jax.random.normal(jax.random.PRNGKey(12), rhs.shape)
+
+    def xla(a, rhs):
+        with jax.default_matmul_precision('float32'):
+            return jax.scipy.linalg.solve_triangular(
+                a + jnp.eye(c, dtype=a.dtype), rhs, lower=True,
+                unit_diagonal=True,
+            )
+
+    blk = 16
+
+    def rows_fwd(a, rhs):
+        t, us = deltanet._unit_lower_inverse(jnp.stack([
+            a[..., i:i + blk, i:i + blk] for i in range(0, c, blk)
+        ], axis=-3)), []
+        for p, i in enumerate(range(0, c, blk)):
+            r = rhs[..., i:i + blk, :]
+            if us:
+                r = r - jnp.matmul(
+                    a[..., i:i + blk, :i], jnp.concatenate(us, -2),
+                    precision=hi,
+                )
+            us.append(jnp.matmul(t[..., p, :, :], r, precision=hi))
+        u = jnp.concatenate(us, -2)
+        return u, (a, t, u)
+
+    def rows_bwd(res, u_bar):
+        a, t, u = res
+        gs = []
+        for p, i in reversed(list(enumerate(range(0, c, blk)))):
+            r = u_bar[..., i:i + blk, :]
+            if gs:
+                r = r - jnp.einsum(
+                    '...ji,...jn->...in', a[..., i + blk:, i:i + blk],
+                    jnp.concatenate(gs, -2), precision=hi,
+                )
+            gs.insert(0, jnp.einsum(
+                '...ji,...jn->...in', t[..., p, :, :], r, precision=hi
+            ))
+        g = jnp.concatenate(gs, -2)
+        a_bar = -jnp.einsum('...in,...jn->...ij', g, u, precision=hi)
+        return jnp.tril(a_bar, -1), g
+
+    rows = jax.custom_vjp(lambda a, rhs: rows_fwd(a, rhs)[0])
+    rows.defvjp(rows_fwd, rows_bwd)
+
+    def merged(blk):
+        def solve(a, rhs):
+            # the block size is read when the function is traced
+            deltanet.SOLVE_BLOCK, keep = blk, deltanet.SOLVE_BLOCK
+            try:
+                return deltanet.unit_lower_solve(a, rhs)
+            finally:
+                deltanet.SOLVE_BLOCK = keep
+        return solve
+
+    forms = {'floor': lambda a, rhs: rhs + a[..., :1], 'xla': xla,
+             'rows_b16': rows}
+    forms.update({f'merged_b{k}': merged(k) for k in (8, 16, 32)})
+    few = slice(0, 2)
+    want = np.linalg.solve(
+        np.eye(c) + np.asarray(a[few], np.float64),
+        np.asarray(rhs[few], np.float64),
+    )
+    for name, solve in forms.items():
+        fwd = jax.jit(solve)
+        both = jax.jit(lambda a, rhs, solve=solve: jax.grad(
+            lambda a, rhs: jnp.sum(solve(a, rhs) * probe), (0, 1)
+        )(a, rhs))
+
+        def err(_t, fwd=fwd):
+            got = np.asarray(fwd(a, rhs)[few], np.float64)
+            return {'fwd_err': float(
+                np.abs(got - want).max() / np.abs(want).max()
+            )}
+
+        measured(f'gdn_solve_{name}_fwd',
+                 lambda k, f=fwd: timeit(f, a, rhs, iters=k), iters,
+                 post=None if name == 'floor' else err)
+        measured(f'gdn_solve_{name}_fwd_bwd',
+                 lambda k, f=both: timeit(f, a, rhs, iters=k), iters)
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument('--sizes', type=int, nargs='*',
@@ -529,6 +654,9 @@ def main():
     p.add_argument('--bubble', action='store_true',
                    help='interleaved-1F1B schedule bubble fractions '
                    '(pure schedule math, no device work)')
+    p.add_argument('--gdn-solve', action='store_true',
+                   help="the DeltaNet chunk's unit-triangular solve: "
+                   "XLA's call against the blocked forms")
     p.add_argument('--skip-factor-ops', action='store_true')
     p.add_argument('--dispatch', choices=['fori_loop', 'legacy'],
                    help='measurement dispatch mode: fori_loop (default; '
@@ -758,6 +886,8 @@ def main():
         bench_vocab_head(args.iters)
     if args.bubble:
         bench_bubble()
+    if args.gdn_solve:
+        bench_gdn_solve(args.iters)
 
 
 if __name__ == '__main__':
