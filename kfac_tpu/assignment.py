@@ -181,6 +181,7 @@ def greedy_assign(
     worker_groups: list[tuple[int, ...]],
     world_size: int,
     colocate_factors: bool = True,
+    a_groups: dict[str, str] | None = None,
 ) -> dict[str, dict[str, int]]:
     """Least-loaded greedy placement of factor work onto devices.
 
@@ -191,34 +192,58 @@ def greedy_assign(
     whole layer goes to the least-loaded device (``colocate_factors``) or
     each factor does, heaviest first. Reference algorithm:
     kfac/assignment.py:227-319.
+
+    ``a_groups`` (member -> leader, ``Registry.a_groups``): the layers of
+    a group share one A factor, so a group is placed as a unit, in one
+    worker group (its members' gradient workers hold the one inverse), at
+    the cost of one A solve, the leader's, and its members' G solves; a
+    follower's ``'A'`` reads its leader's device.
     """
+    a_groups = a_groups or {}
     loads = [0.0] * world_size
-    totals = {layer: sum(fs.values()) for layer, fs in work.items()}
-    order = sorted(work, key=lambda layer: totals[layer], reverse=True)
+    # unit (a group's leader, or a layer alone) -> its members' own work
+    units: dict[str, dict[str, dict[str, float]]] = {}
+    for layer, factors in work.items():
+        leader = a_groups.get(layer, layer)
+        units.setdefault(leader, {})[layer] = {
+            f: c for f, c in factors.items() if f != 'A' or leader == layer
+        }
+    totals = {
+        unit: sum(sum(fs.values()) for fs in members.values())
+        for unit, members in units.items()
+    }
+    order = sorted(units, key=lambda unit: totals[unit], reverse=True)
     placement: dict[str, dict[str, int]] = {}
 
     def least_loaded(devices: Iterable[int]) -> int:
         return min(devices, key=lambda d: (loads[d], d))
 
-    for layer in order:
+    for unit in order:
         group = min(
             worker_groups,
             key=lambda g: (sum(loads[d] for d in g), g),
         )
-        placement[layer] = {}
-        if colocate_factors:
-            dev = least_loaded(group)
-            loads[dev] += totals[layer]
-            for factor in work[layer]:
-                placement[layer][factor] = dev
-        else:
-            heaviest_first = sorted(
-                work[layer].items(), key=lambda kv: (kv[1], kv[0]), reverse=True
-            )
-            for factor, cost in heaviest_first:
+        for layer, factors in units[unit].items():
+            placement[layer] = {}
+            if colocate_factors:
                 dev = least_loaded(group)
-                loads[dev] += cost
-                placement[layer][factor] = dev
+                loads[dev] += sum(factors.values())
+                for factor in factors:
+                    placement[layer][factor] = dev
+            else:
+                heaviest_first = sorted(
+                    factors.items(), key=lambda kv: (kv[1], kv[0]),
+                    reverse=True,
+                )
+                for factor, cost in heaviest_first:
+                    dev = least_loaded(group)
+                    loads[dev] += cost
+                    placement[layer][factor] = dev
+    for layer, leader in a_groups.items():
+        if layer != leader and layer in placement and leader in placement:
+            placement[layer] = {
+                'A': placement[leader]['A'], **placement[layer]
+            }
     return placement
 
 
@@ -234,6 +259,8 @@ class KAISAAssignment(WorkAssignment):
         colocate_factors: place A and G of a layer on the same device
             (required for MEM-OPT, as in reference
             kfac/preconditioner.py:202-211).
+        a_groups: layers that share one A factor, member -> leader: a
+            group is one unit of placement (:func:`greedy_assign`).
     """
 
     def __init__(
@@ -243,6 +270,7 @@ class KAISAAssignment(WorkAssignment):
         world_size: int,
         grad_worker_fraction: float = 1.0,
         colocate_factors: bool = True,
+        a_groups: dict[str, str] | None = None,
     ) -> None:
         self.world_size = world_size
         self.grad_workers = grad_worker_count(world_size, grad_worker_fraction)
@@ -261,7 +289,7 @@ class KAISAAssignment(WorkAssignment):
         self._rows = partition_grad_receivers(world_size, self.grad_workers)
         self.n_cols = len(self._columns)
         self._placement = greedy_assign(
-            work, self._columns, world_size, colocate_factors
+            work, self._columns, world_size, colocate_factors, a_groups
         )
         # Column of a layer = the column containing its inverse worker(s).
         self._layer_column: dict[str, tuple[int, ...]] = {}

@@ -171,16 +171,24 @@ class StaticLayout:
             world, grad_worker_fraction
         )
         self.granularity = int(config.bucket_granularity)
+        self.a_groups = kaisa_lib.stored_a_groups(config)
         self.buckets = kaisa_lib.build_buckets(
-            self.registry, world, self.granularity
+            self.registry, world, self.granularity, self.a_groups
         )
         self.colocate = bool(config.colocate_factors)
         self.a_store, self.g_store = kaisa_lib.build_stores(
             self.registry, world, self.granularity, self.colocate,
-            self.buckets,
+            self.buckets, self.a_groups,
         )
         self._eigen = config.compute_method == enums.ComputeMethod.EIGEN
         self._prediv = self._eigen and config.prediv_eigenvalues
+        # as the engine: a synchronous Newton-Schulz refresh's counters
+        # ride with the inverse stacks (``comms.decomp_reshard_bytes``)
+        self._ns_refresh = (
+            not self._eigen
+            and config.inverse_solver in kaisa_lib._NS_SOLVERS
+            and config.async_inverse is None
+        )
 
     def comms_report(self) -> dict[str, Any]:
         from kfac_tpu.observability import comms as comms_lib

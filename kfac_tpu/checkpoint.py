@@ -58,6 +58,12 @@ def layout_manifest(engine: Any) -> dict[str, Any]:
         man['g_store'] = [_bucket_entry(sb) for sb in engine.g_store]
     if hasattr(engine, 'n_stages'):  # pipeline engine
         man['n_stages'] = int(engine.n_stages)
+    # the A groups the engine stores one A factor for (member -> leader):
+    # the payload holds a group's A under its leader alone. Absent where
+    # there is none, so that a layout without groups reads as it did.
+    groups = getattr(engine, 'a_groups', None)
+    if groups:
+        man['a_groups'] = dict(groups)
     return man
 
 
@@ -75,7 +81,7 @@ def _bucket_entry(sb: Any) -> dict[str, Any]:
 # (compute_method does not: only step + a + g are durable).
 _LAYOUT_KEYS = (
     'engine', 'bucket_granularity', 'colocate_factors', 'a_store',
-    'g_store', 'n_stages',
+    'g_store', 'n_stages', 'a_groups',
 )
 
 
@@ -117,12 +123,17 @@ def _factors_from_saved(
                 for i, name in enumerate(entry['layers']):
                     d = entry['dims'][i]
                     out.setdefault(name, {})[side] = stack[i, :d, :d]
-        return out
-    # dense payload: already layer-keyed
-    for name, a in kfac_payload['a'].items():
-        out.setdefault(name, {})['a'] = a
-    for name, g in kfac_payload['g'].items():
-        out.setdefault(name, {})['g'] = g
+    else:
+        # dense payload: already layer-keyed
+        for name, a in kfac_payload['a'].items():
+            out.setdefault(name, {})['a'] = a
+        for name, g in kfac_payload['g'].items():
+            out.setdefault(name, {})['g'] = g
+    # a follower of an A group was saved without an A of its own: its
+    # leader's is it
+    for name, leader in saved_man.get('a_groups', {}).items():
+        if name in out and 'a' not in out[name] and leader in out:
+            out[name]['a'] = out[leader]['a']
     return out
 
 

@@ -40,12 +40,9 @@ def layer_input(module: nn.Module, a: jax.Array) -> jax.Array:
     (:func:`kfac_tpu.ops.cov.get_cov`). The G side needs no such step: a
     cotangent arrives in the dtype of the layer's output.
     """
-    dtype = getattr(module, 'dtype', None)
-    if dtype is None:
-        dtype = jnp.promote_types(
-            a.dtype, getattr(module, 'param_dtype', a.dtype)
-        )
-    return jax.lax.stop_gradient(a).astype(dtype)
+    return jax.lax.stop_gradient(a).astype(
+        registry_lib.compute_dtype(module, a.dtype)
+    )
 
 
 def _make_gtap(helper: helpers_lib.LayerHelper) -> Callable[..., jax.Array]:
@@ -189,6 +186,23 @@ class CurvatureCapture:
             tap: _make_role_gtap(registry.layers[unit], role)
             for tap, (unit, role) in registry.taps.items()
         }
+        # layers (and stacked projections, whose experts follow slot by
+        # slot) that read their group leader's A factor: g-tapped like any
+        # other, their A contraction left to the leader
+        # (``Registry.a_groups``)
+        self._followers = {
+            n: leader for n, leader in registry.a_groups.items()
+            if leader != n
+        }
+        slot_stack = {
+            slot: name for name, tap in registry.stacks.items()
+            for slot in tap.slots
+        }
+        self._follower_stacks = {
+            name: slot_stack[self._followers[tap.slots[0]]]
+            for name, tap in registry.stacks.items()
+            if tap.slots[0] in self._followers
+        }
 
     def zero_gstats(self) -> dict[str, Any]:
         """Zero dummy arguments whose gradients are the G factors.
@@ -235,25 +249,49 @@ class CurvatureCapture:
         gtaps = self._gtaps
         role_gtaps = self._role_gtaps
         stack_gtaps = self._stack_gtaps
+        followers = self._followers
+        follower_stacks = self._follower_stacks
 
         def wrapped(params: Any, gstats: dict[str, jax.Array], *args: Any, **kwargs: Any):
             a_stats: dict[str, jax.Array] = {}
             counts: dict[str, jax.Array] = {}
             weights: dict[str, jax.Array] = {}
+            # what each group leader was last handed: a follower has to
+            # meet the very same arrays, as it did at the probe
+            led: dict[str, tuple] = {}
 
             def accumulate(name, fac, weight=None):
                 # repeated invocations of one layer sum; ``counts`` (and,
-                # for weighted layers, the summed weights) divide in run()
-                if name in a_stats:
-                    a_stats[name] = a_stats[name] + fac
+                # for weighted layers, the summed weights) divide in run().
+                # ``fac`` None: a follower of an A group, which counts its
+                # invocations (its G sums divide by them) and contracts
+                # nothing
+                if name in counts:
+                    if fac is not None:
+                        a_stats[name] = a_stats[name] + fac
                     counts[name] = counts[name] + 1
                     if weight is not None:
                         weights[name] = weights[name] + weight
                 else:
-                    a_stats[name] = fac
+                    if fac is not None:
+                        a_stats[name] = fac
                     counts[name] = jnp.asarray(1, dtype=jnp.int32)
                     if weight is not None:
                         weights[name] = weight
+
+            def follows(name, leader, *handed):
+                got = led.get(leader)
+                if got is None or len(got) != len(handed) or any(
+                    x is not y for x, y in zip(got, handed)
+                ):
+                    raise ValueError(
+                        f'{name!r} shares the A factor of {leader!r} '
+                        '(Registry.a_groups: the probe saw both handed one '
+                        'array), but this loss_fn hands it another array, '
+                        'or calls it first. Register the model the way the '
+                        'loss applies it, or empty the map '
+                        '(dataclasses.replace(registry, a_groups={})).'
+                    )
 
             def role_tap(mod, name, iargs, ikwargs, next_fun):
                 # fused-unit child projection: embed this role's A block
@@ -276,14 +314,19 @@ class CurvatureCapture:
                 # row, 0; one g-tap on the projection's output
                 tap = registry.stacks[name]
                 x, plan = jax.lax.stop_gradient(iargs[0]), iargs[1]
+                shared = name in follower_stacks
+                if shared:
+                    follows(name, follower_stacks[name], iargs[0], plan)
+                else:
+                    led[name] = (iargs[0], plan)
                 with tracing.capture_scope('a'), tracing.capture_scope(
                     'experts'
                 ):
                     # no ``* live``: zero sums without rows already
                     live = tap.live(plan)
-                    facs = tap.a_factors(x, plan)
+                    facs = None if shared else tap.a_factors(x, plan)
                 for j, slot in enumerate(tap.slots):
-                    accumulate(slot, facs[j], live[j])
+                    accumulate(slot, None if shared else facs[j], live[j])
                 y = next_fun(*iargs, **ikwargs)
                 return stack_gtaps[name](y, gstats[name], plan)
 
@@ -303,9 +346,14 @@ class CurvatureCapture:
                     if name in registry.taps:
                         return role_tap(mod, name, iargs, ikwargs, next_fun)
                     return next_fun(*iargs, **ikwargs)
+                shared = name in followers
+                if shared:
+                    follows(name, followers[name], iargs[0])
+                elif name in registry.a_groups:
+                    led[name] = (iargs[0],)
                 with tracing.capture_scope('a'):
                     a = layer_input(mod, iargs[0])
-                    a_fac = helper.get_a_factor(a)
+                    a_fac = None if shared else helper.get_a_factor(a)
                     if helper.weighted:
                         # traffic-weighted accumulation: sum w_i * F_i
                         # here, divide by sum w_i in run() — a repeated
@@ -314,7 +362,8 @@ class CurvatureCapture:
                         # average toward zero (same convention as
                         # accumulate_stats/average_stats)
                         w = helper.capture_weight(a)
-                        a_fac = a_fac * w
+                        if not shared:
+                            a_fac = a_fac * w
                 accumulate(name, a_fac, w if helper.weighted else None)
                 y = next_fun(*iargs, **ikwargs)
                 return gtaps[name](y, gstats[name])
@@ -353,7 +402,7 @@ class CurvatureCapture:
             g_sums, g_weights = split_g_stats(g_stats)
             a_avg = weighted_average(a_stats, counts, weights)
             g_avg = weighted_average(
-                {n: g_sums[n] for n in a_stats}, counts, g_weights
+                {n: g_sums[n] for n in counts}, counts, g_weights
             )
             w_avg = {
                 n: weights[n] / counts[n].astype(weights[n].dtype)
@@ -368,6 +417,10 @@ class CurvatureCapture:
 @jax.tree_util.register_pytree_node_class
 class CapturedStats:
     """Per-batch factor statistics: name -> A and name -> G matrices.
+
+    ``g`` has every captured layer; ``a`` the layers that contracted an A
+    of their own: a follower of an A group (``Registry.a_groups``) reads
+    its leader's entry.
 
     ``w`` optionally carries per-layer evidence weights in [0, 1] (routed
     MoE layers: the live-row fraction). Engines use them to weight the
@@ -395,25 +448,17 @@ class CapturedStats:
         self.traffic = {} if traffic is None else traffic
 
     def tree_flatten(self):
-        names = sorted(self.a)
-        wnames = sorted(self.w)
-        tnames = sorted(self.traffic)
-        leaves = (
-            tuple(self.a[n] for n in names)
-            + tuple(self.g[n] for n in names)
-            + tuple(self.w[n] for n in wnames)
-            + tuple(self.traffic[n] for n in tnames)
-        )
-        return leaves, (tuple(names), tuple(wnames), tuple(tnames))
+        # ``a`` and ``g`` each under their own names: a follower of an A
+        # group has a G statistic and no A of its own
+        groups = (self.a, self.g, self.w, self.traffic)
+        names = tuple(tuple(sorted(d)) for d in groups)
+        leaves = tuple(d[n] for d, ns in zip(groups, names) for n in ns)
+        return leaves, names
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        names, wnames, tnames = aux
-        n, m = len(names), len(wnames)
-        a = dict(zip(names, leaves[:n]))
-        g = dict(zip(names, leaves[n:2 * n]))
-        w = dict(zip(wnames, leaves[2 * n:2 * n + m]))
-        traffic = dict(zip(tnames, leaves[2 * n + m:]))
+        it = iter(leaves)
+        a, g, w, traffic = ({n: next(it) for n in ns} for ns in aux)
         return cls(a=a, g=g, w=w, traffic=traffic)
 
     def scaled(self, grad_scale: jax.Array | float) -> 'CapturedStats':
@@ -429,6 +474,17 @@ class CapturedStats:
             w=self.w,
             traffic=self.traffic,
         )
+
+
+def a_stat(
+    stats: CapturedStats, registry: registry_lib.Registry, name: str
+) -> jax.Array | None:
+    """``name``'s A statistic of a capture, ``None`` where the capture did
+    not run the layer: its own entry, or the one filed under its A group's
+    leader (``Registry.a_groups``: a group's one contraction), whatever
+    the engine that asks stores."""
+    got = stats.a.get(name)
+    return stats.a.get(registry.a_leader(name)) if got is None else got
 
 
 # Floor for traffic-weight denominators: a fully-starved layer keeps

@@ -141,12 +141,79 @@ class Registry:
     passthrough: dict[str, str] = dataclasses.field(default_factory=dict)
     # the leaves the registered layers do own, in the same form
     kfac_leaves: tuple[str, ...] = ()
+    # A groups, member -> leader (:func:`find_a_groups`): Dense layers that
+    # the probe saw handed the same input array at the same compute dtype
+    # have one A factor, the leader's (the first of them in registration
+    # order; it maps to itself). A layer in no group of two or more is
+    # absent. Capture contracts a group's A once and the engines keep one
+    # A slot for it; emptied (``dataclasses.replace(reg, a_groups={})``)
+    # every layer keeps its own.
+    a_groups: dict[str, str] = dataclasses.field(default_factory=dict)
 
     def __len__(self) -> int:
         return len(self.layers)
 
     def names(self) -> list[str]:
         return list(self.layers)
+
+    def a_leader(self, name: str) -> str:
+        """The layer whose A factor ``name`` preconditions with: its
+        group's leader, or itself."""
+        return self.a_groups.get(name, name)
+
+    def a_members(self) -> dict[str, tuple[str, ...]]:
+        """leader -> its group's members (itself first), in registration
+        order; groups of two or more only."""
+        return group_members(self.a_groups, self.layers)
+
+    def describe(self) -> str:
+        """The A groups, one line each: ``leader <- follower, ...``."""
+        groups = self.a_members()
+        if not groups:
+            return 'A groups: none (every layer keeps its own A factor)'
+        shared = sum(len(m) - 1 for m in groups.values())
+        lines = [
+            f'A groups: {len(groups)} ({shared} of {len(self.layers)} '
+            'layers read their leader\'s A factor)'
+        ]
+        for leader, members in groups.items():
+            lines.append(f'  {leader} <- ' + ', '.join(members[1:]))
+        return '\n'.join(lines)
+
+
+def compute_dtype(module: nn.Module, dtype: Any) -> Any:
+    """The dtype ``module`` multiplies an input of ``dtype`` in (flax: its
+    ``dtype``, or without one the promotion of input and parameters)."""
+    own = getattr(module, 'dtype', None)
+    if own is not None:
+        return jnp.dtype(own)
+    return jnp.promote_types(dtype, getattr(module, 'param_dtype', dtype))
+
+
+def group_members(
+    a_groups: dict[str, str], names: Iterable[str]
+) -> dict[str, tuple[str, ...]]:
+    """leader (as ``a_groups`` names it) -> the members among ``names``,
+    in their order."""
+    out: dict[str, list[str]] = {}
+    for name in names:
+        if name in a_groups:
+            out.setdefault(a_groups[name], []).append(name)
+    return {k: tuple(v) for k, v in out.items()}
+
+
+def regroup(
+    a_groups: dict[str, str], names: Iterable[str]
+) -> dict[str, str]:
+    """``a_groups`` over the layers of ``names`` (in their order) that are
+    left: a group's first surviving member leads, and a group left with
+    one member is none."""
+    return {
+        name: group[0]
+        for group in group_members(a_groups, names).values()
+        if len(group) > 1
+        for name in group
+    }
 
 
 def _mask_value(mask: Any, path: tuple[str, ...], name: str) -> bool:
@@ -259,6 +326,7 @@ def masked_registry(registry: Registry, mask: Any) -> Registry:
             leaf for leaf in registry.kfac_leaves
             if leaf not in passthrough
         ),
+        a_groups=regroup(registry.a_groups, keep),
     )
 
 
@@ -380,6 +448,12 @@ def register_model(
     stacks: dict[str, helpers.ExpertStackTap] = {}
     unit_prefixes: list[tuple[str, ...]] = []
     modules: dict[tuple[str, ...], tuple[str, bool]] = {}
+    # for the A groups: how often the probe called each Dense layer and
+    # stacked projection, and what it was handed the first time (the
+    # arrays by identity: ``alive`` keeps them, so that no id is reused)
+    calls: dict[str, int] = {}
+    handed: dict[str, tuple] = {}
+    alive: list[Any] = []
 
     def interceptor(next_fun, iargs, ikwargs, context):
         mod = context.module
@@ -398,7 +472,14 @@ def register_model(
             return next_fun(*iargs, **ikwargs)
         path = tuple(mod.path)
         if getattr(type(mod), '_kfac_expert_stack', False):
+            calls[name] = calls.get(name, 0) + 1
             if name not in stacks:
+                plan = iargs[1] if len(iargs) > 1 else ikwargs.get('plan')
+                alive.extend((x, plan))
+                handed[name] = (
+                    'stack', id(x), id(plan), compute_dtype(mod, x.dtype),
+                    int(mod.experts),
+                )
                 slots = tuple(f'{name}/e{j}' for j in range(mod.experts))
                 for j, slot in enumerate(slots):
                     found[slot] = helpers.DenseHelper(
@@ -435,6 +516,8 @@ def register_model(
             # directly
             return next_fun(*iargs, **ikwargs)
         helper = make_helper(mod, name, tuple(x.shape), factor_dtype)
+        if helper is not None:
+            calls[name] = calls.get(name, 0) + 1
         if helper is not None and name not in found:
             if any_match(name, routed_patterns):
                 if not isinstance(helper, helpers.DenseHelper):
@@ -446,6 +529,12 @@ def register_model(
                 helper = dataclasses.replace(helper, routed=True)
             found[name] = helper
             param_paths[name] = tuple(mod.path)
+            if isinstance(helper, helpers.DenseHelper):
+                alive.append(x)
+                handed[name] = (
+                    'dense', id(x), compute_dtype(mod, x.dtype),
+                    helper.a_factor_shape, helper.has_bias, helper.routed,
+                )
         return next_fun(*iargs, **ikwargs)
 
     def is_traceable(v: Any) -> bool:
@@ -494,8 +583,48 @@ def register_model(
         stacks=dict(stacks),
         passthrough=passthrough,
         kfac_leaves=kfac_leaves,
+        a_groups=find_a_groups(found, stacks, handed, calls),
     )
     return masked_registry(registry, mask)
+
+
+def find_a_groups(
+    layers: dict[str, helpers.LayerHelper],
+    stacks: dict[str, helpers.ExpertStackTap],
+    handed: dict[str, tuple],
+    calls: dict[str, int],
+) -> dict[str, str]:
+    """The A groups of a probe, member -> leader (``Registry.a_groups``).
+
+    ``A = E[a a^T]`` depends on a layer's input alone, so layers that were
+    handed the same array and multiply it at the same dtype
+    (:func:`compute_dtype`, what ``capture.layer_input`` rounds to) hold
+    equal A factors: they form a group, told by the array's identity at
+    the probe and by nothing else. ``handed``: what each Dense layer
+    (``nn.Dense``: not a convolution, whose A is of patches, nor a LoRA
+    unit's children) or stacked expert projection met at its first call,
+    as ``register_model`` recorded it; ``calls``: how often it was called
+    (a module called more than once sums several inputs' statistics and
+    stays alone). Two stacked projections handed the same ``(x, plan)``
+    pair their experts slot by slot.
+    """
+    leaders: dict[tuple, str] = {}
+    out: dict[str, str] = {}
+    for name, key in handed.items():
+        if calls.get(name, 0) != 1:
+            continue
+        first = leaders.setdefault(key, name)
+        if first == name:
+            continue
+        if key[0] == 'stack':
+            pairs = zip(stacks[name].slots, stacks[first].slots)
+        else:
+            pairs = [(name, first)]
+        for member, leader in pairs:
+            out[leader] = leader
+            out[member] = leader
+    # in registration order, leaders first
+    return regroup(out, layers)
 
 
 def slice_layer_grads(
@@ -545,6 +674,7 @@ def merge_registries(*registries: Registry) -> Registry:
     stacks: dict[str, helpers.ExpertStackTap] = {}
     passthrough: dict[str, str] = {}
     kfac_leaves: tuple[str, ...] = ()
+    a_groups: dict[str, str] = {}
     for r in registries:
         overlap = set(layers) & set(r.layers)
         if overlap:
@@ -557,7 +687,9 @@ def merge_registries(*registries: Registry) -> Registry:
         stacks.update(r.stacks)
         passthrough.update(r.passthrough)
         kfac_leaves += r.kfac_leaves
+        a_groups.update(r.a_groups)
     return Registry(
         layers=layers, param_paths=paths, taps=taps, stacks=stacks,
         passthrough=passthrough, kfac_leaves=kfac_leaves,
+        a_groups=a_groups,
     )

@@ -442,11 +442,15 @@ class SparseMoE(nn.Module):
         lead, d = x.shape[:-1], x.shape[-1]
         xf = x.reshape(-1, d)
         first, held = self.experts_held or (0, self.num_experts)
+        # one float32 array for the router and the shared gate: they read
+        # the same input, and K-FAC keeps one A factor for layers handed
+        # one array (``Registry.a_groups``)
+        xf32 = xf.astype(jnp.float32)
         with tracing.model_scope('moe_route'):
             logits = nn.Dense(
                 self.num_experts, use_bias=False, dtype=jnp.float32,
                 precision=jax.lax.Precision.HIGHEST, name='router',
-            )(xf.astype(jnp.float32))
+            )(xf32)
             wts, idx = self._route(logits)
             if self.norm_topk_prob:
                 total = jnp.sum(wts, axis=-1, keepdims=True)
@@ -462,7 +466,7 @@ class SparseMoE(nn.Module):
             with tracing.model_scope('mlp'):
                 gate = nn.Dense(
                     1, use_bias=False, dtype=jnp.float32, name='shared_gate'
-                )(xf.astype(jnp.float32))
+                )(xf32)
                 shared = GatedMLP(
                     self.shared_width, dtype=self.dtype, name='shared'
                 )(xf)

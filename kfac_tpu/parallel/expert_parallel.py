@@ -466,7 +466,7 @@ def combined_value_stats_and_grad(
         a_all = dict(capture_lib.weighted_average(fa, counts, wts))
         g_all = dict(
             capture_lib.weighted_average(
-                {n: g_sums[n] for n in fa}, counts, g_wts
+                {n: g_sums[n] for n in counts}, counts, g_wts
             )
         )
         w_all: dict[str, jax.Array] = {

@@ -134,12 +134,33 @@ class Bucket(NamedTuple):
 
 
 def build_buckets(
-    registry: registry_lib.Registry, world: int, granularity: int = 128
+    registry: registry_lib.Registry, world: int, granularity: int = 128,
+    a_groups: dict[str, str] | None = None,
 ) -> list[Bucket]:
     """Group registered layers by (A class, G class), pad to the world
-    size."""
+    size.
+
+    A bucket's layers lie in registration order; with ``a_groups`` (member
+    -> leader, :func:`stored_a_groups`) the leaders and the layers in no
+    group come first, those with the most followers ahead, and then the
+    followers, the first of every group, then the second, each in its
+    leader's order. A follower reads its leader's A slot, and so the
+    followers of one rank read consecutive A slots from consecutive G
+    slots: one batched product (``DistributedKFAC._resident_views``'s
+    runs) and not one a layer. With no group the order is the
+    registration's.
+    """
+    a_groups = a_groups or {}
+    members = registry_lib.group_members(a_groups, registry.layers)
+    index = {name: i for i, name in enumerate(registry.layers)}
+
+    def order(name):
+        group = members.get(a_groups.get(name), (name,))
+        return (group.index(name), -len(group), index[group[0]])
+
     groups: dict[tuple[int, int], list[tuple[str, int, int]]] = {}
-    for name, h in registry.layers.items():
+    for name in sorted(registry.layers, key=order):
+        h = registry.layers[name]
         da, dg = h.a_factor_shape[0], h.g_factor_shape[0]
         key = (size_class(da, granularity), size_class(dg, granularity))
         groups.setdefault(key, []).append((name, da, dg))
@@ -183,11 +204,14 @@ def build_side_buckets(
     world: int,
     side: str,
     granularity: int = 128,
+    a_groups: dict[str, str] | None = None,
 ) -> list[StorageBucket]:
     """Group layers by a single factor size class (non-colocated
-    storage)."""
+    storage); on the A side the followers of ``a_groups`` have no slot."""
     groups: dict[int, list[tuple[str, int]]] = {}
     for name, h in registry.layers.items():
+        if side == 'a' and a_groups and a_groups.get(name, name) != name:
+            continue
         d = h.a_factor_shape[0] if side == 'a' else h.g_factor_shape[0]
         groups.setdefault(size_class(d, granularity), []).append((name, d))
     return [
@@ -208,8 +232,14 @@ def build_stores(
     granularity: int,
     colocate: bool,
     buckets: list[Bucket],
+    a_groups: dict[str, str] | None = None,
 ) -> tuple[list[StorageBucket], list[StorageBucket]]:
     """Factor STORAGE layout (A store, G store) for a configuration.
+
+    ``a_groups`` (member -> leader, :func:`stored_a_groups`): the A store
+    is built over the leaders alone: a follower has its G slot and reads
+    its leader's A slot, wherever that lies. With no follower the layout
+    is the one below to the last slot.
 
     Colocated stores mirror the (da, dg) pair buckets (A and G share a
     slot/device); non-colocated stores bucket each side by its own
@@ -220,14 +250,23 @@ def build_stores(
     so the analytic cost model prices exactly the layout the engine
     would build.
     """
+    a_groups = a_groups or {}
     if colocate:
-        a_store = [
-            StorageBucket(
-                b.key, b.layers, b.da, b.padded,
-                tuple(d[0] for d in b.dims),
-            )
-            for b in buckets
-        ]
+        a_store = []
+        for b in buckets:
+            # a pair bucket's leaders keep the bucket's key and order (a
+            # bucket of followers alone has no A stack)
+            lead = [
+                i for i, n in enumerate(b.layers)
+                if a_groups.get(n, n) == n
+            ]
+            if not lead:
+                continue
+            a_store.append(StorageBucket(
+                b.key, tuple(b.layers[i] for i in lead), b.da,
+                -(-len(lead) // total_devices) * total_devices,
+                tuple(b.dims[i][0] for i in lead),
+            ))
         g_store = [
             StorageBucket(
                 b.key, b.layers, b.dg, b.padded,
@@ -237,9 +276,24 @@ def build_stores(
         ]
         return a_store, g_store
     return (
-        build_side_buckets(registry, total_devices, 'a', granularity),
+        build_side_buckets(
+            registry, total_devices, 'a', granularity, a_groups
+        ),
         build_side_buckets(registry, total_devices, 'g', granularity),
     )
+
+
+def stored_a_groups(config: KFACPreconditioner) -> dict[str, str]:
+    """The A groups (``Registry.a_groups``) the stacked engine keeps one A
+    slot for under ``config``: the dense engine's own (none under an async
+    refresh), and none where the eigenvalues are pre-divided, whose fused
+    grid lies slot by slot beside a pair bucket's own A stack."""
+    if (
+        config.compute_method == enums.ComputeMethod.EIGEN
+        and config.prediv_eigenvalues
+    ):
+        return {}
+    return dict(config.a_groups)
 
 
 class DistKFACState(NamedTuple):
@@ -514,8 +568,11 @@ class DistributedKFAC:
         )
         # resolved (never None) by KFACPreconditioner.__post_init__
         self.granularity = int(self.config.bucket_granularity)
+        # member -> leader of the A groups stored as one slot
+        self.a_groups = stored_a_groups(self.config)
         self.buckets = build_buckets(
-            self.registry, self.total_devices, self.granularity
+            self.registry, self.total_devices, self.granularity,
+            self.a_groups,
         )
         self.colocate = bool(self.config.colocate_factors)
         # Parity object: cost-model view of the placement for reporting and
@@ -526,15 +583,29 @@ class DistributedKFAC:
             world_size=self.world,
             grad_worker_fraction=self.grad_workers / self.world,
             colocate_factors=self.colocate,
+            a_groups=self.a_groups,
         )
         self.a_store, self.g_store = build_stores(
             self.registry, self.total_devices, self.granularity,
-            self.colocate, self.buckets,
+            self.colocate, self.buckets, self.a_groups,
         )
+        # every layer's A slot: a follower's is its leader's
         self._a_slot = {
             n: (sb.key, i)
             for sb in self.a_store
             for i, n in enumerate(sb.layers)
+        }
+        self._a_slot = {
+            n: self._a_slot[self.a_leader(n)] for n in self.registry.layers
+        }
+        # pair buckets whose A stack lies slot by slot beside their G
+        # stack (colocated, and no layer of theirs follows another's A)
+        self._a_aligned = {
+            b.key for b in self.buckets
+            if self.colocate and all(
+                self._a_slot[n] == (b.key, i)
+                for i, n in enumerate(b.layers)
+            )
         }
         self._g_slot = {
             n: (sb.key, i)
@@ -641,6 +712,11 @@ class DistributedKFAC:
             n = min(self._async_n_steps, acfg.max_slices or len(units))
             self._async_slices = async_slots.plan_slices(units, n)
             self._async_n_slices = len(self._async_slices)
+
+    def a_leader(self, name: str) -> str:
+        """The layer whose A slot ``name`` reads: its A group's leader
+        (:func:`stored_a_groups`), or itself."""
+        return self.a_groups.get(name, name)
 
     # ------------------------------------------------------------ shardings
 
@@ -867,6 +943,17 @@ class DistributedKFAC:
 
     # ------------------------------------------------------------- stacking
 
+    def _a_stats(
+        self, stats: capture_lib.CapturedStats
+    ) -> dict[str, jax.Array]:
+        """A capture's A statistics under the A store's layers' names
+        (``capture.a_stat``); a layer the capture did not run is absent."""
+        found = {
+            n: capture_lib.a_stat(stats, self.registry, n)
+            for sb in self.a_store for n in sb.layers
+        }
+        return {n: v for n, v in found.items() if v is not None}
+
     def _stack_stats(
         self, state: DistKFACState, stats: capture_lib.CapturedStats
     ) -> tuple[dict[str, jax.Array], dict[str, jax.Array], Any]:
@@ -881,8 +968,8 @@ class DistributedKFAC:
         Returns ``(a_stacks, g_stacks, new_comp_ef)``: the third element
         is the updated error-feedback residual dict when the compressed
         transport carries one, else the state's ``comp_ef`` unchanged. A
-        bucket whose stack is wider than :data:`SOLVE_GROUP_BYTES` comes
-        back as the list of its layers' rows, not stacked.
+        bucket whose float32 stack is :data:`SOLVE_GROUP_BYTES` or wider
+        comes back as the list of its layers' rows, not stacked.
         """
         cfg = self.config
         bucketed = (
@@ -928,7 +1015,7 @@ class DistributedKFAC:
                 rows[sb.key] = r
             return rows
 
-        rows_a = side_rows(self.a_store, stats.a, state.a)
+        rows_a = side_rows(self.a_store, self._a_stats(stats), state.a)
         rows_g = side_rows(self.g_store, stats.g, state.g)
 
         new_ef = getattr(state, 'comp_ef', None)
@@ -992,7 +1079,7 @@ class DistributedKFAC:
             stacks = {}
             for sb in store:
                 r = rows[sb.key]
-                if _solve_groups(sb.padded, sb.d) > 1:
+                if sb.padded * sb.d * sb.d * 4 >= SOLVE_GROUP_BYTES:
                     # a stack too wide to hold whole beside the state and
                     # the statistics themselves: its rows stay apart and
                     # ``update_factors`` folds them into the state in
@@ -1064,24 +1151,25 @@ class DistributedKFAC:
         # is their own state value — and size-class padding) use w=1,
         # which reduces exactly to the unweighted update.
         weights = getattr(stats, 'w', None) or {}
+        a_stats = self._a_stats(stats)
 
-        def slot_alphas(store_bucket):
+        def slot_alphas(store_bucket, seen):
             if not any(
-                n in weights and n in stats.a for n in store_bucket.layers
+                n in weights and n in seen for n in store_bucket.layers
             ):
                 return None
             w = [
-                weights[n] if (n in weights and n in stats.a)
+                weights[n] if (n in weights and n in seen)
                 else jnp.float32(1.0)
                 for n in store_bucket.layers
             ]
             w += [jnp.float32(1.0)] * (store_bucket.padded - len(w))
             return factors_lib.effective_alpha(alpha, jnp.stack(w))
 
-        def ema(store, side_state, stacks):
+        def ema(store, side_state, stacks, seen):
             out = {}
             for sb in store:
-                av = slot_alphas(sb)
+                av = slot_alphas(sb, seen)
                 if isinstance(stacks[sb.key], list):
                     # rows apart (see ``_stack_stats``): a chain of
                     # in-place row updates of the donated stack, each
@@ -1100,8 +1188,8 @@ class DistributedKFAC:
                     out[sb.key] = av * side_state[sb.key] + (1 - av) * s
             return out
 
-        new_a = ema(self.a_store, state.a, a_stacks)
-        new_g = ema(self.g_store, state.g, g_stacks)
+        new_a = ema(self.a_store, state.a, a_stacks, a_stats)
+        new_g = ema(self.g_store, state.g, g_stacks, stats.g)
         updated = set(stats.a) | set(stats.g)
         ok: dict[str, jax.Array] = {}
         new_health = state.health
@@ -1137,8 +1225,13 @@ class DistributedKFAC:
                 gk, gi = self._g_slot[n]
                 ok[n] = ok_a[ak][ai] & ok_g[gk][gi]
             roll = {n: ~v for n, v in ok.items()}
+            # a group's one A slot goes back with any member's rollback
+            roll_a: dict[str, jax.Array] = {}
+            for n, bad in roll.items():
+                la = self.a_leader(n)
+                roll_a[la] = roll_a[la] | bad if la in roll_a else bad
 
-            def rollback(store, old, new):
+            def rollback(store, old, new, roll):
                 out = {}
                 for sb in store:
                     mask = self._slot_mask(roll, sb.layers, sb.padded)
@@ -1160,8 +1253,8 @@ class DistributedKFAC:
                         h.quarantine_events[n],
                     )
                 )
-            new_a = rollback(self.a_store, state.a, new_a)
-            new_g = rollback(self.g_store, state.g, new_g)
+            new_a = rollback(self.a_store, state.a, new_a, roll_a)
+            new_g = rollback(self.g_store, state.g, new_g, roll)
             new_health = h._replace(
                 damping_mult=mult, quarantined=quarantined,
                 quarantine_events=events,
@@ -1755,8 +1848,15 @@ class DistributedKFAC:
                 the assembly — the decomposition exchange non-colocation
                 buys its eigh parallelism with (the reference ships inverses
                 to grad workers the same way, kfac/assignment.py:268-304).
+                The A side of a bucket that holds a follower of an A group
+                is assembled the same way, the leader's row under each of
+                its members, so every member's gradient workers hold it.
                 """
-                if self.colocate:
+                aligned = (
+                    b.key in self._a_aligned if slot_map is self._a_slot
+                    else self.colocate
+                )
+                if aligned:
                     return side_dict[b.key]
                 rws = [
                     jax.lax.with_sharding_constraint(
@@ -1926,10 +2026,11 @@ class DistributedKFAC:
         kfac/gpt_neox/preconditioner.py:394-447).
         """
         out: dict[str, dict[str, jax.Array]] = {}
-        for sb in self.a_store:
-            for i, name in enumerate(sb.layers):
-                d = sb.dims[i]
-                out.setdefault(name, {})['a'] = state.a[sb.key][i, :d, :d]
+        for name, h in self.registry.layers.items():
+            # a follower of an A group reads its leader's slot
+            key, i = self._a_slot[name]
+            d = h.a_factor_shape[0]
+            out[name] = {'a': state.a[key][i, :d, :d]}
         for sb in self.g_store:
             for i, name in enumerate(sb.layers):
                 d = sb.dims[i]
@@ -1943,7 +2044,10 @@ class DistributedKFAC:
     ) -> DistKFACState:
         """Write per-layer factors into this engine's stacked layout
         (inverse of :meth:`extract_factors`; layers absent from
-        ``factors`` keep their current rows). Call
+        ``factors`` keep their current rows). A group's A slot takes its
+        leader's entry; its followers' ``'a'`` entries are dropped (equal
+        to it where this engine wrote them, each layer's own in a
+        checkpoint from before the groups). Call
         :meth:`rematerialize` afterwards to rebuild decompositions."""
 
         def rewrite(store, side):
@@ -2036,6 +2140,13 @@ class DistributedKFAC:
                 for f in self.assignment.get_factors(layer)
             }
             lines.append(f'  {layer}: {workers}')
+        # (the A groups are in the config's lines above)
+        if self.config.a_groups and not self.a_groups:
+            lines.append(
+                'A groups: none stored in the stacked layout (pre-divided '
+                'eigenvalues lie slot by slot beside every layer\'s own A '
+                'factor)'
+            )
         return '\n'.join(lines)
 
     def topology(self) -> dict[str, Any]:

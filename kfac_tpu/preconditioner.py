@@ -442,6 +442,14 @@ class KFACPreconditioner:
             self.registry = registry_lib.masked_registry(
                 self.registry, self.mask
             )
+        # the A groups this engine stores one A factor for
+        # (``Registry.a_groups``). The async refresh modes walk the state
+        # layer by layer on both sides and keep every layer's own A (equal
+        # matrices held several times: nothing else differs)
+        self.a_groups = (
+            dict(self.registry.a_groups) if self.async_inverse is None
+            else {}
+        )
         if self.metrics is True:
             self.metrics = metrics_lib.MetricsConfig()
         elif self.metrics is False:
@@ -669,6 +677,12 @@ class KFACPreconditioner:
             self._async_slices = async_slots.plan_slices(units, n)
             self._async_n_slices = len(self._async_slices)
 
+    def a_leader(self, name: str) -> str:
+        """The layer under whose name ``name``'s A factor, its
+        decomposition and its inverse are kept: its A group's leader
+        (``Registry.a_groups``), or itself."""
+        return self.a_groups.get(name, name)
+
     # ------------------------------------------------------------------ init
 
     def init(self) -> KFACState:
@@ -686,18 +700,24 @@ class KFACPreconditioner:
         for name, h in self.registry.layers.items():
             na = h.a_factor_shape[0]
             ng = h.g_factor_shape[0]
-            a[name] = jnp.eye(na, dtype=self.factor_dtype)
+            # the A side under the group's leader alone (``a_leader``)
+            leads = self.a_leader(name) == name
+            if leads:
+                a[name] = jnp.eye(na, dtype=self.factor_dtype)
             g[name] = jnp.eye(ng, dtype=self.factor_dtype)
             if eigen:
-                qa[name] = jnp.zeros((na, na), dtype=self.inv_dtype)
+                if leads:
+                    qa[name] = jnp.zeros((na, na), dtype=self.inv_dtype)
                 qg[name] = jnp.zeros((ng, ng), dtype=self.inv_dtype)
                 if self.prediv_eigenvalues:
                     dgda[name] = jnp.zeros((ng, na), dtype=self.inv_dtype)
                 else:
-                    da[name] = jnp.zeros((na,), dtype=self.inv_dtype)
+                    if leads:
+                        da[name] = jnp.zeros((na,), dtype=self.inv_dtype)
                     dg[name] = jnp.zeros((ng,), dtype=self.inv_dtype)
             else:
-                a_inv[name] = jnp.zeros((na, na), dtype=self.inv_dtype)
+                if leads:
+                    a_inv[name] = jnp.zeros((na, na), dtype=self.inv_dtype)
                 g_inv[name] = jnp.zeros((ng, ng), dtype=self.inv_dtype)
         state = KFACState(
             step=jnp.asarray(0, dtype=jnp.int32),
@@ -761,11 +781,16 @@ class KFACPreconditioner:
         # the .astype pins the result to factor_dtype: a traced alpha or a
         # float32 capture weight would otherwise promote bf16 factor state
         # and break the step's lax.cond branch-type equality
+        # (a capture files a group's one A under the registry's leader)
+        a_stats = {
+            n: capture_lib.a_stat(stats, self.registry, n) for n in state.a
+        }
         new_a = {
             n: factors_lib.ema_update(
-                state.a[n], stats.a[n].astype(self.factor_dtype), eff_alpha(n)
+                state.a[n], a_stats[n].astype(self.factor_dtype),
+                eff_alpha(n),
             ).astype(self.factor_dtype)
-            if n in stats.a else state.a[n]
+            if a_stats[n] is not None else state.a[n]
             for n in state.a
         }
         new_g = {
@@ -796,17 +821,20 @@ class KFACPreconditioner:
             mult = dict(h.damping_mult)
             quarantined = dict(h.quarantined)
             events = dict(h.quarantine_events)
-            for n in state.a:
-                if n not in stats.a and n not in stats.g:
+            cand_a = dict(new_a)
+            for n in state.g:
+                la = self.a_leader(n)
+                if a_stats[la] is None and n not in stats.g:
                     continue
                 eff = damping * h.damping_mult[n]
                 ok = health_lib.factor_ok(
-                    new_a[n], eff, cfg.quarantine_threshold
+                    cand_a[la], eff, cfg.quarantine_threshold
                 ) & health_lib.factor_ok(
                     new_g[n], eff, cfg.quarantine_threshold
                 )
                 ok_verdicts[n] = ok
-                new_a[n] = jnp.where(ok, new_a[n], state.a[n])
+                # a group's one A goes back with any member's rollback
+                new_a[la] = jnp.where(ok, new_a[la], state.a[la])
                 new_g[n] = jnp.where(ok, new_g[n], state.g[n])
                 mult[n], quarantined[n], events[n] = (
                     health_lib.quarantine_update(
@@ -843,11 +871,15 @@ class KFACPreconditioner:
         ms = state.metrics
         scalars: dict[str, jax.Array] = {}
         touched: dict[str, jax.Array | None] = {}
-        for n in state.a:
-            if n not in stats.a and n not in stats.g:
+        for n in state.g:
+            if (
+                capture_lib.a_stat(stats, self.registry, n) is None
+                and n not in stats.g
+            ):
                 continue
             if mcfg.factor_bounds:
-                lmin_a, lmax_a = metrics_lib.gershgorin_bounds(state.a[n])
+                lmin_a, lmax_a = metrics_lib.gershgorin_bounds(
+                    state.a[self.a_leader(n)])
                 lmin_g, lmax_g = metrics_lib.gershgorin_bounds(state.g[n])
                 scalars[f'factor_lmin/a/{n}'] = lmin_a
                 scalars[f'factor_lmax/a/{n}'] = lmax_a
@@ -892,10 +924,16 @@ class KFACPreconditioner:
             qa, qg = dict(state.qa), dict(state.qg)
             da, dg = dict(state.da), dict(state.dg)
             dgda = dict(state.dgda)
+            adecs: dict[str, factors_lib.EigenDecomp] = {}
             for name in self.registry.layers:
-                adec = factors_lib.compute_eigh(
-                    state.a[name], self.inv_dtype, self.eigh_impl
-                )
+                # a group's A is decomposed once, under its leader (the
+                # first of its members met here)
+                la = self.a_leader(name)
+                if la not in adecs:
+                    adecs[la] = factors_lib.compute_eigh(
+                        state.a[la], self.inv_dtype, self.eigh_impl
+                    )
+                adec = adecs[la]
                 gdec = factors_lib.compute_eigh(
                     state.g[name], self.inv_dtype, self.eigh_impl
                 )
@@ -910,9 +948,9 @@ class KFACPreconditioner:
                     ok = outputs_ok(*cand.values())
                     inv_ok[name] = ok
                     prev = {
-                        'qa': state.qa[name], 'qg': state.qg[name],
+                        'qa': state.qa[la], 'qg': state.qg[name],
                         'dgda': state.dgda.get(name),
-                        'da': state.da.get(name), 'dg': state.dg.get(name),
+                        'da': state.da.get(la), 'dg': state.dg.get(name),
                     }
                     cand = {
                         k: jnp.where(ok, v, prev[k]) for k, v in cand.items()
@@ -920,11 +958,15 @@ class KFACPreconditioner:
                     bad_inv[name] = health_lib.inversion_update(
                         cfg, ok, h.quarantined[name], h.bad_inv[name]
                     )
-                qa[name], qg[name] = cand['qa'], cand['qg']
+                qg[name] = cand['qg']
+                if la == name:
+                    qa[name] = cand['qa']
                 if self.prediv_eigenvalues:
                     dgda[name] = cand['dgda']
                 else:
-                    da[name], dg[name] = cand['da'], cand['dg']
+                    dg[name] = cand['dg']
+                    if la == name:
+                        da[name] = cand['da']
             state = state._replace(qa=qa, qg=qg, da=da, dg=dg, dgda=dgda)
         else:
             # warm-start Newton-Schulz from the previous inverse: the factor
@@ -942,18 +984,28 @@ class KFACPreconditioner:
                 self.newton_schulz_iters, x0=prev, floor=floor,
             )
             a_inv, g_inv = dict(state.a_inv), dict(state.g_inv)
-            for name in state.a:
-                cand_a = inv(state.a[name], state.a_inv[name], eff_damping(name))
+            solved_a: dict[str, jax.Array] = {}
+            for name in state.g:
+                # a group's A is solved once, at its leader's damping
+                la = self.a_leader(name)
+                if la not in solved_a:
+                    solved_a[la] = inv(
+                        state.a[la], state.a_inv[la], eff_damping(la)
+                    )
+                cand_a = solved_a[la]
                 cand_g = inv(state.g[name], state.g_inv[name], eff_damping(name))
                 if cfg is not None:
                     ok = outputs_ok(cand_a, cand_g)
                     inv_ok[name] = ok
-                    cand_a = jnp.where(ok, cand_a, state.a_inv[name])
+                    if la == name:
+                        cand_a = jnp.where(ok, cand_a, state.a_inv[name])
                     cand_g = jnp.where(ok, cand_g, state.g_inv[name])
                     bad_inv[name] = health_lib.inversion_update(
                         cfg, ok, h.quarantined[name], h.bad_inv[name]
                     )
-                a_inv[name], g_inv[name] = cand_a, cand_g
+                if la == name:
+                    a_inv[name] = cand_a
+                g_inv[name] = cand_g
             state = state._replace(a_inv=a_inv, g_inv=g_inv)
         if cfg is not None:
             state = state._replace(health=h._replace(bad_inv=bad_inv))
@@ -978,22 +1030,23 @@ class KFACPreconditioner:
         the matrix form (``helpers.matrix_view``) for the eigen methods,
         the helper's own (``LayerHelper.grad_view``) for explicit
         inverses."""
+        la = self.a_leader(name)
         if self.compute_method == enums.ComputeMethod.EIGEN:
             grad_mat = gview[helpers_lib.MATRIX]
             if self.prediv_eigenvalues:
-                v1 = state.qg[name].T @ grad_mat.astype(self.inv_dtype) @ state.qa[name]
+                v1 = state.qg[name].T @ grad_mat.astype(self.inv_dtype) @ state.qa[la]
                 v2 = v1 * state.dgda[name]
-                pmat = (state.qg[name] @ v2 @ state.qa[name].T).astype(grad_mat.dtype)
+                pmat = (state.qg[name] @ v2 @ state.qa[la].T).astype(grad_mat.dtype)
             else:
                 pmat = factors_lib.eigen_preconditioned_grad(
                     grad_mat,
-                    factors_lib.EigenDecomp(q=state.qa[name], d=state.da[name]),
+                    factors_lib.EigenDecomp(q=state.qa[la], d=state.da[la]),
                     factors_lib.EigenDecomp(q=state.qg[name], d=state.dg[name]),
                     damping,
                 )
             return {helpers_lib.MATRIX: pmat}
         return self.registry.layers[name].inverse_precondition(
-            gview, state.a_inv[name], state.g_inv[name]
+            gview, state.a_inv[la], state.g_inv[name]
         )
 
     @tracing.scope('kfac.precondition')
@@ -1148,8 +1201,8 @@ class KFACPreconditioner:
         (dense state is already layer-keyed; this mirrors the distributed
         engine's API so checkpoints move between engines/configs)."""
         return {
-            name: {'a': state.a[name], 'g': state.g[name]}
-            for name in state.a
+            name: {'a': state.a[self.a_leader(name)], 'g': state.g[name]}
+            for name in state.g
         }
 
     def insert_factors(
@@ -1158,12 +1211,15 @@ class KFACPreconditioner:
         factors: dict[str, dict[str, jax.Array]],
     ) -> KFACState:
         """Inverse of :meth:`extract_factors`; call :meth:`rematerialize`
-        afterwards."""
+        afterwards. A group's A is its leader's entry: the followers' own
+        ``'a'`` entries (equal to it where this engine wrote them, each
+        layer's own in a checkpoint from before the groups) are dropped."""
         new_a = dict(state.a)
         new_g = dict(state.g)
         for name, fg in factors.items():
             if name in new_a:
                 new_a[name] = fg['a'].astype(self.factor_dtype)
+            if name in new_g:
                 new_g[name] = fg['g'].astype(self.factor_dtype)
         return state._replace(a=new_a, g=new_g)
 
@@ -1206,7 +1262,18 @@ class KFACPreconditioner:
                 f'G={h.g_factor_shape[0]}x{h.g_factor_shape[0]}'
                 f'{" +bias" if h.has_bias else ""}'
             )
+        lines.append(self.describe_a_groups())
         return '\n'.join(lines)
+
+    def describe_a_groups(self) -> str:
+        """The A groups as this engine stores them (each group once)."""
+        reg = self.registry
+        if reg.a_groups and not self.a_groups:
+            return (
+                f'A groups: {len(reg.a_members())} found, none stored (the '
+                'async inverse refresh keeps every layer\'s own A factor)'
+            )
+        return reg.describe()
 
     def topology(self) -> dict[str, Any]:
         """Process/device topology snapshot, recorded (informationally)

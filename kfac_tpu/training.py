@@ -625,7 +625,7 @@ class Trainer:
             out = jax.eval_shape(
                 self._run_stats, state.params, (state.model_state, batch)
             )
-            self._executed = set(out[2].a.keys())
+            self._executed = set(out[2].g.keys())
         return self._executed
 
     def _zero_stats(self, executed: set[str]):
@@ -634,10 +634,11 @@ class Trainer:
         factor EMA on exactly the same steps)."""
         reg = self.registry
         return capture_lib.CapturedStats(
+            # a follower of an A group has no A statistic of its own
             a={
                 n: jax.numpy.zeros(h.a_factor_shape, h.factor_dtype)
                 for n, h in reg.layers.items()
-                if n in executed
+                if n in executed and reg.a_leader(n) == n
             },
             g={
                 n: jax.numpy.zeros(h.g_factor_shape, h.factor_dtype)

@@ -92,7 +92,8 @@ def _seeded(reg):
         inv[name] = (
             _spd(ka, h.a_factor_shape[0]), _spd(kg, h.g_factor_shape[0])
         )
-    return inv
+    # the members of an A group hold one A inverse, their leader's
+    return {n: (inv[reg.a_leader(n)][0], g) for n, (_, g) in inv.items()}
 
 
 def _stacked_state(dk, state, inv):
@@ -163,7 +164,7 @@ def test_in_layout_stack_and_dense_engine_agree(case, kl_clip):
     dstate = _degrade(_stacked_state(resident, resident.init(), inv), degraded)
     dense_state = cfg.init()
     dense_state = _degrade(dense_state._replace(
-        a_inv={n: inv[n][0] for n in reg.layers},
+        a_inv={n: inv[n][0] for n in dense_state.a_inv},
         g_inv={n: inv[n][1] for n in reg.layers},
     ), degraded)
 
