@@ -300,7 +300,12 @@ class NewtonSchulzInfo(NamedTuple):
     started over from the cold init (bool scalar: ``warm & ~restarted``
     is a warm start that paid off);
     ``scaled``: the iterations among ``iterations`` that were scaled
-    steps of a cold start (int32 scalar; 0 for a warm start that held).
+    steps of a cold start (int32 scalar; 0 for a warm start that held);
+    ``cold_preferred``: ``x0`` passed the ``< 0.5`` test and was set
+    aside all the same, because the cold start's worst direction was
+    provably no worse than the warm start's average one (bool scalar;
+    the iteration then began cold, so ``warm & cold_preferred`` is never
+    true).
     """
 
     inverse: jax.Array
@@ -309,6 +314,7 @@ class NewtonSchulzInfo(NamedTuple):
     warm: jax.Array
     restarted: jax.Array
     scaled: jax.Array
+    cold_preferred: jax.Array
 
 
 def newton_schulz_inverse_info(
@@ -328,14 +334,37 @@ def newton_schulz_inverse_info(
     PREVIOUS inverse at each ``inv_update_steps`` refresh: the factor EMA
     moves slowly, so the old inverse sits deep inside the quadratic
     convergence basin and the refresh needs a handful of iterations
-    instead of the cold ~log4(kappa)+5. Safeguarded twice. Up front, the
-    warm init is used only when its own residual
-    ``||I - M X0||_F/sqrt(d) < 0.5``, else the Gershgorin cold start
-    runs — an all-zeros x0 (a fresh engine state) therefore falls back
-    automatically. Free: the safeguard's ``M @ X0`` product is the
+    instead of the cold ~log4(kappa)+5. Safeguarded three times. Up
+    front, the warm init is used only when its own residual
+    ``r_warm = ||I - M X0||_F/sqrt(d) < 0.5``, else the Gershgorin cold
+    start runs — an all-zeros x0 (a fresh engine state) therefore falls
+    back automatically. Free: the safeguard's ``M @ X0`` product is the
     iteration's first cached ``mx``, so a warm call costs no extra
-    matmuls over a cold one. That RMS test cannot see the spectral
-    radius of ``I - M X0``, which is what convergence depends on, so a
+    matmuls over a cold one. Also up front, a warm start that passed is
+    still set aside where the cold start is provably the cheap one:
+    ``r_warm >= 1 - l``, ``l`` the cold start's lower bound on the
+    eigenvalues of ``M X_cold`` ("Scaled phase" below, before its clip).
+    The iteration squares ``I - M X`` every trip, so the worst direction
+    sets the trips. The cold start's is at most ``1 - l``: its error
+    matrix ``I - M/||M||_inf`` is symmetric with eigenvalues in
+    ``[0, 1 - l]``. A warm start's is at least about its RMS residual
+    (an average never exceeds the largest; exactly equal where
+    ``I - M X0`` is a multiple of the identity, which is the measured
+    case: through the early phase of a run a factor is its identity init
+    to 1e-10, the cold start *is* its inverse, and the previous inverse
+    is off by the identity weight's decay, residual 0.40, four trips).
+    So the comparison holds a *bound* on one side's worst against an
+    *RMS* of the other's, and errs only towards keeping the warm start:
+    where it flips, the cold start converges no slower in its worst
+    direction, costs no product to form and is not on probation. And
+    since ``r_warm < 0.5``, a flip implies ``l > 0.5``: at most two
+    scaled trips (0.5 -> 0.889 -> 0.9965) and two plain ones, the four a
+    warm start from 0.4 pays (0.4 -> 0.16 -> 0.026 -> 6.5e-4 -> 4e-7),
+    and fewer the nearer ``l`` is to 1, down to none. A factor with a
+    real spectrum has ``l`` near 0 and keeps its warm start exactly as
+    before, bit for bit (``cold_preferred`` says which happened).
+    Afterwards: neither test can see the spectral radius of
+    ``I - M X0``, which is what convergence depends on, so a
     warm-started solve that ends above ``NS_FALLBACK_RESIDUAL`` starts
     over once from the cold init inside the same loop (``iterations``
     counts both attempts).
@@ -471,13 +500,12 @@ def newton_schulz_inverse_info(
     mx_cold = m / lam_max  # == m @ x_cold, sans the matmul
     r_cold = residual(mx_cold)
     # the cold start's eigenvalue bound (see "Scaled phase" above); the
-    # schedule of step sizes is no part of what a caller differentiates
-    l_cold = jax.lax.stop_gradient(
-        jnp.clip(
-            jnp.maximum((floor + damping) / lam_max, 1.0 - r_cold * sqrt_d),
-            NS_SCALE_FROM, NS_SCALED_UNTIL,
-        )
+    # schedule of step sizes, and which start is taken, are no part of
+    # what a caller differentiates
+    l_bound = jax.lax.stop_gradient(
+        jnp.maximum((floor + damping) / lam_max, 1.0 - r_cold * sqrt_d)
     )
+    l_cold = jnp.clip(l_bound, NS_SCALE_FROM, NS_SCALED_UNTIL)
     inf = lam_max * 0.0 + jnp.inf
 
     def body(carry):
@@ -509,10 +537,20 @@ def newton_schulz_inverse_info(
         # cold start (jnp.where keeps this vmap/shard_map-friendly). The
         # m @ warm product doubles as the iteration's cached mx0, and the
         # cold init's product is a scalar rescale of m — so the warm
-        # start costs NO extra matmul over a cold start.
+        # start costs NO extra matmul over a cold start. And keep it
+        # only if the cold start is not provably as good: the trips go
+        # by the worst direction of ``I - M X``, which for the cold
+        # start is at most ``1 - l_bound`` (a bound) and for the warm
+        # one at least about ``r_warm`` (an RMS: the worst is no
+        # smaller), so ``r_warm >= 1 - l_bound`` can only flip a slot
+        # whose cold solve is the cheaper or the same, two scalars that
+        # are both on hand.
         warm = x0.astype(jnp.float32)
         m_warm = jnp.matmul(m, warm, precision=NS_PRECISION)
-        use_warm = residual(m_warm) < 0.5
+        r_warm = residual(m_warm)
+        accepted = r_warm < 0.5
+        use_warm = accepted & (r_warm < 1.0 - l_bound)
+        cold_preferred = accepted & ~use_warm
         x0 = jnp.where(use_warm, warm, x_cold)
         mx0 = jnp.where(use_warm, m_warm, mx_cold)
         # a warm start runs the plain iteration: nothing bounds its
@@ -521,6 +559,7 @@ def newton_schulz_inverse_info(
     else:
         x0, mx0, l0 = x_cold, mx_cold, l_cold
         use_warm = lam_max < 0.0  # False, typed like the rest of the carry
+        cold_preferred = use_warm
 
     # prev starts at inf so the first step always runs; it and the
     # counter derive from lam_max (not a fresh constant) so that under
@@ -554,6 +593,7 @@ def newton_schulz_inverse_info(
         # probation starts as ``use_warm`` and ends only at a restart
         restarted=use_warm & ~on_probation,
         scaled=scaled,
+        cold_preferred=cold_preferred,
     )
 
 

@@ -394,8 +394,12 @@ def _solve_groups(slots: int, d: int) -> int:
 # ``factors.NewtonSchulzInfo`` without the inverse (``iterations``; final
 # ``residual``; ``warm``: the previous inverse was accepted as the start;
 # ``restarted``: and then abandoned for the cold start; ``scaled``: the
-# iterations of a cold start's scaled phase, a part of ``iterations``).
-REFRESH_COLUMNS = ('iterations', 'residual', 'warm', 'restarted', 'scaled')
+# iterations of a cold start's scaled phase, a part of ``iterations``;
+# ``cold_preferred``: the previous inverse passed the start's test and the
+# cold start was taken all the same, being provably no worse).
+REFRESH_COLUMNS = (
+    'iterations', 'residual', 'warm', 'restarted', 'scaled', 'cold_preferred',
+)
 _NS_SOLVERS = ('newton_schulz', 'auto')
 
 
@@ -405,7 +409,7 @@ _NS_SOLVERS = ('newton_schulz', 'auto')
 )
 @dataclasses.dataclass(frozen=True)
 class RefreshState:
-    """``DistKFACState.refresh``. ``solved``: ``(slots, 5)`` float32, a
+    """``DistKFACState.refresh``. ``solved``: ``(slots, 6)`` float32, a
     row a slot and :data:`REFRESH_COLUMNS` across, ``iterations`` -1 in
     every row until a refresh has filled it. ``buckets`` is static aux
     data (as ``MetricsState.keys``: the layout travels with the state and
@@ -424,7 +428,8 @@ class RefreshState:
 def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
     """A :class:`RefreshState` on the host (one ``device_get``), bucket by
     bucket: ``side``, ``key``, the live slots' ``iterations``,
-    ``warm_starts``, ``restarts`` and ``worst_residual``, and the
+    ``warm_starts``, ``restarts``, ``cold_preferred`` and
+    ``worst_residual``, and the
     ``trips`` its loop ran (the vmapped ``while_loop`` runs every slot of
     a device's block until the slowest is done: the largest
     ``iterations`` of all its slots, summed over the groups a wide stack
@@ -434,7 +439,8 @@ def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
     ``factors.newton_schulz_inverse_info``), formed the same way. Empty
     while no refresh has filled the array."""
     rows = np.asarray(jax.device_get(refresh.solved), np.float64)
-    # (the benchmark's hand-made states predate ``scaled``: it reads 0)
+    # (the benchmark's hand-made states predate ``scaled`` and
+    # ``cold_preferred``: they read 0)
     rows = np.pad(rows, ((0, 0), (0, len(REFRESH_COLUMNS) - rows.shape[1])))
     col = dict(zip(REFRESH_COLUMNS, rows.T))
     if not (col['iterations'] >= 0).any():
@@ -450,14 +456,18 @@ def _refresh_by_bucket(refresh: RefreshState) -> list[dict[str, Any]]:
                 for group in np.split(col[column][start:start + padded], n)
             ))
 
+        def flagged(column):  # live slots that raised the flag
+            return int(col[column][start:start + live].sum())
+
         out.append({
             'side': side,
             'key': key,
             'iterations': [int(v) for v in its],
             'trips': slowest('iterations'),
             'scaled_trips': slowest('scaled'),
-            'warm_starts': int(col['warm'][start:start + live].sum()),
-            'restarts': int(col['restarted'][start:start + live].sum()),
+            'warm_starts': flagged('warm'),
+            'restarts': flagged('restarted'),
+            'cold_preferred': flagged('cold_preferred'),
             # np.max, not max(): a NaN residual has to show
             'worst_residual': float(
                 np.max(col['residual'][start:start + live])
@@ -484,6 +494,10 @@ def refresh_totals(refresh: RefreshState) -> dict[str, float]:
     - ``refresh/warm_starts``, ``refresh/restarts``: slots whose previous
       inverse was accepted as the start, and those of them that were
       restarted cold;
+    - ``refresh/cold_preferred``: slots whose previous inverse would have
+      been accepted and whose cold start was taken instead, because its
+      worst direction was provably no worse (none of them is among
+      ``refresh/warm_starts``);
     - ``refresh/worst_residual``: the largest final residual (NaN if any
       slot's is).
     """
@@ -504,6 +518,9 @@ def _refresh_totals(buckets: list[dict[str, Any]]) -> dict[str, float]:
         ),
         'refresh/warm_starts': float(sum(b['warm_starts'] for b in buckets)),
         'refresh/restarts': float(sum(b['restarts'] for b in buckets)),
+        'refresh/cold_preferred': float(
+            sum(b['cold_preferred'] for b in buckets)
+        ),
         'refresh/worst_residual': float(
             np.max([b['worst_residual'] for b in buckets])
         ),
@@ -1606,7 +1623,9 @@ class DistributedKFAC:
         the store's ``layers``); ``trips``, the loop trips the device
         executed (the vmapped ``while_loop`` runs every slot of a block
         until its slowest is done); ``warm_starts`` accepted and
-        ``restarts`` among them; ``worst_residual``. ``totals``:
+        ``restarts`` among them; ``cold_preferred``, the previous inverses
+        set aside for a cold start that was provably no worse;
+        ``worst_residual``. ``totals``:
         :func:`refresh_totals` without the ``refresh/`` prefix. ``{}``
         where the state carries no counters (``DistKFACState.refresh``)
         and until the first refresh has filled them.

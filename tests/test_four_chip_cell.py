@@ -5,6 +5,7 @@ four virtual CPU devices through ``harness.run_cell``.
 """
 
 import os
+import re
 import sys
 import time
 
@@ -121,6 +122,33 @@ def test_benchmark_rows_of_the_four_chip_cell():
     for name in ('dev_ms.capture_a', 'dev_ms.capture_g', 'ns_trips_refresh',
                  'host_ms.launch', 'idle_ms.launch', 'dev_ms.capture_patches'):
         assert CELL in rows[name]['workloads'], name
+
+
+def test_the_refresh_on_the_mesh_exchanges_what_it_did(monkeypatch):
+    """The tiny cell's inverse refresh compiled for the 2x2 mesh of virtual
+    CPU devices: which start a Newton-Schulz solve takes is decided
+    inside the solver's ``shard_map`` block from scalars, so the program
+    holds the collectives it held before the selection (68
+    ``all-gather`` and 68 ``collective-permute`` on the parent of PR 42,
+    the same instructions shape for shape) and the loops it held (34)."""
+    monkeypatch.setattr(
+        preconditioner, 'default_compute_method',
+        lambda platform=None: (enums.ComputeMethod.INVERSE, 'newton_schulz'),
+    )
+    cell = rehearse.tiny_cell(harness.load_cell(CELL))
+    engine = harness.build_run(cell, jax.devices()[:4]).trainer.kfac
+    assert dict(engine.mesh.shape) == {'kfac_gw': 2, 'kfac_col': 2}
+    text = jax.jit(engine.update_inverses).lower(
+        engine.init()
+    ).compile().as_text()
+
+    def count(op):
+        return len(re.findall(rf' {op}(?:-start)?\(', text))
+
+    assert count('collective-permute') <= 68
+    assert count('all-gather') <= 68
+    assert count('all-reduce') == count('all-to-all') == 0
+    assert count('while') == 34
 
 
 def test_the_cell_tiny_on_four_devices_through_the_harness(monkeypatch):

@@ -7,7 +7,9 @@ All on the CPU: what is checked is names, counts and structure. No number
 of these runs is a device time.
 """
 
+import collections
 import glob
+import hashlib
 import re
 
 import jax
@@ -18,6 +20,7 @@ import pytest
 
 import kfac_tpu
 from kfac_tpu import checkpoint, tracing, training
+from kfac_tpu.analysis.ir import visitor
 from kfac_tpu.ops import factors
 from kfac_tpu.parallel import DistributedKFAC, kaisa, kaisa_mesh
 from testing import models
@@ -146,6 +149,192 @@ def test_newton_schulz_info_says_warm_and_restarted():
         assert int(poisoned.scaled) == int(cold.scaled)
 
 
+# What the parent commit's solver (0e77fb3, before the start selection)
+# returned for the case ``drifted_keeps_warm``, read from a checkout of it on this CPU in
+# float32: a warm start that is kept has to run that iteration to the bit.
+_PARENT_KEPT = {
+    'iterations': 3,
+    'residual': float.fromhex('0x1.045f22p-19'),  # 1.9399e-06
+    'inverse_sha256': 'ed93f3cdf930160c',
+}
+_IDENTITY_WEIGHT = 0.34  # what is left of a factor's identity init
+_DAMPING = 0.003
+
+
+_SOLO_CASES = (
+    'identity_prefers_cold', 'drifted_keeps_warm', 'zero_x0_is_neither',
+    'poisoned_restarts',
+)
+
+
+def _parent_plain_iteration(m, x, tol=1e-6, max_iters=40):
+    """The parent's loop from an accepted warm start that holds: plain
+    steps until the residual is under ``tol`` or stops falling."""
+    eye = jnp.eye(m.shape[-1], dtype=jnp.float32)
+    mx = jnp.matmul(m, x, precision=factors.NS_PRECISION)
+    resid = jnp.linalg.norm(eye - mx) / jnp.sqrt(jnp.float32(m.shape[-1]))
+    prev, k = jnp.inf, 0
+    while k < max_iters and resid > tol and resid < prev:
+        prev = resid
+        x, mx, resid = factors.newton_schulz_step(m, x, mx)
+        k += 1
+    return x, resid, k
+
+
+def _solve_for(case, **kwargs):
+    """``(factor, x0, info)`` of one of :data:`_SOLO_CASES`, 64 wide."""
+    ema = _drifted_factors(64)
+    eye = jnp.eye(64, dtype=jnp.float32)
+    factor, x0 = {
+        # ``c I`` and the inverse of ``(c I + damping)/0.6``: a factor
+        # still at its identity init a refresh later, the init's weight
+        # having decayed by 0.6 in between. ``M X0 = 0.6 I``: residual
+        # 0.40, inside the ``< 0.5`` test; ``I/||M||`` is the inverse
+        'identity_prefers_cold': lambda: (
+            _IDENTITY_WEIGHT * eye,
+            0.6 / (_IDENTITY_WEIGHT + _DAMPING) * eye,
+        ),
+        # a real spectrum (cold bound ~1e-4) and the inverse of nearly
+        # the same matrix
+        'drifted_keeps_warm': lambda: (
+            ema(3), factors.newton_schulz_inverse(ema(3), 0.004)
+        ),
+        'zero_x0_is_neither': lambda: (ema(3), 0.0 * eye),
+        # passes the RMS test and diverges in the one grown direction
+        'poisoned_restarts': lambda: (
+            ema(3), factors.newton_schulz_inverse(ema(1), _DAMPING)
+        ),
+    }[case]()
+    return factor, x0, factors.newton_schulz_inverse_info(
+        factor, _DAMPING, x0=x0, **kwargs
+    )
+
+
+def _flags(info):
+    return tuple(
+        np.asarray(v).tolist()
+        for v in (info.warm, info.restarted, info.cold_preferred)
+    )
+
+
+@pytest.mark.parametrize(
+    'case', _SOLO_CASES + ('vmap_mixes_the_three', 'differentiable_agrees')
+)
+def test_newton_schulz_start_selection(case):
+    """Which of its two starts a solve takes (``newton_schulz_inverse_info``,
+    "Safeguarded three times"), a case a parameter, float32 on the CPU."""
+    if case == 'identity_prefers_cold':
+        factor, x0, got = _solve_for(case)
+        m = factor + _DAMPING * jnp.eye(64)
+        # premise: the parent's test would have accepted this start
+        r_warm = float(jnp.linalg.norm(jnp.eye(64) - m @ x0) / 8.0)
+        assert r_warm == pytest.approx(0.40, abs=1e-6)
+        assert _flags(got) == (False, False, True)
+        assert int(got.iterations) <= 1 and int(got.scaled) == 0
+        assert float(got.residual) < 1e-6
+        np.testing.assert_allclose(
+            np.asarray(got.inverse), np.linalg.inv(np.asarray(m)), rtol=1e-6
+        )
+        # the same factor with no start at all: the same solve
+        cold = factors.newton_schulz_inverse_info(factor, _DAMPING)
+        assert _flags(cold) == (False, False, False)
+        assert int(cold.iterations) == int(got.iterations)
+        np.testing.assert_array_equal(
+            np.asarray(cold.inverse), np.asarray(got.inverse)
+        )
+    elif case == 'drifted_keeps_warm':
+        factor, x0, got = _solve_for(case)
+        assert _flags(got) == (True, False, False)
+        assert int(got.scaled) == 0
+        # the parent's recorded values, exactly
+        assert int(got.iterations) == _PARENT_KEPT['iterations']
+        assert float(got.residual) == _PARENT_KEPT['residual']
+        inverse = np.asarray(got.inverse)
+        assert hashlib.sha256(inverse.tobytes()).hexdigest()[:16] == (
+            _PARENT_KEPT['inverse_sha256']
+        )
+        # and the parent's loop, run by hand on this machine
+        m = factor + _DAMPING * jnp.eye(64, dtype=jnp.float32)
+        x, resid, k = _parent_plain_iteration(m, x0)
+        assert k == int(got.iterations)
+        assert float(resid) == float(got.residual)
+        np.testing.assert_array_equal(np.asarray(x), inverse)
+    elif case == 'zero_x0_is_neither':
+        factor, _, got = _solve_for(case)
+        assert _flags(got) == (False, False, False)
+        cold = factors.newton_schulz_inverse_info(factor, _DAMPING)
+        assert int(got.iterations) == int(cold.iterations)
+        assert int(got.scaled) == int(cold.scaled) > 0
+        np.testing.assert_array_equal(
+            np.asarray(got.inverse), np.asarray(cold.inverse)
+        )
+    elif case == 'poisoned_restarts':
+        factor, _, got = _solve_for(case)
+        assert _flags(got) == (True, True, False)
+        cold = factors.newton_schulz_inverse_info(factor, _DAMPING)
+        assert int(got.iterations) > int(cold.iterations)
+        assert float(got.residual) < 1e-5
+    elif case == 'vmap_mixes_the_three':
+        alone = [_solve_for(c) for c in _SOLO_CASES]
+        stack = jnp.stack([f for f, _, _ in alone])
+        starts = jnp.stack([w for _, w, _ in alone])
+        got = jax.vmap(
+            lambda f, w: factors.newton_schulz_inverse_info(
+                f, _DAMPING, x0=w
+            )
+        )(stack, starts)
+        assert _flags(got) == (
+            [False, True, False, True], [False, False, False, True],
+            [True, False, False, False],
+        )
+        for i, (_, _, solo) in enumerate(alone):
+            assert int(got.iterations[i]) == int(solo.iterations)
+            assert int(got.scaled[i]) == int(solo.scaled)
+            np.testing.assert_allclose(
+                np.asarray(got.inverse[i]), np.asarray(solo.inverse),
+                rtol=1e-5, atol=1e-7,
+            )
+        assert not np.any(np.asarray(got.warm & got.cold_preferred))
+        # the batched 'auto' pass hands the field through
+        auto = factors.batched_damped_inverse_auto_info(
+            stack, _DAMPING, x0=starts
+        )
+        assert _flags(auto) == _flags(got)
+    else:
+        for each in _SOLO_CASES:
+            _, _, loop = _solve_for(each)
+            _, _, scan = _solve_for(each, differentiable=True)
+            assert _flags(scan) == _flags(loop), each
+            assert int(scan.iterations) == int(loop.iterations), each
+            np.testing.assert_array_equal(
+                np.asarray(scan.inverse), np.asarray(loop.inverse)
+            )
+
+
+@pytest.mark.parametrize('differentiable', [False, True])
+def test_choosing_the_start_adds_no_product_and_no_loop(differentiable):
+    """The program-shape guard of the start selection: with an ``x0`` the
+    solve holds three products (the warm start's ``M @ X0`` and the
+    body's two) and one loop; without, the body's two. Choosing between
+    the two starts is scalar arithmetic and selects."""
+    d = 48
+    m = jnp.eye(d, dtype=jnp.float32)
+    for x0, products in ((m, 3), (None, 2)):
+        jaxpr = jax.make_jaxpr(
+            lambda f, w: factors.newton_schulz_inverse_info(
+                f, 0.003, x0=w, floor=0.3, differentiable=differentiable
+            )
+        )(m, x0).jaxpr
+        # sub-jaxprs (the loop's body and condition, a scan's) included
+        got = collections.Counter(
+            eqn.primitive.name for eqn, _ in visitor.iter_eqns(jaxpr)
+        )
+        assert got['dot_general'] == products
+        assert got['while'] + got['scan'] == 1
+        assert got['scan' if differentiable else 'while'] == 1
+        assert not got['conv_general_dilated'] and not got['cond']
+
+
 def test_batched_auto_info_keeps_the_iterations_own_fields():
     ema = _drifted_factors(64)
     stack = jnp.stack([ema(1), ema(3)])
@@ -161,6 +350,7 @@ def test_batched_auto_info_keeps_the_iterations_own_fields():
     assert not np.asarray(info.restarted).any()
     assert info.scaled.shape == (2,)
     assert (np.asarray(info.scaled) > 0).all()
+    assert not np.asarray(info.cold_preferred).any()  # no start to prefer
 
 
 def _dense_engine(solver='newton_schulz', method='inverse', frac=1.0, dim=255):
@@ -205,6 +395,8 @@ def test_refresh_report_cold_then_warm_then_poisoned(solver):
     assert cold['totals']['slots'] == 2 * n_layers  # an A and a G each
     assert cold['totals']['warm_starts'] == 0
     assert cold['totals']['restarts'] == 0
+    # a fresh state's zero inverses are no start to prefer anything to
+    assert cold['totals']['cold_preferred'] == 0
     assert cold['totals']['iterations'] > 0
     assert cold['totals']['worst_residual'] < 1e-5
     key, slot = engine._a_slot[layer]
@@ -222,9 +414,16 @@ def test_refresh_report_cold_then_warm_then_poisoned(solver):
     assert 0 < bucket['scaled_trips'] < bucket['trips']
     assert cold['totals']['scaled_trips'] == bucket['scaled_trips']
 
-    # the same factors again: every slot starts from its own inverse
+    # the same factors again: the wide factor starts from its own
+    # inverse; the others are still the identity they were made as, whose
+    # cold start is the inverse itself and is taken
     warm = engine.refresh_report(refresh(state))
-    assert warm['totals']['warm_starts'] == warm['totals']['slots']
+    assert warm['buckets']['a'][key]['warm_starts'] == 1
+    assert warm['buckets']['a'][key]['cold_preferred'] == (
+        len(sb.layers) - 1
+    )
+    assert warm['totals']['warm_starts'] == 1
+    assert warm['totals']['cold_preferred'] == warm['totals']['slots'] - 1
     assert warm['totals']['restarts'] == 0
     assert warm['totals']['scaled_trips'] == 0
     assert warm['totals']['iterations'] < cold['totals']['iterations']
@@ -239,7 +438,10 @@ def test_refresh_report_cold_then_warm_then_poisoned(solver):
     assert poisoned['totals']['scaled_trips'] == (
         poisoned['buckets']['a'][key]['scaled_trips']
     ) > 0
-    assert poisoned['totals']['warm_starts'] == poisoned['totals']['slots']
+    assert poisoned['totals']['warm_starts'] == 1
+    assert poisoned['totals']['cold_preferred'] == (
+        poisoned['totals']['slots'] - 1
+    )
     assert (poisoned['buckets']['a'][key]['iterations'][slot]
             > cold['buckets']['a'][key]['iterations'][slot])
     assert poisoned['totals']['worst_residual'] < 1e-5
@@ -273,9 +475,10 @@ def test_refresh_field_is_one_ephemeral_leaf():
     state = engine.init()
     assert kaisa.DistKFACState._fields[-1] == 'refresh'
     stores = engine.a_store + engine.g_store
-    # the device holds the solve's five columns; the layout is static
+    # the device holds the solve's six columns; the layout is static
     assert kaisa.REFRESH_COLUMNS == (
-        'iterations', 'residual', 'warm', 'restarted', 'scaled'
+        'iterations', 'residual', 'warm', 'restarted', 'scaled',
+        'cold_preferred',
     )
     assert state.refresh.solved.shape == (
         sum(sb.padded for sb in stores), len(kaisa.REFRESH_COLUMNS)
@@ -371,7 +574,7 @@ def test_collector_folds_the_refresh_totals():
     }
     assert set(totals) == {
         'slots', 'iterations', 'trips', 'scaled_trips', 'warm_starts',
-        'restarts', 'worst_residual',
+        'restarts', 'cold_preferred', 'worst_residual',
     }
 
 
