@@ -28,6 +28,7 @@ from kfac_tpu import training
 from kfac_tpu.models import (
     ConvMoELM,
     HybridLM,
+    LatentMoELM,
     TransformerLM,
     hybrid_lm_loss,
     lm_loss,
@@ -87,7 +88,8 @@ def main(argv=None, on_step=None) -> float:
     p.add_argument('--seq-len', type=int, default=256)
     p.add_argument('--vocab-size', type=int, default=8192)
     p.add_argument(
-        '--model', choices=['transformer', 'hybrid', 'conv-moe'],
+        '--model',
+        choices=['transformer', 'hybrid', 'conv-moe', 'latent-moe'],
         default='transformer',
         help="'hybrid': the sparse hybrid decoder (models.HybridLM: Gated "
         'DeltaNet layers with a gated-attention layer every fourth, top-k '
@@ -98,18 +100,24 @@ def main(argv=None, on_step=None) -> float:
         'every fourth, one leading dense MLP 4 d wide, then sigmoid-routed '
         'experts d/2 wide with a selection bias); name the dense MLP in '
         "--kfac-skip-layers ('block0/mlp/.*') to leave it to the "
-        'first-order update',
+        "first-order update. 'latent-moe': the latent-attention sparse "
+        'decoder (models.LatentMoELM: multi-head latent attention with a '
+        'latent d/4 wide and heads of d/heads without positions beside a '
+        'rotary part half as wide, one leading dense MLP 3 d wide, then '
+        'sigmoid-routed experts 3 d/8 wide with a selection bias and a '
+        'routing scale beside two ungated shared experts, an untied head)',
     )
     p.add_argument(
         '--num-experts', type=int, default=16,
-        help='hybrid, conv-moe: experts the router scores',
+        help='hybrid, conv-moe, latent-moe: experts the router scores',
     )
     p.add_argument('--experts-per-token', type=int, default=2)
     p.add_argument(
         '--experts-held', type=int, nargs=2, default=None,
         metavar=('FIRST', 'COUNT'),
-        help='hybrid, conv-moe: the share of every layer\'s experts that lives in '
-        'this process (an expert-parallel rank\'s); the router still scores '
+        help='hybrid, conv-moe, latent-moe: the share of every layer\'s '
+        'experts that lives in this process (an expert-parallel rank\'s); '
+        'the router still scores '
         'all of them, and what the absent ones would add is left out. '
         'Default: all',
     )
@@ -153,7 +161,7 @@ def main(argv=None, on_step=None) -> float:
     )
     tokens_np, vocab = data.lm_corpus(args.data_dir, args.vocab_size)
     dtype = jnp.bfloat16 if args.bf16 else jnp.float32
-    sparse = args.model in ('hybrid', 'conv-moe')
+    sparse = args.model in ('hybrid', 'conv-moe', 'latent-moe')
     if sparse and (args.model_shards > 1 or args.seq_shards > 1):
         raise SystemExit(f'--model {args.model} runs data-parallel only')
     experts_held = tuple(args.experts_held) if args.experts_held else None
@@ -170,6 +178,18 @@ def main(argv=None, on_step=None) -> float:
             head_dim=d // args.num_heads,
             num_experts=args.num_experts, top_k=args.experts_per_token,
             expert_width=d // 2, experts_held=experts_held,
+            attention_chunk=min(1024, args.seq_len), dtype=dtype,
+        )
+    elif args.model == 'latent-moe':
+        d = args.d_model
+        model = LatentMoELM(
+            vocab_size=vocab, d_model=d, num_layers=args.num_layers,
+            num_dense_layers=1, dense_width=3 * d, num_heads=args.num_heads,
+            qk_nope_head_dim=d // args.num_heads,
+            qk_rope_head_dim=d // args.num_heads // 2,
+            v_head_dim=d // args.num_heads, kv_lora_rank=d // 4,
+            num_experts=args.num_experts, top_k=args.experts_per_token,
+            expert_width=3 * d // 8, experts_held=experts_held,
             attention_chunk=min(1024, args.seq_len), dtype=dtype,
         )
     elif args.model == 'hybrid':

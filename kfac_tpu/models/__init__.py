@@ -1,11 +1,14 @@
 """Model families: MLP, CIFAR/ImageNet ResNets, Transformer LM, MoE, the
 sparse hybrid LM (Gated DeltaNet + gated attention + top-k experts) and the
 conv-hybrid sparse LM (gated short convolutions + grouped-query attention
-+ sigmoid-routed experts behind leading dense layers)."""
++ sigmoid-routed experts behind leading dense layers) and the
+latent-attention sparse LM (multi-head latent attention + scaled
+sigmoid-routed experts beside ungated shared ones)."""
 
 from kfac_tpu.models.conv_moe import ConvMoELM
 
 from kfac_tpu.models.lora import LoRADense
+from kfac_tpu.models.mla import LatentAttention, LatentMoELM
 from kfac_tpu.models.mlp import MLP
 from kfac_tpu.models.resnet import (
     CifarResNet,
@@ -38,6 +41,8 @@ __all__ = [
     'GatedDeltaNet',
     'HybridLM',
     'ImageNetResNet',
+    'LatentAttention',
+    'LatentMoELM',
     'SparseMoE',
     'TransformerLM',
     'expert_tp_overrides',

@@ -39,7 +39,6 @@ from kfac_tpu import tracing
 from kfac_tpu.models import attention as attention_lib
 from kfac_tpu.models import moe as moe_lib
 from kfac_tpu.models import transformer
-from kfac_tpu.ops import losses
 
 LAYER_TYPES = ('conv', 'full_attention')
 
@@ -278,12 +277,4 @@ class ConvMoELM(nn.Module):
             def head(x):
                 return jnp.dot(x.astype(self.dtype), table.T)
 
-            if targets is None:
-                return head(x)
-            seq = x.shape[1]
-            step = self.loss_chunk if seq % self.loss_chunk == 0 else seq
-            nll = jax.checkpoint(losses.vocab_parallel_nll)
-            return jnp.concatenate([
-                nll(head(x[:, i:i + step]), targets[:, i:i + step])
-                for i in range(0, seq, step)
-            ], axis=1)
+            return transformer.head_or_nll(head, x, targets, self.loss_chunk)

@@ -399,6 +399,11 @@ class SparseMoE(nn.Module):
     unbiased scores and no gradient reaches the bias (an aux-loss-free
     balancer would set it from the load; nothing here moves it).
     ``renorm_eps`` is added to the sum the top-k weights are divided by.
+
+    ``routed_scale`` multiplies the (renormalised) top-k weights: the
+    source's ``routed_scaling_factor``. ``shared_gated=False`` adds the
+    shared expert as it is, ``y + shared(x)``: the layer then declares no
+    ``shared_gate`` and K-FAC has no layer for it.
     """
 
     num_experts: int
@@ -412,6 +417,8 @@ class SparseMoE(nn.Module):
     scoring: str = 'softmax'
     selection_bias: bool = False
     renorm_eps: float = 0.0
+    routed_scale: float = 1.0
+    shared_gated: bool = True
 
     def _route(self, logits: jax.Array) -> tuple[jax.Array, jax.Array]:
         """The top-k experts of every token and their weights, before
@@ -457,6 +464,8 @@ class SparseMoE(nn.Module):
                 if self.renorm_eps:
                     total = total + self.renorm_eps
                 wts = wts / total
+            if self.routed_scale != 1.0:
+                wts = wts * self.routed_scale
             plan = make_plan(idx, wts, first, held, self.block_rows)
         with tracing.model_scope('moe_experts'):
             y = Experts(held, self.width, dtype=self.dtype, name='experts')(
@@ -464,11 +473,16 @@ class SparseMoE(nn.Module):
             )
         if self.shared_width:
             with tracing.model_scope('mlp'):
-                gate = nn.Dense(
-                    1, use_bias=False, dtype=jnp.float32, name='shared_gate'
-                )(xf32)
+                if self.shared_gated:
+                    gate = nn.Dense(
+                        1, use_bias=False, dtype=jnp.float32,
+                        name='shared_gate',
+                    )(xf32)
                 shared = GatedMLP(
                     self.shared_width, dtype=self.dtype, name='shared'
                 )(xf)
-                y = y + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
+                if self.shared_gated:
+                    y = y + jax.nn.sigmoid(gate) * shared.astype(jnp.float32)
+                else:
+                    y = y + shared.astype(jnp.float32)
         return y.reshape(*lead, d)

@@ -356,15 +356,23 @@ class HybridLM(nn.Module):
                 self.vocab_size, use_bias=False, dtype=self.dtype,
                 name='lm_head',
             )
-            if targets is None:
-                return head(x)
-            seq = x.shape[1]
-            step = self.loss_chunk if seq % self.loss_chunk == 0 else seq
-            nll = jax.checkpoint(losses.vocab_parallel_nll)
-            return jnp.concatenate([
-                nll(head(x[:, i:i + step]), targets[:, i:i + step])
-                for i in range(0, seq, step)
-            ], axis=1)
+            return head_or_nll(head, x, targets, self.loss_chunk)
+
+
+def head_or_nll(head, x: jax.Array, targets: jax.Array | None, chunk: int):
+    """``head(x)``, the logits ``(B, S, V)``; with ``targets`` every
+    position's negative log-likelihood ``(B, S)`` instead, ``chunk``
+    positions at a time (the whole sequence where it is no multiple), each
+    chunk's softmax rematerialised: the logits are never held whole."""
+    if targets is None:
+        return head(x)
+    seq = x.shape[1]
+    step = chunk if seq % chunk == 0 else seq
+    nll = jax.checkpoint(losses.vocab_parallel_nll)
+    return jnp.concatenate([
+        nll(head(x[:, i:i + step]), targets[:, i:i + step])
+        for i in range(0, seq, step)
+    ], axis=1)
 
 
 def lm_loss(model: TransformerLM):

@@ -662,6 +662,24 @@ class DistributedKFAC:
         self.in_layout_share = sum(
             n for n, own in sizes if own and self._in_layout
         ) / max(1, sum(n for n, _ in sizes))
+        # logical bytes of factors and inverses by the part of a block a
+        # layer lies under (the second component of its name: 'mixer',
+        # 'mlp', 'moe'; a name of one component is its own part): a
+        # square a side for the factor and one for its inverse, the A
+        # side counted once a group, slot padding not counted
+        item = (
+            jnp.dtype(self.config.factor_dtype).itemsize
+            + jnp.dtype(self.config.inv_dtype).itemsize
+        )
+        self.state_bytes_by_part: dict[str, int] = {}
+        for name, h in self.registry.layers.items():
+            part = name.split('/')[:2][-1]
+            dims = [h.g_factor_shape[0]]
+            if self.a_leader(name) == name:
+                dims.append(h.a_factor_shape[0])
+            self.state_bytes_by_part[part] = self.state_bytes_by_part.get(
+                part, 0
+            ) + item * sum(d * d for d in dims)
         # inverse_solver='auto' is served by
         # factors.batched_damped_inverse_auto_info: one scalar runtime cond
         # per device-local block, so the batched Cholesky runs only when some
@@ -2116,6 +2134,10 @@ class DistributedKFAC:
                 'device' if self._in_layout else
                 'gradient stacks laid out like the decompositions'
             ) + ')',
+            'factor and inverse bytes by part of a block: ' + ', '.join(
+                f'{part} {n / 1e9:.3f} GB'
+                for part, n in self.state_bytes_by_part.items()
+            ),
             self.config.describe(),
             'stat transport buckets (stacked batched decompositions):',
         ]

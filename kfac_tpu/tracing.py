@@ -73,7 +73,10 @@ CAPTURE_SCOPES = {
 # core between them (QK-norm, rotary, scores, softmax or the flash
 # partials, values, the gate), ``model.gdn_scan`` the chunked delta-rule
 # scan and ``model.short_conv`` the gated short convolution (``B * x~``,
-# the depthwise taps, ``C * conv``). ``model.mlp`` is a dense or gated MLP
+# the depthwise taps, ``C * conv``), ``model.mla_latent`` latent
+# attention's glue between its projections and the core (rotary on the
+# rotary parts, the shared key head broadcast, the heads put together).
+# ``model.mlp`` is a dense or gated MLP
 # (a shared expert with its gate too), ``model.moe_route`` the router with
 # its top-k and row plan, ``model.moe_experts`` the grouped expert
 # products. ``model.norm`` is a block's norms (QK-norm is the attention
@@ -89,6 +92,7 @@ MODEL_SCOPES = {
     'attention': 'model.attention',
     'gdn_scan': 'model.gdn_scan',
     'short_conv': 'model.short_conv',
+    'mla_latent': 'model.mla_latent',
     'mlp': 'model.mlp',
     'moe_route': 'model.moe_route',
     'moe_experts': 'model.moe_experts',

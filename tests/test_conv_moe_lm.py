@@ -607,7 +607,8 @@ def test_new_rows_of_the_benchmark_name_the_new_cell():
     assert rows['kfac_first_order_share'] == {
         'name': 'kfac_first_order_share', 'unit': '%', 'better': 'lower',
         'source': 'program_counter', 'layer': 'engine',
-        'moves': 'kfac_overhead', 'workloads': [CELL],
+        'moves': 'kfac_overhead',
+        'workloads': [CELL, 'kanana-2-30b-a3b.kfac-10-100'],
     }
     read = {m['name'] for m in harness.layer_rows(harness.load_cell(CELL))}
     for name in (
