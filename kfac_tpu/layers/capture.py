@@ -133,6 +133,42 @@ def _make_stack_gtap(
     return gtap
 
 
+def contract_late(
+    registry: registry_lib.Registry, a_stats: dict[str, Any], after: Any
+) -> dict[str, jax.Array]:
+    """The A factors :meth:`CurvatureCapture.tapped` left uncontracted
+    (``LayerHelper.contracts_late``), contracted once ``after`` (the
+    gradients) exists.
+
+    Such a layer hands on its inputs, a tuple with one entry an
+    invocation, and its factor (their factors' sum) is made here from
+    each input times a one the compiler cannot see through before
+    ``after`` is computed. What the products read is then a value of
+    their own, made from what the backward pass keeps anyway and gone
+    with them, and the step's fullest moment (the start of the backward
+    pass) holds none of their results; contracted in the forward pass
+    the layer's input is written out whole there, its weight gradient
+    keeps that copy in place of recomputing it, and the finished factors
+    wait beside it (0.5 GB of ResNet-50's capture step). The patch rows'
+    products need no such help: the compiler already leaves rows nine
+    times their input for last.
+    """
+    late = {n: v for n, v in a_stats.items() if isinstance(v, tuple)}
+    if not late:
+        return a_stats
+    out = dict(a_stats)
+    with tracing.capture_scope('a'):
+        one, _ = jax.lax.optimization_barrier(
+            (jnp.ones((), jnp.float32), after)
+        )
+        for name, inputs in late.items():
+            out[name] = sum(
+                registry.layers[name].get_a_factor(a * one.astype(a.dtype))
+                for a in inputs
+            )
+    return out
+
+
 def expand_stacks(
     registry: registry_lib.Registry, g_stats: dict[str, Any]
 ) -> tuple[dict[str, Any], dict[str, jax.Array]]:
@@ -244,6 +280,9 @@ class CurvatureCapture:
         Differentiating w.r.t. ``gstats`` yields the G factors.
         ``weights`` holds per-capture evidence weights for layers whose
         helper defines one (routed MoE layers); other layers are absent.
+        ``a_stats`` holds a layer's summed A factors, or for a layer that
+        contracts late the tuple of its inputs: :func:`contract_late`
+        makes the factor of those once the gradients exist.
         """
         registry = self.registry
         gtaps = self._gtaps
@@ -353,7 +392,11 @@ class CurvatureCapture:
                     led[name] = (iargs[0],)
                 with tracing.capture_scope('a'):
                     a = layer_input(mod, iargs[0])
-                    a_fac = None if shared else helper.get_a_factor(a)
+                    if helper.contracts_late:
+                        a_stats[name] = a_stats.get(name, ()) + (a,)
+                        a_fac = None
+                    else:
+                        a_fac = None if shared else helper.get_a_factor(a)
                     if helper.weighted:
                         # traffic-weighted accumulation: sum w_i * F_i
                         # here, divide by sum w_i in run() — a repeated
@@ -400,6 +443,7 @@ class CurvatureCapture:
             )
             g_stats, traffic = expand_stacks(self.registry, g_stats)
             g_sums, g_weights = split_g_stats(g_stats)
+            a_stats = contract_late(self.registry, a_stats, grads)
             a_avg = weighted_average(a_stats, counts, weights)
             g_avg = weighted_average(
                 {n: g_sums[n] for n in counts}, counts, g_weights

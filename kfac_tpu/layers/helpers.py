@@ -10,7 +10,7 @@ preconditioner operates on.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Sequence
+from typing import Any, Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +44,10 @@ class LayerHelper:
     def get_a_factor(self, a: jax.Array) -> jax.Array:
         """Per-batch A factor from the layer input (forward tap)."""
         raise NotImplementedError
+
+    #: whether the capture leaves :meth:`get_a_factor` for after the
+    #: backward pass (:func:`kfac_tpu.layers.capture.contract_late`)
+    contracts_late = False
 
     @property
     def weighted(self) -> bool:
@@ -294,6 +298,22 @@ class Conv2dHelper(LayerHelper):
     def g_factor_shape(self) -> tuple[int, int]:
         return (self.out_channels, self.out_channels)
 
+    @property
+    def patchless(self) -> bool:
+        """Whether the A factor is assembled from the activation's
+        autocorrelation, no patch row written: the geometry decides
+        (:func:`kfac_tpu.ops.cov.conv2d_a_is_patchless`)."""
+        return cov.conv2d_a_is_patchless(
+            self.kernel_size, self.strides, self.padding
+        )
+
+    @property
+    def contracts_late(self) -> bool:
+        """The autocorrelation's products read the layer's input whole:
+        left where the tap is, they hold it and their results through
+        the step's fullest moment (``capture.contract_late``)."""
+        return self.patchless
+
     def get_a_factor(self, a: jax.Array) -> jax.Array:
         return cov.conv2d_a_factor(
             a,
@@ -496,6 +516,17 @@ class ExpertStackTap:
         return jnp.concatenate(
             [plan.rows, plan.dropped[None]]
         ).astype(jnp.float32)
+
+
+def patchless_share(layers: Iterable[LayerHelper]) -> float | None:
+    """The share of the convolutions with a kernel larger than 1 x 1
+    whose A factor takes the patchless route
+    (:attr:`Conv2dHelper.patchless`); ``None`` where there is none."""
+    routes = [
+        h.patchless for h in layers
+        if isinstance(h, Conv2dHelper) and tuple(h.kernel_size) != (1, 1)
+    ]
+    return sum(routes) / len(routes) if routes else None
 
 
 def matrix_param_count(helper: LayerHelper) -> int:

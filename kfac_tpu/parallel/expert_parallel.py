@@ -463,6 +463,8 @@ def combined_value_stats_and_grad(
         # the inputs, G-side from the cotangents — others by invocation
         # count); EP stats are already normalized in-body
         g_sums, g_wts = capture_lib.split_g_stats(flax_g)
+        if cap is not None:
+            fa = capture_lib.contract_late(cap.registry, fa, grads)
         a_all = dict(capture_lib.weighted_average(fa, counts, wts))
         g_all = dict(
             capture_lib.weighted_average(

@@ -662,6 +662,9 @@ class DistributedKFAC:
         self.in_layout_share = sum(
             n for n, own in sizes if own and self._in_layout
         ) / max(1, sum(n for n, _ in sizes))
+        # the share of the convolutions wider than 1 x 1 whose A factor is
+        # assembled with no patch rows (``None``: no such convolution)
+        self.patchless_share = self.config.patchless_share
         # logical bytes of factors and inverses by the part of a block a
         # layer lies under (the second component of its name: 'mixer',
         # 'mlp', 'moe'; a name of one component is its own part): a
@@ -2134,6 +2137,7 @@ class DistributedKFAC:
                 'device' if self._in_layout else
                 'gradient stacks laid out like the decompositions'
             ) + ')',
+            *self.config.describe_patchless(),
             'factor and inverse bytes by part of a block: ' + ', '.join(
                 f'{part} {n / 1e9:.3f} GB'
                 for part, n in self.state_bytes_by_part.items()

@@ -450,6 +450,12 @@ class KFACPreconditioner:
             dict(self.registry.a_groups) if self.async_inverse is None
             else {}
         )
+        # counted once, from the registry: the share of the convolutions
+        # with a kernel larger than 1 x 1 whose A factor is assembled from
+        # the activation's autocorrelation (``None``: no such convolution)
+        self.patchless_share = helpers_lib.patchless_share(
+            self.registry.layers.values()
+        )
         if self.metrics is True:
             self.metrics = metrics_lib.MetricsConfig()
         elif self.metrics is False:
@@ -1255,6 +1261,7 @@ class KFACPreconditioner:
                 f'  metrics: grad_norms={mc.grad_norms} '
                 f'factor_bounds={mc.factor_bounds} staleness={mc.staleness}'
             )
+        lines.extend('  ' + line for line in self.describe_patchless())
         for name, h in self.registry.layers.items():
             lines.append(
                 f'  {name}: {type(h).__name__} '
@@ -1264,6 +1271,16 @@ class KFACPreconditioner:
             )
         lines.append(self.describe_a_groups())
         return '\n'.join(lines)
+
+    def describe_patchless(self) -> list[str]:
+        """The line of :meth:`describe` for :attr:`patchless_share`, or
+        none where no convolution wider than 1 x 1 is registered."""
+        if self.patchless_share is None:
+            return []
+        return [
+            'convolution A factors with no patch rows: '
+            f'{self.patchless_share:.1%} of the kernels larger than 1x1'
+        ]
 
     def describe_a_groups(self) -> str:
         """The A groups as this engine stores them (each group once)."""

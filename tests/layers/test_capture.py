@@ -139,3 +139,84 @@ def test_grad_scale_unscaling():
     )
     # A stats are unaffected by loss scaling
     np.testing.assert_allclose(stats_scaled.a['fc1'], stats.a['fc1'], rtol=1e-6)
+
+
+class _SameConvTwice(nn.Module):
+    """A stride-1 'SAME' convolution applied twice (its A factor is the
+    autocorrelation route's), a strided one, and a head."""
+
+    @nn.compact
+    def __call__(self, x):
+        same = nn.Conv(3, (3, 3), padding='SAME', name='same')
+        x = nn.relu(same(x))
+        x = nn.relu(same(x))
+        x = nn.Conv(4, (3, 3), strides=(2, 2), name='strided')(x)
+        return nn.Dense(2, name='head')(x.mean(axis=(1, 2)))
+
+
+def _primitives(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+def test_patchless_conv_contracts_after_the_gradients():
+    """A convolution whose A factor is its input's autocorrelation hands
+    its inputs on (one an invocation) and ``contract_late`` makes the
+    factor once the gradients exist: the same statistics as contracting
+    at the tap, behind an ``optimization_barrier`` that holds the
+    gradients; a model without such a layer traces to no barrier."""
+    m = _SameConvTwice()
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 8, 8, 3))
+    params = m.init(jax.random.PRNGKey(0), x)['params']
+    reg = registry_lib.register_model(m, x)
+    assert [h.patchless for h in reg.layers.values() if hasattr(
+        h, 'patchless')] == [True, False]
+
+    def loss_fn(p, xx):
+        return jnp.sum(m.apply({'params': p}, xx) ** 2)
+
+    cap = capture_lib.CurvatureCapture(reg)
+    run = cap.value_stats_and_grad(loss_fn)
+    (loss, _), grads, stats = jax.jit(run)(params, x)
+    loss0, grads0 = jax.value_and_grad(loss_fn)(params, x)
+    np.testing.assert_allclose(loss, loss0, rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(grads0)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+    # the tapped function hands on the two inputs, uncontracted
+    _, (_, a_stats, counts, _) = cap.tapped(loss_fn)(
+        params, cap.zero_gstats(), x)
+    assert isinstance(a_stats['same'], tuple) and len(a_stats['same']) == 2
+    assert int(counts['same']) == 2
+    assert a_stats['strided'].shape == reg.layers['strided'].a_factor_shape
+    h = nn.relu(nn.Conv(3, (3, 3), padding='SAME').apply(
+        {'params': params['same']}, x))
+    helper = reg.layers['same']
+    expected = (helper.get_a_factor(x) + helper.get_a_factor(h)) / 2
+    np.testing.assert_allclose(stats.a['same'], expected, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(
+        stats.a['strided'], a_stats['strided'], rtol=1e-6)
+
+    eqns = _primitives(jax.make_jaxpr(run)(params, x).jaxpr, [])
+    barrier, = [e for e in eqns if e.primitive.name == 'optimization_barrier']
+    # a scalar one and every gradient leaf go in; the convolutions of the
+    # late factor come after it
+    assert len(barrier.invars) == 1 + len(jax.tree_util.tree_leaves(grads))
+    order = [e.primitive.name for e in eqns]
+    late = [i for i, e in enumerate(eqns)
+            if e.primitive.name == 'conv_general_dilated'
+            and e.params['batch_group_count'] == 4]
+    assert len(late) == 2 and min(late) > order.index('optimization_barrier')
+
+    tiny = models.TinyModel()
+    tx, ty = models.regression_data(jax.random.PRNGKey(1), n=16, dim=6)
+    tparams = tiny.init(jax.random.PRNGKey(0), tx)['params']
+    trun = capture_lib.CurvatureCapture(
+        registry_lib.register_model(tiny, tx)
+    ).value_stats_and_grad(models.mse_loss(tiny))
+    assert 'optimization_barrier' not in str(
+        jax.make_jaxpr(trun)(tparams, (tx, ty)))

@@ -39,19 +39,27 @@ def _normal(seed, shape, dtype):
     return x.astype(dtype)
 
 
-def _dots(fn, *args):
-    """Every ``dot_general`` equation in ``fn``'s jaxpr, nested ones too."""
+def _products(fn, *args):
+    """Every ``dot_general`` and ``conv_general_dilated`` equation in
+    ``fn``'s jaxpr, nested ones too."""
     found = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == 'dot_general':
+            if eqn.primitive.name in ('dot_general', 'conv_general_dilated'):
                 found.append(eqn)
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return found
+
+
+def _dots(fn, *args):
+    """Every ``dot_general`` equation among them."""
+    return [
+        e for e in _products(fn, *args) if e.primitive.name == 'dot_general'
+    ]
 
 
 def _row_contractions(fn, *args):
@@ -207,6 +215,140 @@ def test_conv_scale_beyond_int32():
         assert _rel(got, x64.T @ x64 / s) < 10 * ACC_TOL
 
 
+# ------------------------------ the convolution's A side without patch rows
+
+
+def _im2col_factor(x, k, stride, has_bias):
+    """The float64 factor of the im2col rows, ``rows^T rows / (N s^2)``."""
+    patches = _im2col(_f64(x), k, stride)
+    s = patches.shape[1] * patches.shape[2]
+    rows = patches.reshape(-1, patches.shape[-1])
+    if has_bias:
+        rows = _with_ones(rows, np.ones(len(rows)))
+    return rows.T @ rows / (len(rows) * s * s)
+
+
+def _parent_conv_a(x, kernel_size, strides, padding, has_bias):
+    """``conv2d_a_factor`` as it stood before the patchless route: patch
+    rows, then ``get_cov``."""
+    patches = cov.extract_patches_nhwc(x, kernel_size, strides, padding)
+    s = patches.shape[1] * patches.shape[2]
+    rows = patches.reshape(-1, patches.shape[-1])
+    if has_bias:
+        rows = cov.append_bias_ones(rows)
+    return cov.get_cov(rows, scale=float(rows.shape[0] * s**2))
+
+
+@pytest.mark.parametrize('explicit', [False, True], ids=['SAME', 'pairs'])
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('has_bias', [False, True], ids=['nobias', 'bias'])
+@pytest.mark.parametrize(
+    'k,h,w', [(3, 6, 4), (3, 3, 3), (3, 1, 5), (5, 7, 9), (5, 5, 5),
+              (5, 2, 3)],
+    ids=lambda v: str(v),
+)
+def test_patchless_a_factor_is_the_float64_im2col_factor(
+    k, h, w, has_bias, dtype, explicit
+):
+    """The autocorrelation, less the halo's rows and columns, plus its
+    corners, is the factor of the patch rows: maps wider than tall, as
+    small as the kernel and as small as the kernel's reach, both paddings
+    that keep the grid."""
+    x = _normal(20 + k, (3, h, w, 4), dtype)
+    padding = [(k // 2, k // 2)] * 2 if explicit else 'SAME'
+    assert cov.conv2d_a_is_patchless((k, k), (1, 1), padding)
+    fn = jax.jit(
+        lambda x: cov.conv2d_a_factor(x, (k, k), (1, 1), padding, has_bias))
+    assert 'conv_general_dilated_patches' not in str(jax.make_jaxpr(fn)(x))
+    assert all(
+        e.params['dimension_numbers'].lhs_spec[0] == 3  # batch contracted
+        for e in _products(fn, x)
+    )
+    got = fn(x)
+    assert got.dtype == jnp.float32
+    assert got.shape == (4 * k * k + has_bias,) * 2
+    assert _rel(got, _im2col_factor(x, k, 1, has_bias)) < ACC_TOL
+    assert np.array_equal(np.asarray(got), np.asarray(got).T)
+
+
+@pytest.mark.parametrize(
+    'kernel,strides,padding,patchless',
+    [
+        ((3, 3), (1, 1), 'SAME', True),
+        ((3, 3), (1, 1), 'same', True),
+        ((3, 3), (1, 1), [(1, 1), (1, 1)], True),
+        ((5, 5), (1, 1), ((2, 2), (2, 2)), True),
+        ((7, 7), (1, 1), 'SAME', True),
+        ((1, 3), (1, 1), 'SAME', True),
+        ((3, 3), (2, 2), 'SAME', False),
+        ((3, 3), (1, 2), 'SAME', False),
+        ((7, 7), (2, 2), [(3, 3), (3, 3)], False),
+        ((1, 1), (1, 1), 'SAME', False),
+        ((1, 1), (1, 1), 'VALID', False),
+        ((2, 2), (1, 1), 'SAME', False),
+        ((4, 3), (1, 1), 'SAME', False),
+        ((3, 3), (1, 1), 'VALID', False),
+        ((3, 3), (1, 1), [(0, 0), (0, 0)], False),
+        ((3, 3), (1, 1), [(1, 1), (0, 2)], False),
+        ((3, 3), (1, 1), [(2, 2), (2, 2)], False),
+    ],
+)
+def test_route_predicate_and_the_fallback_to_the_bit(
+    kernel, strides, padding, patchless
+):
+    """Stride, kernel and padding decide, nothing else; and a geometry
+    that keeps im2col gives the parent's matrix bit for bit."""
+    assert cov.conv2d_a_is_patchless(kernel, strides, padding) is patchless
+    x = _normal(31, (2, 9, 8, 3), jnp.bfloat16)
+    for has_bias in (False, True):
+        got = cov.conv2d_a_factor(x, kernel, strides, padding, has_bias)
+        parent = _parent_conv_a(x, kernel, strides, padding, has_bias)
+        if patchless:
+            assert _rel(got, _f64(parent)) < 2 * ACC_TOL
+        else:
+            assert np.array_equal(np.asarray(got), np.asarray(parent))
+
+
+def test_a_map_inside_the_kernels_reach_keeps_im2col():
+    """A 5 x 5 kernel reaches 2 beyond an edge; a map 1 tall has no band
+    2 deep to read the halo from, and takes the rows."""
+    x = _normal(32, (2, 1, 6, 3), jnp.bfloat16)
+    got = cov.conv2d_a_factor(x, (5, 5), (1, 1), 'SAME', True)
+    parent = _parent_conv_a(x, (5, 5), (1, 1), 'SAME', True)
+    assert np.array_equal(np.asarray(got), np.asarray(parent))
+    assert _rel(got, _im2col_factor(x, 5, 1, True)) < ACC_TOL
+
+
+@pytest.mark.parametrize('use_bias', [False, True], ids=['nobias', 'bias'])
+@pytest.mark.parametrize('k', [3, 5])
+def test_patchless_factor_is_in_the_order_grads_to_matrix_packs(k, use_bias):
+    """Channel-major ``(c, kh, kw)`` with the bias column last: with ``W``
+    a real kernel packed by ``Conv2dHelper.grads_to_matrix``, ``sum |y|^2``
+    of the layer's own output ``y = P W^T`` is ``N s^2 tr(W A W^T)``; any
+    other order of A's features breaks it."""
+    conv = nn.Conv(5, (k, k), padding='SAME', use_bias=use_bias)
+    x = _normal(40 + k, (2, 6, 7, 3), jnp.float32)
+    params = conv.init(jax.random.PRNGKey(1), x)['params']
+    if use_bias:
+        params = {**params, 'bias': _normal(41, (5,), jnp.float32)}
+    registry = kfac_tpu.register_model(conv, x)
+    helper, = registry.layers.values()
+    assert helper.patchless
+    a = np.asarray(helper.get_a_factor(x), np.float64)
+    assert np.array_equal(a, a.T)
+    w = np.asarray(helper.grads_to_matrix(params), np.float64)
+    y = np.asarray(conv.apply({'params': params}, x), np.float64)
+    n, s = y[..., 0].size, 6 * 7
+    assert np.isclose(
+        np.sum(y * y), n * s * s * np.trace(w @ a @ w.T), rtol=1e-5)
+    # and not by symmetry of the data: a permuted order does break it
+    perm = np.arange(a.shape[0])
+    perm[:k * k] = perm[:k * k][::-1]
+    assert not np.isclose(
+        np.sum(y * y), n * s * s * np.trace(w @ a[perm][:, perm] @ w.T),
+        rtol=1e-3)
+
+
 def test_routed_factor_of_an_empty_buffer_is_zero():
     a = jnp.zeros((2, 8, 6), jnp.bfloat16)
     assert not np.asarray(cov.routed_linear_a_factor(a, True)).any()
@@ -228,14 +370,24 @@ FACTORS = {
 
 @pytest.mark.parametrize('kind', sorted(FACTORS))
 def test_float32_factor_is_traced_at_highest_and_bf16_is_not(kind):
+    """Whatever products a factor is made of (one row-contracting
+    ``dot_general``; for the stride-1 3 x 3 convolution's A side the four
+    correlations of the patchless route, and no ``dot_general``): operands
+    in the dtype they arrive in, float32 accumulation, ``HIGHEST`` exactly
+    for float32."""
     for dtype, highest in ((jnp.float32, True), (jnp.bfloat16, False)):
-        products = _row_contractions(
-            FACTORS[kind], jnp.ones((128, 16), dtype))
-        assert len(products) == 1
-        eqn, = products
-        assert {v.aval.dtype for v in eqn.invars} == {jnp.dtype(dtype)}
-        assert eqn.params['preferred_element_type'] == jnp.float32
-        assert _is_highest(eqn) is highest
+        ones = jnp.ones((128, 16), dtype)
+        products = _products(FACTORS[kind], ones)
+        names = sorted(e.primitive.name for e in products)
+        if kind == 'conv_a':
+            assert names == ['conv_general_dilated'] * 4
+        else:
+            assert names == ['dot_general']
+            assert len(_row_contractions(FACTORS[kind], ones)) == 1
+        for eqn in products:
+            assert {v.aval.dtype for v in eqn.invars} == {jnp.dtype(dtype)}
+            assert eqn.params['preferred_element_type'] == jnp.float32
+            assert _is_highest(eqn) is highest
 
 
 @pytest.mark.parametrize(
@@ -374,3 +526,37 @@ def test_no_kernel_in_any_context_and_all_agree(monkeypatch, context, dtype):
     assert _rel(got, a64.T @ a64 / 512) < ACC_TOL
     np.testing.assert_allclose(
         got, jax.jit(cov.get_cov)(a), rtol=1e-5, atol=1e-6)
+
+
+def _conv_a(x):
+    return cov.conv2d_a_factor(x, (3, 3), (1, 1), 'SAME', True)
+
+
+def _conv_a_local_batches(axis_names):
+    def run(x):
+        return jax.shard_map(
+            lambda rows: jax.lax.pmean(_conv_a(rows), 'a'), mesh=_mesh(),
+            in_specs=P('a'), out_specs=P(), check_vma=False, **axis_names,
+        )(x)
+
+    return run
+
+
+CONV_CONTEXTS = {
+    'gspmd': lambda x: _conv_a(jax.lax.with_sharding_constraint(
+        x, NamedSharding(_mesh(), P(('a', 'b'))))),
+    'shard_map': _conv_a_local_batches({}),
+    'partial_manual': _conv_a_local_batches({'axis_names': {'a'}}),
+}
+
+
+@pytest.mark.parametrize('context', sorted(CONV_CONTEXTS))
+def test_patchless_factor_over_a_sharded_batch(context):
+    """The batch is what the route's convolutions contract: sharded under
+    GSPMD the partitioner sums the devices' partial correlations, and in a
+    ``shard_map`` (fully or partly manual) the mean of the local factors
+    is the factor, as with the row-contracting product."""
+    x = _normal(50, (8, 6, 5, 4), jnp.bfloat16)
+    got = jax.jit(CONV_CONTEXTS[context])(x)
+    assert _rel(got, _im2col_factor(x, 3, 1, True)) < ACC_TOL
+    np.testing.assert_allclose(got, _conv_a(x), rtol=1e-5, atol=1e-7)

@@ -10,6 +10,7 @@ inverses, and by the primitives the replicated program is made of.
 
 import json
 import os
+import types
 
 import flax.linen as nn
 import jax
@@ -22,6 +23,7 @@ from kfac_tpu import health as health_lib
 from kfac_tpu.layers import registry as registry_lib
 from kfac_tpu.models import moe
 from kfac_tpu.parallel import DistributedKFAC, kaisa, kaisa_mesh
+from benchmark import harness
 from testing import models
 
 
@@ -451,29 +453,87 @@ def test_in_layout_share_counts_gradient_elements():
     assert 0.0 < dk.in_layout_share < 1.0
 
 
+class _FourConvs(nn.Module):
+    """A stem, a stride-1 and a stride-2 3 x 3, a 1 x 1 and a head: the
+    kinds of convolution a bottleneck ResNet registers."""
+
+    @nn.compact
+    def __call__(self, x):
+        x = nn.Conv(4, (7, 7), strides=(2, 2), padding=[(3, 3), (3, 3)],
+                    use_bias=False, name='stem')(x)
+        x = nn.Conv(4, (3, 3), padding='SAME', use_bias=False, name='same')(x)
+        x = nn.Conv(4, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)],
+                    use_bias=False, name='strided')(x)
+        x = nn.Conv(8, (1, 1), use_bias=False, name='pointwise')(x)
+        return nn.Dense(3, name='head')(x.mean(axis=(1, 2)))
+
+
+def test_patchless_share_counts_the_convolutions_wider_than_1x1():
+    """The capture layer's counter, once at construction from the
+    registry, on both engines: of the convolutions with a kernel larger
+    than 1 x 1 the share whose A factor is assembled with no patch rows;
+    ``None`` where none is registered."""
+    reg, dk = _share(_FourConvs(), jnp.ones((2, 16, 16, 3)))
+    assert dk.patchless_share == dk.config.patchless_share == 1 / 3
+    assert [h.patchless for h in reg.layers.values() if hasattr(
+        h, 'patchless')] == [False, True, False, False]
+    for text in (dk.describe(), dk.config.describe()):
+        assert 'no patch rows: 33.3% of the kernels larger than 1x1' in text
+    _, dk = _share(models.TinyConvNet(), jnp.ones((2, 28, 28, 1)))
+    assert dk.patchless_share == 0.0  # two 5 x 5 'VALID' convolutions
+    _, dk = _share(BiasedWide(), jnp.ones((16, 768)))
+    assert dk.patchless_share is None and dk.config.patchless_share is None
+    assert 'patch rows' not in dk.describe() + dk.config.describe()
+
+
+def _reader_context(engine):
+    """What a per-layer reader is handed, around ``engine`` alone."""
+    run = types.SimpleNamespace(trainer=types.SimpleNamespace(kfac=engine))
+    return harness.LayerContext(
+        cell={}, run=run, devices=[], first_order_rows=[], rows=[],
+        traced_rows=[], trace={'planes': []}, windows={}, throughput=0.0,
+    )
+
+
+def test_benchmark_row_reads_the_patchless_counter():
+    """``capture_patchless_share`` of ``BENCHMARK.json``: the engine's
+    counter in percent in the two ResNet-50 cells, nothing where no
+    convolution is registered or the engine has no such counter."""
+    name = 'capture_patchless_share'
+    _, dk = _share(_FourConvs(), jnp.ones((2, 16, 16, 3)))
+    read = harness.read_layer_metric
+    assert read(name, _reader_context(dk)) == pytest.approx(100 / 3)
+    _, lm = _share(BiasedWide(), jnp.ones((16, 768)))
+    assert read(name, _reader_context(lm)) is None
+    assert read(name, _reader_context(types.SimpleNamespace())) is None
+    with open(os.path.join(harness.ROOT, 'BENCHMARK.json')) as f:
+        bench = json.load(f)
+    cells = ['resnet50.kfac-10-100', 'resnet50.kaisa-hybrid-4chip']
+    assert next(m for m in bench['per_layer'] if m['name'] == name) == {
+        'name': name, 'unit': '%', 'better': 'higher',
+        'source': 'program_counter', 'layer': 'capture',
+        'moves': 'kfac_overhead', 'workloads': cells,
+    }
+    for w in bench['workloads']:
+        rows = harness.layer_rows(harness.load_cell(w['name']))
+        assert (name in {m['name'] for m in rows}) is (w['name'] in cells)
+
+
 def test_benchmark_row_reads_the_engines_counter():
     """``precondition_in_layout_share`` of ``BENCHMARK.json``: the engine's
     counter in percent in every cell, nothing on a program without it."""
-    import types
-
-    from benchmark import harness
-
-    def ctx(engine):
-        run = types.SimpleNamespace(trainer=types.SimpleNamespace(kfac=engine))
-        return harness.LayerContext(
-            cell={}, run=run, devices=[], first_order_rows=[], rows=[],
-            traced_rows=[], trace={'planes': []}, windows={}, throughput=0.0,
-        )
-
     name = 'precondition_in_layout_share'
     _, dk = _share(
         models.TinyConvNet(), jnp.ones((2, 28, 28, 1)),
         compute_method='inverse',
     )
-    assert harness.read_layer_metric(name, ctx(dk)) == pytest.approx(
+    assert harness.read_layer_metric(
+        name, _reader_context(dk)
+    ) == pytest.approx(
         100.0 * dk.in_layout_share
     )
-    assert harness.read_layer_metric(name, ctx(types.SimpleNamespace())) is None
+    assert harness.read_layer_metric(
+        name, _reader_context(types.SimpleNamespace())) is None
     with open(os.path.join(harness.ROOT, 'BENCHMARK.json')) as f:
         bench = json.load(f)
     # by name, wherever the row stands: later PRs append rows behind it
