@@ -7,9 +7,17 @@ module closes it for *compilation* and *memory*:
 
 1. **Recompile attribution.** :meth:`CompileWatch.wrap` turns a jitted
    entry point into a :class:`WatchedFunction` that dispatches through
-   ahead-of-time ``lower()``/``compile()`` keyed by an argument
-   *fingerprint* (shape/dtype/sharding per leaf, value for static
-   scalars). Every compilation emits exactly one structured event —
+   ahead-of-time ``lower()``/``compile()``. Its executables are keyed by
+   an argument *fingerprint* (shape/dtype/sharding per leaf, value for
+   static scalars), but a call is fingerprinted only when its program
+   may have changed: it is first handed to the executable the entry ran
+   last, whose own C++ call path checks the argument tree and every
+   leaf's shape and dtype and raises before anything runs or is donated
+   when they differ. A first call, a rejected one, and one whose
+   static / ``bool`` / ``str`` values differ from the last call's take a
+   fingerprint (:meth:`CompileWatch.dispatch_counters` counts both
+   kinds); a steady-state call does no Python work per leaf.
+   Every compilation emits exactly one structured event —
    entry name, compile wall-clock, the fingerprint, and a diff against
    the previous fingerprint for that entry naming exactly which
    dimension/dtype/sharding changed. The old ``jit._cache_size() == 1``
@@ -379,6 +387,11 @@ class CompileWatch:
         # rather than a config field: it is per-run state, not a knob.
         self.run_id: str | None = None
         self._counts: dict[str, int] = {}
+        # per entry, calls by how they were dispatched ('fast' without a
+        # fingerprint, 'fingerprinted' with one); kept here, not on the
+        # wrapper, so that an entry wrapped again (Trainer.rebind_engine)
+        # keeps counting
+        self._dispatched: dict[str, dict[str, int]] = {}
         self._last_fp: dict[str, dict[str, Any]] = {}
         self._wrapped: dict[str, WatchedFunction] = {}
         self._lock = threading.Lock()
@@ -416,6 +429,21 @@ class CompileWatch:
     def counters(self) -> dict[str, int]:
         """Per-entry compile counts (a copy)."""
         return dict(self._counts)
+
+    def dispatch_counters(self) -> dict[str, dict[str, int]]:
+        """Per entry, the calls made so far: ``fast`` were handed to the
+        executable of the entry's previous call with no fingerprint
+        taken, ``fingerprinted`` took one (a first call, a rejected fast
+        attempt, changed static values, an entry pinned to plain
+        dispatch). Their sum is the entry's calls; never reset."""
+        with self._lock:
+            return {e: dict(c) for e, c in self._dispatched.items()}
+
+    def _count_dispatch(self, entry: str, kind: str) -> None:
+        with self._lock:
+            counts = self._dispatched.setdefault(
+                entry, {'fast': 0, 'fingerprinted': 0})
+            counts[kind] += 1
 
     def events_for(self, entry: str) -> list[dict[str, Any]]:
         return [e for e in self.events if e['entry'] == entry]
@@ -477,6 +505,54 @@ class CompileWatch:
                 self.events.pop(0)
 
 
+def _same_values(old: Sequence[Any], new: Sequence[Any]) -> bool:
+    """Whether two sequences of static values agree, type for type: the
+    fingerprint keys on ``True`` and ``1`` apart."""
+    return len(old) == len(new) and all(
+        type(a) is type(b) and a == b for a, b in zip(old, new))
+
+
+def _leaves(args: Sequence[Any], kwargs: Mapping[str, Any]) -> list[Any]:
+    from jax import tree_util
+
+    return tree_util.tree_leaves((args, kwargs))
+
+
+@dataclasses.dataclass(frozen=True)
+class _LastCall:
+    """What an entry's previous call ran, and what of that call the
+    executable's own check does not see while the fingerprint keys on
+    it: the static values, each leaf's Python type (a Python scalar is
+    weak-typed where an array of its dtype is not) and the values of the
+    ``bool``/``str``/``bytes`` leaves. While a call agrees in these, the
+    executable accepts it exactly when its fingerprint's program view
+    would be the last call's."""
+
+    executable: Any
+    statics: tuple
+    leaf_types: list[type]
+    selectors: dict[int, Any]  # leaf position -> bool/str/bytes value
+
+    @classmethod
+    def of(cls, executable: Any, statics: tuple, args: Sequence[Any],
+           kwargs: Mapping[str, Any]) -> '_LastCall':
+        leaves = _leaves(args, kwargs)
+        return cls(
+            executable, statics, list(map(type, leaves)),
+            {i: leaf for i, leaf in enumerate(leaves)
+             if isinstance(leaf, (bool, str, bytes))})
+
+    def selects(self, statics: tuple, args: Sequence[Any],
+                kwargs: Mapping[str, Any]) -> bool:
+        """One flatten and one comparison of two lists of types, both
+        in C: no Python runs a leaf unless a selector leaf is there."""
+        if not _same_values(self.statics, statics):
+            return False
+        leaves = _leaves(args, kwargs)
+        return list(map(type, leaves)) == self.leaf_types and all(
+            leaves[i] == value for i, value in self.selectors.items())
+
+
 class WatchedFunction:
     """A jitted entry point dispatched through the watch's own
     fingerprint-keyed AOT executable cache (see module docstring)."""
@@ -493,6 +569,7 @@ class WatchedFunction:
         self._fn = fn
         self._static = static_argnames
         self._cache: dict[str, Any] = {}
+        self._last: _LastCall | None = None
 
     def cache_size(self) -> int:
         """Distinct fingerprints compiled so far for this wrapper."""
@@ -515,7 +592,27 @@ class WatchedFunction:
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         statics = {k: kwargs[k] for k in self._static if k in kwargs}
-        call_kwargs = {k: v for k, v in kwargs.items() if k not in statics}
+        call_kwargs = (
+            {k: v for k, v in kwargs.items() if k not in statics}
+            if statics else kwargs)
+        static_values = tuple(statics.values())
+        rejected = None
+        last = self._last
+        if last is not None and last.selects(
+                static_values, args, call_kwargs):
+            try:
+                # jax.stages.Compiled checks the argument tree and each
+                # leaf's shape and dtype (then committed shardings and
+                # layouts) in its own call path, and raises before
+                # anything runs or is donated: the check a fingerprint
+                # key would only repeat, in Python and a leaf at a time
+                out = last.executable(*args, **call_kwargs)
+            except (TypeError, ValueError):
+                rejected = last.executable
+            else:
+                self._watch._count_dispatch(self.entry, 'fast')
+                return out
+        self._watch._count_dispatch(self.entry, 'fingerprinted')
         fp = fingerprint_args(
             args, call_kwargs, statics,
             include_sharding=self._watch.config.include_sharding)
@@ -523,16 +620,26 @@ class WatchedFunction:
         executable = self._cache.get(key)
         if executable is _FALLBACK:
             return self._fn(*args, **kwargs)
-        if executable is not None:
+        if executable is not None and executable is not rejected:
             try:
-                return executable(*args, **call_kwargs)
+                out = executable(*args, **call_kwargs)
             except (TypeError, ValueError):
-                # XLA rejected the input (sharding/layout changed under
-                # an unchanged program view, or a fingerprint collision):
-                # drop the stale executable and recompile — the event's
-                # diff names what moved
-                self._cache.pop(key, None)
-        return self._compile_and_call(fp, key, args, kwargs, call_kwargs)
+                pass
+            else:
+                self._last = _LastCall.of(
+                    executable, static_values, args, call_kwargs)
+                return out
+        # no executable under this key, or XLA rejected the input
+        # (sharding/layout changed under an unchanged program view, or a
+        # fingerprint collision): drop the stale one and compile — the
+        # event's diff names what moved
+        self._cache.pop(key, None)
+        out = self._compile_and_call(fp, key, args, kwargs, call_kwargs)
+        executable = self._cache[key]
+        if executable is not _FALLBACK:
+            self._last = _LastCall.of(
+                executable, static_values, args, call_kwargs)
+        return out
 
     def _compile_and_call(
         self,

@@ -487,3 +487,324 @@ def test_postmortem_bundle_carries_compile_events(tmp_path):
     loaded = kfac_inspect.load_bundle(bundle)
     assert loaded['compile_events'] == events
     assert loaded['compile_memory'] == mem
+
+
+# ------------------------------------------------------------ fast dispatch
+#
+# PR 45: a call is handed to the executable the entry ran last and is
+# fingerprinted only when that cannot be right (a first call, a rejected
+# one, changed static / bool / str values, an entry on plain dispatch).
+
+
+class _Spy:
+    """Counts what a steady-state call may not do: the two fingerprint
+    functions, and ``str()`` / ``repr()`` of a ``NamedSharding``."""
+
+    def __init__(self, monkeypatch):
+        self.fingerprints = 0
+        self.keys = 0
+        self.sharding_strs = 0
+        self.seen_args = []
+        real_fp, real_key = cw.fingerprint_args, cw.fingerprint_key
+        real_repr = jax.sharding.NamedSharding.__repr__
+
+        def fp(args, kwargs, *a, **kw):
+            self.fingerprints += 1
+            self.seen_args.append(args)
+            return real_fp(args, kwargs, *a, **kw)
+
+        def key(fp_):
+            self.keys += 1
+            return real_key(fp_)
+
+        def sharding_repr(sharding):
+            self.sharding_strs += 1
+            return real_repr(sharding)
+
+        monkeypatch.setattr(cw, 'fingerprint_args', fp)
+        monkeypatch.setattr(cw, 'fingerprint_key', key)
+        monkeypatch.setattr(
+            jax.sharding.NamedSharding, '__repr__', sharding_repr)
+
+
+def _sharded(x, *spec):
+    mesh = jax.sharding.Mesh(jax.devices()[:2], ('d',))
+    return jax.device_put(
+        x, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(*spec)))
+
+
+def _calls(watch, entry):
+    c = watch.dispatch_counters()[entry]
+    return c['fast'], c['fingerprinted']
+
+
+def test_steady_state_takes_one_fingerprint_and_no_sharding_str(monkeypatch):
+    """N calls of one signature: one fingerprint, one key, one compile
+    event, and after the first call no ``str()`` of a sharding: the
+    acceptance pin on ``WatchedFunction.__call__`` in steady state."""
+    watch = cw.CompileWatch()
+    f = watch.wrap('e', jax.jit(lambda t, b: (
+        jax.tree.map(lambda x: x + b.sum(), t), b * 2)))
+    tree = {f'k{i}': _sharded(jnp.ones((4, 2)), 'd') for i in range(12)}
+    b = _sharded(jnp.arange(8.0))
+    spy = _Spy(monkeypatch)
+    tree, _ = f(tree, b)
+    assert (spy.fingerprints, spy.keys) == (1, 1)
+    assert spy.sharding_strs >= 13  # the one fingerprint records them
+    spy.sharding_strs = 0
+    for _ in range(9):
+        tree, out = f(tree, b)
+    assert (spy.fingerprints, spy.keys, spy.sharding_strs) == (1, 1, 0)
+    assert float(tree['k0'][0, 0]) == 1 + 10 * 28.0
+    assert float(out[1]) == 2.0
+    assert len(watch.events) == 1 and watch.recompile_count() == 0
+    assert _calls(watch, 'e') == (9, 1)
+    assert f.cache_size() == 1 and len(f.executables()) == 1
+
+
+def test_shape_change_after_fast_calls_is_one_named_event(monkeypatch):
+    watch = cw.CompileWatch()
+    f = watch.wrap('e', jax.jit(lambda p, x: (p + x.sum(), x * 2)))
+    p = jnp.zeros(())
+    for _ in range(4):
+        p, _ = f(p, jnp.ones((8, 3)))
+    assert _calls(watch, 'e') == (3, 1)
+    spy = _Spy(monkeypatch)
+    p, out = f(p, jnp.ones((6, 3)))
+    assert out.shape == (6, 3) and float(p) == 4 * 24 + 18
+    assert spy.fingerprints == 1  # the rejected fast attempt's
+    (first, new) = watch.events
+    assert first['diff'] is None
+    assert new['diff'] == ['[0][1]: dim 0 8 -> 6']
+    assert _calls(watch, 'e') == (3, 2)
+    f(p, jnp.ones((6, 3)))
+    assert _calls(watch, 'e') == (4, 2) and len(watch.events) == 2
+
+
+def _alternating(kind):
+    """(wrapped entry, its watch, call A, call B, A's result, B's)."""
+    watch = cw.CompileWatch()
+    if kind == 'shape':
+        f = watch.wrap('e', jax.jit(lambda x: x.sum()))
+        return (f, watch, lambda: f(jnp.ones((8,))),
+                lambda: f(jnp.ones((5,))), (8.0, 'float32'),
+                (5.0, 'float32'))
+    if kind == 'static':
+        f = watch.wrap(
+            'e', jax.jit(lambda x, mode: x.sum() if mode == 'sum'
+                         else x.max(), static_argnames=('mode',)),
+            static_argnames=('mode',))
+        x = jnp.arange(4.0)
+        return (f, watch, lambda: f(x, mode='sum'),
+                lambda: f(x, mode='max'), (6.0, 'float32'),
+                (3.0, 'float32'))
+    if kind == 'static_type':
+        # 1 == True, but the fingerprint keys on the type too
+        f = watch.wrap(
+            'e', jax.jit(lambda x, n: x.sum() * (2 if n is True else 1),
+                         static_argnames=('n',)), static_argnames=('n',))
+        x = jnp.arange(4.0)
+        return (f, watch, lambda: f(x, n=1), lambda: f(x, n=True),
+                (6.0, 'float32'), (12.0, 'float32'))
+    if kind == 'weak_scalar':
+        # a Python scalar is weak-typed where an array of its dtype is
+        # not: the executable's own check (shape and dtype) would let
+        # one stand in for the other, and the result's dtype would be
+        # the other program's
+        f = watch.wrap('e', jax.jit(lambda x, k: (x * k)[3]))
+        x = jnp.arange(4, dtype=jnp.int8)
+        return (f, watch, lambda: f(x, 2), lambda: f(x, jnp.int32(2)),
+                (6.0, 'int8'), (6.0, 'int32'))
+    assert kind == 'bool_leaf'
+    f = watch.wrap('e', jax.jit(
+        lambda x, opts: jnp.where(opts['flip'], -x.sum(), x.sum())))
+    x = jnp.arange(4.0)
+    return (f, watch, lambda: f(x, {'flip': False}),
+            lambda: f(x, {'flip': True}), (6.0, 'float32'),
+            (-6.0, 'float32'))
+
+
+@pytest.mark.parametrize(
+    'kind', ['shape', 'static', 'static_type', 'bool_leaf', 'weak_scalar'])
+def test_two_alternating_signatures_never_compile_a_third_time(
+        kind, monkeypatch):
+    """A short last batch, ``with_stats`` True/False, a ``bool`` leaf, a
+    Python scalar where an array was: each keeps selecting its own
+    program, as its fingerprint would; a switch costs at most one
+    fingerprint and, after the first of each, no compile."""
+    f, watch, call_a, call_b, want_a, want_b = _alternating(kind)
+    spy = _Spy(monkeypatch)
+    calls = 0
+    for _ in range(3):
+        for call, want in ((call_a, want_a), (call_a, want_a),
+                           (call_b, want_b), (call_b, want_b)):
+            out = call()
+            assert (float(out), str(out.dtype)) == want
+            calls += 1
+    assert len(watch.events) == 2 and f.cache_size() == 2
+    assert watch.compile_count('e') == 2
+    assert spy.fingerprints == 6  # one a switch, none on a repeat
+    assert _calls(watch, 'e') == (calls - 6, 6)
+    assert len({e['fingerprint_key'] for e in watch.events}) == 2
+
+
+def test_rejected_fast_attempt_leaves_donated_arguments_alive(monkeypatch):
+    """The last executable refuses a call before anything runs or is
+    donated: the program compiled next still finds its inputs."""
+    watch = cw.CompileWatch()
+    f = watch.wrap('e', jax.jit(
+        lambda p, x: p + x.sum(), donate_argnums=(0,)))
+    p = f(jnp.zeros((3,)), jnp.ones((8,)))
+    p = f(p, jnp.ones((8,)))
+    assert _calls(watch, 'e') == (1, 1)
+    spy = _Spy(monkeypatch)
+    donated = p
+    out = f(donated, jnp.ones((5,)))      # fast attempt rejected
+    (seen,) = spy.seen_args               # fingerprinted after it
+    assert seen[0] is donated
+    assert [float(v) for v in out] == [21.0] * 3
+    assert donated.is_deleted()           # by the program that ran
+    assert _calls(watch, 'e') == (1, 2) and len(watch.events) == 2
+
+
+def test_sharding_rejected_input_recompiles_once_with_a_named_diff():
+    """An unchanged program view whose committed sharding the executable
+    refuses: the stale executable is dropped and one event names the
+    sharding, as before; the new layout then dispatches fast."""
+    watch = cw.CompileWatch()
+    f = watch.wrap('e', jax.jit(lambda x: x * 2))
+    x = jnp.arange(8.0)
+    f(_sharded(x, 'd'))
+    f(_sharded(x, 'd'))
+    out = f(_sharded(x))                  # replicated: refused
+    assert [float(v) for v in out] == [2.0 * i for i in range(8)]
+    (first, new) = watch.events
+    assert first['fingerprint_key'] == new['fingerprint_key']
+    (line,) = new['diff']
+    assert line.startswith('[0][0]: sharding ')
+    assert f.cache_size() == 1
+    f(_sharded(x))
+    assert _calls(watch, 'e') == (2, 2) and len(watch.events) == 2
+
+
+class _NoAOT:
+    """A jitted callable whose ``lower`` fails: the watch pins its
+    fingerprints to plain dispatch."""
+
+    def __init__(self, fn):
+        self._jit = jax.jit(fn)
+
+    def __call__(self, *args, **kwargs):
+        return self._jit(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        raise RuntimeError('no ahead-of-time lowering here')
+
+
+def test_fallback_entry_keeps_fingerprinting_every_call():
+    watch = cw.CompileWatch()
+    f = watch.wrap('e', _NoAOT(lambda x: x.sum()))
+    for n in (4, 4, 4, 6, 4):
+        assert float(f(jnp.ones((n,)))) == n
+    assert [e['aot'] for e in watch.events] == [False, False]
+    assert watch.events[0]['aot_error'].startswith('lower: RuntimeError')
+    assert f.cache_size() == 2 and f.executables() == []
+    assert _calls(watch, 'e') == (0, 5)
+
+
+def test_dispatch_counters_outlive_a_rewrapped_entry():
+    """``Trainer.rebind_engine`` wraps its entries again: the counters
+    are the watch's, by entry, and never reset."""
+    watch = cw.CompileWatch()
+    assert watch.dispatch_counters() == {}
+    jitted = jax.jit(lambda x: x + 1)
+    for _ in range(2):
+        f = watch.wrap('e', jitted)
+        for _ in range(3):
+            f(jnp.ones((2,)))
+    g = watch.wrap('other', jitted)
+    g(jnp.ones((2,)))
+    assert watch.dispatch_counters() == {
+        'e': {'fast': 4, 'fingerprinted': 2},
+        'other': {'fast': 0, 'fingerprinted': 1},
+    }
+    watch.dispatch_counters()['e']['fast'] = 0  # a copy
+    assert _calls(watch, 'e') == (4, 2)
+    assert watch.compile_count('e') == 2
+
+
+def test_trainer_steps_dispatch_fast(trainer_mod):
+    """The surface the benchmark times: after each step variant's first
+    call every ``Trainer.step`` is a fast dispatch, and the counters
+    add up to the calls made."""
+    trainer, params, (x, y), kfac = trainer_mod
+    watch = kfac.compile_watcher()
+    state = trainer.init(params)
+    state, _ = trainer.step(state, (x, y))
+    def step_calls():
+        by_entry = watch.dispatch_counters()
+        return [sum(c[kind] for e, c in by_entry.items()
+                    if e.startswith('trainer.step/'))
+                for kind in ('fast', 'fingerprinted')]
+
+    fast0, fingerprinted0 = step_calls()
+    events = len(watch.events)
+    for _ in range(6):
+        state, _ = trainer.step(state, (x, y))
+    fast, fingerprinted = step_calls()
+    assert fast - fast0 >= 5  # a variant's first call may be new
+    assert (fast - fast0) + (fingerprinted - fingerprinted0) == 6
+    assert len(watch.events) <= events + 1
+
+
+# ------------------------------------------------- the benchmark's reader
+
+
+def test_benchmark_row_reads_the_dispatch_counters():
+    """``launch_fast_share`` of ``BENCHMARK.json``: fast dispatches over
+    all watched calls of the K-FAC trainer's watch, in percent, in all
+    six cells; nothing on a watch without the counter (the parent's)."""
+    import types
+
+    from benchmark import harness
+
+    def context(engine):
+        run = types.SimpleNamespace(
+            trainer=types.SimpleNamespace(kfac=engine))
+        return harness.LayerContext(
+            cell={}, run=run, devices=[], first_order_rows=[], rows=[],
+            traced_rows=[], trace={'planes': []}, windows={},
+            throughput=0.0,
+        )
+
+    def engine(watch):
+        return types.SimpleNamespace(compile_watcher=lambda: watch)
+
+    name = 'launch_fast_share'
+    read = harness.read_layer_metric
+    watch = cw.CompileWatch()
+    assert read(name, context(engine(watch))) is None  # nothing called
+    for entry, kind, n in (('trainer.step/with_stats', 'fingerprinted', 1),
+                           ('trainer.step/with_stats', 'fast', 19),
+                           ('trainer.step/no_stats', 'fingerprinted', 1),
+                           ('trainer.step/no_stats', 'fast', 179)):
+        for _ in range(n):
+            watch._count_dispatch(entry, kind)
+    assert read(name, context(engine(watch))) == pytest.approx(99.0)
+    parent = types.SimpleNamespace(events=[], counters=dict)
+    assert read(name, context(engine(parent))) is None
+    assert read(name, context(engine(None))) is None
+    assert read(name, context(types.SimpleNamespace())) is None
+    with open(os.path.join(harness.ROOT, 'BENCHMARK.json')) as f:
+        bench = json.load(f)
+    cells = [w['name'] for w in bench['workloads']]
+    assert len(cells) == 6
+    row = next(m for m in bench['per_layer'] if m['name'] == name)
+    assert dict(row, workloads=sorted(row['workloads'])) == {
+        'name': name, 'unit': '%', 'better': 'higher',
+        'source': 'program_counter', 'layer': 'trainer',
+        'moves': 'throughput', 'workloads': sorted(cells),
+    }
+    for cell in cells:
+        rows = harness.layer_rows(harness.load_cell(cell))
+        assert name in {m['name'] for m in rows}
