@@ -15,7 +15,7 @@ PY ?= python
 TEST_ENV = JAX_PLATFORMS=cpu \
 	XLA_FLAGS="--xla_force_host_platform_device_count=8"
 
-.PHONY: test test-fast test-unit test-integration faults async compress fleet chaos compilewatch ledger serve obs prof tune resilience lint lint-ir lint-pod inspect native
+.PHONY: test test-fast test-unit test-integration faults async chaos compilewatch ledger serve obs prof tune resilience lint lint-ir lint-pod inspect native
 
 test:
 	$(TEST_ENV) $(PY) -m pytest tests/ -q
@@ -42,18 +42,6 @@ faults:
 async:
 	$(TEST_ENV) $(PY) -m pytest tests/test_async_inverse.py -q
 	$(TEST_ENV) $(PY) tools/lint_named_scopes.py
-
-# compressed curvature collectives + cold-factor host offload:
-# quantization/error-feedback/offload suite (bit-exactness, wire-ratio
-# and convergence-parity gates; see docs/ARCHITECTURE.md
-# "Compression & offload")
-compress:
-	$(TEST_ENV) $(PY) -m pytest tests/test_compression.py -q
-
-# self-driving fleet: retune-on-restore + drift-triggered live layout
-# migration suite (see docs/ROBUSTNESS.md "Self-driving fleet")
-fleet:
-	$(TEST_ENV) $(PY) -m pytest tests/test_fleet.py -q
 
 # pod-scale chaos harness: CLI selftest (processless reconcile/grammar
 # checks) + the chaos suite including the deterministic 4-proc scripted
@@ -99,23 +87,19 @@ serve:
 	$(TEST_ENV) $(PY) tools/kfac_serve.py --selftest
 
 # telemetry spine: observability + flight-recorder test suites, the
-# compression/offload suite (its wire-bytes accounting is part of the
-# comms report contract), the self-driving fleet suite (its drift
-# detector consumes the flight recorder's skew columns), the
 # measurement-truth layer (prof: dispatch-free microbench,
 # calibration), the compile & memory truth layer
 # (compilewatch: recompile attribution, XLA memory accounting,
 # mid-compile heartbeats), the unified static-analysis pass (which
-# includes the named-scope, metric-key, plan-schema, compression-knob,
-# fleet-knob, calibration-knob, topology-knob, chaos-knob and
-# compile-watch-knob lints as
-# KFL101-KFL103/KFL105/KFL106/KFL108/KFL109/KFL111/KFL112 plus the
+# includes the named-scope, metric-key, plan-schema, calibration-knob,
+# topology-knob, chaos-knob and compile-watch-knob lints as
+# KFL101-KFL103/KFL108/KFL109/KFL111/KFL112 plus the
 # IR-tier smoke pass via lint-ir), the unified run ledger (ledger:
 # adapters, correlation timeline, perf-regression sentinel, KFL113),
 # the posterior serving tier (serve: bucketed-engine parity + routing +
 # recompile pins + the kfac_serve selftest, KFL114), and the
 # kfac_inspect analysis selftest (see docs/OBSERVABILITY.md)
-obs: async lint compress fleet chaos prof compilewatch ledger serve
+obs: async lint chaos prof compilewatch ledger serve
 	$(TEST_ENV) $(PY) -m pytest tests/test_observability.py \
 		tests/test_flight_recorder.py -q
 	$(PY) tools/kfac_inspect.py --selftest

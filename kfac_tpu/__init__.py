@@ -14,13 +14,7 @@ from kfac_tpu import observability
 from kfac_tpu import resilience
 from kfac_tpu.autotune import TunedPlan
 from kfac_tpu.async_inverse import AsyncInverseConfig
-from kfac_tpu.compression import CompressionConfig, OffloadConfig
-from kfac_tpu.resilience import (
-    CheckpointManager,
-    FleetConfig,
-    FleetController,
-    Preempted,
-)
+from kfac_tpu.resilience import CheckpointManager, Preempted
 from kfac_tpu.health import HealthConfig, HealthState
 from kfac_tpu.observability import (
     CompileWatch,
@@ -65,12 +59,9 @@ __all__ = [
     'AsyncInverseConfig',
     'CapturedStats',
     'CheckpointManager',
-    'CompressionConfig',
     'ComputeMethod',
     'CurvatureCapture',
     'DistributedStrategy',
-    'FleetConfig',
-    'FleetController',
     'CompileWatch',
     'CompileWatchConfig',
     'FlightRecorderConfig',
@@ -82,7 +73,6 @@ __all__ = [
     'LaplacePosterior',
     'MetricsCollector',
     'MetricsConfig',
-    'OffloadConfig',
     'PostmortemWriter',
     'Preempted',
     'Registry',

@@ -40,8 +40,8 @@ from kfac_tpu.analysis.core import (  # noqa: F401
 
 AST_RULE_CODES = ('KFL001', 'KFL002', 'KFL003', 'KFL004', 'KFL005')
 PROJECT_RULE_CODES = (
-    'KFL100', 'KFL101', 'KFL102', 'KFL103', 'KFL104', 'KFL105', 'KFL106',
-    'KFL107', 'KFL108', 'KFL109', 'KFL111', 'KFL112',
+    'KFL100', 'KFL101', 'KFL102', 'KFL103', 'KFL104', 'KFL107', 'KFL108',
+    'KFL109', 'KFL111', 'KFL112',
 )
 IR_RULE_CODES = ('KFL201', 'KFL202', 'KFL203', 'KFL204', 'KFL205')
 POD_RULE_CODES = ('KFL301', 'KFL302', 'KFL303', 'KFL304', 'KFL305')

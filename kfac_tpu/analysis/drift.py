@@ -30,7 +30,6 @@ OBSERVABILITY_DOC = 'docs/OBSERVABILITY.md'
 AUTOTUNE_DOC = 'docs/AUTOTUNE.md'
 ROBUSTNESS_DOC = 'docs/ROBUSTNESS.md'
 SERVING_DOC = 'docs/SERVING.md'
-ARCHITECTURE_DOC = 'docs/ARCHITECTURE.md'
 LAPLACE_DOC = 'docs/LAPLACE.md'
 
 #: documented metric keys that are drain-record fields, not metric_keys
@@ -306,80 +305,6 @@ def _signals() -> list[core.Finding]:
         ROBUSTNESS_DOC, '## Signal semantics', next_heading=r'^#{1,3} '
     )
     return _doc_findings('KFL104', ROBUSTNESS_DOC, line, check_signals())
-
-
-# -------------------------------------------------- KFL105 compression knobs
-
-
-def check_compression_knobs(doc_path: str = ARCHITECTURE_DOC) -> list[str]:
-    """Drift between the docs/ARCHITECTURE.md compression/offload knob
-    table and the ``CompressionConfig``/``OffloadConfig`` dataclass
-    fields — the knobs `stat_compression=` / `offload=` actually accept."""
-    import dataclasses
-
-    section, _ = doc_section(doc_path, '### Compression & offload knobs')
-    documented = table_first_cells(section)
-    from kfac_tpu.compression import config as compression_config_lib
-
-    actual = {
-        f.name
-        for cls in (
-            compression_config_lib.CompressionConfig,
-            compression_config_lib.OffloadConfig,
-        )
-        for f in dataclasses.fields(cls)
-    }
-    problems = []
-    for k in sorted(actual - documented):
-        problems.append(f'undocumented config field (add to {doc_path}): {k}')
-    for k in sorted(documented - actual):
-        problems.append(
-            f'documented knob is not a CompressionConfig/OffloadConfig '
-            f'field: {k}'
-        )
-    return problems
-
-
-def _compression_knobs() -> list[core.Finding]:
-    try:
-        _, line = doc_section(
-            ARCHITECTURE_DOC, '### Compression & offload knobs'
-        )
-        problems = check_compression_knobs()
-    except (OSError, ValueError) as exc:
-        return _doc_findings('KFL105', ARCHITECTURE_DOC, 1, [str(exc)])
-    return _doc_findings('KFL105', ARCHITECTURE_DOC, line, problems)
-
-
-# ------------------------------------------------------ KFL106 fleet knobs
-
-
-def check_fleet_knobs(doc_path: str = ROBUSTNESS_DOC) -> list[str]:
-    """Drift between the docs/ROBUSTNESS.md fleet knob table and the
-    ``FleetConfig`` dataclass fields — the policy knobs the self-driving
-    fleet controller actually accepts."""
-    import dataclasses
-
-    section, _ = doc_section(doc_path, '### Fleet knobs')
-    documented = table_first_cells(section)
-    from kfac_tpu.resilience import fleet as fleet_lib
-
-    actual = {f.name for f in dataclasses.fields(fleet_lib.FleetConfig)}
-    problems = []
-    for k in sorted(actual - documented):
-        problems.append(f'undocumented config field (add to {doc_path}): {k}')
-    for k in sorted(documented - actual):
-        problems.append(f'documented knob is not a FleetConfig field: {k}')
-    return problems
-
-
-def _fleet_knobs() -> list[core.Finding]:
-    try:
-        _, line = doc_section(ROBUSTNESS_DOC, '### Fleet knobs')
-        problems = check_fleet_knobs()
-    except (OSError, ValueError) as exc:
-        return _doc_findings('KFL106', ROBUSTNESS_DOC, 1, [str(exc)])
-    return _doc_findings('KFL106', ROBUSTNESS_DOC, line, problems)
 
 
 # ---------------------------------------------------- KFL107 laplace knobs
@@ -710,31 +635,6 @@ core.register(core.Rule(
 ))
 
 core.register(core.Rule(
-    code='KFL105',
-    name='compression-knobs-doc',
-    what='drift between the docs/ARCHITECTURE.md "Compression & offload '
-         'knobs" table and the CompressionConfig/OffloadConfig dataclass '
-         'fields',
-    why='the wire-quantization and offload knobs change numerics and '
-        'memory residency; an undocumented (or phantom) knob is how a '
-        'convergence regression gets configured by folklore',
-    check=_compression_knobs,
-    kind='project',
-))
-
-core.register(core.Rule(
-    code='KFL106',
-    name='fleet-knobs-doc',
-    what='drift between the docs/ROBUSTNESS.md "Fleet knobs" table and '
-         'the FleetConfig dataclass fields',
-    why='the fleet knobs gate when a live job re-layouts itself; an '
-        'undocumented (or phantom) knob turns an autonomous migration '
-        'policy into a surprise',
-    check=_fleet_knobs,
-    kind='project',
-))
-
-core.register(core.Rule(
     code='KFL107',
     name='laplace-knobs-doc',
     what='drift between the docs/LAPLACE.md "LaplaceConfig knobs" / '
@@ -753,9 +653,9 @@ core.register(core.Rule(
     name='calibration-knobs-doc',
     what='drift between the docs/OBSERVABILITY.md "Calibration knobs" '
          'table and the CalibrationConfig dataclass fields',
-    why='the calibration monitor feeds the fleet controller\'s retune '
-        'trigger; an undocumented (or phantom) knob means the drift '
-        'threshold that re-layouts a live job is configured by folklore',
+    why='the calibration monitor says how far the tuned plan\'s cost '
+        'model is off; an undocumented (or phantom) knob means the window '
+        'that verdict is averaged over is configured by folklore',
     check=_calibration_knobs,
     kind='project',
 ))

@@ -10,8 +10,8 @@ per engine config, which is why profiles exist:
   bounded wall-clock for ``make lint`` / tier-1 CI.
 - ``default`` — smoke + the dense engine + a Newton–Schulz bucketed
   config + an async-host config, so every rule has real coverage.
-- ``full``    — the strategy × method × transport matrix including int8
-  compression and host-eigh; used by the ``slow``-marked tests.
+- ``full``    — the strategy × method × transport matrix including
+  host-eigh; used by the ``slow``-marked tests.
 
 Entry points are *registered by the engines themselves* via the
 ``IR_ENTRY_POINTS`` class attribute (see ``kfac_tpu/preconditioner.py``
@@ -108,8 +108,6 @@ def _callback_allowlist(cfg: Any) -> frozenset[str]:
         allow.add('io_callback')
     if getattr(cfg, 'eigh_impl', 'xla') in ('host', 'eig_host'):
         allow.add('pure_callback')
-    if getattr(cfg, 'offload', None) is not None:
-        allow.add('io_callback')  # cold-factor spill/fetch at boundaries
     return frozenset(allow)
 
 
@@ -173,8 +171,6 @@ def _specs(profile: str, world: int) -> list[_ConfigSpec]:
     full = default + [
         _ConfigSpec('kaisa-eigen-dense-f0.5', 'kaisa', 16, 0.5, {}),
         _ConfigSpec('kaisa-eigen-dense-f0.125', 'kaisa', 16, 0.125, {}),
-        _ConfigSpec('kaisa-eigen-bucketed-int8-f0.5', 'kaisa', 16, 0.5,
-                    {**bucketed, 'stat_compression': 'int8'}),
         _ConfigSpec('kaisa-ns-dense-f0.125', 'kaisa', 16, 0.125, ns),
         # COMM-OPT with explicit inverses: precondition builds no stack
         _ConfigSpec('kaisa-ns-dense-f1.0', 'kaisa', 16, 1.0, ns),

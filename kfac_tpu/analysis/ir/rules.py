@@ -75,11 +75,7 @@ def check_collective_axes(suite: harness.Suite) -> list[core.Finding]:
         if t.entry == 'update_factors' and t.comms is not None:
             st = t.comms['stat_transport']
             chunks = st.get('chunks') or []
-            if chunks:
-                per_chunk = 2 if st.get('compression') else 1
-                want = len(chunks) * per_chunk
-            else:
-                want = st['collectives']
+            want = len(chunks) if chunks else st['collectives']
             pins = [
                 p for p in visitor.constraint_pins(t.jaxpr) if p.replicated
             ]
@@ -148,8 +144,8 @@ def check_sharding_contract(suite: harness.Suite) -> list[core.Finding]:
 
 def check_step_callbacks(suite: harness.Suite) -> list[core.Finding]:
     """Host callbacks inside step-path programs must be declared (async
-    host refresh, host eigh, cold-factor offload) — anything else is a
-    per-step host round-trip."""
+    host refresh, host eigh) — anything else is a per-step host
+    round-trip."""
     findings: list[core.Finding] = []
     for t in suite.traces:
         if not t.step_path:
@@ -326,7 +322,7 @@ core.register(core.Rule(
 core.register(core.Rule(
     code='KFL204', name='ir-callback-in-step-path',
     what='io_callback/pure_callback eqns inside step-path programs that '
-         'are not on the config\'s async/offload allowlist',
+         'are not on the config\'s async/host-eigh allowlist',
     why='an undeclared host callback serializes every training step on '
         'a device->host round-trip — the exact failure async_inverse '
         'exists to avoid',

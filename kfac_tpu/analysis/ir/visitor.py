@@ -203,7 +203,7 @@ def _float_kind(dtype, floor_bits: int) -> str | None:
     # ml_dtypes extension floats (bfloat16, float8_*) register with
     # numpy kind 'V', not 'f' — match them by name
     if dt.kind != 'f' and 'float' not in dt.name:
-        return None  # int8 compression wires etc. are intentional
+        return None  # integer values are not factor math
     bits = dt.itemsize * 8
     if bits < floor_bits:
         return 'demote'

@@ -1,10 +1,10 @@
 """Pod tier, stage 2: bounded model check of declared protocol tables.
 
-``resilience/manager.py`` and ``resilience/fleet.py`` declare their
+``resilience/manager.py`` and ``resilience/chaos.py`` declare their
 coordination protocols as module-level ``*_PROTOCOL`` dict literals —
 a *sequence* machine for checkpoint save (ordered steps with ranks and
-filesystem effects) and a *state* machine for fleet migration (states,
-events, vote outcomes, what each transition mutates). This module
+filesystem effects) and a *state* machine for a pod worker's life in a
+storm (states, events, vote outcomes, what each transition mutates). This module
 replays those tables against the invariants the fault injectors probe:
 
 - **sequence machines**: single-writer discipline for the LATEST

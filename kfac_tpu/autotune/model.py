@@ -27,14 +27,7 @@ Cost terms (documented in docs/AUTOTUNE.md):
 - **padding waste** rides implicitly in every term through the padded
   class dims and slot counts;
 - **per-device factor-state memory** against an HBM budget, pruning
-  infeasible candidates before any is timed;
-- **compressed transport** rides implicitly: the stat-transport bytes
-  come from ``comms_summary``, whose ``bytes`` are WIRE bytes (quantized
-  payload + block scales) when the candidate carries
-  ``stat_compression``;
-- **cold-factor offload** (``offload=True``) removes the factor stacks
-  from the HBM term (the budget becomes a soft constraint the search can
-  satisfy by spilling) and adds the amortized host round-trip.
+  infeasible candidates before any is timed.
 """
 
 from __future__ import annotations
@@ -74,13 +67,6 @@ class Candidate:
     # synchronous boundary refresh; trailing with a default so existing
     # positional construction and old plans stay valid
     async_inverse: str | None = None
-    # stat-transport quantization dtype ('int8' | 'fp8') or None for the
-    # uncompressed wire; only meaningful with ALLREDUCE_BUCKETED —
-    # trailing-default, like async_inverse, for old-plan compatibility
-    stat_compression: str | None = None
-    # cold-factor host offload: when True the factor stacks leave the
-    # per-device memory budget and a host round-trip rides the cost model
-    offload: bool = False
 
     def knobs(self, world: int) -> dict[str, Any]:
         """This candidate as a TunedPlan ``knobs`` dict (adds the derived
@@ -97,8 +83,6 @@ class Candidate:
             'inv_update_steps': self.inv_update_steps,
             'colocate_factors': self.colocate_factors,
             'async_inverse': self.async_inverse,
-            'stat_compression': self.stat_compression,
-            'offload': self.offload,
             # KAISA-grid candidates carry no mesh factorization; the 3D
             # planner (kfac_tpu.planner) overrides this on its rows
             'topology': None,
@@ -139,8 +123,6 @@ def candidate_config(base: Any, cand: Candidate) -> Any:
         'inv_update_steps': cand.inv_update_steps,
         'colocate_factors': cand.colocate_factors,
         'async_inverse': cand.async_inverse,
-        'stat_compression': cand.stat_compression,
-        'offload': cand.offload,
     })
 
 
@@ -312,19 +294,6 @@ def predict(
         'decomps': reshard_bytes / layout.n_cols,
         'grad_stacks': float(grad_bytes),
     }
-    offload_transfer_s = 0.0
-    if cand.offload:
-        # cold factors spill to host RAM between their use windows: the
-        # stacks leave the HBM budget (HBM becomes a soft constraint) and
-        # the model prices the spill+restore round trip, amortized over
-        # the cold window — factors are next touched at the earlier of
-        # the factor/inverse cadence boundaries
-        memory['factors_offloaded'] = memory.pop('factors')
-        memory['factors'] = 0.0
-        window = max(1, min(cand.factor_update_steps, cand.inv_update_steps))
-        offload_transfer_s = (
-            2.0 * (factor_total / world) / hardware.host_bandwidth / window
-        )
     memory['total'] = (
         memory['factors'] + memory['decomps'] + memory['grad_stacks']
     )
@@ -353,12 +322,10 @@ def predict(
         # worst single step's refresh overshoot above steady state — the
         # latency-jitter term the async backends exist to flatten
         'refresh_spike_s': refresh_spike_s,
-        'offload_transfer_s': offload_transfer_s,
         'predicted_step_s': (
             flops_per_step / hardware.matmul_flops
             + bytes_per_step / hardware.collective_bandwidth
             + host_transfer_s / cand.inv_update_steps
-            + offload_transfer_s
         ),
     }
 

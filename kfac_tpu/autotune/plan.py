@@ -42,18 +42,18 @@ KNOB_KEYS = (
     'inv_update_steps',
     'colocate_factors',
     'async_inverse',
-    'stat_compression',
-    'offload',
     'topology',
     'serving',
 )
+
+# Knobs plans carried (at these names) until the options they set were
+# removed: ignored at their off values, refused when set.
+REMOVED_KNOBS = ('stat_compression', 'offload')
 
 # Knobs added after schema-v1 plans shipped: absent in older documents,
 # filled with these defaults on load so old plans keep applying cleanly.
 OPTIONAL_KNOBS: dict[str, Any] = {
     'async_inverse': None,
-    'stat_compression': None,
-    'offload': False,
     # PR-14 3D planner output: {dp, tp, pp, virtual_chunks, microbatches,
     # schedule} or None for pure-KAISA plans. Mesh-side like strategy /
     # grad_worker_fraction — resolve_auto_layout consumes it, apply_knobs
@@ -170,9 +170,21 @@ class TunedPlan:
         ]
         if knob_missing:
             raise ValueError(f'TunedPlan knobs missing {knob_missing}')
+        # every plan written while these were knobs carries them; one that
+        # turned either on asks for a layout no engine builds any more
+        knob_removed = [k for k in REMOVED_KNOBS if doc['knobs'].get(k)]
+        if knob_removed:
+            raise ValueError(
+                f'TunedPlan knobs set {knob_removed}, which are no longer '
+                'supported: tune again'
+            )
         fields = {k: doc[k] for k in PLAN_KEYS}
         fields['knobs'] = {
-            **OPTIONAL_KNOBS, **fields['knobs']
+            **OPTIONAL_KNOBS,
+            **{
+                k: v for k, v in fields['knobs'].items()
+                if k not in REMOVED_KNOBS
+            },
         }
         return cls(**fields)
 
@@ -233,10 +245,6 @@ def apply_knobs(config: Any, knobs: dict[str, Any]) -> Any:
         colocate_factors=bool(knobs['colocate_factors']),
         # normalized by the config's __post_init__ (mode string or None)
         async_inverse=knobs.get('async_inverse'),
-        # post-v1 knobs: dtype string / bool shorthands, normalized to
-        # CompressionConfig / OffloadConfig by the config's __post_init__
-        stat_compression=knobs.get('stat_compression'),
-        offload=knobs.get('offload', False) or None,
     )
 
 

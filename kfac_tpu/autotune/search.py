@@ -20,18 +20,10 @@ into async refresh (``async_inverse=``). An async window amortizes the
 refresh off the critical path, so longer cadences stop costing latency
 spikes and become worth enumerating: the grid then widens to
 {c, 2c, 4c} and every candidate carries the base's async mode.
-
-Candidates inherit the base config's ``stat_compression`` (bucketed
-transports only — the quantizer rides the packed flat buffers) and
-``offload`` knobs. When NO candidate fits ``hardware.hbm_bytes``, the
-grid is retried once with cold-factor offload enabled — the HBM budget
-is a soft constraint when factor stacks can spill to host RAM — before
-the search gives up (recorded as ``meta['offload_fallback']``).
 """
 
 from __future__ import annotations
 
-import dataclasses
 import statistics
 import time
 from typing import Any, Callable, Sequence
@@ -55,20 +47,6 @@ def _async_mode(base: Any) -> str | None:
     AsyncInverseConfig and a raw mode string)."""
     acfg = getattr(base, 'async_inverse', None)
     return getattr(acfg, 'mode', acfg)
-
-
-def _compression_dtype(base: Any) -> str | None:
-    """The base config's stat-compression wire dtype ('int8' | 'fp8') or
-    None (accepts both the normalized CompressionConfig and a raw dtype
-    string). Candidates carry it only on the bucketed transport — the
-    quantizer operates on the packed flat buffers."""
-    ccfg = getattr(base, 'stat_compression', None)
-    return getattr(ccfg, 'dtype', ccfg)
-
-
-def _offload_enabled(base: Any) -> bool:
-    """Whether the base config runs the cold-factor host offload."""
-    return getattr(base, 'offload', None) is not None
 
 
 def enumerate_candidates(
@@ -97,8 +75,6 @@ def enumerate_candidates(
         # explicit call, see the module docstring)
         inv_cadences = (c, 2 * c, 4 * c) if async_mode else (c,)
     factor_cadence = _static_cadence(base.factor_update_steps)
-    comp = _compression_dtype(base)
-    offload = _offload_enabled(base)
     out = []
     for frac in fractions:
         workers = assignment_lib.grad_worker_count(world, frac)
@@ -119,10 +95,6 @@ def enumerate_candidates(
                             else bool(base.colocate_factors)
                         ),
                         async_inverse=async_mode,
-                        stat_compression=(
-                            comp if method == 'ALLREDUCE_BUCKETED' else None
-                        ),
-                        offload=offload,
                     ))
     return out
 
@@ -167,11 +139,6 @@ def baseline_candidates(world: int, base: Any) -> list[model_lib.Candidate]:
                 else bool(base.colocate_factors)
             ),
             async_inverse=_async_mode(base),
-            stat_compression=(
-                _compression_dtype(base)
-                if method == 'ALLREDUCE_BUCKETED' else None
-            ),
-            offload=_offload_enabled(base),
         )
         for f in fracs
     ]
@@ -297,30 +264,13 @@ def autotune(
         if b not in cands:
             cands.append(b)
 
-    def _rank(rows):
-        order = sorted(
-            range(len(cands)),
-            key=lambda i: (
-                not rows[i]['feasible'], rows[i]['predicted_step_s'], i),
-        )
-        return order, [i for i in order if rows[i]['feasible']]
-
     rows = [model_lib.predict(c, base, world, hardware) for c in cands]
-    order, feasible = _rank(rows)
-    offload_fallback = False
-    if not feasible:
-        # The HBM budget is a SOFT constraint once cold factors can spill
-        # to host RAM: retry the whole grid with offload on before giving
-        # up. No fallback exists under 'sliced' async refresh — it reads
-        # factor slices mid-window, so the stacks can never leave HBM.
-        if _async_mode(base) != 'sliced' and not all(c.offload for c in cands):
-            offload_fallback = True
-            cands = [dataclasses.replace(c, offload=True) for c in cands]
-            baselines = [
-                dataclasses.replace(b, offload=True) for b in baselines
-            ]
-            rows = [model_lib.predict(c, base, world, hardware) for c in cands]
-            order, feasible = _rank(rows)
+    order = sorted(
+        range(len(cands)),
+        key=lambda i: (
+            not rows[i]['feasible'], rows[i]['predicted_step_s'], i),
+    )
+    feasible = [i for i in order if rows[i]['feasible']]
     if not feasible:
         raise ValueError(
             'no candidate fits the HBM budget; raise hardware.hbm_bytes '
@@ -378,6 +328,5 @@ def autotune(
             'measured_candidates': len(trial_set) if do_measure else 0,
             'warmup': warmup,
             'iters': iters,
-            'offload_fallback': offload_fallback,
         },
     )

@@ -145,36 +145,13 @@ def durable_state(state: Any) -> dict[str, Any]:
     dict state of :class:`kfac_tpu.parallel.PipelineKFAC`. The health
     counters are stored as a plain field dict of per-layer scalars —
     layout-independent, so they also survive cross-layout migration.
-
-    The compressed-transport error-feedback residuals (``comp_ef``) are
-    durable too: the residual is deferred factor mass, and dropping it at
-    a restore would bias the next EMA by exactly the noise error feedback
-    exists to cancel.
-
-    Raises on a state whose factors are cold-offload placeholders
-    (spilled to host RAM): persisting zero-size stubs would silently
-    write an unusable checkpoint. The Trainer's checkpoint driver hands
-    the manager's resident ``host_view`` here instead — this raise is the
-    backstop for direct ``save`` calls on a spilled state.
     """
     if isinstance(state, dict):
         return {'step': state['step'], 'a': state['a'], 'g': state['g']}
-    from kfac_tpu.compression import offload as offload_lib
-
-    if offload_lib.is_spilled(state):
-        raise ValueError(
-            'cannot checkpoint a spilled K-FAC state: the factor slots are '
-            'cold-offload placeholders (the real factors live in host RAM). '
-            'Use OffloadManager.host_view(state) for a resident view, or '
-            'let the Trainer checkpoint driver handle it.'
-        )
     out = {'step': state.step, 'a': state.a, 'g': state.g}
     health = getattr(state, 'health', None)
     if health is not None:
         out['health'] = health._asdict()
-    comp_ef = getattr(state, 'comp_ef', None)
-    if comp_ef is not None:
-        out['comp_ef'] = dict(comp_ef)
     return out
 
 
@@ -189,9 +166,23 @@ def _with_durable(state: Any, loaded: dict[str, Any]) -> Any:
     )
     if 'health' in loaded and getattr(state, 'health', None) is not None:
         state = state._replace(health=_health_from_saved(loaded['health']))
-    if 'comp_ef' in loaded and getattr(state, 'comp_ef', None) is not None:
-        state = state._replace(comp_ef=dict(loaded['comp_ef']))
     return state
+
+
+def _refuse_comp_ef(path: str, saved_kfac: Any) -> None:
+    """A checkpoint written under the removed ``stat_compression`` option
+    holds error-feedback residuals (``kfac/comp_ef``): deferred factor mass
+    this engine has nowhere to put. Refuse it by name; restoring the
+    factors and dropping the residuals would bias the next EMA silently."""
+    if 'comp_ef' in saved_kfac:
+        raise ValueError(
+            f'checkpoint at {path!r} carries error-feedback residuals of '
+            'a compressed stat transport (kfac/comp_ef), which are no '
+            'longer supported: no engine of this version can restore them, '
+            'and the factors are not restored without them. Restore it '
+            'with the version that wrote it and save again with '
+            'stat_compression off.'
+        )
 
 
 def _health_from_saved(saved: Any) -> Any:
@@ -479,12 +470,9 @@ def _retry_health_mismatch(
     health-enabled engine (counters start fresh), and one written WITH
     them must restore into a health-disabled engine (counters dropped) —
     toggling the sentinel between runs is configuration, not a layout
-    change. Likewise a pre-compression checkpoint (no ``comp_ef``) must
-    restore into an error-feedback engine: the residual starts from
-    init()'s zeros. (The opposite comp_ef direction — an EF checkpoint
-    into an EF-less engine — has no template to offer orbax and falls
-    through to the layout diagnosis, which names ``stat_compression``.)
-    Anything else re-raises the layout diagnosis."""
+    change. A checkpoint that carries error-feedback residuals is refused
+    by name (:func:`_refuse_comp_ef`). Anything else re-raises the layout
+    diagnosis."""
     kfac_t = template['kfac']
     health_toggled = None
     if 'health' in kfac_t:
@@ -500,37 +488,36 @@ def _retry_health_mismatch(
                 **kfac_t,
                 'health': health_lib.init_health(reg.names())._asdict(),
             }
-    variants = []
     if health_toggled is not None:
-        variants.append(health_toggled)
-    # toggle comp_ef independently and jointly with the health toggle
-    for base in (kfac_t, health_toggled):
-        if base is not None and 'comp_ef' in base:
-            variants.append(
-                {k: v for k, v in base.items() if k != 'comp_ef'}
-            )
-    for kf in variants:
         try:
-            payload = ckptr.restore(path, target={**template, 'kfac': kf})
+            payload = ckptr.restore(
+                path, target={**template, 'kfac': health_toggled}
+            )
         except (ValueError, KeyError):
-            continue
-        # either health direction resolves to "no health in the loaded
-        # payload": a sentinel-less checkpoint keeps init()'s fresh
-        # counters; a sentinel-less engine drops the saved ones. A
-        # comp_ef-less payload keeps init()'s zero residuals.
-        payload['kfac'].pop('health', None)
-        return payload
+            pass
+        else:
+            # either health direction resolves to "no health in the loaded
+            # payload": a sentinel-less checkpoint keeps init()'s fresh
+            # counters; a sentinel-less engine drops the saved ones.
+            payload['kfac'].pop('health', None)
+            return payload
+    _refuse_comp_ef(path, _saved_tree_metadata(path).get('kfac', {}))
     raise ValueError(
         f'checkpoint at {path!r} does not match the engine state '
         'layout. For DistributedKFAC the stacked bucket keys/shapes '
         'depend on the config (notably bucket_granularity and '
-        'colocate_factors), and error-feedback residuals saved under '
-        'stat_compression need a compression-enabled engine (or the same '
-        'chunking) to restore into: restore with the SAME values the '
+        'colocate_factors): restore with the SAME values the '
         'checkpoint was saved under — or write checkpoints with '
         'save(..., engine=engine) so restore can diagnose and migrate '
         f'layout changes. Original error: {exc}'
     ) from exc
+
+
+def _saved_tree_metadata(path: str) -> Any:
+    """The saved payload's tree of per-leaf metadata (orbax wraps it in
+    StepMetadata): what a checkpoint holds, read without its arrays."""
+    reader = ocp.Checkpointer(ocp.PyTreeCheckpointHandler())
+    return reader.metadata(path).item_metadata.tree
 
 
 def _raw_host_restore(path: str) -> dict[str, Any]:
@@ -551,15 +538,13 @@ def _raw_host_restore(path: str) -> dict[str, Any]:
     from orbax.checkpoint import checkpoint_utils
 
     reader = ocp.Checkpointer(ocp.PyTreeCheckpointHandler())
-    # orbax wraps the saved tree's metadata in StepMetadata
-    meta = reader.metadata(path).item_metadata.tree
     meta = jax.tree_util.tree_map(
         lambda m: (
             dataclasses.replace(m, sharding=None)
             if dataclasses.is_dataclass(m) and hasattr(m, 'sharding')
             else m
         ),
-        meta,
+        _saved_tree_metadata(path),
     )
     restore_args = checkpoint_utils.construct_restore_args(meta)
     raw = reader.restore(
@@ -595,6 +580,7 @@ def _migrate_restore(
     # insert_factors' scatter) and break outright when the device set
     # changed (elastic shrink/grow)
     raw = _raw_host_restore(path)
+    _refuse_comp_ef(path, raw['kfac'])
     factors = _factors_from_saved(raw['kfac'], saved_man)
     if factors is None or 'n_stages' in cur_man:
         raise ValueError(

@@ -9,7 +9,7 @@ they can be refit on held-out data (:func:`kfac_tpu.laplace
 
 The knob table in docs/LAPLACE.md is pinned to these fields by the
 KFL107 drift rule (kfac_tpu/analysis/drift.py) — the same doc-vs-code
-contract as the compression (KFL105) and fleet (KFL106) knob tables.
+contract as the calibration (KFL108) and chaos (KFL111) knob tables.
 """
 
 from __future__ import annotations

@@ -91,17 +91,7 @@ def _exportable_layers(registry: Any, cfg: config_lib.LaplaceConfig) -> list[str
 
 def _refuse_unhealthy(state: Any) -> None:
     """Exporting quarantined curvature would bake known-bad factors into a
-    served posterior; the checkpoint path has the same backstop for
-    spilled states (checkpoint.durable_state)."""
-    from kfac_tpu.compression import offload as offload_lib
-
-    if not isinstance(state, dict) and offload_lib.is_spilled(state):
-        raise ValueError(
-            'cannot export a Laplace posterior from a spilled K-FAC state: '
-            'the factor slots are cold-offload placeholders (the real '
-            'factors live in host RAM). Use OffloadManager.host_view(state) '
-            'for a resident view first.'
-        )
+    served posterior."""
     health = getattr(state, 'health', None)
     if health is None:
         return
@@ -143,9 +133,8 @@ def export_posterior(
         engine: :class:`kfac_tpu.KFACPreconditioner` or
             :class:`kfac_tpu.parallel.DistributedKFAC` (anything with
             ``registry`` + ``extract_factors``).
-        state: the engine's state at export time. Refused while spilled
-            (cold-offload placeholders) or while any layer is under
-            numerical quarantine.
+        state: the engine's state at export time. Refused while any
+            layer is under numerical quarantine.
         params: the MAP parameter pytree (stored in the artifact; the
             posterior samples around it).
         path: artifact directory (created; refused if it already holds a

@@ -16,8 +16,7 @@ Five small modules, one per concern:
   the KAISA transports and size-class padding waste.
 - :mod:`kfac_tpu.observability.calibration` — live comparison of
   measured step/spike times (and XLA-reported HBM bytes) against the
-  autotune plan's cost model, with a drift bridge into the fleet
-  controller's retune path.
+  autotune plan's cost model.
 - :mod:`kfac_tpu.observability.compile_watch` — recompile attribution
   (per-entry compile events with fingerprint diffs), per-compile XLA
   ``memory_analysis()`` accounting, and crash-safe mid-compile heartbeat
@@ -44,7 +43,6 @@ from kfac_tpu.observability import sinks
 from kfac_tpu.observability.calibration import (
     CalibrationConfig,
     CalibrationMonitor,
-    fleet_drift_keys,
 )
 from kfac_tpu.observability.comms import comms_summary
 from kfac_tpu.observability.compile_watch import (
@@ -107,7 +105,6 @@ __all__ = [
     'comms_summary',
     'compile_watch',
     'drain_flight',
-    'fleet_drift_keys',
     'flight_recorder',
     'ledger',
     'measured_hbm_bytes',

@@ -14,27 +14,9 @@ plus XLA-reported HBM bytes via :meth:`CalibrationMonitor.observe_memory`
 bridge, see docs/OBSERVABILITY.md "Compile & memory truth");
 it maintains rolling residual ratios ``measured / predicted``, exposes
 them as ``calib/*`` metric keys for the JSONL / rate-limited-logger
-sinks, folds a headline ``calib/model_error`` into drained
-flight-recorder records, and — via :func:`CalibrationMonitor.wrap_drain`
-— speaks the fleet controller's native drift dialect so a drifted cost
-model drives the EXISTING retune path
-(:class:`kfac_tpu.resilience.fleet.FleetController`) with no new
-controller machinery:
-
-    monitor = calibration.CalibrationMonitor.from_plan(plan)
-    cfg = fleet_lib.FleetConfig(drift_keys=calibration.fleet_drift_keys())
-    fleet = fleet_lib.FleetController(..., drain=monitor.wrap_drain())
-    ...
-    monitor.observe_step(step_wall_s)   # each step, host-side
-
-The bridge works because the controller already thresholds
-``flight_recorder.skew_ratio`` — ``(skew_max - skew_min) / |skew_mean|``
-— per drift key. The monitor injects synthetic skew columns for
-:data:`DRIFT_KEY` with ``min = mean = 1`` and ``max = fold_error``, so
-the ratio the controller sees IS ``fold_error - 1``: a calibration fold
-error of 2x reads as skew 1.0 and trips the default 0.5 threshold the
-same way a real cross-host straggler would. Purely host-side: nothing
-new is jitted, no recompilation (the no-recompile test pins this).
+sinks, and folds a headline ``calib/model_error`` into drained
+flight-recorder records. Purely host-side: nothing new is jitted, no
+recompilation (the no-recompile test pins this).
 
 See docs/OBSERVABILITY.md "Measurement truth" for the knob table
 (linted by KFL108) and a worked quickstart.
@@ -45,18 +27,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import math
-from typing import Any, Callable, Iterable, Sequence
-
-#: the headline key the fleet controller thresholds for cost-model drift
-DRIFT_KEY = 'calib/model_error'
-
-
-def fleet_drift_keys(
-    extra: Sequence[str] = ('grad_norm',),
-) -> tuple[str, ...]:
-    """``FleetConfig.drift_keys`` value that adds cost-model drift to the
-    usual straggler keys."""
-    return (DRIFT_KEY,) + tuple(k for k in extra if k != DRIFT_KEY)
+from typing import Any, Iterable, Sequence
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,8 +45,7 @@ class CalibrationConfig:
             compile and autotune warmup steps are not model residuals.
         prefix: metric-key namespace for emitted keys
             (``<prefix>/step_ratio`` etc.). Change it only if ``calib/``
-            collides with a user metric; the fleet drift bridge's
-            :data:`DRIFT_KEY` stays ``calib/model_error`` regardless.
+            collides with a user metric.
     """
 
     window: int = 32
@@ -259,8 +229,7 @@ class CalibrationMonitor:
         """Direction-free fold error of the cost model: the worst of the
         step-time and memory folds ``max(r, 1/r)``; 1.0 with no evidence
         yet, so an idle monitor never looks drifted. A 2x-wrong memory
-        model therefore reads exactly like a 2x-wrong time model and
-        drives the same fleet drift path."""
+        model therefore reads exactly like a 2x-wrong time model."""
         return max(self._fold(self.step_ratio()), self._fold(self.mem_ratio()))
 
     # ------------------------------------------------------------ emission
@@ -301,45 +270,3 @@ class CalibrationMonitor:
         return it) — the flight-recorder headline path."""
         record.update(self.record())
         return record
-
-    # -------------------------------------------------------- fleet bridge
-
-    def drift_skew_columns(self) -> dict[str, float]:
-        """Synthetic skew columns encoding the current fold error in the
-        controller's dialect: ``skew_ratio(rec, DRIFT_KEY) ==
-        model_error() - 1``."""
-        fold = self.model_error()
-        return {
-            DRIFT_KEY: fold,
-            f'skew_min/{DRIFT_KEY}': 1.0,
-            f'skew_max/{DRIFT_KEY}': fold,
-            f'skew_mean/{DRIFT_KEY}': 1.0,
-        }
-
-    def wrap_drain(
-        self,
-        drain: Callable[[Any], list[dict[str, Any]]] | None = None,
-    ) -> Callable[[Any], list[dict[str, Any]]]:
-        """A ``FleetController(drain=...)`` callable that stamps every
-        drained flight record with :meth:`drift_skew_columns`, making
-        cost-model drift visible to the controller's existing
-        ``skew_ratio`` thresholding alongside real cross-host skew.
-
-        ``drain=None`` wraps the controller's default
-        (:func:`kfac_tpu.observability.flight_recorder.drain_flight`
-        with the standard skew keys).
-        """
-        if drain is None:
-            from kfac_tpu.observability import flight_recorder as flight_lib
-
-            def drain(state: Any) -> list[dict[str, Any]]:
-                return flight_lib.drain_flight(state)
-
-        def calibrated_drain(state: Any) -> list[dict[str, Any]]:
-            records = drain(state)
-            cols = self.drift_skew_columns()
-            for rec in records:
-                rec.update(cols)
-            return records
-
-        return calibrated_drain

@@ -22,13 +22,6 @@ Accounted flows, per ``DistributedKFAC``:
 - **padding waste**: resident factor bytes split into true-dim content,
   identity padding inside each size-class slot, and whole padding slots
   added to round stacks to the device count.
-- **compressed transport** (``stat_compression``): each bucketed chunk
-  reports ``raw_bytes`` (uncompressed, at the promoted transport dtype)
-  next to ``wire_bytes`` (quantized payload + float32 block scales).
-- **cold-factor offload** (``offload``): the static spill plan
-  (``spill_bytes``, cadence knobs); ``engine.comms_report()`` merges the
-  live spill/prefetch counters from the running
-  :class:`kfac_tpu.compression.OffloadManager` on top.
 
 Bytes are global logical bytes moved per occurrence of each flow (what
 you would compare across transports/configs), not per-device wire bytes
@@ -92,16 +85,11 @@ def transport_report(engine: Any) -> dict[str, Any]:
     stacked rows, padded to class dims) ride byte-capped flat buffers;
     ``savings`` is relative to shipping the same rows dense.
 
-    Every entry carries ``raw_bytes`` (the payload at its uncompressed
-    transport dtype — the PROMOTED chunk dtype for bucketed buffers, not
-    a blanket factor-dtype assumption) and ``wire_bytes`` (what actually
-    crosses the interconnect). With ``stat_compression`` on, the wire is
-    the quantized payload plus its float32 per-block scales
-    (:func:`kfac_tpu.compression.quant.wire_bytes`) and the
-    ``compression`` subdict records the knobs and achieved ratio; off,
-    ``wire_bytes == raw_bytes``. ``bytes`` always equals ``wire_bytes``
-    (backward compatible: identical to the pre-compression figure when
-    compression is off).
+    Every entry carries ``raw_bytes`` (the payload at its transport
+    dtype — the PROMOTED chunk dtype for bucketed buffers, not a blanket
+    factor-dtype assumption) and ``wire_bytes`` (what crosses the
+    interconnect): the transport ships the payload as it is, so the two
+    are equal, and ``bytes`` equals both.
     """
     cfg = engine.config
     item = _itemsize(cfg.factor_dtype)
@@ -126,7 +114,6 @@ def transport_report(engine: Any) -> dict[str, Any]:
             'wire_dtype': str(jnp.dtype(cfg.factor_dtype)),
             'dense_bytes': dense,
             'savings': 0.0,
-            'compression': None,
             'chunks': [],
         }
     # same row order as _stack_stats' flat_rows: all A rows, then all G
@@ -139,29 +126,16 @@ def transport_report(engine: Any) -> dict[str, Any]:
     from kfac_tpu.parallel import collectives
 
     cap = cfg.allreduce_bucket_cap_mb
-    chunks = collectives.plan_chunks(
-        specs, max_bytes=None if cap is None else cap * 1e6)
-    ccfg = getattr(cfg, 'stat_compression', None)
-    out_chunks: list[dict[str, Any]] = []
-    for c in chunks:
-        entry = dict(c)
-        entry['raw_bytes'] = c['bytes']
-        if ccfg is None:
-            entry['wire_bytes'] = c['bytes']
-            entry['wire_dtype'] = c['dtype']
-        else:
-            from kfac_tpu.compression import quant as quant_lib
-
-            wb = quant_lib.wire_bytes(
-                c['elements'], ccfg.dtype, ccfg.block_size
-            )
-            entry.update(wb)
-            entry['wire_dtype'] = ccfg.dtype
-            entry['bytes'] = wb['wire_bytes']
-        out_chunks.append(entry)
-    raw = sum(c['raw_bytes'] for c in out_chunks)
-    wire = sum(c['wire_bytes'] for c in out_chunks)
-    wire_dtypes = sorted({str(c['wire_dtype']) for c in out_chunks})
+    chunks = [
+        dict(
+            c, raw_bytes=c['bytes'], wire_bytes=c['bytes'],
+            wire_dtype=c['dtype'],
+        )
+        for c in collectives.plan_chunks(
+            specs, max_bytes=None if cap is None else cap * 1e6)
+    ]
+    wire = sum(c['bytes'] for c in chunks)
+    wire_dtypes = sorted({str(c['dtype']) for c in chunks})
     dense = sum(
         sb.d * sb.d * len(sb.layers) * item
         for store in (engine.a_store, engine.g_store)
@@ -169,21 +143,15 @@ def transport_report(engine: Any) -> dict[str, Any]:
     )
     return {
         'method': 'ALLREDUCE_BUCKETED',
-        'collectives': len(out_chunks),
+        'collectives': len(chunks),
         'bytes': wire,
-        'raw_bytes': raw,
+        'raw_bytes': wire,
         'wire_bytes': wire,
         'wire_dtype': '|'.join(wire_dtypes) if wire_dtypes else str(
             jnp.dtype(cfg.factor_dtype)),
         'dense_bytes': dense,
         'savings': 1.0 - wire / dense if dense else 0.0,
-        'compression': None if ccfg is None else {
-            'dtype': ccfg.dtype,
-            'block_size': ccfg.block_size,
-            'error_feedback': ccfg.error_feedback,
-            'ratio': raw / wire if wire else 1.0,
-        },
-        'chunks': out_chunks,
+        'chunks': chunks,
     }
 
 
@@ -257,23 +225,6 @@ def comms_summary(engine: Any) -> dict[str, Any]:
         n_cols = int(engine.n_cols)
 
     padding = padding_report(engine)
-    ocfg = getattr(engine.config, 'offload', None)
-    if ocfg is None:
-        offload = None
-    else:
-        item = _itemsize(engine.config.factor_dtype)
-        offload = {
-            'min_cold_steps': int(ocfg.min_cold_steps),
-            'prefetch_lead': int(ocfg.prefetch_lead),
-            # factor stack bytes a spill moves host-side (global logical
-            # bytes, same convention as every flow here); the engine's
-            # comms_report() merges the live transfer/hit counters on top
-            'spill_bytes': sum(
-                sb.padded * sb.d * sb.d * item
-                for store in (engine.a_store, engine.g_store)
-                for sb in store
-            ),
-        }
     return {
         'strategy': engine.strategy.name,
         'grad_worker_fraction': engine.grad_workers / engine.world,
@@ -283,7 +234,6 @@ def comms_summary(engine: Any) -> dict[str, Any]:
         'stat_transport': transport_report(engine),
         'grad_broadcast_bytes': grad_broadcast_bytes(engine),
         'decomp_reshard_bytes': decomp_reshard_bytes(engine),
-        'offload': offload,
         'padding': padding,
         'padding_totals': {
             'resident_bytes': sum(

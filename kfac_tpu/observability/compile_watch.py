@@ -389,8 +389,8 @@ class CompileWatch:
         self._counts: dict[str, int] = {}
         # per entry, calls by how they were dispatched ('fast' without a
         # fingerprint, 'fingerprinted' with one); kept here, not on the
-        # wrapper, so that an entry wrapped again (Trainer.rebind_engine)
-        # keeps counting
+        # wrapper, so that an entry wrapped again (a second Trainer on one
+        # engine) keeps counting
         self._dispatched: dict[str, dict[str, int]] = {}
         self._last_fp: dict[str, dict[str, Any]] = {}
         self._wrapped: dict[str, WatchedFunction] = {}

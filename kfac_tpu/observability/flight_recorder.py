@@ -312,28 +312,6 @@ def _add_skew_columns(
             rec[f'skew_mean/{k}'] = float(np.mean(col))
 
 
-def skew_ratio(record: dict[str, Any], key: str) -> float:
-    """Relative cross-host spread of one drained record's headline
-    scalar: ``(skew_max - skew_min) / (|skew_mean| + eps)``.
-
-    0.0 on a perfectly balanced pod (and always on single-process
-    drains, where min == max == mean) — and 0.0 when the record carries
-    no skew columns for ``key`` (the key wasn't in the drain's
-    ``skew_keys``), so callers can scan heterogeneous records without
-    guarding. This is the drift signal the fleet controller
-    (:mod:`kfac_tpu.resilience.fleet`) thresholds.
-    """
-    lo = record.get(f'skew_min/{key}')
-    hi = record.get(f'skew_max/{key}')
-    mean = record.get(f'skew_mean/{key}')
-    if lo is None or hi is None or mean is None:
-        return 0.0
-    return float((hi - lo) / (abs(mean) + 1e-12))
-
-
-# -------------------------------------------------------------- fingerprint
-
-
 def fingerprint(engine: Any = None) -> dict[str, Any]:
     """Library-version + mesh/topology snapshot for offline triage.
 

@@ -49,8 +49,6 @@ from kfac_tpu import tracing
 from kfac_tpu.async_inverse import host as async_host
 from kfac_tpu.async_inverse import sliced as async_sliced
 from kfac_tpu.async_inverse import slots as async_slots
-from kfac_tpu.compression import offload as offload_lib
-from kfac_tpu.compression import quant as quant_lib
 from kfac_tpu.layers import capture as capture_lib
 from kfac_tpu.layers import helpers as helpers_lib
 from kfac_tpu.layers import registry as registry_lib
@@ -340,14 +338,6 @@ class DistKFACState(NamedTuple):
     # 'sliced' is enabled (kfac_tpu/async_inverse); ephemeral like
     # metrics/flight — a restore rematerializes and resets it
     shadow: Any = None
-    # per-chunk error-feedback residuals ('c0', 'c1', ...) of the
-    # compressed stat transport when stat_compression.error_feedback is
-    # on, else None. DURABLE (unlike shadow): the residual is deferred
-    # factor mass — dropping it at a restore would bias the next EMA by
-    # exactly the noise error feedback exists to cancel. Float32,
-    # replicated, shaped by the host-side chunk plan
-    # (``_plan_compression``).
-    comp_ef: Any = None
     # what the last capture saw of the routed experts held here, where the
     # registry has stacked expert projections (``Registry.stacks``):
     # float32 :data:`TRAFFIC_COLUMNS`. ``None`` without them. Ephemeral
@@ -699,39 +689,6 @@ class DistributedKFAC:
             and self.config.inverse_solver in _NS_SOLVERS
             and self._async_mode is None
         )
-        self._plan_compression()
-        self._plan_offload()
-
-    def _plan_compression(self) -> None:
-        """Precompute the host-side chunk plan of the compressed stat
-        transport (exact mirror of the runtime packing in
-        ``_stack_stats``: A-store rows then G-store rows through
-        ``collectives.plan_chunks`` with the same byte cap), so error-
-        feedback residual shapes are known without tracing a step."""
-        ccfg = self.config.stat_compression
-        self._compression = ccfg
-        self._comp_plan = None
-        if ccfg is None:
-            return
-        cfg = self.config
-        specs = [
-            (sb.d * (sb.d + 1) // 2, jnp.dtype(cfg.factor_dtype))
-            for store in (self.a_store, self.g_store)
-            for sb in store
-            for _ in sb.layers
-        ]
-        cap = cfg.allreduce_bucket_cap_mb
-        self._comp_plan = collectives.plan_chunks(
-            specs, max_bytes=None if cap is None else cap * 1e6
-        )
-
-    def _plan_offload(self) -> None:
-        """Attach the cold-factor offload manager (host-side state only;
-        config validation lives in KFACPreconditioner.__post_init__)."""
-        self._offload_manager = (
-            None if self.config.offload is None
-            else offload_lib.OffloadManager(self)
-        )
 
     def _plan_async(self) -> None:
         """Precompute the async refresh plan over the STACKED layout
@@ -838,12 +795,6 @@ class DistributedKFAC:
             )
         else:
             shadow_sh = None
-        if self._compression is not None and self._compression.error_feedback:
-            comp_ef_sh = {
-                f'c{i}': rep for i in range(len(self._comp_plan))
-            }
-        else:
-            comp_ef_sh = None
         return DistKFACState(
             step=rep,
             a=adict(fac),
@@ -860,7 +811,6 @@ class DistributedKFAC:
             metrics=metrics_sh,
             flight=flight_sh,
             shadow=shadow_sh,
-            comp_ef=comp_ef_sh,
             refresh=(
                 RefreshState(
                     self._refresh_buckets(), rep, self._refresh_groups()
@@ -913,16 +863,6 @@ class DistributedKFAC:
                     dgda[b.key] = jnp.zeros(
                         (b.padded, b.dg, b.da), cfg.inv_dtype
                     )
-            if (
-                self._compression is not None
-                and self._compression.error_feedback
-            ):
-                comp_ef = {
-                    f'c{i}': jnp.zeros((int(ch['elements']),), jnp.float32)
-                    for i, ch in enumerate(self._comp_plan)
-                }
-            else:
-                comp_ef = None
             return DistKFACState(
                 step=jnp.asarray(0, jnp.int32),
                 a=a, g=g, qa=qa, qg=qg, da=da, dg=dg, dgda=dgda,
@@ -950,7 +890,6 @@ class DistributedKFAC:
                     )
                     if cfg.flight is not None else None
                 ),
-                comp_ef=comp_ef,
                 refresh=(
                     self._pack_refresh([
                         # iterations -1: no refresh has filled the row
@@ -994,7 +933,7 @@ class DistributedKFAC:
 
     def _stack_stats(
         self, state: DistKFACState, stats: capture_lib.CapturedStats
-    ) -> tuple[dict[str, jax.Array], dict[str, jax.Array], Any]:
+    ) -> tuple[dict[str, jax.Array], dict[str, jax.Array]]:
         """Stack per-layer stats into bucket layout.
 
         Registered layers absent from ``stats`` (not executed by this
@@ -1003,11 +942,9 @@ class DistributedKFAC:
         (kfac_tpu/preconditioner.py:update_factors) and the reference's
         hooks, which simply never fire for unexecuted modules.
 
-        Returns ``(a_stacks, g_stacks, new_comp_ef)``: the third element
-        is the updated error-feedback residual dict when the compressed
-        transport carries one, else the state's ``comp_ef`` unchanged. A
-        bucket whose float32 stack is :data:`SOLVE_GROUP_BYTES` or wider
-        comes back as the list of its layers' rows, not stacked.
+        Returns ``(a_stacks, g_stacks)``. A bucket whose float32 stack is
+        :data:`SOLVE_GROUP_BYTES` or wider comes back as the list of its
+        layers' rows, not stacked.
         """
         cfg = self.config
         bucketed = (
@@ -1056,7 +993,6 @@ class DistributedKFAC:
         rows_a = side_rows(self.a_store, self._a_stats(stats), state.a)
         rows_g = side_rows(self.g_store, stats.g, state.g)
 
-        new_ef = getattr(state, 'comp_ef', None)
         if bucketed:
             flat_rows = [
                 m for sb in self.a_store for m in rows_a[sb.key]
@@ -1068,40 +1004,10 @@ class DistributedKFAC:
             packed = collectives.concat_flat_chunked(
                 tris, max_bytes=None if cap is None else cap * 1e6
             )
-            ccfg = self._compression
-            if ccfg is None:
-                chunks = [
-                    (jax.lax.with_sharding_constraint(flat, rep), specs)
-                    for flat, specs in packed
-                ]
-            else:
-                # Quantize each flat chunk blockwise to the wire dtype and
-                # pin the QUANTIZED payload + scales to replicated — the
-                # sharding constraint IS the collective under GSPMD, so
-                # this is what crosses the interconnect. Error feedback
-                # adds the carried residual before quantizing and keeps
-                # what the wire dropped for the next factor update.
-                ef_in = new_ef
-                ef_out: dict[str, jax.Array] = {}
-                chunks = []
-                for i, (flat, specs) in enumerate(packed):
-                    key = f'c{i}'
-                    carried = flat.astype(jnp.float32)
-                    if ef_in is not None:
-                        carried = carried + ef_in[key]
-                    payload, scales = quant_lib.quantize_blockwise(
-                        carried, ccfg.dtype, ccfg.block_size
-                    )
-                    payload = jax.lax.with_sharding_constraint(payload, rep)
-                    scales = jax.lax.with_sharding_constraint(scales, rep)
-                    deq = quant_lib.dequantize_blockwise(
-                        payload, scales, flat.shape[0], ccfg.block_size
-                    )
-                    if ef_in is not None:
-                        ef_out[key] = carried - deq
-                    chunks.append((deq.astype(flat.dtype), specs))
-                if ef_in is not None:
-                    new_ef = ef_out
+            chunks = [
+                (jax.lax.with_sharding_constraint(flat, rep), specs)
+                for flat, specs in packed
+            ]
             unpacked = iter(
                 collectives.fill_triu(m.shape, t)
                 for m, t in zip(
@@ -1133,7 +1039,6 @@ class DistributedKFAC:
         return (
             stack_side(self.a_store, rows_a),
             stack_side(self.g_store, rows_g),
-            new_ef,
         )
 
     # --------------------------------------------------------------- health
@@ -1180,7 +1085,7 @@ class DistributedKFAC:
         reference's explicit factor allreduce, kfac/layers/base.py:282-336).
         """
         alpha = _resolve(self.config.factor_decay, state.step)
-        a_stacks, g_stacks, new_ef = self._stack_stats(state, stats)
+        a_stacks, g_stacks = self._stack_stats(state, stats)
         fac = NamedSharding(self.mesh, self._factor_spec())
         # Capture weights (routed MoE layers): per-slot effective decay
         # alpha_eff = 1 - (1-alpha)*w so the EMA moves proportionally to
@@ -1297,9 +1202,7 @@ class DistributedKFAC:
                 damping_mult=mult, quarantined=quarantined,
                 quarantine_events=events,
             )
-        state = state._replace(
-            a=new_a, g=new_g, health=new_health, comp_ef=new_ef
-        )
+        state = state._replace(a=new_a, g=new_g, health=new_health)
         traffic = getattr(stats, 'traffic', None)
         if traffic and state.traffic is not None:
             seen = [traffic[n] for n in sorted(traffic)]
@@ -1984,23 +1887,14 @@ class DistributedKFAC:
         kfac_tpu/preconditioner.py:step). ``loss``, when given, rides
         into the flight-recorder ring next to this step's scalars."""
         cfg = self.config
-        # Spilled interior step (cold-factor offload): the factor stacks
-        # are zero-size host-offload placeholders, statically detectable
-        # at trace time. The offload pump guarantees residency on every
-        # cadence boundary, so skipping the factor/inverse branches here
-        # is exact — they would be no-op cond arms anyway — and keeps the
-        # placeholders out of the traced branches.
-        spilled = offload_lib.is_spilled(state)
-        if stats is not None and not spilled:
+        if stats is not None:
             state = jax.lax.cond(
                 state.step % _resolve(cfg.factor_update_steps, state.step) == 0,
                 lambda s: self.update_factors(s, stats),
                 lambda s: s,
                 state,
             )
-        if spilled:
-            pass
-        elif self._async_mode == 'sliced':
+        if self._async_mode == 'sliced':
             state = async_sliced.kaisa_async_step(self, state)
         elif self._async_mode == 'host':
             state = async_host.kaisa_host_step(self, state)
@@ -2041,10 +1935,6 @@ class DistributedKFAC:
         worker output discarded) — the first boundary after a mid-window
         restore skips the swap, the next window refreshes normally.
         """
-        if self._offload_manager is not None:
-            # restored states are resident by construction — drop any
-            # stale host copies/prefetches from before the restore
-            self._offload_manager.reset()
         state = self.update_inverses(state)
         if self._async_mode == 'sliced':
             state = state._replace(
@@ -2236,13 +2126,7 @@ class DistributedKFAC:
         gradient-broadcast payloads, and per-size-class padding waste —
         the measurable side of the KAISA gradient-worker-fraction trade.
         """
-        out = comms_lib.comms_summary(self)
-        if self._offload_manager is not None and 'offload' in out:
-            # static plan (comms_summary) + live transfer/hit counters
-            out['offload'] = dict(
-                out['offload'], **self._offload_manager.stats
-            )
-        return out
+        return comms_lib.comms_summary(self)
 
     def compile_watcher(
         self,
@@ -2305,12 +2189,6 @@ class DistributedKFAC:
         breaks resident factor bytes out of the size-class padding, per
         storage bucket plus totals, so the cost of bucket granularity is
         visible next to the resident footprint.
-
-        On a SPILLED state (cold-factor offload interior step) the
-        ``a_factors``/``g_factors`` categories read ~0 bytes — the
-        placeholders' true footprint — which is exactly the HBM relief
-        the offload buys; ``comms_report()['offload']`` carries the
-        host-resident byte count.
         """
         shard_f = 1.0 / self.total_devices
         if self.strategy == enums.DistributedStrategy.COMM_OPT:

@@ -193,10 +193,9 @@ def agree_decision(ok: bool) -> bool:
     """Pod-unanimous go/no-go vote: True only when EVERY process voted
     True.
 
-    The fleet controller's live layout migration uses this as its commit
-    gate — any host whose save/rebuild/elastic-restore failed vetoes the
-    swap pod-wide, so no host ever trains under a layout its peers
-    failed to reach. Built on :func:`allgather_scalars` (min-reduction
+    The chaos worker's recovery uses this as its gate — any host whose
+    restore walk failed vetoes the resume pod-wide, so no host ever trains
+    from a state its peers failed to reach. Built on :func:`allgather_scalars` (min-reduction
     over one fixed-shape gather), so single-process it is a pure-Python
     identity; every process must call it at the same point in its call
     sequence (SPMD symmetry).

@@ -24,8 +24,8 @@ count, and its predicted step cost composes three ingredient families:
   group (fraction ``1/dp``) supplies the same ``comms_summary`` byte
   terms and decomposition/preconditioning FLOPs the KAISA model uses,
   scaled by the per-rank model share ``1/pp``. The base config's
-  cadence, async-inverse, compression and offload knobs ride into the
-  layout unchanged, so those knobs are co-planned with the mesh shape.
+  cadence and async-inverse knobs ride into the layout unchanged, so
+  those knobs are co-planned with the mesh shape.
 - **per-stage HBM** — params, activations in flight (residual ring +
   inboxes + microbatch feeds, ring depths exactly as the scan bodies
   allocate them) and second-order state, pruned against
@@ -319,8 +319,6 @@ def _base_candidate(base: Any, frac: float) -> model_lib.Candidate:
         inv_update_steps=search_lib._static_cadence(base.inv_update_steps),
         colocate_factors=bool(base.colocate_factors),
         async_inverse=search_lib._async_mode(base),
-        stat_compression=search_lib._compression_dtype(base),
-        offload=search_lib._offload_enabled(base),
     )
 
 
@@ -446,15 +444,6 @@ def predict_topology(
         'decomps': comms['decomp_reshard_bytes'] * share,
         'grad_stacks': comms['grad_broadcast_bytes'] * share,
     }
-    offload_transfer_s = 0.0
-    if kaisa_cand.offload:
-        memory['factors_offloaded'] = memory.pop('factors')
-        memory['factors'] = 0.0
-        window = max(1, min(f_cad, i_cad))
-        offload_transfer_s = (
-            2.0 * (factor_total * share / group)
-            / hardware.host_bandwidth / window
-        )
     memory['total'] = sum(
         memory[k]
         for k in ('params', 'activations', 'factors', 'decomps',
@@ -496,14 +485,12 @@ def predict_topology(
         'flops_per_device_per_step': kfac_flops + compute_dev,
         'memory_per_device_bytes': memory,
         'refresh_spike_s': refresh_spike_s,
-        'offload_transfer_s': offload_transfer_s,
         'predicted_step_s': (
             compute_s
             + pipe_bytes / hardware.collective_bandwidth
             + kfac_flops / hardware.matmul_flops
             + kfac_bytes_per_step / hardware.collective_bandwidth
             + host_transfer_s / i_cad
-            + offload_transfer_s
         ),
     }
 

@@ -36,8 +36,6 @@ from kfac_tpu.async_inverse import config as async_config_lib
 from kfac_tpu.async_inverse import host as async_host
 from kfac_tpu.async_inverse import sliced as async_sliced
 from kfac_tpu.async_inverse import slots as async_slots
-from kfac_tpu.compression import config as compression_config_lib
-from kfac_tpu.compression import offload as offload_lib
 from kfac_tpu.layers import capture as capture_lib
 from kfac_tpu.layers import helpers as helpers_lib
 from kfac_tpu.layers import registry as registry_lib
@@ -396,31 +394,6 @@ class KFACPreconditioner:
     async_inverse: 'async_config_lib.AsyncInverseConfig | str | bool | None' = (
         None
     )
-    # Compressed stat transport (kfac_tpu/compression, docs/ARCHITECTURE.md
-    # "Compression & offload"): int8/fp8 blockwise-scaled quantization of
-    # the bucketed factor-allreduce payloads, with a per-chunk
-    # error-feedback residual carried as DURABLE engine state so the
-    # quantization noise stays zero-mean in the factor EMA. Requires
-    # allreduce_method=ALLREDUCE_BUCKETED (the flat-buffer transport is
-    # what gets quantized). None disables; True selects int8 defaults; a
-    # dtype string ('int8'/'fp8') is a shorthand; or pass a
-    # compression.CompressionConfig. Ignored by the dense engine (which
-    # has no transport) but validated here so configs fail fast.
-    stat_compression: (
-        'compression_config_lib.CompressionConfig | str | bool | None'
-    ) = None
-    # Cold-factor host offload (kfac_tpu/compression/offload.py,
-    # docs/ARCHITECTURE.md "Compression & offload"): spill the factor
-    # state to host RAM between factor/inverse cadence boundaries and
-    # prefetch it back ahead of the next boundary, so HBM holds only the
-    # hot decomposition state on interior steps. Driven host-side by the
-    # Trainer's eager step paths (scan paths keep the state resident).
-    # Requires static int cadences and is incompatible with
-    # async_inverse='sliced' (which reads the factors every step, so they
-    # are never cold). None disables; True selects defaults; an int is a
-    # min_cold_steps shorthand; or pass a compression.OffloadConfig.
-    # Honored by both engines.
-    offload: 'compression_config_lib.OffloadConfig | int | bool | None' = None
     # Compile watch (kfac_tpu/observability/compile_watch.py,
     # docs/OBSERVABILITY.md "Compile & memory truth"): recompile
     # attribution, per-compile XLA memory accounting, and crash-safe
@@ -619,48 +592,7 @@ class KFACPreconditioner:
                 'refresh window phase is compiled into the step dispatch); '
                 'got a schedule'
             )
-        self.stat_compression = compression_config_lib.as_compression_config(
-            self.stat_compression
-        )
-        if (
-            self.stat_compression is not None
-            and self.allreduce_method
-            != enums.AllreduceMethod.ALLREDUCE_BUCKETED
-        ):
-            raise ValueError(
-                'stat_compression quantizes the bucketed flat-buffer '
-                "transport; set allreduce_method='allreduce_bucketed'"
-            )
-        self.offload = compression_config_lib.as_offload_config(self.offload)
-        if self.offload is not None:
-            if (
-                self.async_inverse is not None
-                and self.async_inverse.mode == 'sliced'
-            ):
-                raise ValueError(
-                    "offload is incompatible with async_inverse='sliced': "
-                    'the sliced refresh reads the factor state every step, '
-                    'so it is never cold'
-                )
-            if callable(self.factor_update_steps) or callable(
-                self.inv_update_steps
-            ):
-                raise ValueError(
-                    'offload requires static int factor_update_steps and '
-                    'inv_update_steps (the host-side pump computes cadence '
-                    'boundaries from them); got a schedule'
-                )
         self._plan_async()
-        self._plan_offload()
-
-    def _plan_offload(self) -> None:
-        """Attach the cold-factor offload manager (the dense engine is its
-        own config carrier, so the manager hangs off ``self``; the
-        distributed engine builds its own in ``DistributedKFAC``)."""
-        self._offload_manager = (
-            None if self.offload is None
-            else offload_lib.OffloadManager(self)
-        )
 
     def _plan_async(self) -> None:
         """Precompute the async refresh plan (slice buckets, window size).
@@ -1124,23 +1056,14 @@ class KFACPreconditioner:
         next to this step's scalars (the Trainer passes it on every
         path); without one the ring slot's loss is marked invalid.
         """
-        # Spilled interior step (cold-factor offload): the factor dicts
-        # hold zero-size host-offload placeholders, statically detectable
-        # at trace time. The offload pump guarantees residency on every
-        # cadence boundary, so skipping the factor/inverse branches here
-        # is exact — they would be no-op cond arms anyway — and keeps the
-        # placeholders out of the traced branches.
-        spilled = offload_lib.is_spilled(state)
-        if stats is not None and not spilled:
+        if stats is not None:
             state = jax.lax.cond(
                 state.step % _resolve(self.factor_update_steps, state.step) == 0,
                 lambda s: self.update_factors(s, stats),
                 lambda s: s,
                 state,
             )
-        if spilled:
-            pass
-        elif self._async_mode == 'sliced':
+        if self._async_mode == 'sliced':
             state = async_sliced.dense_async_step(self, state)
         elif self._async_mode == 'host':
             state = async_host.dense_host_step(self, state)
@@ -1187,10 +1110,6 @@ class KFACPreconditioner:
         incomplete shadow and skips the swap — deterministic, no torn
         slot — and the following window refreshes normally.
         """
-        if self._offload_manager is not None:
-            # restored states are resident by construction — drop any
-            # stale host copies/prefetches from before the restore
-            self._offload_manager.reset()
         state = self.update_inverses(state)
         if self._async_mode == 'sliced':
             state = state._replace(

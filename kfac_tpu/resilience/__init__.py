@@ -7,13 +7,6 @@ blocking save when a preemption signal arrives, and restores the newest
 *good* checkpoint with last-good fallback and elastic cross-topology
 migration. See docs/ROBUSTNESS.md ("Preemption & resume").
 
-``FleetController`` closes the loop into a self-driving fleet: restores
-onto a changed topology re-tune the layout through the autotuner's
-cost-model-only fast path, and sustained cross-host drift (flight-
-recorder skew columns) triggers a pod-coordinated live layout migration
-at the next checkpoint boundary. See docs/ROBUSTNESS.md ("Self-driving
-fleet").
-
 ``ChaosConductor`` turns all of the above into a measured claim: it
 drives a real multi-process gloo pod through scripted or seeded
 preemption storms (SIGTERM waves, torn checkpoints, topology
@@ -31,7 +24,6 @@ from kfac_tpu.resilience.chaos import (
     ChaosError,
     ChaosReport,
 )
-from kfac_tpu.resilience.fleet import FleetConfig, FleetController
 from kfac_tpu.resilience.manager import (
     CheckpointManager,
     Preempted,
@@ -44,8 +36,6 @@ __all__ = [
     'ChaosError',
     'ChaosReport',
     'CheckpointManager',
-    'FleetConfig',
-    'FleetController',
     'Preempted',
     'RestoreResult',
     'signals',

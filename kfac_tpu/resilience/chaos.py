@@ -4,9 +4,8 @@ Every resilience ingredient in this repo ships — and is tested —
 separately: signal-driven emergency saves (``signals.py`` +
 ``CheckpointManager.on_step``), rotation fallback across torn
 checkpoints (``restore_latest``), elastic restore onto a changed
-topology (``fleet._topology_fits``), drift→retune→vote→migrate
-(``FleetController``), and real gloo CPU collectives across OS
-processes (``tests/parallel/test_multihost.py``). This module composes
+topology (``restore_latest(engine=)``), and real gloo CPU collectives
+across OS processes (``tests/parallel/test_multihost.py``). This module composes
 them under sustained adversarial pressure and measures how fast the
 stack actually heals.
 
@@ -25,12 +24,12 @@ Architecture — one conductor, many victims:
 
 * The worker side (:func:`run_worker` / :func:`worker_recover`, called
   by ``testing/chaos_worker.py``) runs the REAL stack — Trainer +
-  DistributedKFAC over the global gloo mesh + CheckpointManager, with
-  an optional FleetController — and emits one JSON line per event
+  DistributedKFAC over the global gloo mesh + CheckpointManager — and
+  emits one JSON line per event
   (the ``resilience_worker.py`` convention). Its pod choreography is
   declared in :data:`CHAOS_RECOVERY_PROTOCOL` /
   :data:`CHAOS_STORM_PROTOCOL` so kfaclint's pod tier (KFL301–KFL305)
-  bounded-model-checks it like the save and migration protocols.
+  bounded-model-checks it like the save protocol.
 
 * :class:`ChaosReport` reconciles the per-rank streams into
   per-fault-class SLO rows — downtime steps (work re-executed after
@@ -56,11 +55,11 @@ events, each a dict:
   back to the next committed rotation entry (fallback depth >= 1).
 * ``{'fault': 'shrink', 'procs': 2, 'at_step': 9}`` (or ``'grow'``) —
   SIGTERM wave, then respawn with a different process count: the
-  elastic-restore path (changed topology fingerprint; with a fleet, a
-  retune onto the new world).
+  elastic-restore path (changed topology fingerprint).
 * ``{'fault': 'skew', 'ratio': 2.0, 'at_step': 6}`` — SIGTERM wave,
-  then respawn with an injected flight-recorder skew
-  (``testing.faults.skewed_drain``) so a fleet controller sees drift.
+  then respawn with ``skew`` in the worker's config. The worker's one
+  reader of it, the fleet controller's drain, is gone: the class now
+  downs the pod and loosens nothing but the divergence check.
 * ``{'fault': 'sigusr1', 'ranks': (1,), 'at_step': 10}`` — in-flight
   continue-signal: the pod snapshots at the agreed boundary and keeps
   training (no respawn).
@@ -218,8 +217,6 @@ class ChaosConfig:
             seed instead of using ``schedule`` (None: scripted).
         storm_events: pod-down events in a seeded storm.
         fault_mix: fault classes a seeded storm draws from.
-        use_fleet: wrap the worker's engine in a FleetController (the
-            elastic-restore + retune/migration paths; slower).
         step_sleep_s: per-step worker sleep so signal delivery lands
             mid-run deterministically on a loaded host.
         budget_downtime_steps: max steps of re-executed work per
@@ -251,7 +248,6 @@ class ChaosConfig:
         'sigterm_wave', 'torn_checkpoint', 'corrupt_payload', 'shrink',
         'sigusr1',
     )
-    use_fleet: bool = False
     step_sleep_s: float = 0.05
     budget_downtime_steps: int = 6
     budget_recovery_s: float = 600.0
@@ -423,16 +419,6 @@ def worker_recover(trainer: Any, params: Any) -> tuple[Any, dict]:
     }
 
 
-def _fleet_stats(trainer: Any) -> dict | None:
-    fleet = getattr(trainer, 'fleet', None)
-    if fleet is None:
-        return None
-    return {
-        'stats': dict(fleet.stats),
-        'events': [dict(e) for e in fleet.events],
-    }
-
-
 def run_worker(
     trainer: Any,
     manager: Any,
@@ -449,8 +435,7 @@ def run_worker(
     JSON line per step. A SIGTERM anywhere in the pod surfaces here as
     :class:`Preempted` after the coordinated emergency save — exit 0,
     the conductor respawns. ``make_batch(trainer)`` is called every
-    step so the batch always lands on the CURRENT engine's mesh (a
-    fleet migration can swap it mid-run)."""
+    step so the batch lands on the trainer's engine's mesh."""
     state, meta = worker_recover(trainer, params)
     emit(
         event='start',
@@ -479,7 +464,6 @@ def run_worker(
             final_step=int(jax.device_get(state.kfac_state.step)),
             latest=manager.latest_step(),
             rotation=manager.rotation_steps(),
-            fleet=_fleet_stats(trainer),
         )
     except Preempted as exc:
         emit(
@@ -488,7 +472,6 @@ def run_worker(
             saved_step=exc.step,
             latest=manager.latest_step(),
             rotation=manager.rotation_steps(),
-            fleet=_fleet_stats(trainer),
         )
     return 0
 
@@ -607,7 +590,6 @@ class ChaosConductor:
                 'save_interval': self.config.save_interval,
                 'keep': self.config.keep,
                 'step_sleep_s': self.config.step_sleep_s,
-                'use_fleet': self.config.use_fleet,
                 'skew': skew,
             }, f)
         procs = []

@@ -412,7 +412,7 @@ class CheckpointManager:
         latches.
         """
         # only a SIGNAL-driven save suppresses re-deliveries; a health or
-        # fleet-migration save must still latch an incoming SIGTERM (the
+        # degrade save must still latch an incoming SIGTERM (the
         # preemption notice outlives this save)
         bracket = (
             signals_lib.save_in_flight(reason)

@@ -112,9 +112,8 @@ MODEL_SCOPES = {
 TRAINER_SCOPES = {'optimizer': 'trainer.optimizer'}
 
 # Host spans inside one Trainer step, in order: what runs before the jitted
-# call (async-inverse and offload pumps, the cadence decision), the call
-# itself, and what runs after it (health warnings, checkpoint autopilot,
-# fleet controller).
+# call (the async-inverse pump, the cadence decision), the call itself,
+# and what runs after it (health warnings, checkpoint autopilot).
 HOST_SPANS = {
     'pre_step': 'kfac.host.pre_step',
     'launch': 'kfac.host.launch',

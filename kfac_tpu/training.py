@@ -26,7 +26,6 @@ import optax
 from kfac_tpu import health as health_lib
 from kfac_tpu import tracing
 from kfac_tpu.async_inverse import host as async_host_lib
-from kfac_tpu.compression import offload as offload_lib
 from kfac_tpu.layers import capture as capture_lib
 from kfac_tpu.observability import ledger as ledger_lib
 
@@ -98,16 +97,6 @@ class Trainer:
             so the plan can pick both the config knobs and the mesh. A
             fingerprint mismatch falls back to the default layout with a
             rate-limited :class:`~kfac_tpu.warnings.LayoutPlanWarning`.
-        fleet: optional
-            :class:`kfac_tpu.resilience.FleetController`. Like
-            ``auto_layout`` it requires a bare
-            :class:`kfac_tpu.KFACPreconditioner` config (and excludes
-            ``auto_layout`` — the fleet owns the plan lifecycle): the
-            controller builds the engine under the freshest plan for the
-            live topology (re-tuning on a fingerprint mismatch), takes
-            over the ``checkpoints`` slot with its own manager, drives
-            drift checks/migrations from every step path, and serves
-            :meth:`restore_latest` elastically.
         run_id: shared run identifier threaded into every telemetry
             stream this Trainer touches (the engine's compile-watch
             journal stamps it per record; :meth:`run_header` builds the
@@ -124,36 +113,11 @@ class Trainer:
     donate_state: bool = False
     checkpoints: Any = None
     auto_layout: Any = None
-    fleet: Any = None
     run_id: str | None = None
 
     def __post_init__(self) -> None:
         if self.run_id is None:
             self.run_id = ledger_lib.new_run_id()
-        if self.fleet is not None:
-            if self.auto_layout is not None:
-                raise ValueError(
-                    'Trainer(fleet=...) excludes auto_layout: the fleet '
-                    'controller owns the plan lifecycle (pass the plan '
-                    'to the FleetController instead)'
-                )
-            if self.kfac is None or hasattr(self.kfac, 'mesh'):
-                raise ValueError(
-                    'Trainer(fleet=...) requires kfac to be the bare '
-                    'KFACPreconditioner config: the fleet must be free '
-                    'to pick (and later migrate) the layout and mesh'
-                )
-            if (
-                self.checkpoints is not None
-                and self.checkpoints is not self.fleet.manager
-            ):
-                raise ValueError(
-                    'Trainer(fleet=...) uses the fleet controller\'s '
-                    'own CheckpointManager; drop the checkpoints= '
-                    'argument (or pass fleet.manager)'
-                )
-            self.checkpoints = self.fleet.manager
-            self.kfac = self.fleet.attach(self.kfac)
         if self.auto_layout is not None:
             if self.kfac is None:
                 raise ValueError(
@@ -361,43 +325,6 @@ class Trainer:
         if self._step_count is None:
             self.resume(state)
 
-    def rebind_engine(self, engine: Any) -> None:
-        """Swap in a rebuilt preconditioner engine (the fleet
-        controller's live layout migration).
-
-        Re-resolves the config-derived attributes and drops every
-        compiled step program: the new engine's state pytree generally
-        has a different structure (bucket shapes, shardings), and even
-        when it happens to match, a cached trace would keep executing
-        the OLD engine's collectives. The registry — and therefore the
-        curvature capture — is unchanged, so ``_run_stats`` survives.
-        """
-        self.kfac = engine
-        self._kfac_takes_loss = (
-            'loss' in inspect.signature(engine.step).parameters
-        )
-        cfg = engine.config if hasattr(engine, 'config') else engine
-        self.factor_update_steps = cfg.factor_update_steps
-        for attr in ('_jit_scan', '_jit_grads_stats', '_jit_grads_only',
-                     '_jit_apply_kfac', '_jit_accum_scan', '_executed'):
-            if hasattr(self, attr):
-                delattr(self, attr)
-        donate = (0,) if self.donate_state else ()
-        self._jit_with_stats = self._watched(
-            'trainer.step/with_stats',
-            jax.jit(self._step_with_stats, donate_argnums=donate),
-        )
-        self._jit_no_stats = self._watched(
-            'trainer.step/no_stats',
-            jax.jit(self._step_no_stats, donate_argnums=donate),
-        )
-        watch = self._compile_watch()
-        if watch is not None:
-            watch.run_id = self.run_id
-        self._step_count = None  # resyncs from the next state's counter
-        if self.checkpoints is not None:
-            self.checkpoints.engine = engine
-
     def _capture_now(self) -> bool:
         """Evaluate the factor cadence host-side (schedules are pure
         functions of the step, so the host can run them concretely)."""
@@ -449,29 +376,6 @@ class Trainer:
             return state
         return state._replace(kfac_state=ks)
 
-    def _drive_offload(
-        self, state: TrainState, step: int | None
-    ) -> TrainState:
-        """Tick the cold-factor offload state machine (``offload`` config;
-        no-op otherwise) — spill/prefetch/restore decisions are host-side,
-        see :func:`kfac_tpu.compression.offload.pump`.
-
-        With ``step``: full spill/prefetch/restore cadence logic. Without
-        one (the scan paths, where the host cannot intervene mid-scan):
-        restores residency and leaves the factors resident for the whole
-        scan.
-        """
-        if (
-            self.kfac is None
-            or state.kfac_state is None
-            or getattr(self.kfac, '_offload_manager', None) is None
-        ):
-            return state
-        ks = offload_lib.pump(self.kfac, state.kfac_state, step=step)
-        if ks is state.kfac_state:
-            return state
-        return state._replace(kfac_state=ks)
-
     def _drive_checkpoints(self, state: TrainState) -> None:
         """Tick the checkpoint autopilot after a completed step.
 
@@ -480,30 +384,9 @@ class Trainer:
         the device counter itself. A :class:`kfac_tpu.resilience
         .Preempted` raised here propagates out of the step call — by
         then the emergency checkpoint is already durable.
-
-        If a save lands while the factor state is spilled (cold-factor
-        offload), the manager is handed a RESIDENT view assembled from
-        the offload manager's host copies — zero device traffic, and the
-        checkpoint never contains offload placeholders.
         """
-        if self.checkpoints is None:
-            return
-        mgr = getattr(self.kfac, '_offload_manager', None)
-        view = state
-        if mgr is not None and mgr.spilled and state.kfac_state is not None:
-            view = state._replace(
-                kfac_state=mgr.host_view(state.kfac_state)
-            )
-        self.checkpoints.on_step(view, step=self._step_count)
-
-    def _drive_fleet(self, state: TrainState) -> TrainState:
-        """Tick the fleet controller after a completed step (no-op
-        without one). Returns the possibly-migrated TrainState — a live
-        layout migration at a checkpoint boundary swaps both the engine
-        (via :meth:`rebind_engine`) and the state mid-loop."""
-        if self.fleet is None:
-            return state
-        return self.fleet.on_step(self, state)
+        if self.checkpoints is not None:
+            self.checkpoints.on_step(state, step=self._step_count)
 
     def restore_latest(
         self, params: Any, model_state: Any = None
@@ -518,10 +401,7 @@ class Trainer:
         templates to begin training). On success the returned TrainState
         carries the restored params, optimizer state, model state, and
         rematerialized K-FAC state, and the Trainer's cadence dispatch
-        is re-aligned to the restored step. With a ``fleet`` controller
-        the restore is elastic (:meth:`FleetController.restore_elastic`):
-        the checkpoint reshards into the freshest tuned layout, falling
-        back to the canonical one if that fails.
+        is re-aligned to the restored step.
         """
         if self.checkpoints is None:
             raise ValueError(
@@ -534,15 +414,9 @@ class Trainer:
         }
         if model_state is not None:
             template['model_state'] = model_state
-        if self.fleet is not None:
-            result = self.fleet.restore_elastic(extra_template=template)
-            if self.kfac is not self.fleet.engine:
-                # the tuned restore fell back to the canonical layout
-                self.rebind_engine(self.fleet.engine)
-        else:
-            result = self.checkpoints.restore_latest(
-                engine=self.kfac, extra_template=template
-            )
+        result = self.checkpoints.restore_latest(
+            engine=self.kfac, extra_template=template
+        )
         if result is None:
             return None
         state = TrainState(
@@ -591,7 +465,6 @@ class Trainer:
         step = self._step_count
         with tracing.host_span('pre_step', step):
             state = self._drive_async(state, step)
-            state = self._drive_offload(state, step)
             capture = self.kfac is not None and self._capture_now()
         with jax.profiler.StepTraceAnnotation('train', step_num=step):
             with tracing.host_span('launch', step):
@@ -603,9 +476,6 @@ class Trainer:
         with tracing.host_span('post_step', step):
             self._maybe_warn(out[0])
             self._drive_checkpoints(out[0])
-            new_state = self._drive_fleet(out[0])
-        if new_state is not out[0]:
-            out = (new_state, out[1])
         return out
 
     # ------------------------------------------------------- compiled loops
@@ -707,7 +577,6 @@ class Trainer:
         Returns (final_state, per-step losses).
         """
         state = self._drive_async(state, None)
-        state = self._drive_offload(state, None)
         if not hasattr(self, '_jit_scan'):
             donate = (0,) if self.donate_state else ()
             executed = (
@@ -732,7 +601,6 @@ class Trainer:
             state, losses = self._jit_scan(state, batches)
         self._step_count = None  # host mirror resyncs from the device step
         self._drive_checkpoints(state)
-        state = self._drive_fleet(state)
         return state, losses
 
     # --------------------------------------------------------- accumulation
@@ -844,7 +712,6 @@ class Trainer:
         )
         loss = acc['loss'] / n
         state = self._drive_async(state, self._step_count)
-        state = self._drive_offload(state, self._step_count)
         with tracing.host_span('launch', self._step_count):
             new_state = self._jit_apply_kfac(
                 state,
@@ -858,7 +725,6 @@ class Trainer:
         self._step_count += 1
         self._maybe_warn(new_state)
         self._drive_checkpoints(new_state)
-        new_state = self._drive_fleet(new_state)
         return new_state, loss
 
     @tracing.trace(name='trainer/step_accumulate')
@@ -904,7 +770,6 @@ class Trainer:
             )
         self._sync_step_count(state)
         state = self._drive_async(state, self._step_count)
-        state = self._drive_offload(state, self._step_count)
         capture_now = self._capture_now()
         if not hasattr(self, '_jit_accum_scan'):
             executed = self._executed_layers(
@@ -970,9 +835,6 @@ class Trainer:
         self._step_count += 1
         self._maybe_warn(out[0])
         self._drive_checkpoints(out[0])
-        new_state = self._drive_fleet(out[0])
-        if new_state is not out[0]:
-            out = (new_state, out[1])
         return out
 
     def _apply_accumulated(

@@ -30,13 +30,6 @@ class LayoutPlanWarning(Warning):
     engine fell back to its explicit/default configuration."""
 
 
-class FleetWarning(Warning):
-    """A self-driving fleet event (kfac_tpu/resilience/fleet.py) an
-    operator should know about: a topology-change retune, a drift-
-    triggered migration abort/rollback, a fallback to the canonical
-    layout."""
-
-
 # (layer, cause) pairs already warned about — each fires ONCE per process,
 # not once per step: a persistently sick layer would otherwise spam the log
 # at training-step frequency while saying nothing new.
@@ -97,28 +90,3 @@ def warn_layout_event(cause: str, detail: str = '') -> bool:
 def reset_layout_warnings() -> None:
     """Forget emitted plan-fallback events (tests)."""
     _layout_events_emitted.clear()
-
-
-# fleet causes already warned about — once per process per cause, like
-# the layout channel: the per-occurrence record lives in
-# FleetController.events, the warning only flags the first one.
-_fleet_events_emitted: set[str] = set()
-
-
-def warn_fleet_event(cause: str, detail: str = '') -> bool:
-    """Emit a rate-limited :class:`FleetWarning` (once per ``cause``).
-
-    Returns True when a warning was actually emitted."""
-    if cause in _fleet_events_emitted:
-        return False
-    _fleet_events_emitted.add(cause)
-    msg = f'kfac-tpu fleet: {cause}'
-    if detail:
-        msg += f' ({detail})'
-    _warnings.warn(msg, FleetWarning, stacklevel=2)
-    return True
-
-
-def reset_fleet_warnings() -> None:
-    """Forget emitted fleet events (tests)."""
-    _fleet_events_emitted.clear()

@@ -2,16 +2,15 @@
 
 Launched by :class:`ChaosConductor` as a real OS process with the
 KFAC_TPU_* rendezvous env surface set. Builds the REAL stack — a
-DistributedKFAC engine over the global gloo mesh (or a FleetController
-owning one), a CheckpointManager rotation shared by every rank, and a
-Trainer — then hands control to :func:`kfac_tpu.resilience.chaos
+DistributedKFAC engine over the global gloo mesh, a CheckpointManager
+rotation shared by every rank, and a Trainer — then hands control to :func:`kfac_tpu.resilience.chaos
 .run_worker`, which recovers via the pod-coordinated
 CHAOS_RECOVERY_PROTOCOL and trains to ``max_steps`` emitting one JSON
 line per event (the ``resilience_worker.py`` convention).
 
 Usage: ``python chaos_worker.py <config.json>`` where the JSON carries
 ``ckpt_dir`` / ``max_steps`` / ``save_interval`` / ``keep`` /
-``step_sleep_s`` / ``use_fleet`` / ``skew`` (written by the conductor).
+``step_sleep_s`` (written by the conductor).
 
 Determinism is the contract: model init keys, the per-step batch, and
 the optimizer are fixed, so the loss at step k is a pure function of k
@@ -76,41 +75,17 @@ def main() -> int:
         pred = m.apply({'params': params}, bx)
         return jnp.mean((pred - by) ** 2), model_state
 
-    fleet = None
-    if cfg.get('use_fleet'):
-        from kfac_tpu.resilience import FleetConfig, FleetController
-        from testing import faults
-
-        manager = CheckpointManager(
-            cfg['ckpt_dir'], save_interval_steps=cfg['save_interval'],
-            keep=cfg['keep'],
-        )
-        skew = float(cfg.get('skew') or 0.0)
-        fleet = FleetController(
-            manager,
-            FleetConfig(
-                check_every=2, drift_keys=('grad_norm',),
-                drift_threshold=0.5, drift_window=2, drift_patience=1,
-                cooldown_steps=4,
-            ),
-            drain=faults.skewed_drain('grad_norm', skew) if skew else None,
-        )
-        trainer = kfac_tpu.Trainer(
-            loss_fn=loss_fn, optimizer=optax.sgd(0.05), kfac=bare,
-            fleet=fleet,
-        )
-    else:
-        engine = DistributedKFAC(
-            config=bare, mesh=multihost.hybrid_kaisa_mesh(0.5)
-        )
-        manager = CheckpointManager(
-            cfg['ckpt_dir'], engine=engine,
-            save_interval_steps=cfg['save_interval'], keep=cfg['keep'],
-        )
-        trainer = kfac_tpu.Trainer(
-            loss_fn=loss_fn, optimizer=optax.sgd(0.05), kfac=engine,
-            checkpoints=manager,
-        )
+    engine = DistributedKFAC(
+        config=bare, mesh=multihost.hybrid_kaisa_mesh(0.5)
+    )
+    manager = CheckpointManager(
+        cfg['ckpt_dir'], engine=engine,
+        save_interval_steps=cfg['save_interval'], keep=cfg['keep'],
+    )
+    trainer = kfac_tpu.Trainer(
+        loss_fn=loss_fn, optimizer=optax.sgd(0.05), kfac=engine,
+        checkpoints=manager,
+    )
 
     def make_batch(trainer):
         mesh = getattr(trainer.kfac, 'mesh', None)

@@ -9,20 +9,12 @@ micro-batch inside an accumulation, poisoned curvature statistics, a
 factor blow-up past the conditioning bound, factors corrupted at rest
 (e.g. a bad checkpoint), and torn checkpoint writes on disk (host crash
 or preemption mid-write — the resilience rotation's fallback trigger).
-
-The fleet injectors (:func:`change_topology`, :func:`induce_skew` /
-:func:`skewed_drain`) simulate the two deployment events the
-self-driving fleet controller (kfac_tpu/resilience/fleet.py) reacts to
-— a restore onto a resized pod, and sustained cross-host comms skew —
-so the whole retune/migrate loop is testable on a single CPU host.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import json
 import os
-from typing import Any, Callable
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -261,97 +253,3 @@ def corrupt_checkpoint(path: str, mode: str = 'truncate') -> str:
         with open(victim, 'r+b') as f:
             f.write(b'\xde\xad\xbe\xef' * 16)
     return victim
-
-
-def change_topology(
-    plan: Any,
-    *,
-    device_count: int | None = None,
-    local_device_count: int | None = None,
-    process_count: int | None = None,
-    backend: str | None = None,
-) -> Any:
-    """A copy of a ``TunedPlan`` whose fingerprint claims a different
-    topology — the "job restored onto a resized pod" fault.
-
-    The knobs/cost table are untouched (the plan was genuinely tuned,
-    just for a pod that no longer exists); only the topology fields of
-    the fingerprint are doctored, so ``fingerprint_matches`` fails in
-    this process and the fleet controller's retune-on-restore path
-    fires. With no explicit override the device count doubles (the
-    archetypal elastic resize). Deterministic, input unmutated.
-
-    Accepts a ``TunedPlan`` or a path to a plan file; given a path, the
-    doctored plan is also written back to it (like
-    :func:`corrupt_checkpoint`, the on-disk artifact is the fault site)
-    and returned.
-    """
-    from kfac_tpu.autotune import plan as plan_lib
-
-    path = None
-    if isinstance(plan, (str, os.PathLike)):
-        path = os.fspath(plan)
-        plan = plan_lib.TunedPlan.load(path)
-    fp = json.loads(json.dumps(plan.fingerprint))
-    if (
-        device_count is None and local_device_count is None
-        and process_count is None and backend is None
-    ):
-        device_count = int(fp.get('device_count', 1)) * 2
-    if device_count is not None:
-        fp['device_count'] = int(device_count)
-    if local_device_count is not None:
-        fp['local_device_count'] = int(local_device_count)
-    if process_count is not None:
-        fp['process_count'] = int(process_count)
-    if backend is not None:
-        fp['backend'] = backend
-    doctored = dataclasses.replace(plan, fingerprint=fp)
-    if path is not None:
-        doctored.save(path)
-    return doctored
-
-
-def induce_skew(
-    records: list[dict[str, Any]],
-    key: str = 'grad_norm',
-    ratio: float = 1.0,
-) -> list[dict[str, Any]]:
-    """Widen the cross-host skew columns of drained flight records.
-
-    Returns a new record list (inputs unmutated) where every record
-    carrying ``key`` gets ``skew_min/skew_max`` spread symmetrically
-    around its mean such that the relative skew
-    ``(skew_max - skew_min) / (|skew_mean| + eps)`` — the fleet
-    controller's drift signal, :func:`kfac_tpu.observability
-    .flight_recorder.skew_ratio` — equals exactly ``ratio``. The mean
-    comes from the record's existing ``skew_mean`` column when present
-    (so single-host drains gain plausible multi-host columns), else the
-    local value.
-    """
-    out = []
-    for rec in records:
-        rec = dict(rec)
-        if key in rec:
-            mean = float(rec.get(f'skew_mean/{key}', rec[key]))
-            half = 0.5 * ratio * (abs(mean) + 1e-12)
-            rec[f'skew_mean/{key}'] = mean
-            rec[f'skew_min/{key}'] = mean - half
-            rec[f'skew_max/{key}'] = mean + half
-        out.append(rec)
-    return out
-
-
-def skewed_drain(
-    key: str = 'grad_norm', ratio: float = 1.0
-) -> Callable[[Any], list[dict[str, Any]]]:
-    """A drop-in flight-recorder drain injecting deterministic
-    cross-host skew — pass as ``FleetController(drain=...)`` to drive
-    the drift detector on a single-host CPU test."""
-    from kfac_tpu.observability import flight_recorder as flight_lib
-
-    def drain(state: Any) -> list[dict[str, Any]]:
-        records = flight_lib.drain_flight(state, skew_keys=(key,))
-        return induce_skew(records, key=key, ratio=ratio)
-
-    return drain

@@ -430,6 +430,31 @@ def test_pre_async_plan_document_still_loads():
     assert applied.async_inverse is None
 
 
+@pytest.mark.parametrize('knob,value', [
+    ('stat_compression', None), ('offload', False),
+    ('stat_compression', 'int8'), ('offload', True),
+])
+def test_plan_with_a_removed_knob(knob, value):
+    """Every plan written while stat compression and cold-factor offload
+    were knobs carries both: at its off value the key is ignored (the plan
+    loads and applies as it always did), set it asks for a layout nothing
+    builds any more and is refused like any malformed plan, by name."""
+    cfg, *_ = _base()
+    doc = autotune.autotune(cfg, measure=False).to_json()
+    assert knob not in doc['knobs']  # no plan is written with it now
+    old = json.loads(json.dumps(doc))
+    old['knobs'][knob] = value
+    if value:
+        with pytest.raises(ValueError, match=knob):
+            kfac_tpu.TunedPlan.from_json(old)
+        return
+    loaded = kfac_tpu.TunedPlan.from_json(old)
+    assert loaded.knobs == doc['knobs']
+    applied = autotune.apply_knobs(cfg, loaded.knobs)
+    assert applied == autotune.apply_knobs(cfg, doc['knobs'])
+    assert not hasattr(applied, knob)
+
+
 def test_apply_knobs_only_touches_layout_fields():
     cfg, *_ = _base()
     plan = autotune.autotune(cfg, measure=False)

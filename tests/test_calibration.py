@@ -7,50 +7,30 @@ Two surfaces, both CPU-runnable:
   error), the ``calib/*`` record/annotate emission contract, the
   rotating :class:`~kfac_tpu.observability.sinks.JSONLWriter`, and the
   rate-limited logger's ``calib/model_error`` headline;
-- the ISSUE acceptance headline: a doctored 2x cost-model error drives
-  the EXISTING :class:`kfac_tpu.FleetController` through its native
-  drift -> retune -> armed -> migrated path, with no new controller
-  machinery — the monitor only stamps synthetic skew columns into the
-  drain. A perfectly calibrated control run never re-layouts, and the
-  jit cache stays at one entry on both engines (host-side only).
-
-The fleet harness mirrors tests/test_fleet.py (TIGHT_HBM sized between
-the MEM-OPT and COMM-OPT footprints forces the drift retune off the
-canonical COMM-OPT layout).
+- observing and annotating every step leaves the jit cache at one entry
+  on both engines (host-side only).
 """
 
 import json
 import os
-import warnings
 
 import jax
-import optax
 import pytest
 
 import kfac_tpu
-from kfac_tpu.autotune import model as model_lib
 from kfac_tpu.autotune import search as search_lib
-from kfac_tpu.enums import DistributedStrategy
 from kfac_tpu.observability import calibration
-from kfac_tpu.observability import flight_recorder as flight_lib
 from kfac_tpu.observability.sinks import JSONLWriter, RateLimitedLogger
-from kfac_tpu.resilience import CheckpointManager
-from kfac_tpu.warnings import reset_fleet_warnings, reset_layout_warnings
+from kfac_tpu.warnings import reset_layout_warnings
 from testing import compile_pins, models
 
 WORLD = 8
 
-#: see tests/test_fleet.py — between MEM-OPT and COMM-OPT footprints, so
-#: the model-only retune must leave the canonical COMM-OPT layout
-TIGHT_HBM = model_lib.HardwareSpec(hbm_bytes=8000.0)
-
 
 @pytest.fixture(autouse=True)
 def _clean_warning_state():
-    reset_fleet_warnings()
     reset_layout_warnings()
     yield
-    reset_fleet_warnings()
     reset_layout_warnings()
 
 
@@ -212,8 +192,6 @@ def test_monitor_record_and_annotate_contract():
             warmup_steps=0, prefix='cm'))
     alt.observe_step(0.02)
     assert 'cm/model_error' in alt.record()
-    # ...but the fleet bridge's drift key stays fixed
-    assert calibration.DRIFT_KEY in alt.drift_skew_columns()
 
 
 def test_monitor_from_real_tuned_plan():
@@ -235,40 +213,31 @@ def test_monitor_from_real_tuned_plan():
     assert mon2.predicted_step_s == pytest.approx(mon.predicted_step_s)
 
 
-def test_fleet_drift_keys_dedup():
-    assert calibration.fleet_drift_keys() == (
-        'calib/model_error', 'grad_norm')
-    assert calibration.fleet_drift_keys(
-        ('calib/model_error', 'loss')) == ('calib/model_error', 'loss')
-
-
-def test_drift_skew_columns_speak_controller_dialect():
+def test_model_error_of_a_drifted_and_of_an_idle_monitor():
     cfg = calibration.CalibrationConfig(warmup_steps=0)
     mon = calibration.CalibrationMonitor(0.01, config=cfg)
     for _ in range(2):
         mon.observe_step(0.02)
-    cols = mon.drift_skew_columns()
-    # the controller's own skew_ratio reads fold_error - 1 off them
-    assert flight_lib.skew_ratio(cols, calibration.DRIFT_KEY) == (
-        pytest.approx(mon.model_error() - 1.0))
-    # and an uncalibrated monitor reads as zero skew (no false drift)
+    assert mon.model_error() == pytest.approx(2.0)
+    # the fold is direction-free: twice too fast reads like twice too slow
+    fast = calibration.CalibrationMonitor(0.01, config=cfg)
+    fast.observe_step(0.005)
+    assert fast.model_error() == pytest.approx(2.0)
+    # and an uncalibrated monitor reads no drift
     idle = calibration.CalibrationMonitor(0.01, config=cfg)
-    assert flight_lib.skew_ratio(
-        idle.drift_skew_columns(), calibration.DRIFT_KEY) == 0.0
+    assert idle.model_error() == 1.0
+    assert idle.record() == {}
 
 
-def test_wrap_drain_stamps_every_record():
+def test_annotate_stamps_every_drained_record():
     cfg = calibration.CalibrationConfig(warmup_steps=0)
     mon = calibration.CalibrationMonitor(0.01, config=cfg)
     mon.observe_step(0.02)
-    drain = mon.wrap_drain(lambda state: [{'step': 1}, {'step': 2}])
-    records = drain(None)
-    assert len(records) == 2
+    records = [mon.annotate(r) for r in ({'step': 1}, {'step': 2})]
+    assert [r['step'] for r in records] == [1, 2]
     for rec in records:
-        assert rec[calibration.DRIFT_KEY] == pytest.approx(2.0)
-        assert rec[f'skew_max/{calibration.DRIFT_KEY}'] == (
-            pytest.approx(2.0))
-        assert rec[f'skew_mean/{calibration.DRIFT_KEY}'] == 1.0
+        assert rec['calib/model_error'] == pytest.approx(2.0)
+        assert rec['calib/step_ratio'] == pytest.approx(2.0)
 
 
 def test_rate_limited_logger_headlines_model_error(caplog):
@@ -293,7 +262,7 @@ def test_monitor_records_flow_through_jsonl(tmp_path):
     assert lines[0]['calib/model_error'] == pytest.approx(2.0)
 
 
-# ------------------------------------------------- fleet drift headline
+# ------------------------------------------------------- a tuned plan
 
 
 def _setup():
@@ -322,75 +291,11 @@ def _comm_opt_plan(bare):
     )
 
 
-def _calibrated_fleet(directory, bare, loss_fn, plan, monitor):
-    cfg = kfac_tpu.FleetConfig(
-        check_every=2, drift_keys=calibration.fleet_drift_keys(),
-        drift_threshold=0.5, drift_window=2, drift_patience=1,
-        cooldown_steps=1,
-    )
-    mgr = CheckpointManager(
-        directory, save_interval_steps=4, keep=3,
-        install_signals=(), async_save=False,
-    )
-    ctrl = kfac_tpu.FleetController(
-        mgr, cfg, plan=plan, hardware=TIGHT_HBM,
-        drain=monitor.wrap_drain(),
-    )
-    trainer = kfac_tpu.Trainer(
-        loss_fn=loss_fn, optimizer=optax.sgd(0.05), kfac=bare(), fleet=ctrl,
-    )
-    return trainer, ctrl
-
-
-def test_cost_model_drift_drives_existing_retune_path(tmp_path):
-    """The ISSUE acceptance headline: a doctored 2x cost-model error —
-    nothing else — walks the UNMODIFIED FleetController through drift ->
-    retune -> armed -> migrated, while a perfectly calibrated control
-    run on the same plan never re-layouts."""
-    m, batch, params, bare, loss_fn = _setup()
-    plan = _comm_opt_plan(bare)
-    ccfg = calibration.CalibrationConfig(warmup_steps=0, window=4)
-
-    drifted = calibration.CalibrationMonitor.from_plan(plan, ccfg)
-    calm = calibration.CalibrationMonitor.from_plan(plan, ccfg)
-    for _ in range(4):
-        # steps measure 2x the model's prediction vs spot-on
-        drifted.observe_step(2.0 * drifted.predicted_step_s)
-        calm.observe_step(calm.predicted_step_s)
-    assert drifted.model_error() == pytest.approx(2.0)
-    assert calm.model_error() == pytest.approx(1.0)
-
-    trainer, ctrl = _calibrated_fleet(
-        tmp_path / 'a', bare, loss_fn, plan, drifted)
-    control, ctrl_c = _calibrated_fleet(
-        tmp_path / 'b', bare, loss_fn, plan, calm)
-    assert ctrl.engine.grad_workers == WORLD  # COMM-OPT until drift
-
-    state, cstate = trainer.init(params), control.init(params)
-    with warnings.catch_warnings():
-        warnings.simplefilter('ignore')
-        for _ in range(6):
-            state, _ = trainer.step(state, batch)
-            cstate, _ = control.step(cstate, batch)
-
-    names = [e['event'] for e in ctrl.events]
-    assert names[:4] == ['drift', 'retune', 'armed', 'migrated']
-    assert ctrl.stats['migrations'] == 1
-    # the tight HBM budget forced the retune off the canonical layout
-    assert ctrl.engine.grad_workers == 1
-    assert ctrl.engine.strategy == DistributedStrategy.MEM_OPT
-    # the calibrated pod never moves
-    assert ctrl_c.events == []
-    assert ctrl_c.engine.grad_workers == WORLD
-
-
-def test_memory_residual_drives_existing_retune_path(tmp_path):
-    """PR-17 acceptance mirror of the time-residual headline: a doctored
-    2x XLA-memory residual — step timings spot-on — walks the UNMODIFIED
-    FleetController through drift -> retune -> armed -> migrated with
-    zero new controller machinery, while a fully calibrated control run
-    never re-layouts."""
-    m, batch, params, bare, loss_fn = _setup()
+def test_memory_residual_reads_like_a_time_residual():
+    """A 2x XLA-memory residual with step timings spot-on is a 2x model
+    error; a monitor whose plan's memory and timings both hold reads
+    none."""
+    _, _, _, bare, _ = _setup()
     plan = _comm_opt_plan(bare)
     ccfg = calibration.CalibrationConfig(warmup_steps=0, window=4)
 
@@ -398,8 +303,6 @@ def test_memory_residual_drives_existing_retune_path(tmp_path):
     calm = calibration.CalibrationMonitor.from_plan(plan, ccfg)
     assert drifted.predicted_mem_bytes is not None  # plan carries memory
     for _ in range(4):
-        # both pods time exactly as modelled; only the drifted pod's
-        # measured HBM comes back 2x the cost model's prediction
         drifted.observe_step(drifted.predicted_step_s)
         drifted.observe_memory(2.0 * drifted.predicted_mem_bytes)
         calm.observe_step(calm.predicted_step_s)
@@ -407,28 +310,6 @@ def test_memory_residual_drives_existing_retune_path(tmp_path):
     assert drifted.step_ratio() == pytest.approx(1.0)
     assert drifted.model_error() == pytest.approx(2.0)  # memory channel
     assert calm.model_error() == pytest.approx(1.0)
-
-    trainer, ctrl = _calibrated_fleet(
-        tmp_path / 'a', bare, loss_fn, plan, drifted)
-    control, ctrl_c = _calibrated_fleet(
-        tmp_path / 'b', bare, loss_fn, plan, calm)
-    assert ctrl.engine.grad_workers == WORLD  # COMM-OPT until drift
-
-    state, cstate = trainer.init(params), control.init(params)
-    with warnings.catch_warnings():
-        warnings.simplefilter('ignore')
-        for _ in range(6):
-            state, _ = trainer.step(state, batch)
-            cstate, _ = control.step(cstate, batch)
-
-    names = [e['event'] for e in ctrl.events]
-    assert names[:4] == ['drift', 'retune', 'armed', 'migrated']
-    assert ctrl.stats['migrations'] == 1
-    assert ctrl.engine.grad_workers == 1
-    assert ctrl.engine.strategy == DistributedStrategy.MEM_OPT
-    # the calibrated pod never moves
-    assert ctrl_c.events == []
-    assert ctrl_c.engine.grad_workers == WORLD
 
 
 # ------------------------------------------------- no-recompile pinning

@@ -713,8 +713,9 @@ def test_fallback_entry_keeps_fingerprinting_every_call():
 
 
 def test_dispatch_counters_outlive_a_rewrapped_entry():
-    """``Trainer.rebind_engine`` wraps its entries again: the counters
-    are the watch's, by entry, and never reset."""
+    """An entry wrapped a second time (a second Trainer on one engine)
+    keeps counting: the counters are the watch's, by entry, and never
+    reset."""
     watch = cw.CompileWatch()
     assert watch.dispatch_counters() == {}
     jitted = jax.jit(lambda x: x + 1)

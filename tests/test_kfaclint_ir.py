@@ -124,15 +124,6 @@ def test_kfl201_reports_trace_errors_once():
     assert len(findings) == 1 and 'failed to trace' in findings[0].message
 
 
-def test_kfl201_int8_compression_wire_is_not_a_violation():
-    def quantize(a):
-        scale = jnp.max(jnp.abs(a)) / 127.0
-        return (a / scale).astype(jnp.int8), scale
-
-    x = jnp.zeros((8,), jnp.float32)
-    assert rules.check_dtype_drift(suite_of(make_trace(quantize, x))) == []
-
-
 # ------------------------------------------------------------------ KFL202
 
 
@@ -361,10 +352,9 @@ def test_default_profile_clean_at_head(default_suite):
 def test_full_matrix_clean_at_head():
     suite = harness.build('full')
     assert suite.errors == []
-    # the full matrix must include compression, prediv, host-eigh and
-    # the sub-unity fractions — guard against silent profile shrinkage
+    # the full matrix must include prediv, host-eigh and the sub-unity
+    # fractions — guard against silent profile shrinkage
     names = {t.config_name for t in suite.traces}
-    assert any('int8' in n for n in names)
     assert any('prediv' in n for n in names)
     assert any('eigh-host' in n for n in names)
     findings = run_all(suite)

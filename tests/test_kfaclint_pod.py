@@ -607,12 +607,12 @@ def test_pod_rules_clean_on_head():
     assert findings == [], [f.render() for f in findings]
 
 
-def test_head_declares_both_protocol_tables():
+def test_head_declares_the_save_protocol_table():
     project, _ = analysis.load_project(REPO_ROOT, ['kfac_tpu'])
     tables, problems = protocol.load_protocol_tables(project)
     assert problems == []
     names = {t.name for t in tables}
-    assert {'SAVE_PROTOCOL', 'MIGRATION_PROTOCOL'} <= names
+    assert 'SAVE_PROTOCOL' in names
     machines = {t.table['machine'] for t in tables}
     assert machines == {'sequence', 'state'}
 

@@ -170,18 +170,6 @@ def test_export_refuses_quarantined(trained, tmp_path):
         )
 
 
-def test_export_refuses_spilled(trained, tmp_path):
-    _, params, _, kfac, state, _ = trained
-    spilled = state._replace(
-        a={n: jnp.zeros((0,), jnp.float32) for n in state.a},
-        g={n: jnp.zeros((0,), jnp.float32) for n in state.g},
-    )
-    with pytest.raises(ValueError, match='spilled'):
-        kfac_tpu.export_posterior(
-            kfac, spilled, params, tmp_path / 's', overwrite=True
-        )
-
-
 def test_laplace_config_validation():
     with pytest.raises(ValueError, match='mode'):
         LaplaceConfig(mode='banana')

@@ -126,17 +126,35 @@ def test_health_metric_keys_match_counters():
     assert set(counters) == set(health.health_metric_keys(reg.names()))
 
 
-def test_checkpoint_roundtrip_ignores_metrics(tmp_path):
-    """Metrics state is ephemeral: restore rebuilds it fresh."""
-    _, params, batch, _, kfac, run = _dense_setup(metrics=True)
+@pytest.mark.parametrize('ephemeral', ['metrics', 'flight'])
+@pytest.mark.parametrize('engine', ['dense', 'kaisa'])
+def test_checkpoint_roundtrip_ignores_metrics(tmp_path, engine, ephemeral):
+    """Metrics state and the flight-recorder ring are ephemeral: a
+    checkpoint holds neither, and restore rebuilds both fresh."""
+    from kfac_tpu.parallel import DistributedKFAC, kaisa_mesh
+
+    _, params, batch, _, kfac, run = _dense_setup(
+        kl_clip=0.001, **{ephemeral: True}
+    )
+    if engine == 'kaisa':
+        kfac = DistributedKFAC(
+            config=kfac, mesh=kaisa_mesh(grad_worker_fraction=0.5)
+        )
     state, _ = _run_steps(kfac, run, params, batch, 2)
+    assert set(checkpoint.durable_state(state)) == {'step', 'a', 'g'}
     path = str(tmp_path / 'ckpt')
     checkpoint.save(path, state)
     restored, _ = checkpoint.restore(path, kfac)
     assert int(restored.step) == 2
-    assert restored.metrics is not None
-    # freshly initialized, not the saved live values
+    # freshly initialized, not the saved live values (flight=True turns
+    # the metrics on too: the ring records that schema)
     assert float(restored.metrics.as_dict()['kl_clip_scale']) == 1.0
+    assert float(state.metrics.as_dict()['kl_clip_scale']) < 1.0
+    if ephemeral == 'flight':
+        assert int((state.flight.steps >= 0).sum()) == 2
+        assert int((restored.flight.steps >= 0).sum()) == 0
+    else:
+        assert restored.flight is None
 
 
 # -------------------------------------------------------------- config edges
@@ -343,10 +361,10 @@ def test_lint_named_scopes_clean():
 # ------------------------------------------------------------ comms
 
 
-def _dist_engine(transport, **cfg_kw):
+def _dist_engine(transport, fraction=0.5, **cfg_kw):
     from kfac_tpu.parallel import DistributedKFAC, kaisa_mesh
 
-    mesh = kaisa_mesh(grad_worker_fraction=0.5)
+    mesh = kaisa_mesh(grad_worker_fraction=fraction)
     m = models.TinyModel(hidden=8, out=4)
     x, _ = models.regression_data(jax.random.PRNGKey(1), n=64, dim=6)
     reg = kfac_tpu.register_model(m, x)
@@ -372,6 +390,37 @@ def test_comms_report_transports():
         per_class = rep['padding']
         assert totals['resident_bytes'] == sum(
             p['resident_bytes'] for p in per_class.values())
+
+
+@pytest.mark.parametrize('transport', ['allreduce', 'allreduce_bucketed'])
+@pytest.mark.parametrize('fraction', [1.0, 0.5, 0.125])
+def test_wire_bytes_are_the_raw_bytes(fraction, transport):
+    """The stat transport ships the payload as it is, under every
+    strategy and both transports: ``wire_bytes`` (what the IR tier's
+    KFL205 holds the traced program's collective bytes to), ``raw_bytes``
+    and ``bytes`` are one number, chunk by chunk too, and the report has
+    no sub-report of a transport or a store that is not there."""
+    dk = _dist_engine(transport, fraction=fraction)
+    rep = dk.comms_report()
+    st = rep['stat_transport']
+    assert st['wire_bytes'] == st['raw_bytes'] == st['bytes'] > 0
+    assert st['wire_dtype'] == 'float32'
+    assert 'compression' not in st and 'offload' not in rep
+    for c in st['chunks']:
+        assert c['wire_bytes'] == c['raw_bytes'] == c['bytes']
+        assert c['bytes'] == c['elements'] * 4
+    if transport == 'allreduce_bucketed':
+        assert sum(c['bytes'] for c in st['chunks']) == st['wire_bytes']
+        # upper triangles of the class-dim rows of every stored factor
+        assert st['wire_bytes'] == 4 * sum(
+            sb.d * (sb.d + 1) // 2 * len(sb.layers)
+            for sb in dk.a_store + dk.g_store
+        )
+    else:
+        assert st['chunks'] == []
+        assert st['wire_bytes'] == 4 * sum(
+            d * d for sb in dk.a_store + dk.g_store for d in sb.dims
+        )
 
 
 def test_comms_report_respects_bucket_cap():

@@ -1,6 +1,6 @@
 """3D topology planner: enumeration, executed-schedule bubble terms, the
 committed measured bubble table, ppermute wire parity against the traced
-scans, and the plan plumbing (resolve_auto_layout / fleet guards).
+scans, and the plan plumbing (resolve_auto_layout).
 
 The committed-artifact test re-derives every row of
 ``planner/bubble_table.json`` from the schedule simulators: the
@@ -237,19 +237,6 @@ def test_resolve_auto_layout_topology(base_config):
         )
     assert not applied and mesh is None
     assert any(isinstance(r.message, LayoutPlanWarning) for r in rec)
-
-
-def test_fleet_topology_fits(base_config):
-    from kfac_tpu.resilience.fleet import FleetController
-
-    plan = topology.plan_topology(base_config, world=WORLD)
-    assert FleetController._topology_fits(plan)
-    flat = plan_mod.TunedPlan.from_json(plan.to_json())
-    flat.knobs['topology'] = None
-    assert FleetController._topology_fits(flat)
-    bad = plan_mod.TunedPlan.from_json(plan.to_json())
-    bad.knobs['topology'] = dict(bad.knobs['topology'], pp=3, tp=1)
-    assert not FleetController._topology_fits(bad)
 
 
 def test_load_bubble_table_env_override(tmp_path, monkeypatch):

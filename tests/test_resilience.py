@@ -4,7 +4,8 @@ Covers the rotation invariants (fresh step dirs, atomic LATEST pointer,
 keep-N pruning), the signal machinery (flag-only handlers, exit-outranks-
 continue priority, on_step emergency flush), torn-write fallback via
 testing/faults.corrupt_checkpoint, transient-I/O retry/backoff, elastic
-dense <-> stacked restore through the manager, Trainer-integrated periodic
+restore across the dense engine and the three KAISA strategies through
+``Trainer.restore_latest``, Trainer-integrated periodic
 saves + resume continuity, and — slow-marked — a real ``kill -TERM``
 against a subprocess training run that must leave a durable, resumable
 checkpoint behind.
@@ -332,8 +333,8 @@ def test_save_emergency_idempotent_under_stacked_sigterm(tmp_path):
     """End-to-end storm idempotence: a second SIGTERM delivered WHILE
     save_emergency('SIGTERM') is writing must not re-enter the save or
     leave a pending flag; a SIGTERM delivered during a non-signal save
-    (fleet migration) must still latch — the preemption notice outlives
-    that save."""
+    (the postmortem writer's) must still latch — the preemption notice
+    outlives that save."""
     m, batch, params, reg, kfac = _dense_setup()
     state, _, _ = _run_steps(kfac, reg, m, params, batch)
     with CheckpointManager(
@@ -355,10 +356,10 @@ def test_save_emergency_idempotent_under_stacked_sigterm(tmp_path):
         assert path == mgr.checkpoint_path(1)
         # the storm was absorbed: no pending flag, nothing to re-enter
         assert signals.preemption_requested() is None
-        # non-signal reason: a SIGTERM arriving DURING a fleet-migration
-        # save still latches — the preemption notice outlives that save
+        # non-signal reason: a SIGTERM arriving DURING a degrade save
+        # still latches — the preemption notice outlives that save
         mgr.save = storming_save
-        mgr.save_emergency(state, reason='fleet-migration', step=2)
+        mgr.save_emergency(state, reason='degrade', step=2)
         assert calls == [1, 2]
         assert signals.preemption_requested() == 'SIGTERM'
         signals.reset()
@@ -518,60 +519,143 @@ def test_retry_exhaustion_raises(tmp_path, monkeypatch):
 # ------------------------------------------------------------------- elastic
 
 
-def test_elastic_restore_dense_and_stacked_via_manager(tmp_path):
-    """Acceptance: a dense checkpoint restores through the manager into a
-    stacked engine with a different bucket_granularity (and back),
-    factors allclose, on the 8-device CPU mesh."""
+#: the dense engine and the three KAISA strategies on the 8-device mesh,
+#: by grad-worker fraction
+LAYOUTS = {'dense': None, 'comm': 1.0, 'hybrid': 0.5, 'mem': 1.0 / 8}
+
+
+def _trainer_on(layout, directory, **manager_kw):
+    """A Trainer of ``TinyModel`` on one layout with a manager on
+    ``directory``; ``(trainer, manager, params, batch)``."""
     from kfac_tpu.parallel import DistributedKFAC, kaisa_mesh
 
-    m, batch, params, reg, kfac = _dense_setup()
-    state, params, grads = _run_steps(kfac, reg, m, params, batch, steps=2)
-    mgr = CheckpointManager(
-        tmp_path / 'fwd', engine=kfac, install_signals=(), async_save=False
+    m = models.TinyModel()
+    x, y = models.regression_data(jax.random.PRNGKey(1), n=64)
+    params = m.init(jax.random.PRNGKey(0), x)['params']
+    kfac = kfac_tpu.KFACPreconditioner(
+        registry=kfac_tpu.register_model(m, x), kl_clip=None
     )
-    mgr.save(state)
+    if LAYOUTS[layout] is not None:
+        kfac = DistributedKFAC(
+            config=kfac,
+            mesh=kaisa_mesh(grad_worker_fraction=LAYOUTS[layout]),
+        )
 
-    mesh = kaisa_mesh(grad_worker_fraction=0.5)
-    dk = DistributedKFAC(
-        config=kfac_tpu.KFACPreconditioner(
-            registry=reg, kl_clip=None, bucket_granularity=128
-        ),
-        mesh=mesh,
-    )
-    with pytest.warns(UserWarning, match='migrating'):
-        result = mgr.restore_latest(engine=dk)
-    assert result.step == 2
-    src = kfac.extract_factors(state)
-    dst = dk.extract_factors(result.state)
-    for name, fg in src.items():
-        for side in ('a', 'g'):
-            np.testing.assert_allclose(
-                np.asarray(dst[name][side]), np.asarray(fg[side]),
-                rtol=1e-6, err_msg=f'{name}/{side}',
-            )
-    p1 = kfac.precondition(state, grads)
-    p2 = dk.precondition(result.state, grads)
-    np.testing.assert_allclose(
-        np.asarray(p1['fc1']['kernel']), np.asarray(p2['fc1']['kernel']),
-        rtol=1e-4, atol=1e-6,
-    )
+    def loss_fn(p, model_state, batch):
+        bx, by = batch
+        pred = m.apply({'params': p}, bx)
+        return jnp.mean((pred - by) ** 2), model_state
 
-    # and back: stacked -> fresh dense engine
-    mgr2 = CheckpointManager(
-        tmp_path / 'back', engine=dk, install_signals=(), async_save=False
+    manager_kw.setdefault('install_signals', ())
+    mgr = CheckpointManager(directory, engine=kfac, **manager_kw)
+    trainer = kfac_tpu.Trainer(
+        loss_fn=loss_fn, optimizer=optax.sgd(0.05), kfac=kfac,
+        checkpoints=mgr,
     )
-    mgr2.save(result.state)
-    kfac2 = kfac_tpu.KFACPreconditioner(registry=reg, kl_clip=None)
-    with pytest.warns(UserWarning, match='migrating'):
-        back = mgr2.restore_latest(engine=kfac2)
-    assert back.step == 2
-    for name, fg in src.items():
+    return trainer, mgr, params, (x, y)
+
+
+@pytest.fixture(scope='module')
+def saved_runs(tmp_path_factory):
+    """Under each layout: two steps, one explicit save of the whole
+    TrainState, two more steps. What a restore is held to."""
+    runs = {}
+    for layout in LAYOUTS:
+        directory = tmp_path_factory.mktemp(f'saved_{layout}')
+        trainer, mgr, params, batch = _trainer_on(
+            layout, directory, async_save=False
+        )
+        state = trainer.init(params)
+        for _ in range(2):
+            state, _ = trainer.step(state, batch)
+        mgr.save(state)
+        grads = jax.tree_util.tree_map(jnp.ones_like, params)
+        runs[layout] = {
+            'directory': directory,
+            'factors': jax.device_get(
+                trainer.kfac.extract_factors(state.kfac_state)
+            ),
+            'params': jax.device_get(state.params),
+            'preconditioned': jax.device_get(
+                trainer.kfac.precondition(state.kfac_state, grads)
+            ),
+            'losses': [],
+        }
+        for _ in range(2):
+            state, loss = trainer.step(state, batch)
+            runs[layout]['losses'].append(float(loss))
+    signals.reset()
+    return runs
+
+
+@pytest.mark.parametrize('restored', list(LAYOUTS))
+@pytest.mark.parametrize('saved', list(LAYOUTS))
+def test_elastic_restore_across_layouts(saved_runs, saved, restored):
+    """A TrainState saved under any layout resumes under any other
+    through ``Trainer.restore_latest`` (the job that comes back on
+    another mesh): factors to the bit under the layout that wrote them
+    and to rounding under the other, decompositions rematerialised, extras
+    on the restoring engine's mesh, and the run goes on where the saved
+    one went."""
+    want = saved_runs[saved]
+    trainer, mgr, params, batch = _trainer_on(restored, want['directory'])
+    # the three strategies shard one stacked layout: between them a
+    # restore is exact, and only dense <-> stacked goes through the
+    # per-layer factors
+    same_layout = (saved == 'dense') == (restored == 'dense')
+    if same_layout:
+        state = trainer.restore_latest(params)
+    else:
+        with pytest.warns(UserWarning, match='migrating'):
+            state = trainer.restore_latest(params)
+    assert int(jax.device_get(state.kfac_state.step)) == 2
+    assert trainer._step_count == 2
+
+    got = trainer.kfac.extract_factors(state.kfac_state)
+    assert set(got) == set(want['factors'])
+    for name, fg in want['factors'].items():
         for side in ('a', 'g'):
-            np.testing.assert_allclose(
-                np.asarray(kfac2.extract_factors(back.state)[name][side]),
-                np.asarray(fg[side]), rtol=1e-6,
-                err_msg=f'{name}/{side}',
-            )
+            if same_layout:
+                np.testing.assert_array_equal(
+                    np.asarray(got[name][side]), fg[side], f'{name}/{side}'
+                )
+            else:
+                np.testing.assert_allclose(
+                    np.asarray(got[name][side]), fg[side], rtol=1e-6,
+                    err_msg=f'{name}/{side}',
+                )
+    # the decompositions are made again from the factors, not read: the
+    # restored state preconditions as the saved one did
+    grads = jax.tree_util.tree_map(jnp.ones_like, params)
+    pre = trainer.kfac.precondition(state.kfac_state, grads)
+    for layer in ('fc1', 'fc2'):
+        np.testing.assert_allclose(
+            np.asarray(pre[layer]['kernel']),
+            want['preconditioned'][layer]['kernel'], rtol=1e-4, atol=1e-6,
+        )
+
+    # the extras: the saved values, placed where the next step needs them
+    for layer in ('fc1', 'fc2'):
+        np.testing.assert_array_equal(
+            np.asarray(state.params[layer]['kernel']),
+            want['params'][layer]['kernel'],
+        )
+    mesh = getattr(trainer.kfac, 'mesh', None)
+    for leaf in jax.tree_util.tree_leaves((state.params, state.opt_state)):
+        if mesh is None:
+            assert len(leaf.sharding.device_set) == 1
+        else:
+            assert leaf.sharding.is_fully_replicated
+            assert leaf.sharding.device_set == set(mesh.devices.flat)
+
+    # the next step's loss reads the restored parameters alone; the one
+    # after it has been through the restored curvature
+    state, loss3 = trainer.step(state, batch)
+    state, loss4 = trainer.step(state, batch)
+    np.testing.assert_allclose(float(loss3), want['losses'][0], rtol=1e-6)
+    np.testing.assert_allclose(float(loss4), want['losses'][1], rtol=1e-4)
+    assert trainer._step_count == 4
+    assert mgr.engine is trainer.kfac
 
 
 @pytest.mark.faults
@@ -614,8 +698,7 @@ def test_restore_latest_every_candidate_corrupt_returns_none(tmp_path):
 def test_elastic_restore_engine_overrides_manager_granularity(tmp_path):
     """restore_latest(engine=...) with a DIFFERENT bucket granularity
     than the manager's own engine migrates into the caller's layout —
-    the manager binding is a default, not a constraint (the fleet
-    controller's speculative-migration restore relies on this)."""
+    the manager binding is a default, not a constraint."""
     from kfac_tpu.parallel import DistributedKFAC, kaisa_mesh
 
     m, batch, params, reg, _ = _dense_setup()
@@ -653,30 +736,12 @@ def test_elastic_restore_engine_overrides_manager_granularity(tmp_path):
 # -------------------------------------------------------- Trainer lifecycle
 
 
-def test_trainer_periodic_saves_and_resume_continuity(tmp_path):
-    m = models.TinyModel()
-    x, y = models.regression_data(jax.random.PRNGKey(1))
-    params = m.init(jax.random.PRNGKey(0), x)['params']
-    reg = kfac_tpu.register_model(m, x)
-
-    def loss_fn(p, model_state, batch):
-        bx, by = batch
-        pred = m.apply({'params': p}, bx)
-        return jnp.mean((pred - by) ** 2), model_state
-
+@pytest.mark.parametrize('layout', list(LAYOUTS))
+def test_trainer_periodic_saves_and_resume_continuity(tmp_path, layout):
     def make(directory):
-        kfac = kfac_tpu.KFACPreconditioner(registry=reg, kl_clip=None)
-        mgr = CheckpointManager(
-            directory, engine=kfac, save_interval_steps=2, keep=2,
-            install_signals=(),
-        )
-        trainer = kfac_tpu.Trainer(
-            loss_fn=loss_fn, optimizer=optax.sgd(0.05), kfac=kfac,
-            checkpoints=mgr,
-        )
-        return trainer, mgr
+        return _trainer_on(layout, directory, save_interval_steps=2, keep=2)
 
-    trainer, mgr = make(tmp_path)
+    trainer, mgr, params, (x, y) = make(tmp_path)
     state = trainer.init(params)
     losses, state_at_4 = [], None
     for i in range(5):
@@ -688,7 +753,7 @@ def test_trainer_periodic_saves_and_resume_continuity(tmp_path):
     assert mgr.latest_step() == 4
     assert mgr.rotation_steps() == [4, 2]
 
-    trainer2, mgr2 = make(tmp_path)
+    trainer2, *_ = make(tmp_path)
     resumed = trainer2.restore_latest(params)
     assert resumed is not None
     assert int(jax.device_get(resumed.kfac_state.step)) == 4
@@ -704,8 +769,64 @@ def test_trainer_periodic_saves_and_resume_continuity(tmp_path):
     assert int(jax.device_get(resumed.kfac_state.step)) == 5
 
     # an empty rotation hands the caller back to a fresh start
-    trainer3, _ = make(tmp_path / 'empty')
+    trainer3, *_ = make(tmp_path / 'empty')
     assert trainer3.restore_latest(params) is None
+
+
+class _TickRecorder:
+    """Stands where the CheckpointManager stands on a Trainer and keeps
+    what each tick was handed."""
+
+    def __init__(self):
+        self.ticks = []
+
+    def on_step(self, state, step=None):
+        self.ticks.append((state, step))
+
+
+@pytest.mark.parametrize(
+    'path', ['step', 'scan_steps', 'step_accumulate', 'step_accumulate_scan']
+)
+def test_every_step_path_ticks_the_manager_once(path):
+    """Each of the Trainer's four step paths hands the manager the state
+    it returns, once, after the update: an optimizer step a tick on the
+    three that the host drives step by step, with the step's number; a
+    compiled scan a tick, without one (the manager reads the device's)."""
+    m, (x, y), params, reg, kfac = _dense_setup()
+
+    def loss_fn(p, model_state, batch):
+        bx, by = batch
+        return jnp.mean((m.apply({'params': p}, bx) - by) ** 2), model_state
+
+    recorder = _TickRecorder()
+    trainer = kfac_tpu.Trainer(
+        loss_fn=loss_fn, optimizer=optax.sgd(0.05), kfac=kfac,
+        checkpoints=recorder,
+    )
+    two = (jnp.stack([x, x]), jnp.stack([y, y]))
+    calls = {
+        'step': lambda s: trainer.step(s, (x, y)),
+        'scan_steps': lambda s: trainer.scan_steps(s, two),
+        'step_accumulate': lambda s: trainer.step_accumulate(
+            s, [(x, y), (x, y)]
+        ),
+        'step_accumulate_scan': lambda s: trainer.step_accumulate_scan(
+            s, two
+        ),
+    }
+    state = trainer.init(params)
+    returned = []
+    for _ in range(2):
+        state, _ = calls[path](state)
+        returned.append(state)
+    assert len(recorder.ticks) == 2
+    steps_a_call = 2 if path == 'scan_steps' else 1
+    for n, ((view, step), out) in enumerate(
+        zip(recorder.ticks, returned), start=1
+    ):
+        assert view is out  # the state itself, no copy and no other view
+        assert int(jax.device_get(view.kfac_state.step)) == n * steps_a_call
+        assert step == (None if path == 'scan_steps' else n)
 
 
 @pytest.mark.faults

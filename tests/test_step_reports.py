@@ -551,6 +551,64 @@ def test_no_newton_schulz_no_counters(method, solver):
     )
 
 
+#: every field of the two engines' states, in order: a field that comes
+#: or goes is a new state layout and belongs in the table below
+STATE_FIELDS = {
+    'dense': (
+        'step', 'a', 'g', 'qa', 'qg', 'da', 'dg', 'dgda', 'a_inv', 'g_inv',
+        'health', 'metrics', 'flight', 'shadow',
+    ),
+    'kaisa': (
+        'step', 'a', 'g', 'qa', 'qg', 'da', 'dg', 'dgda', 'a_inv', 'g_inv',
+        'inv_damping', 'health', 'metrics', 'flight', 'shadow', 'traffic',
+        'refresh',
+    ),
+}
+
+
+@pytest.mark.parametrize('mode', [None, 'sliced', 'host'])
+@pytest.mark.parametrize('method', ['eigen', 'inverse'])
+@pytest.mark.parametrize('engine', ['dense', 'kaisa'])
+def test_state_fields(engine, method, mode):
+    """One state layout per (method, async mode): which fields are
+    ``None`` and which decomposition dicts are empty follows from those
+    two alone (with the options that hang a report on the state off, as
+    the cells have them, and no stacked experts in the registry)."""
+    model = models.TinyModel(hidden=8, out=4)
+    x, _ = models.regression_data(jax.random.PRNGKey(1), n=16, dim=6)
+    cfg = kfac_tpu.KFACPreconditioner(
+        registry=kfac_tpu.register_model(model, x),
+        compute_method=method, inverse_solver='newton_schulz',
+        inv_update_steps=4, async_inverse=mode,
+    )
+    state = (
+        cfg if engine == 'dense'
+        else DistributedKFAC(cfg, mesh=kaisa_mesh(0.5))
+    ).init()
+    assert state._fields == STATE_FIELDS[engine]
+
+    is_none = {'health', 'metrics', 'flight'}
+    if mode != 'sliced':
+        is_none.add('shadow')  # the sliced mode's second set of slots
+    if engine == 'kaisa':
+        is_none.add('traffic')
+        if method == 'eigen' or mode is not None:
+            # the synchronous Newton-Schulz refresh alone counts itself
+            is_none.add('refresh')
+    empty = (
+        {'a_inv', 'g_inv', 'dgda'} if method == 'eigen'
+        else {'qa', 'qg', 'da', 'dg', 'dgda'}
+    )
+    assert {f for f in state._fields if getattr(state, f) is None} == is_none
+    assert {
+        f for f in state._fields
+        if isinstance(getattr(state, f), dict) and not getattr(state, f)
+    } == empty
+    # what is neither holds arrays: the durable three among them
+    for field in ('step', 'a', 'g'):
+        assert jax.tree_util.tree_leaves(getattr(state, field))
+
+
 def test_refresh_counters_survive_a_restore(tmp_path):
     engine = _dense_engine(dim=6)
     state = jax.jit(engine.update_inverses)(engine.init())

@@ -144,7 +144,6 @@ def main() -> int:
                     help='seeded random storm instead of the canonical '
                          'scripted one')
     ap.add_argument('--storm-events', type=int, default=3)
-    ap.add_argument('--use-fleet', action='store_true')
     ap.add_argument('--root', default=None,
                     help='conductor scratch dir (default: a tempdir)')
     ap.add_argument('--out', default=None,
@@ -162,7 +161,6 @@ def main() -> int:
         max_steps=args.max_steps,
         seed=args.seed,
         storm_events=args.storm_events,
-        use_fleet=args.use_fleet,
     )
     root = args.root or tempfile.mkdtemp(prefix='kfac_chaos_')
     print(f'chaos storm: procs={config.procs} max_steps={config.max_steps} '
